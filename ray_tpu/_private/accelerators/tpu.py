@@ -16,8 +16,8 @@ compatibility.
 """
 from __future__ import annotations
 
-import glob
 import os
+import pathlib
 import re
 from typing import Dict, List, Optional
 
@@ -30,8 +30,21 @@ TPU_TOPOLOGY_ENV = "TPU_TOPOLOGY"  # e.g. "2x2x2"
 TPU_WORKER_ID_ENV = "TPU_WORKER_ID"
 GKE_TPU_ACCELERATOR_ENV = "TPU_ACCELERATOR_TYPE"
 
+# chips one process may hold -> the bounds libtpu needs to lay them out
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
 # single-host slice chip counts that can be sub-sliced (reference: tpu.py:13)
-VALID_CHIP_COUNTS = (1, 2, 4, 8)
+VALID_CHIP_COUNTS = tuple(_CHIP_BOUNDS)
+
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def compile_cache_dir() -> str:
+    """Where chip processes keep JAX's persistent compilation cache:
+    JAX_COMPILATION_CACHE_DIR when the machine sets it (no code sets
+    another), else one fixed directory in the checkout — the path is
+    part of the cache key, so it never carries a pid, time or temp name."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(_CHECKOUT / ".jax_cache")
+
 
 GCE_METADATA_URL = "http://metadata.google.internal/computeMetadata/v1/instance/attributes/"
 
@@ -86,15 +99,23 @@ class TPUAcceleratorManager(AcceleratorManager):
 
     @staticmethod
     def set_visible_accelerator_ids(ids: List[str]) -> None:
-        """Restrict a worker to a chip subset (reference: tpu.py:157-196
-        sets TPU_VISIBLE_CHIPS plus host bounds for 1/2/4-chip slices)."""
+        """Restrict THIS process to a chip subset, before libtpu loads
+        (reference: tpu.py:157-196). libtpu reads the chips it may open
+        and the shape they form from the environment; the names are the
+        ones JAX documents for several processes on one host, and the
+        older aliases are dropped so a host-wide setting inherited from
+        the machine cannot contradict the grant."""
         os.environ[TPU_VISIBLE_CHIPS_ENV] = ",".join(ids)
-        n = len(ids)
-        if n in (1, 2):
-            os.environ["TPU_CHIPS_PER_HOST_BOUNDS"] = f"1,{n},1"
-            os.environ["TPU_PROCESS_BOUNDS"] = "1,1,1"
-        elif n == 4:
-            os.environ["TPU_CHIPS_PER_HOST_BOUNDS"] = "2,2,1"
+        for legacy in ("TPU_CHIPS_PER_HOST_BOUNDS", "TPU_HOST_BOUNDS"):
+            os.environ.pop(legacy, None)
+        bounds = _CHIP_BOUNDS.get(len(ids))
+        if bounds is None:
+            # no rectangle for this count: libtpu decides, and refuses
+            # loudly at start-up what it cannot lay out
+            os.environ.pop("TPU_CHIPS_PER_PROCESS_BOUNDS", None)
+            os.environ.pop("TPU_PROCESS_BOUNDS", None)
+        else:
+            os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] = bounds
             os.environ["TPU_PROCESS_BOUNDS"] = "1,1,1"
 
     @staticmethod

@@ -27,12 +27,20 @@ def build_native_library(src_path: str, prefix: str,
     with _lock:
         if force or not os.path.exists(lib):
             tmp = lib + f".tmp.{os.getpid()}"
-            subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o", tmp, src_path,
-                 *extra_flags],
-                check=True,
-                capture_output=True,
-            )
+            cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o", tmp, src_path,
+                   *extra_flags]
+            # the .so is not in git: a checkout builds it on first use, so
+            # a missing or failing compiler has to say so in full
+            try:
+                proc = subprocess.run(cmd, capture_output=True, text=True)
+            except FileNotFoundError as e:
+                raise RuntimeError(
+                    f"ray_tpu builds {os.path.basename(src_path)} with g++ on first use, "
+                    "and no g++ is on PATH") from e
+            if proc.returncode != 0:
+                raise RuntimeError(f"{' '.join(cmd)} failed:\n{proc.stderr}")
+            # atomic: raylet, driver and workers may all build at once on
+            # a fresh checkout; each renames its own complete file
             os.replace(tmp, lib)
             # drop builds of older source revisions
             d = os.path.dirname(lib)

@@ -80,6 +80,8 @@ class WorkerHandle:
         self.log_offset = 0  # bytes already streamed to the driver
         self.idle_since = time.time()
         self.oom_killed = False  # set by the memory monitor before SIGKILL
+        self.used = False  # has been handed a task or lease (may have loaded JAX)
+        self.tpu_chips: List[int] = []  # chip indices granted; back on process exit
 
 
 class Raylet:
@@ -111,6 +113,8 @@ class Raylet:
         self.starting = 0
         self.queued: collections.deque = collections.deque()
         self.max_workers = int(max(resources.get("CPU", 1), 1)) + 64  # actors beyond pool
+        # the GCS counts `TPU`; which chips a grant means is decided here
+        self._free_chips: List[int] = list(range(int(resources.get("TPU", 0))))
 
         self._gcs: Optional[protocol.Connection] = None
         self._peer_conns: Dict[str, protocol.Connection] = {}
@@ -468,10 +472,12 @@ class Raylet:
                 "RAY_TPU_NODE_IP": self.node_ip,
                 "RAY_TPU_SHM_PATH": self.shm_path,
                 "RAY_TPU_WORKER_ID": worker_id,
-                # workers must not grab the TPU; tasks that want it set this
-                # themselves via resources (reference: CUDA_VISIBLE_DEVICES
-                # plumbing in _private/accelerators; here JAX_PLATFORMS)
-                "JAX_PLATFORMS": env.get("RAY_TPU_WORKER_JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", "cpu")),
+                # a worker sees no chip until a task granted `TPU` lands
+                # on it (worker_proc._apply_tpu_grant), whatever platform
+                # the machine's environment names; only the explicit pin
+                # overrides (reference: CUDA_VISIBLE_DEVICES plumbing in
+                # _private/accelerators)
+                "JAX_PLATFORMS": env.get("RAY_TPU_WORKER_JAX_PLATFORMS") or "cpu",
             }
         )
         log_path = os.path.join(self.session_dir, "logs", f"worker-{worker_id[:12]}.log")
@@ -510,6 +516,8 @@ class Raylet:
                 if code is None:
                     continue
                 self.workers.pop(worker_id, None)
+                # the process is gone, so libtpu has let go of its chips
+                self._free_chips = sorted(self._free_chips + h.tpu_chips)
                 # final log drain BEFORE the handle disappears: the crash
                 # traceback a worker wrote on its way down is exactly what
                 # the driver needs to see
@@ -555,21 +563,36 @@ class Raylet:
                     await self._gcs.request("lease.done", {"lease_id": h.lease_id})
                 self._pump()
 
+    def _pop_idle(self, unused: bool = False) -> Optional[WorkerHandle]:
+        """Next live idle worker; `unused` only one that was never handed
+        anything, so cannot have loaded JAX yet."""
+        for wid in list(self.idle):
+            h = self.workers.get(wid)
+            if h is None or h.proc.poll() is not None or h.conn is None:
+                self.idle.remove(wid)
+            elif not (unused and h.used):
+                self.idle.remove(wid)
+                h.used = True
+                return h
+        return None
+
     def _pump(self):
         """Dispatch queued specs onto idle workers; spawn when short."""
         while self.queued:
-            worker = None
-            while self.idle:
-                wid = self.idle.popleft()
-                h = self.workers.get(wid)
-                if h is not None and h.proc.poll() is None:
-                    worker = h
-                    break
+            n_chips = int((self.queued[0].get("resources") or {}).get("TPU", 0))
+            if n_chips > len(self._free_chips):
+                # the GCS has the count back but the last holder's
+                # process is still exiting; the reap loop pumps again
+                return
+            worker = self._pop_idle(unused=n_chips > 0)
             if worker is None:
                 if self.starting == 0 and len(self.workers) < self.max_workers:
                     self._start_worker()
                 return
             spec = self.queued.popleft()
+            if n_chips:
+                worker.tpu_chips = [self._free_chips.pop(0) for _ in range(n_chips)]
+                spec["tpu_chips"] = worker.tpu_chips
             asyncio.get_running_loop().create_task(self._run_on_worker(worker, spec))
 
     async def _run_on_worker(self, h: WorkerHandle, spec: Dict[str, Any]):
@@ -615,7 +638,14 @@ class Raylet:
             self._return_worker(h)
 
     def _return_worker(self, h: WorkerHandle):
-        if h.worker_id in self.workers and not h.is_actor:
+        if h.tpu_chips:
+            # it holds its chips until it exits and cannot be re-pointed
+            # at others: one grant per process, the reap loop frees them
+            try:
+                h.proc.kill()
+            except ProcessLookupError:
+                pass
+        elif h.worker_id in self.workers and not h.is_actor:
             h.idle_since = time.time()
             self.idle.append(h.worker_id)
         self._pump()
@@ -739,13 +769,7 @@ class Raylet:
             if conn.closed:
                 await self._gcs.request("lease.done", {"lease_id": lease_id})
                 return {"ok": False, "reason": "owner connection closed"}
-            worker = None
-            while self.idle:
-                wid = self.idle.popleft()
-                h = self.workers.get(wid)
-                if h is not None and h.proc.poll() is None and h.conn is not None:
-                    worker = h
-                    break
+            worker = self._pop_idle()
             if worker is not None:
                 worker.lease_id = lease_id
                 self._conn_leases.setdefault(conn, set()).add(lease_id)

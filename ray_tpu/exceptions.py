@@ -85,5 +85,12 @@ class RuntimeEnvSetupError(RayTpuError):
     pass
 
 
+class TPUGrantError(RayTpuError):
+    """A worker was granted TPU chips it cannot use: the host exposes no
+    such device, or the process had loaded JAX before the grant arrived
+    (its backend choice can no longer be changed). The task fails with
+    this instead of computing on the CPU under a TPU grant."""
+
+
 class PlacementGroupError(RayTpuError):
     pass

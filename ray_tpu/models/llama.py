@@ -191,7 +191,24 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh=None, rules=None):
     if impl == "flash":
         from ray_tpu.ops.flash_attention import flash_attention
 
-        return flash_attention(q, k, v, True)
+        if mesh is None or rules is None or mesh.size == 1:
+            return flash_attention(q, k, v, True)
+        # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+        # shard_map"): run it on each device's shard, split on batch and
+        # heads exactly as the activations already are. KV heads split
+        # with the query heads, so every shard keeps whole GQA groups;
+        # where they do not divide, heads stay whole instead.
+        from jax import shard_map
+
+        n_head_shards = 1
+        for a in rules.rules.get("act_heads") or ():
+            n_head_shards *= mesh.shape[a]
+        heads = "act_heads" if k.shape[2] % n_head_shards == 0 else None
+        spec = rules.spec(("batch", None, heads, None))
+        return shard_map(
+            lambda q, k, v: flash_attention(q, k, v, True), mesh=mesh,
+            in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
+        )(q, k, v)
     if impl == "ring":
         sp_axes = rules.rules.get("seq") if rules is not None else None
         if mesh is not None and sp_axes and all(mesh.shape[a] > 1 for a in sp_axes):
@@ -200,7 +217,8 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh=None, rules=None):
             # ppermute while each device attends its local Q shard
             import functools as _ft
 
-            from ray_tpu.parallel._shard_map import shard_map
+            from jax import shard_map
+
             from ray_tpu.parallel.ring_attention import ring_attention
 
             qspec = rules.spec(("batch", "seq", "act_heads", None))

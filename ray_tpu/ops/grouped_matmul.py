@@ -2,11 +2,11 @@
 
 The MoE grouped-dispatch path sorts tokens by expert and multiplies each
 contiguous expert segment by that expert's weight matrix — one ragged
-matmul instead of E capacity-padded dense ones. On TPU (and current-JAX
-CPU) this lowers through `jax.lax.ragged_dot`, which tiles the segments
-onto the MXU without materializing any per-expert padding; where the
-primitive is unavailable the segment-loop fallback computes the same
-contraction as E masked dense matmuls (reference numerics, not perf).
+matmul instead of E capacity-padded dense ones. It lowers through
+`jax.lax.ragged_dot`, which tiles the segments onto the MXU without
+materializing any per-expert padding. `_grouped_matmul_segments` computes
+the same contraction as E masked dense matmuls and is the tests'
+reference, never a dispatch target.
 
 lhs:         [M, K]    tokens, sorted so each group is contiguous
 rhs:         [G, K, N] per-group weights
@@ -15,16 +15,8 @@ out:         [M, N]
 """
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
-
-
-def _have_ragged_dot() -> bool:
-    if os.environ.get("RAY_TPU_GROUPED_MATMUL", "") == "loop":
-        return False
-    return hasattr(jax.lax, "ragged_dot")
 
 
 def grouped_matmul(lhs, rhs, group_sizes):
@@ -38,10 +30,7 @@ def grouped_matmul(lhs, rhs, group_sizes):
     G, K2, N = rhs.shape
     assert K == K2, f"lhs K={K} vs rhs K={K2}"
     assert group_sizes.shape == (G,)
-    group_sizes = group_sizes.astype(jnp.int32)
-    if _have_ragged_dot():
-        return _ragged_dot_safe(lhs, rhs, group_sizes)
-    return _grouped_matmul_segments(lhs, rhs, group_sizes)
+    return _ragged_dot_safe(lhs, rhs, group_sizes.astype(jnp.int32))
 
 
 def unshard_dim(arr, dim: int):
@@ -96,8 +85,8 @@ _ragged_dot_safe.defvjp(_ragged_dot_safe_fwd, _ragged_dot_safe_bwd)
 
 
 def _grouped_matmul_segments(lhs, rhs, group_sizes):
-    """Fallback: one masked dense matmul per group (O(G·M·K·N) FLOPs —
-    correct everywhere, only meant for backends without ragged_dot)."""
+    """Test reference: one masked dense matmul per group (O(G·M·K·N)
+    FLOPs), independent of ragged_dot."""
     M = lhs.shape[0]
     G, _, N = rhs.shape
     ends = jnp.cumsum(group_sizes)

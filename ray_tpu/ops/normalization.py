@@ -1,62 +1,14 @@
-"""Fused normalization ops: RMSNorm (pallas) + layer norm.
-
-RMSNorm is the per-token norm used by the Llama family. The pallas kernel
-fuses square-mean / rsqrt / scale in VMEM so the activation is read once
-from HBM (XLA usually fuses this too; the kernel guarantees it and is the
-template for further fusions like norm+quant).
+"""Normalization ops: RMSNorm (the Llama family's per-token norm) and
+layer norm, in f32 whatever the activation dtype. Plain jax.numpy: XLA
+fuses square-mean / rsqrt / scale into one pass over the activation.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-
-def _rms_kernel(x_ref, w_ref, o_ref, *, eps):
-    x = x_ref[:].astype(jnp.float32)
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    o_ref[:] = (x * jax.lax.rsqrt(var + eps) * w_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
-
-
-def rms_norm_pallas(x, weight, eps: float = 1e-6, block_rows: int = 256, interpret: bool = False):
-    """x: [..., D]; weight: [D]."""
-    orig_shape = x.shape
-    D = orig_shape[-1]
-    xr = x.reshape(-1, D)
-    N = xr.shape[0]
-    br = min(block_rows, N)
-    pad = (-N) % br
-    if pad:
-        xr = jnp.pad(xr, ((0, pad), (0, 0)))
-    out = pl.pallas_call(
-        functools.partial(_rms_kernel, eps=eps),
-        grid=((N + pad) // br,),
-        in_specs=[
-            pl.BlockSpec((br, D), lambda i: (i, 0)),
-            pl.BlockSpec((1, D), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((br, D), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(xr.shape, x.dtype),
-        interpret=interpret,
-    )(xr, weight.reshape(1, D))
-    if pad:
-        out = out[:N]
-    return out.reshape(orig_shape)
 
 
 def rms_norm(x, weight, eps: float = 1e-6):
-    """Differentiable RMSNorm; pallas forward on TPU, XLA elsewhere.
-
-    Backward goes through the XLA formulation (custom_vjp wrapping keeps
-    the pallas forward out of the autodiff trace).
-    """
-    return _rms_norm_xla(x, weight, eps)
-
-
-def _rms_norm_xla(x, weight, eps):
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     return (xf * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)).astype(x.dtype)

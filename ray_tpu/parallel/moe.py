@@ -39,7 +39,6 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.parallel._shard_map import axis_size as _axis_size
 
 
 def compute_capacity(tokens: int, num_experts: int, capacity_factor: float) -> int:
@@ -205,7 +204,7 @@ def moe_layer(
     expert_fn(params_e, tokens) applies one expert. `dispatch` picks the
     queue construction: "grouped" (gather, default) or "onehot" (einsum
     reference)."""
-    ep = _axis_size(axis_name)
+    ep = jax.lax.axis_size(axis_name)
     orig_shape = x.shape
     D = orig_shape[-1]
     tokens = x.reshape(-1, D)
@@ -355,7 +354,7 @@ def expert_parallel_moe_inline(
     over every axis x is sharded on, so it leaves the shard_map truly
     replicated."""
     from jax.sharding import PartitionSpec as P
-    from ray_tpu.parallel._shard_map import shard_map
+    from jax import shard_map
 
     if x_spec is None:
         x_spec = P()
@@ -395,7 +394,7 @@ def _ep_moe_jitted(mesh, axis_name, capacity_factor, expert_fn, top_k, dispatch,
     bounded maxsize keeps that mistake from pinning compiled programs
     forever."""
     from jax.sharding import PartitionSpec as P
-    from ray_tpu.parallel._shard_map import shard_map
+    from jax import shard_map
 
     fn = functools.partial(
         moe_layer, axis_name=axis_name, capacity_factor=capacity_factor,

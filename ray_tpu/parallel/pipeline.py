@@ -20,7 +20,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.parallel._shard_map import axis_size as _axis_size
 
 
 def pipeline_apply(
@@ -33,7 +32,7 @@ def pipeline_apply(
     x_microbatches: [M, mb, ...] (replicated input; stage 0 consumes it).
     Returns [M, mb, ...] outputs (valid on the last stage; replicated out
     by a final ppermute-broadcast)."""
-    P = _axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     M = x_microbatches.shape[0]
     mb_shape = x_microbatches.shape[1:]
@@ -88,7 +87,7 @@ def pipelined(
     then runs per data-parallel slice. Callable from inside jit (the
     shard_map inlines into the surrounding program)."""
     from jax.sharding import PartitionSpec as P
-    from ray_tpu.parallel._shard_map import shard_map
+    from jax import shard_map
 
     B = x.shape[0]
     assert B % num_microbatches == 0

@@ -28,7 +28,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.parallel._shard_map import axis_size as _axis_size
 
 from ray_tpu.ops.blockwise_attention import _fwd_impl
 
@@ -58,7 +57,7 @@ def ring_attention(
 ):
     """Call inside shard_map; q/k/v are the local sequence shards
     [B, T_local, H, D]. Returns the local output shard."""
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     B, Tl, H, D = q.shape
     perm = [(i, (i + 1) % sp) for i in range(sp)]
@@ -108,7 +107,7 @@ def ulysses_attention(
     sequence while sharding heads, run dense flash attention, swap back."""
     from ray_tpu.ops.flash_attention import flash_attention
 
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     B, Tl, H, D = q.shape
     assert H % sp == 0, f"heads {H} must divide sp {sp} for ulysses"
 
@@ -138,7 +137,7 @@ def sequence_parallel_attention(
     """shard_map wrapper: q/k/v are global arrays sharded on `sp` along
     the sequence axis; returns the global output with the same sharding."""
     from jax.sharding import PartitionSpec as P
-    from ray_tpu.parallel._shard_map import shard_map
+    from jax import shard_map
 
     spec = P(None, axis_name, None, None)
 

@@ -6,6 +6,7 @@ sub-jaxprs) and, via XLA cost analysis, bounds the grouped path's
 non-expert FLOPs to O(T·k·D) — CPU-checkable proxies for the TPU win.
 """
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 
@@ -75,9 +76,9 @@ def _walk_avals(jaxpr):
 
 
 def _sub_jaxprs(p):
-    if isinstance(p, jax.core.ClosedJaxpr):
+    if isinstance(p, jax.extend.core.ClosedJaxpr):
         yield p.jaxpr
-    elif isinstance(p, jax.core.Jaxpr):
+    elif isinstance(p, jax.extend.core.Jaxpr):
         yield p
     elif isinstance(p, (list, tuple)):
         for item in p:
@@ -172,8 +173,5 @@ def test_ragged_path_skips_capacity_padding():
                     walk(sub)
 
     walk(jaxpr.jaxpr)
-    from ray_tpu.ops.grouped_matmul import _have_ragged_dot
-
-    if _have_ragged_dot():
-        assert prims.count("ragged_dot") >= 3  # fwd gate/up/down
+    assert prims.count("ragged_dot_general") >= 3  # fwd gate/up/down
     assert not padded, "ragged path built a capacity-padded [E, C, D] queue"

@@ -19,6 +19,7 @@ reference by the time the requests finish.
 import numpy as np
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 
 N_SLOTS, MAX_LEN, BLOCK = 3, 40, 16  # 40 % 16 != 0 on purpose
@@ -48,9 +49,9 @@ def _walk_avals(jaxpr):
 
 
 def _sub_jaxprs(p):
-    if isinstance(p, jax.core.ClosedJaxpr):
+    if isinstance(p, jax.extend.core.ClosedJaxpr):
         yield p.jaxpr
-    elif isinstance(p, jax.core.Jaxpr):
+    elif isinstance(p, jax.extend.core.Jaxpr):
         yield p
     elif isinstance(p, (list, tuple)):
         for item in p:

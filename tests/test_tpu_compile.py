@@ -1,0 +1,128 @@
+"""Compile-only tests: the main path's kernels, at the widths chip_smoke.py
+runs, for a TPU v5e that is described and not attached.
+
+The TPU compiler is installed in the CPU sandbox, so what it would refuse
+on the chip (a tile that does not align, too much VMEM, a kernel GSPMD
+cannot partition) it refuses here, at no chip time. Nothing runs: these
+say nothing about results or speed. Everything that touches libtpu lives
+inside module-scoped fixtures — one process at a time may load it, so a
+call at import time would break every other xdist worker's collection —
+and in this one file, which `--dist loadfile` gives to one worker.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+
+from ray_tpu.models.llama import LlamaConfig, _attention
+from ray_tpu.ops import flash_attention as FA
+from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+from ray_tpu.parallel.sharding import LogicalAxisRules
+
+# (batch, seq, heads, kv_heads, head_dim)
+SHAPES = {
+    "8b-train-2048": (1, 2048, 32, 8, 128),    # chip_smoke.py train phase
+    "8b-shard-512": (1, 512, 16, 4, 128),      # one device's share under fsdp=2 x tp=2
+    "8b-long-8192": (1, 8192, 32, 8, 128),     # max_seq_len, several 1024-blocks
+    "d64-2048": (1, 2048, 32, 8, 64),          # head_dim 64, which kernel_supported admits
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip cannot be read back
+    # without one: keep these compiles out of the persistent cache
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _qkv(shape, sharding):
+    B, T, H, KVH, D = shape
+    q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((B, T, KVH, D), jnp.bfloat16, sharding=sharding)
+    return q, kv, kv
+
+
+def _blocks(shape):
+    T = shape[1]
+    assert FA.kernel_supported(T, T, shape[4])
+    return FA._fit_block(T, 1024), FA._fit_block(T, 1024)
+
+
+@pytest.mark.parametrize("name", SHAPES)
+def test_flash_forward_kernel_compiles(one_chip, name):
+    shape = SHAPES[name]
+    bq, bk = _blocks(shape)
+    fwd = functools.partial(FA._flash_fwd_pallas, causal=True, sm_scale=None,
+                            block_q=bq, block_k=bk, interpret=False)
+    lowered = jax.jit(fwd).lower(*_qkv(shape, one_chip))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    lowered.compile()
+
+
+@pytest.mark.parametrize("name", SHAPES)
+def test_flash_backward_kernels_compile(one_chip, name):
+    """dK/dV and dQ: two kernels in one backward."""
+    shape = SHAPES[name]
+    B, T, H, _, D = shape
+    bq, bk = _blocks(shape)
+    q, k, v = _qkv(shape, one_chip)
+    lse = jax.ShapeDtypeStruct((B, T, H), jnp.float32, sharding=one_chip)
+    bwd = functools.partial(FA._flash_bwd_pallas, causal=True, sm_scale=None,
+                            block_q=bq, block_k=bk)
+    lowered = jax.jit(bwd).lower(q, k, v, q, lse, q)
+    assert lowered.as_text().count("tpu_custom_call") == 2
+    lowered.compile()
+
+
+def test_public_flash_attention_reaches_the_kernel_on_tpu(one_chip, monkeypatch):
+    """On a TPU backend a supported shape takes the kernel forward and
+    backward. The backend query is steered here, in the test: under the
+    suite's JAX_PLATFORMS=cpu it names the CPU."""
+    monkeypatch.setattr(FA, "_on_tpu", lambda: True)
+
+    def loss(q, k, v):
+        return FA.flash_attention(q, k, v, True).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_qkv(SHAPES["8b-train-2048"], one_chip))
+    assert lowered.as_text().count("tpu_custom_call") == 3
+    lowered.compile()
+
+
+def test_flash_attention_under_fsdp_tp_compiles_for_four_chips(topo, monkeypatch):
+    """GSPMD refuses to partition a Mosaic kernel; llama._attention runs it
+    per shard. On a 2x2 fsdp+tp mesh each device gets half the batch and
+    half the heads, query and KV alike."""
+    monkeypatch.setattr(FA, "_on_tpu", lambda: True)
+    cfg = LlamaConfig.llama3_8b(n_layers=1)
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), list(topo.devices))
+    rules = LogicalAxisRules.for_strategy("fsdp+tp")
+    act = NamedSharding(mesh, rules.spec(("batch", None, "act_heads", None)))
+
+    def loss(q, k, v):
+        return _attention(q, k, v, cfg, mesh, rules).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*_qkv((2, 512, 32, 8, 128), act))
+    assert lowered.as_text().count("tpu_custom_call") == 3
+    compiled = lowered.compile()
+    # the shards' shapes, not the global ones, reach the kernel
+    assert "bf16[16,512,128]" in compiled.as_text()
