@@ -376,6 +376,15 @@ def admit_slots_masked(params, prompts, lengths, slots, rems, cache, feed,
     return first, {"k": k_big, "v": v_big, "pos": pos, "remaining": rem}, feed
 
 
+# The two halves of a macro-step phase, as `jax.named_scope`s: every device
+# operation of the admission branch carries ADMIT_SCOPE in its name stack and
+# every one of a decode step DECODE_SCOPE, so a device trace splits one
+# dispatch's time into prefill and decode (benchmark/program_spans.py reads
+# them). Metadata only: the compiled program is the same without them.
+ADMIT_SCOPE = "admit_prefill"
+DECODE_SCOPE = "decode_chunk"
+
+
 def macro_step_slots(params, cache, feed, steps, has_admit, prompts, lengths,
                      slots, rems, chunk: int, cfg: LlamaConfig):
     """Execute a K-phase macro plan as ONE jitted dispatch: a lax.scan
@@ -414,9 +423,10 @@ def macro_step_slots(params, cache, feed, steps, has_admit, prompts, lengths,
 
         def do_admit(op):
             c, fd = op
-            return admit_slots_masked(
-                params, prompts_k, lengths_k, slots_k, rems_k, c, fd, cfg
-            )
+            with jax.named_scope(ADMIT_SCOPE):
+                return admit_slots_masked(
+                    params, prompts_k, lengths_k, slots_k, rems_k, c, fd, cfg
+                )
 
         def no_admit(op):
             c, fd = op
@@ -427,8 +437,9 @@ def macro_step_slots(params, cache, feed, steps, has_admit, prompts, lengths,
         def step(c, t):
             def run(op):
                 cc, fd = op
-                logits, cc = decode_step_slots(params, cc, fd, cfg)
-                return cc, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope(DECODE_SCOPE):
+                    logits, cc = decode_step_slots(params, cc, fd, cfg)
+                    return cc, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
             cc, fd = jax.lax.cond(t < steps_k, run, lambda op: op, c)
             return (cc, fd), fd
@@ -851,11 +862,12 @@ def macro_step_slots_paged(params, cache, feed, steps, has_admit, prompts,
 
         def do_admit(op):
             c, fd = op
-            return admit_slots_paged(
-                params, prompts_k, lengths_k, starts_k, slots_k, rems_k,
-                seeds_k, c, fd, tables_k, temps_k, topk_k, topp_k, stop_k,
-                cfg, sampled=sampled,
-            )
+            with jax.named_scope(ADMIT_SCOPE):
+                return admit_slots_paged(
+                    params, prompts_k, lengths_k, starts_k, slots_k, rems_k,
+                    seeds_k, c, fd, tables_k, temps_k, topk_k, topp_k, stop_k,
+                    cfg, sampled=sampled,
+                )
 
         def no_admit(op):
             c, fd = op
@@ -866,10 +878,11 @@ def macro_step_slots_paged(params, cache, feed, steps, has_admit, prompts,
         def step(c, t):
             def run(op):
                 cc, fd = op
-                _, nxt, cc = decode_step_slots_paged(
-                    params, cc, fd, tables_k, temps_k, topk_k, topp_k,
-                    stop_k, cfg, sampled=sampled,
-                )
+                with jax.named_scope(DECODE_SCOPE):
+                    _, nxt, cc = decode_step_slots_paged(
+                        params, cc, fd, tables_k, temps_k, topk_k, topp_k,
+                        stop_k, cfg, sampled=sampled,
+                    )
                 return cc, nxt
 
             cc, fd = jax.lax.cond(t < steps_k, run, lambda op: op, c)
@@ -1239,32 +1252,33 @@ def macro_step_slots_spec(params, draft_params, cache, draft_cache, feed,
 
         def do_admit(op):
             c, dc, fd = op
-            first, c, fd = admit_slots_paged(
-                params, prompts_k, lengths_k, starts_k, slots_k, rems_k,
-                seeds_k, c, fd, tables_k, temps_k, topk_k, topp_k, stop_k,
-                cfg, sampled=sampled,
-            )
-            if dc is None:
-                # shared-pool self-drafting: the target admission IS the
-                # draft admission — no mirror prefill, no bookkeeping
-                return first, c, None, fd
-            _, dk2, dv2 = _forward_tokens_paged(
-                draft_params, dc["k"], dc["v"], prompts_k,
-                tables_k[slots_k], starts_k, lengths_k > 0, draft_cfg,
-                with_logits=False,
-            )
-            # seed the slot's previous token with the last prompt token
-            # (position pos - 1, whose draft KV the mirror prefill just
-            # wrote — the first round's 2-wide pass rewrites it
-            # idempotently). Plan-padding rows route to index B and the
-            # scatter drops them, so a real admission is never clobbered.
-            last = jnp.take_along_axis(
-                prompts_k, jnp.maximum(lengths_k - 1, 0)[:, None],
-                axis=1)[:, 0]
-            prev = dc["prev"].at[
-                jnp.where(lengths_k > 0, slots_k, B)
-            ].set(last, mode="drop")
-            return first, c, {"k": dk2, "v": dv2, "prev": prev}, fd
+            with jax.named_scope(ADMIT_SCOPE):
+                first, c, fd = admit_slots_paged(
+                    params, prompts_k, lengths_k, starts_k, slots_k, rems_k,
+                    seeds_k, c, fd, tables_k, temps_k, topk_k, topp_k, stop_k,
+                    cfg, sampled=sampled,
+                )
+                if dc is None:
+                    # shared-pool self-drafting: the target admission IS the
+                    # draft admission — no mirror prefill, no bookkeeping
+                    return first, c, None, fd
+                _, dk2, dv2 = _forward_tokens_paged(
+                    draft_params, dc["k"], dc["v"], prompts_k,
+                    tables_k[slots_k], starts_k, lengths_k > 0, draft_cfg,
+                    with_logits=False,
+                )
+                # seed the slot's previous token with the last prompt token
+                # (position pos - 1, whose draft KV the mirror prefill just
+                # wrote — the first round's 2-wide pass rewrites it
+                # idempotently). Plan-padding rows route to index B and the
+                # scatter drops them, so a real admission is never clobbered.
+                last = jnp.take_along_axis(
+                    prompts_k, jnp.maximum(lengths_k - 1, 0)[:, None],
+                    axis=1)[:, 0]
+                prev = dc["prev"].at[
+                    jnp.where(lengths_k > 0, slots_k, B)
+                ].set(last, mode="drop")
+                return first, c, {"k": dk2, "v": dv2, "prev": prev}, fd
 
         def no_admit(op):
             c, dc, fd = op
@@ -1276,11 +1290,12 @@ def macro_step_slots_spec(params, draft_params, cache, draft_cache, feed,
         def step(c, t):
             def run(op):
                 cc, dc, fd = op
-                out, counts, fd, cc, dc = spec_round_slots_paged(
-                    params, draft_params, cc, dc, fd, tables_k, temps_k,
-                    topk_k, topp_k, stop_k, n_spec, cfg, draft_cfg,
-                    sampled=sampled,
-                )
+                with jax.named_scope(DECODE_SCOPE):
+                    out, counts, fd, cc, dc = spec_round_slots_paged(
+                        params, draft_params, cc, dc, fd, tables_k, temps_k,
+                        topk_k, topp_k, stop_k, n_spec, cfg, draft_cfg,
+                        sampled=sampled,
+                    )
                 return (cc, dc, fd), (out, counts)
 
             def skip(op):
@@ -1301,9 +1316,19 @@ def macro_step_slots_spec(params, draft_params, cache, draft_cache, feed,
     return toks, counts, firsts, feed, cache, draft_cache
 
 
+def _bind(f, **static):
+    """`functools.partial(f, **static)` under `f`'s own name. `jax.jit`
+    names a program after its function's `__name__`, and a bare partial
+    has none: every program below would read `jit__unknown` in a device
+    trace, where a reader has to find the macro-step by name."""
+    bound = functools.partial(f, **static)
+    bound.__name__ = f.__name__
+    return bound
+
+
 @functools.lru_cache(maxsize=64)
 def _jitted_prefill(cfg: LlamaConfig):
-    return jax.jit(functools.partial(prefill, cfg=cfg))
+    return jax.jit(_bind(prefill, cfg=cfg))
 
 
 # engine-side jitted programs, memoized per (cfg, chunk) so every
@@ -1312,13 +1337,13 @@ def _jitted_prefill(cfg: LlamaConfig):
 # of engines used to recompile the whole macro program from scratch
 @functools.lru_cache(maxsize=16)
 def jitted_prefill_into_slots(cfg: LlamaConfig):
-    return jax.jit(functools.partial(prefill_into_slots, cfg=cfg))
+    return jax.jit(_bind(prefill_into_slots, cfg=cfg))
 
 
 @functools.lru_cache(maxsize=16)
 def jitted_decode_chunk_slots(cfg: LlamaConfig, chunk: int):
     return jax.jit(
-        functools.partial(decode_chunk_slots, chunk=chunk, cfg=cfg),
+        _bind(decode_chunk_slots, chunk=chunk, cfg=cfg),
         donate_argnums=(1,),
     )
 
@@ -1326,7 +1351,7 @@ def jitted_decode_chunk_slots(cfg: LlamaConfig, chunk: int):
 @functools.lru_cache(maxsize=16)
 def jitted_macro_step_slots(cfg: LlamaConfig, chunk: int):
     return jax.jit(
-        functools.partial(macro_step_slots, chunk=chunk, cfg=cfg),
+        _bind(macro_step_slots, chunk=chunk, cfg=cfg),
         donate_argnums=(1,),
     )
 
@@ -1335,8 +1360,7 @@ def jitted_macro_step_slots(cfg: LlamaConfig, chunk: int):
 def jitted_macro_step_slots_paged(cfg: LlamaConfig, chunk: int,
                                   sampled: bool = True):
     return jax.jit(
-        functools.partial(macro_step_slots_paged, chunk=chunk, cfg=cfg,
-                          sampled=sampled),
+        _bind(macro_step_slots_paged, chunk=chunk, cfg=cfg, sampled=sampled),
         donate_argnums=(1,),
     )
 
@@ -1371,8 +1395,8 @@ def jitted_macro_step_slots_spec(cfg: LlamaConfig, draft_cfg: LlamaConfig,
     beside the greedy/sampled pair. Keyed on (cfg, draft_cfg, chunk,
     n_spec, sampled); both KV pools are donated."""
     return jax.jit(
-        functools.partial(macro_step_slots_spec, chunk=chunk, n_spec=n_spec,
-                          cfg=cfg, draft_cfg=draft_cfg, sampled=sampled),
+        _bind(macro_step_slots_spec, chunk=chunk, n_spec=n_spec,
+              cfg=cfg, draft_cfg=draft_cfg, sampled=sampled),
         donate_argnums=(2, 3),
     )
 
@@ -1380,13 +1404,13 @@ def jitted_macro_step_slots_spec(cfg: LlamaConfig, draft_cfg: LlamaConfig,
 @functools.lru_cache(maxsize=64)
 def _jitted_decode_loop(cfg: LlamaConfig, n_steps: int):
     return jax.jit(
-        functools.partial(decode_loop, cfg=cfg, n_steps=n_steps), donate_argnums=(1,)
+        _bind(decode_loop, cfg=cfg, n_steps=n_steps), donate_argnums=(1,)
     )
 
 
 @functools.lru_cache(maxsize=64)
 def _jitted_decode_step(cfg: LlamaConfig):
-    return jax.jit(functools.partial(decode_step, cfg=cfg), donate_argnums=(1,))
+    return jax.jit(_bind(decode_step, cfg=cfg), donate_argnums=(1,))
 
 
 def sample_loop(params, cache, logits, rng, temperature, top_k, top_p,
@@ -1421,7 +1445,7 @@ def sample_loop(params, cache, logits, rng, temperature, top_k, top_p,
 @functools.lru_cache(maxsize=64)
 def _jitted_sample_loop(cfg: LlamaConfig, n_steps: int):
     return jax.jit(
-        functools.partial(sample_loop, cfg=cfg, n_steps=n_steps),
+        _bind(sample_loop, cfg=cfg, n_steps=n_steps),
         donate_argnums=(1,),
     )
 

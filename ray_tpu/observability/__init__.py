@@ -28,7 +28,23 @@ from ray_tpu.observability.step_telemetry import (  # noqa: F401
 )
 from ray_tpu.observability.trace_export import export_trace  # noqa: F401
 
+# The spans of the serving engine's macro loop (serve/llm_engine.py), each a
+# `jax.profiler.TraceAnnotation` on the engine's loop thread: they land in the
+# profiler's own trace, on the device events' clock, and cost about a
+# microsecond while no profiler session is open. The first five tile one loop
+# iteration; `engine.fetch` lies inside `engine.resolve`.
+ENGINE_SPANS = (
+    "engine.idle",      # waiting on `_wake`: no request queued, resident or resuming, or nothing plannable
+    "engine.intake",    # queue and job drains, deadline shedding, plan repair, the throttled snapshot push
+    "engine.plan",      # `_plan()`: admissions, block tables and decode chunks for up to `macro_phases` phases
+    "engine.dispatch",  # `_dispatch_macro()`: plan arrays built and the macro-step enqueued; stats: seq, phases,
+                        # steps, admissions, A, P, prompt_tokens, lane_steps, finishing, finish_wait_steps
+    "engine.resolve",   # `_resolve()` of dispatch `seq`: the fetch, then delivery of its tokens to the requests
+    "engine.fetch",     # inside resolve: the blocking device-to-host reads of the dispatch's tokens
+)
+
 __all__ = [
+    "ENGINE_SPANS",
     "StepTelemetry",
     "instrument_step",
     "export_trace",

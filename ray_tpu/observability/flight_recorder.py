@@ -51,12 +51,13 @@ assert _HDR.size <= _HDR_SIZE and _REC.size <= _REC_SIZE
 
 # event-kind registry (u16 on the wire). The lifeline layer uses the
 # same ids, so one table decodes both the in-memory timeline and a
-# post-mortem ring dump.
+# post-mortem ring dump. Ids 4, 17 and 20 stay unused: their kinds were
+# registered and never written (the engine loop's stages are spans in
+# the profiler's trace now, observability.ENGINE_SPANS).
 EV = {
     "submit": 1,
     "route": 2,
     "admit": 3,
-    "plan": 4,
     "dispatch": 5,
     "first_token": 6,
     "finish": 7,
@@ -69,10 +70,8 @@ EV = {
     "migrate": 14,
     "error": 15,
     "inventory_probe": 16,
-    "prefix_export": 17,
     "prefix_import": 18,
     "resume_submit": 19,
-    "deliver": 20,
 }
 EV_NAMES = {v: k for k, v in EV.items()}
 

@@ -123,6 +123,7 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k, interpret):
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qr, kr, vr)
     o = o.reshape(B, H, T, D).transpose(0, 2, 1, 3)
     lse = lse.reshape(B, H, T).transpose(0, 2, 1)  # [B, T, H] (from (BH, nq, 1, bq))
@@ -277,6 +278,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k):
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
+        name="flash_bwd_dkdv",
     )(qr, dor, lse_t, delta_t, kr, vr)
 
     dqk = functools.partial(
@@ -296,6 +298,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k):
         out_specs=[pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))],
         out_shape=[jax.ShapeDtypeStruct((B * H, T, D), q.dtype)],
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        name="flash_bwd_dq",
     )(qr, dor, lse_t, delta_t, kr, vr)[0]
 
     dq = dq_r.reshape(B, H, T, D).transpose(0, 2, 1, 3)

@@ -82,6 +82,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ray_tpu.observability import ENGINE_SPANS
 from ray_tpu.observability import flight_recorder as _flightrec
 from ray_tpu.observability import lifeline as _lifeline
 from ray_tpu.util.metrics import metric_singletons as _metric_singletons
@@ -92,6 +93,11 @@ logger = logging.getLogger(__name__)
 # must be a constant-arg call (lint-pinned — no dict lookup, no
 # allocation on the dispatch path)
 _EV_DISPATCH = _flightrec.EV["dispatch"]
+
+# the macro loop's spans in a `jax.profiler` trace (what each covers is
+# said once, beside ENGINE_SPANS)
+(_SPAN_IDLE, _SPAN_INTAKE, _SPAN_PLAN, _SPAN_DISPATCH, _SPAN_RESOLVE,
+ _SPAN_FETCH) = ENGINE_SPANS
 
 # latency histogram boundaries (seconds): wide enough for a cold
 # compile inside the first request (TTFT can run seconds) and fine
@@ -285,6 +291,43 @@ class _Request:
         self._trace_ctx: Optional[Dict[str, str]] = None
 
 
+def _suffix_len(req: "_Request") -> int:
+    """Prompt tokens an admission prefills: those past its reused prefix."""
+    return len(req.prompt) - req._start
+
+
+def _dispatch_counts(phases: List[Dict[str, Any]]) -> Dict[str, int]:
+    """What one macro dispatch carries, from the plan alone: the keyword
+    arguments of its `engine.dispatch` span (host integers; nothing is
+    read from the device). `_plan` leaves every request at its
+    post-dispatch state, so a request of the plan that owes no more
+    decode steps gets its last token in this dispatch (`finishing`), and
+    `finish_wait_steps` sums, over those, the decode steps the dispatch
+    still runs after that token exists: what a finished answer waits on
+    the device before the host can see it. A speculative plan holds
+    estimates and decrements nothing, so only requests that owe no decode
+    step at admission count there."""
+    total = sum(ph["steps"] for ph in phases)
+    done = 0  # decode steps of this dispatch run so far
+    last: Dict[int, int] = {}  # finishing request -> `done` at its last token
+    admissions = prompt_tokens = lane_steps = 0
+    for ph in phases:
+        admissions += len(ph["admissions"])
+        for _, req in ph["admissions"]:
+            prompt_tokens += _suffix_len(req)
+            if req._remaining == 0:
+                last[id(req)] = done  # the prefill's token, unless it decodes
+        done += ph["steps"]
+        for _, req, take in ph["takes"]:
+            lane_steps += take
+            if take and req._remaining == 0:
+                last[id(req)] = done
+    return {"phases": len(phases), "steps": total, "admissions": admissions,
+            "prompt_tokens": prompt_tokens, "lane_steps": lane_steps,
+            "finishing": len(last),
+            "finish_wait_steps": sum(total - d for d in last.values())}
+
+
 def _finish(req: "_Request", error: Optional[str] = None,
             reason: Optional[str] = None,
             exc: Optional[BaseException] = None) -> bool:
@@ -343,6 +386,9 @@ class ContinuousBatchingEngine:
         self.role = role
 
         self._jax = jax
+        # a span on the profiler's own clock, beside the device's events in
+        # the same trace; about a microsecond while no session is open
+        self._span = jax.profiler.TraceAnnotation
         self._D = D
         self.params = params
         self.cfg = cfg
@@ -1415,6 +1461,26 @@ class ContinuousBatchingEngine:
             b *= 2
         return min(max(b, self.block_size), self._mb * self.block_size)
 
+    def _variant(self, phases: List[Dict[str, Any]]):
+        """(A, P) of the compiled macro-step a plan needs: admission
+        lanes and padded prompt width, both bucketed to powers of two so
+        the jit cache stays small."""
+        max_admit = max((len(p["admissions"]) for p in phases), default=0)
+        A = 1
+        while A < max(1, max_admit):
+            A *= 2
+        if self.paged:
+            P = self._bucket_paged(max(
+                (_suffix_len(r) for p in phases for _, r in p["admissions"]),
+                default=1,
+            ))
+        else:
+            P = self._bucket(max(
+                (len(r.prompt) for p in phases for _, r in p["admissions"]),
+                default=1,
+            ))
+        return A, P
+
     def _dispatch_macro(self, phases: List[Dict[str, Any]]) -> None:
         """Ship the plan as ONE jitted dispatch and append the result to
         the fetch frontier (resolved one macro-step behind). In paged
@@ -1424,21 +1490,8 @@ class ContinuousBatchingEngine:
         import jax.numpy as jnp
 
         K = self.macro_phases
-        max_admit = max((len(p["admissions"]) for p in phases), default=0)
-        A = 1
-        while A < max(1, max_admit):
-            A *= 2
-        suffix_len = lambda r: len(r.prompt) - r._start  # noqa: E731
-        if self.paged:
-            P = self._bucket_paged(max(
-                (suffix_len(r) for p in phases for _, r in p["admissions"]),
-                default=1,
-            ))
-        else:
-            P = self._bucket(max(
-                (len(r.prompt) for p in phases for _, r in p["admissions"]),
-                default=1,
-            ))
+        A, P = self._variant(phases)
+        seq = self._m["dispatches"]
         steps = np.zeros(K, np.int32)
         has_admit = np.zeros(K, bool)
         prompts = np.zeros((K, A, P), np.int32)
@@ -1525,7 +1578,8 @@ class ContinuousBatchingEngine:
                         self._m["useful_slot_steps"] += sum(
                             t for _, _, t in ph["takes"])
                     self._pending.append(
-                        ("spec", (toks_dev, counts_dev), firsts_dev, phases))
+                        ("spec", (toks_dev, counts_dev), firsts_dev, phases,
+                         seq))
                     return
                 toks_dev, firsts_dev, self._next_dev, self.cache = (
                     self._macro_paged_fn(
@@ -1549,7 +1603,7 @@ class ContinuousBatchingEngine:
             # park the plan so _die can fail requests whose ONLY remaining
             # reference is this plan (admitted AND fully planned-out slots
             # are already evicted from the host bookkeeping)
-            self._pending.append(("macro", None, None, phases))
+            self._pending.append(("macro", None, None, phases, seq))
             raise
         self._record_dispatch(
             t0, time.perf_counter(),
@@ -1561,7 +1615,7 @@ class ContinuousBatchingEngine:
         for ph in phases:
             self._m["slot_steps"] += ph["steps"] * self.n_slots
             self._m["useful_slot_steps"] += sum(t for _, _, t in ph["takes"])
-        self._pending.append(("macro", toks_dev, firsts_dev, phases))
+        self._pending.append(("macro", toks_dev, firsts_dev, phases, seq))
 
     def _shed_expired(self) -> None:
         """Deadline shed at plan boundaries: a QUEUED request whose
@@ -1611,38 +1665,57 @@ class ContinuousBatchingEngine:
             self._waiting = deque(
                 r for r in self._waiting if not r.done.is_set())
 
+    def _resolve_next(self) -> None:
+        """Resolve the oldest dispatch in flight, under its span."""
+        entry = self._pending.popleft()
+        with self._span(_SPAN_RESOLVE, seq=entry[4]):
+            self._resolve(entry)
+
     def _loop_macro(self) -> None:
+        # every stretch of an iteration runs under one of ENGINE_SPANS, so
+        # a device trace can say what this thread did while the device idled
+        span = self._span
         while self._running:
-            self._drain_queue()
-            self._drain_jobs()
-            self._shed_expired()
-            self._repair()
+            with span(_SPAN_INTAKE):
+                self._drain_queue()
+                self._drain_jobs()
+                self._shed_expired()
+                self._repair()
             if (not self._waiting
                     and not any(r is not None for r in self._slots)
                     and self._rqueue.empty() and not self._resuming):
                 while self._pending:
-                    self._resolve(self._pending.popleft())
-                self._repair()
-                self._maybe_publish(time.perf_counter())
-                self._wake.wait(timeout=0.05)
-                self._wake.clear()
+                    self._resolve_next()
+                with span(_SPAN_INTAKE):
+                    self._repair()
+                    self._maybe_publish(time.perf_counter())
+                with span(_SPAN_IDLE):
+                    self._wake.wait(timeout=0.05)
+                    self._wake.clear()
                 continue
-            phases = self._plan()
+            with span(_SPAN_PLAN):
+                phases = self._plan()
+                if phases:
+                    A, P = self._variant(phases)
+                    counts = _dispatch_counts(phases)
             if phases:
-                self._dispatch_macro(phases)
+                with span(_SPAN_DISPATCH, seq=self._m["dispatches"], A=A, P=P,
+                          **counts):
+                    self._dispatch_macro(phases)
                 # fetch one macro-step BEHIND: overlaps the one just
                 # dispatched
                 while len(self._pending) > 1:
-                    self._resolve(self._pending.popleft())
+                    self._resolve_next()
             elif self._pending:
                 # nothing plannable until in-flight results land (spec
                 # mode: every resident lane's round estimate is spent) —
                 # resolve the frontier NOW so the acceptance resync can
                 # unblock the next plan instead of spinning
-                self._resolve(self._pending.popleft())
+                self._resolve_next()
             else:
-                self._wake.wait(timeout=0.01)
-                self._wake.clear()
+                with span(_SPAN_IDLE):
+                    self._wake.wait(timeout=0.01)
+                    self._wake.clear()
 
     # ---- legacy per-chunk path (macro_phases=0): kept for A/B tests ----
     def _admit(self) -> None:
@@ -1903,11 +1976,12 @@ class ContinuousBatchingEngine:
 
     def _resolve_inner(self, entry) -> None:
         if entry[0] == "spec":
-            _, toks_counts, firsts_dev, phases = entry
+            _, toks_counts, firsts_dev, phases, _seq = entry
             toks_dev, counts_dev = toks_counts
-            toks = np.asarray(toks_dev)      # (K, chunk, B, n_spec + 1)
-            counts = np.asarray(counts_dev)  # (K, chunk, B)
-            firsts = np.asarray(firsts_dev)
+            with self._span(_SPAN_FETCH):
+                toks = np.asarray(toks_dev)      # (K, chunk, B, n_spec + 1)
+                counts = np.asarray(counts_dev)  # (K, chunk, B)
+                firsts = np.asarray(firsts_dev)
             for k, ph in enumerate(phases):
                 for a, (_slot, req) in enumerate(ph["admissions"]):
                     self._deliver(req, [int(firsts[k, a])])
@@ -1946,9 +2020,10 @@ class ContinuousBatchingEngine:
                         req._rounds_est = max(0, est)
             return
         if entry[0] == "macro":
-            _, toks_dev, firsts_dev, phases = entry
-            toks = np.asarray(toks_dev)
-            firsts = np.asarray(firsts_dev)
+            _, toks_dev, firsts_dev, phases, _seq = entry
+            with self._span(_SPAN_FETCH):
+                toks = np.asarray(toks_dev)
+                firsts = np.asarray(firsts_dev)
             for k, ph in enumerate(phases):
                 for a, (_slot, req) in enumerate(ph["admissions"]):
                     self._deliver(req, [int(firsts[k, a])])
@@ -1984,7 +2059,7 @@ class ContinuousBatchingEngine:
         doomed = set()
         for entry in self._pending:
             if entry[0] in ("macro", "spec"):
-                for ph in entry[-1]:
+                for ph in entry[3]:
                     doomed.update(r for _, r in ph["admissions"])
                     doomed.update(r for _, r, _ in ph["takes"])
             else:

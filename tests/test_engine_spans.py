@@ -1,0 +1,338 @@
+"""Names, scopes and spans that a device trace is read by (ISSUE 27).
+
+- every jitted decode program carries its function's name (never
+  `jit__unknown`) and the three flash-attention Pallas calls their own;
+- the names and the two `jax.named_scope`s of the macro-step are metadata
+  only: the lowered program, names and metadata stripped, is the one the same
+  code gives with `named_scope`, `name=` and `_bind` patched out;
+- the engine's macro loop tiles its thread with `ENGINE_SPANS` in a
+  `jax.profiler` trace, and each `engine.dispatch` carries the plan's counts.
+
+CPU, tiny sizes; the Pallas calls are lowered FOR the tpu platform
+(`lowering_platforms`), which needs no chip and compiles nothing. The profiler
+is started inside a test, never at import.
+"""
+import base64
+import contextlib
+import dataclasses
+import functools
+import glob
+import re
+import time
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import llama
+from ray_tpu.models import llama_decode as D
+from ray_tpu.observability import ENGINE_SPANS
+from ray_tpu.ops import flash_attention as FA
+from ray_tpu.serve import llm_engine
+from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
+
+N_SLOTS, MAX_LEN, BLOCK, N_BLOCKS = 2, 32, 8, 10
+MB = MAX_LEN // BLOCK
+K, A, P, NS, CHUNK, N_SPEC = 2, 1, 16, 4, 4, 2
+
+
+@functools.lru_cache(maxsize=1)
+def _cfg_params():
+    cfg = llama.LlamaConfig.tiny(dtype=jnp.float32, attn_impl="blockwise", remat=False)
+    return cfg, llama.init_params(jax.random.PRNGKey(0), cfg)
+
+
+def _plan_args(paged: bool):
+    """Plan arrays in the order the macro-steps take them after `feed`."""
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
+    head = (i32(K), jnp.zeros(K, bool), i32(K, A, P), i32(K, A))
+    if not paged:
+        return head + (i32(K, A), i32(K, A))                        # slots, rems
+    return head + (i32(K, A), i32(K, A), i32(K, A),                 # starts, slots, rems
+                   jnp.zeros((K, A), jnp.uint32), i32(K, N_SLOTS, MB),
+                   jnp.zeros((K, N_SLOTS), jnp.float32), i32(K, N_SLOTS),
+                   jnp.ones((K, N_SLOTS), jnp.float32),
+                   jnp.full((K, N_SLOTS, NS), -1, jnp.int32))
+
+
+def _spec_parts():
+    from ray_tpu.serve._internal.speculative import resolve_draft_model
+
+    cfg, params = _cfg_params()
+    draft_params, draft_cfg = resolve_draft_model(
+        {"cfg": dataclasses.replace(cfg, d_model=96, d_ff=192)}, params, cfg)
+    return draft_params, draft_cfg
+
+
+def _program(name: str):
+    """(jitted program from its factory, arguments to lower it with)."""
+    cfg, params = _cfg_params()
+    feed = jnp.zeros(N_SLOTS, jnp.int32)
+    dense = lambda: D.init_cache(cfg, 1, MAX_LEN)  # noqa: E731
+    slots = lambda: D.init_slot_cache(cfg, N_SLOTS, MAX_LEN)  # noqa: E731
+    paged = lambda: D.init_paged_cache(cfg, N_SLOTS, N_BLOCKS, BLOCK)  # noqa: E731
+    blocks = jnp.zeros(2, jnp.int32)
+    kv = jnp.zeros((cfg.n_layers, 2, BLOCK, cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
+    if name == "prefill":
+        return D._jitted_prefill(cfg), (params, jnp.zeros((1, 8), jnp.int32), dense())
+    if name == "decode_step":
+        return D._jitted_decode_step(cfg), (params, dense(), jnp.zeros(1, jnp.int32))
+    if name == "decode_loop":
+        return D._jitted_decode_loop(cfg, 3), (params, dense(), jnp.zeros(1, jnp.int32))
+    if name == "sample_loop":
+        return D._jitted_sample_loop(cfg, 3), (
+            params, dense(), jnp.zeros((1, cfg.vocab_size), jnp.float32),
+            jax.random.PRNGKey(0), 1.0, 0, 1.0)
+    if name == "prefill_into_slots":
+        return D.jitted_prefill_into_slots(cfg), (
+            params, jnp.zeros((A, P), jnp.int32), jnp.zeros(A, jnp.int32),
+            jnp.zeros(A, jnp.int32), slots())
+    if name == "decode_chunk_slots":
+        return D.jitted_decode_chunk_slots(cfg, CHUNK), (params, slots(), feed)
+    if name == "macro_step_slots":
+        return D.jitted_macro_step_slots(cfg, CHUNK), (params, slots(), feed) + _plan_args(False)
+    if name == "macro_step_slots_paged":
+        return D.jitted_macro_step_slots_paged(cfg, CHUNK, sampled=True), (
+            params, paged(), feed) + _plan_args(True)
+    if name == "macro_step_slots_spec":
+        draft_params, draft_cfg = _spec_parts()
+        return D.jitted_macro_step_slots_spec(cfg, draft_cfg, CHUNK, N_SPEC), (
+            params, draft_params, paged(),
+            D.init_spec_cache(draft_cfg, N_SLOTS, N_BLOCKS, BLOCK), feed) + _plan_args(True)
+    if name == "gather_kv_blocks":
+        return D.jitted_gather_kv_blocks(), (paged(), blocks)
+    if name == "scatter_kv_blocks":
+        return D.jitted_scatter_kv_blocks(), (paged(), blocks, kv, kv)
+    if name == "import_kv_blocks":
+        return D.jitted_import_kv_blocks(), (
+            paged(), blocks, kv, kv, jnp.int32(0), jnp.int32(0), jnp.int32(0),
+            jnp.zeros(2, jnp.uint32))
+    raise KeyError(name)
+
+
+PROGRAMS = ("prefill", "decode_step", "decode_loop", "sample_loop", "prefill_into_slots",
+            "decode_chunk_slots", "macro_step_slots", "macro_step_slots_paged",
+            "macro_step_slots_spec", "gather_kv_blocks", "scatter_kv_blocks",
+            "import_kv_blocks")
+
+
+def test_every_jitted_factory_is_listed():
+    factories = {n for n in vars(D) if n.startswith(("jitted_", "_jitted_"))}
+    assert factories == {("_jitted_" if n in ("prefill", "decode_step", "decode_loop",
+                                              "sample_loop") else "jitted_") + n
+                         for n in PROGRAMS}
+
+
+@pytest.mark.parametrize("name", PROGRAMS)
+def test_jitted_program_carries_its_functions_name(name):
+    jitted, args = _program(name)
+    module = re.match(r"module @(\S+)", jitted.lower(*args).as_text()).group(1)
+    assert module == "jit_" + name, module
+
+
+# ------------------------------------------------------- the Pallas calls
+def _flash_lowered(which: str):
+    B, T, H, KVH, Dh = 1, 256, 4, 2, 64
+    q = jax.ShapeDtypeStruct((B, T, H, Dh), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, T, KVH, Dh), jnp.bfloat16)
+    if which == "fwd":
+        fn = functools.partial(FA._flash_fwd_pallas, causal=True, sm_scale=None,
+                               block_q=128, block_k=128, interpret=False)
+        args = (q, kv, kv)
+    else:
+        fn = functools.partial(FA._flash_bwd_pallas, causal=True, sm_scale=None,
+                               block_q=128, block_k=128)
+        lse = jax.ShapeDtypeStruct((B, T, H), jnp.float32)
+        args = (q, kv, kv, q, lse, q)
+    return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+
+
+def test_pallas_calls_carry_their_names():
+    names = re.findall(r'kernel_name = "([^"]*)"',
+                       _flash_lowered("fwd").as_text() + _flash_lowered("bwd").as_text())
+    assert names == ["flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"]
+
+
+# ------------------------------------- names and scopes are metadata only
+_MOSAIC_BODY = re.compile(r'(body\\22: \\22)([A-Za-z0-9+/=]+)')
+
+
+def _mosaic_text(body_b64: str) -> str:
+    """A Pallas kernel rides its custom call as serialized MLIR; its text
+    without locations, so that two kernels compare as programs."""
+    from jax.extend.mlir import ir
+    from jax.interpreters import mlir
+
+    with mlir.make_ir_context() as ctx:
+        ctx.allow_unregistered_dialects = True
+        module = ir.Module.parse(base64.b64decode(body_b64))
+        return module.operation.get_asm(enable_debug_info=False)
+
+
+def _stripped(lowered) -> str:
+    """The lowered program without names and metadata: `as_text()` carries no
+    locations (where a named scope lives); module and kernel names blanked."""
+    text = _MOSAIC_BODY.sub(lambda m: m.group(1) + _mosaic_text(m.group(2)), lowered.as_text())
+    text = re.sub(r'kernel_name = "[^"]*"', 'kernel_name = ""', text)
+    return re.sub(r"module @\S+", "module @_", text)
+
+
+@contextlib.contextmanager
+def _names_patched_out(monkeypatch):
+    """The same code with no `named_scope`, no `name=` on a Pallas call and
+    bare partials under `jax.jit`."""
+    real_pl = FA.pl
+    pl = types.SimpleNamespace(**{k: getattr(real_pl, k) for k in dir(real_pl)
+                                  if not k.startswith("__")})
+    pl.pallas_call = lambda *a, name=None, **kw: real_pl.pallas_call(*a, **kw)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        m.setattr(D, "_bind", functools.partial)
+        m.setattr(FA, "pl", pl)
+        yield
+
+
+def _lower_macro(name: str):
+    for f in (D.jitted_macro_step_slots, D.jitted_macro_step_slots_paged,
+              D.jitted_macro_step_slots_spec):
+        f.cache_clear()  # the factories memoize the jitted function
+    jitted, args = _program(name)
+    return jitted.lower(*args)
+
+
+def _lower_train_step(monkeypatch):
+    """The program's own train step with the flash kernels in it, lowered for
+    the tpu platform (on the CPU `attn_impl="flash"` takes the XLA route)."""
+    from ray_tpu.train.step import setup_sharded_training
+
+    monkeypatch.setattr(FA, "_on_tpu", lambda: True)
+    cfg = llama.LlamaConfig.tiny(d_model=256, attn_impl="flash", remat=True)  # head_dim 64
+    _, init_fn, step_fn, shard_batch, _ = setup_sharded_training(
+        cfg, strategy="dp", devices=jax.devices()[:1])
+    state = init_fn(jax.random.PRNGKey(0))
+    batch = shard_batch({"tokens": np.zeros((1, 129), np.int32)})
+    return step_fn.__wrapped__.trace(state, batch).lower(lowering_platforms=("tpu",))
+
+
+@pytest.mark.parametrize("name", ["macro_step_slots", "macro_step_slots_paged",
+                                  "macro_step_slots_spec", "train_step"])
+def test_names_and_scopes_are_metadata_only(name, monkeypatch):
+    lower = (functools.partial(_lower_train_step, monkeypatch) if name == "train_step"
+             else functools.partial(_lower_macro, name))
+    named = lower()
+    with _names_patched_out(monkeypatch):
+        bare = lower()
+    with_locations = named.as_text(debug_info=True)
+    if name == "train_step":
+        assert with_locations.count("tpu_custom_call") == 4  # fwd, its remat, dK/dV, dQ
+        assert 'kernel_name = "flash_fwd"' in named.as_text()
+        assert 'kernel_name = "flash_fwd"' not in bare.as_text()
+    else:
+        assert D.ADMIT_SCOPE in with_locations and D.DECODE_SCOPE in with_locations
+        assert D.ADMIT_SCOPE not in bare.as_text(debug_info=True)
+        assert bare.as_text().startswith("module @jit__unknown")
+    assert _stripped(named) == _stripped(bare)
+    for f in (D.jitted_macro_step_slots, D.jitted_macro_step_slots_paged,
+              D.jitted_macro_step_slots_spec):
+        f.cache_clear()  # nothing built under the patch outlives it
+
+
+# ------------------------------------------------- the plan's counts (d)
+def _req(prompt_len, remaining, start=0):
+    return types.SimpleNamespace(prompt=[0] * prompt_len, _start=start, _remaining=remaining)
+
+
+def test_finish_wait_steps_on_a_hand_built_plan():
+    """Two lanes, chunk 4, four phases; `_remaining` is the state `_plan`
+    leaves behind (0 = the request's last token is in this dispatch)."""
+    a, b, c, d = _req(9, 0), _req(12, 7), _req(20, 0, start=8), _req(5, 0)
+    phases = [
+        {"steps": 2, "admissions": [(0, a), (1, b)], "takes": [(0, a, 2), (1, b, 2)]},
+        {"steps": 4, "admissions": [(0, c)], "takes": [(0, c, 4), (1, b, 4)]},
+        {"steps": 2, "admissions": [], "takes": [(0, c, 2), (1, b, 2)]},
+        # d owes one token only: the prefill's own, before this phase's steps
+        {"steps": 4, "admissions": [(0, d)], "takes": [(1, b, 4)]},
+    ]
+    counts = llm_engine._dispatch_counts(phases)
+    assert counts == {
+        "phases": 4, "steps": 12, "admissions": 4, "prompt_tokens": 9 + 12 + 12 + 5,
+        "lane_steps": 20, "finishing": 3,
+        # a after 2 of 12 steps, c after 8, d after 8 (its phase's 4 steps follow it)
+        "finish_wait_steps": 10 + 4 + 4}
+    # a plan that finishes nobody waits for nothing
+    assert llm_engine._dispatch_counts([{"steps": 4, "admissions": [], "takes": [(1, b, 4)]}])[
+        "finish_wait_steps"] == 0
+
+
+# ---------------------------------------------- spans in a real trace (c)
+def _engine_events(trace_dir):
+    """{line: [(name, start_ns, end_ns, stats)]} of the host lines that hold
+    an `engine.*` event."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        for i, line in enumerate(plane.lines):
+            events = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+                      for e in line.events if e.name.startswith("engine.")]
+            if events:
+                lines[(plane.name, i)] = sorted(events, key=lambda e: e[1])
+    return lines
+
+
+def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
+    cfg, params = _cfg_params()
+    eng = ContinuousBatchingEngine(params, cfg, paged=True, n_slots=2, chunk=4,
+                                   macro_phases=4, max_len=64, block_size=8)
+    rng = np.random.default_rng(0)
+    prompt = lambda n: rng.integers(0, cfg.vocab_size, n).tolist()  # noqa: E731
+    try:
+        eng.generate(prompt(9), 5)  # the loop is up and a program compiled
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            m0 = eng.metrics()
+            lengths, answers = (9, 17, 12, 9, 30), (3, 20, 7, 1, 11)
+            reqs = [eng.submit(prompt(n), new) for n, new in zip(lengths, answers)]
+            assert all(r.done.wait(120) for r in reqs)
+            assert all(r.error is None for r in reqs)
+            m1 = eng.metrics()
+            time.sleep(0.15)  # the last dispatch resolves, then a few idle iterations
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        eng.shutdown()
+
+    lines = _engine_events(tmp_path)
+    assert len(lines) == 1, "engine spans on more than the engine's loop thread"
+    (events,) = lines.values()
+    assert {e[0] for e in events} == set(ENGINE_SPANS)
+
+    top = [e for e in events if e[0] != "engine.fetch"]
+    for (_, _, end, _), (name, start, _, _) in zip(top, top[1:]):
+        assert start >= end, f"{name} begins inside the span before it"
+    for name, start, end, _ in events:
+        if name == "engine.fetch":  # lies inside one engine.resolve
+            assert any(n == "engine.resolve" and s <= start and end <= e for n, s, e, _ in top)
+
+    dispatches = [stats for name, _, _, stats in top if name == "engine.dispatch"]
+    keys = {"seq", "phases", "steps", "admissions", "A", "P", "prompt_tokens", "lane_steps",
+            "finishing", "finish_wait_steps"}
+    assert all(set(d) == keys for d in dispatches)
+    diff = {k: m1[k] - m0[k] for k in ("dispatches", "slot_steps", "useful_slot_steps",
+                                       "prefill_tokens", "requests_completed")}
+    assert len(dispatches) == diff["dispatches"] >= 2
+    assert [d["seq"] for d in dispatches] == list(
+        range(m0["dispatches"], m0["dispatches"] + len(dispatches)))
+    assert sum(d["steps"] for d in dispatches) * eng.n_slots == diff["slot_steps"]
+    assert sum(d["lane_steps"] for d in dispatches) == diff["useful_slot_steps"]
+    assert sum(d["admissions"] for d in dispatches) == len(reqs)
+    assert sum(d["prompt_tokens"] for d in dispatches) == diff["prefill_tokens"]
+    assert sum(d["finishing"] for d in dispatches) == diff["requests_completed"] == len(reqs)
+    assert all(d["P"] in (16, 32) and d["A"] in (1, 2) for d in dispatches)
+    resolved = [stats["seq"] for name, _, _, stats in top if name == "engine.resolve"]
+    assert resolved == [d["seq"] for d in dispatches]
