@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.ops.blockwise_attention import NEG_INF
 from ray_tpu.ops.normalization import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
@@ -681,12 +682,14 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
     return logits, nxt, new_cache
 
 
-def _gqa_attend_paged_prefill(q, k_ctx, v_ctx, positions, cfg: LlamaConfig):
-    """Suffix-prefill attention against gathered paged context: q
-    (A, P, h, hd) at absolute `positions` (A, P); k_ctx/v_ctx
-    (A, S, kvh, hd) hold the full context INCLUDING the suffix's own
-    just-written K/V, so the causal mask s <= positions[a, t] covers
-    both the reused prefix and intra-suffix causality in one score."""
+def _gqa_attend_span(q, k_ctx, v_ctx, positions, cfg: LlamaConfig):
+    """Few-query attention against a gathered table span: q (R, T, h, hd)
+    at absolute `positions` (R, T); k_ctx/v_ctx (R, S, kvh, hd) hold the
+    full context INCLUDING the queries' own just-written K/V, so the
+    causal mask s <= positions[r, t] covers both the older context and
+    causality among the T queries in one (T x S) score. For T = n_spec + 1
+    (speculative verify and draft passes); admission, whose T is a whole
+    prompt, goes through _attend_admission."""
     A, P, h, hd = q.shape
     S = k_ctx.shape[1]
     qg = q.reshape(A, P, cfg.n_kv_heads, h // cfg.n_kv_heads, hd)
@@ -703,6 +706,87 @@ def _gqa_attend_paged_prefill(q, k_ctx, v_ctx, positions, cfg: LlamaConfig):
     return out.reshape(A, P, h * hd).astype(cfg.dtype)
 
 
+# positions of reused prefix one iteration of the admission's prefix loop
+# gathers from the pool and scores (rounded to whole blocks)
+PREFIX_CHUNK = 512
+
+
+def _attend_admission(q, k, v, k_layer, v_layer, adm_tables, starts,
+                      cfg: LlamaConfig):
+    """Admission attention, in proportion to the context a row has: q
+    (A, P, h, hd) and the rows' own just-projected k/v (A, P, kvh, hd) at
+    positions starts[n] + t; k_layer/v_layer (n_blocks, bs, kvh, hd) the
+    layer's pool AFTER every row's suffix write; adm_tables (A, MB).
+
+    A row's context has two parts. Its own SUFFIX is causal P x P on k/v
+    as they are, no pool read (the flash forward: Pallas on the chip,
+    blockwise XLA elsewhere); a real query at t < length never sees a
+    right-pad key at s > t. Its reused PREFIX, positions s < starts[n],
+    is read from the pool PREFIX_CHUNK positions at a time under an
+    online softmax, with a trip count ceil(max(starts) / chunk) that is
+    data in the program; the two merge by their log-sum-exp. When no row
+    has a prefix the loop and the merge are skipped (a cond on the same
+    plan array). Nowhere is there a (P x table span) score, nor a gather
+    of the span. bf16 operands, f32 accumulation and softmax,
+    probabilities cast to the value dtype for the PV product."""
+    # imported where it is traced, as models/llama.py does: Pallas takes a
+    # second to import, and only a process that traces a program needs it
+    from ray_tpu.ops.flash_attention import flash_attention_fwd
+
+    A, P, h, hd = q.shape
+    kvh = cfg.n_kv_heads
+    bs = k_layer.shape[1]
+    MB = adm_tables.shape[1]
+    o_s, lse_s = flash_attention_fwd(q, k, v, causal=True)  # (A,P,h,hd), (A,P,h)
+
+    cb = min(max(PREFIX_CHUNK // bs, 1), MB)  # blocks a chunk
+    C = cb * bs
+    # whole chunks only: the tail names the null block and is never live
+    chunked = jnp.pad(adm_tables, ((0, 0), (0, -MB % cb)))
+    qg = q.reshape(A, P, kvh, h // kvh, hd)
+    longest = jnp.max(starts)
+
+    def chunk(i, carry):
+        acc, m, l = carry
+        blocks = jax.lax.dynamic_slice_in_dim(chunked, i * cb, cb, axis=1)
+        kc = k_layer[blocks].reshape(A, C, kvh, hd)
+        vc = v_layer[blocks].reshape(A, C, kvh, hd)
+        s = jnp.einsum(
+            "apkgd,ackd->akgpc", qg, kc, preferred_element_type=jnp.float32
+        ) * (hd**-0.5)
+        live = (i * C + jnp.arange(C))[None, :] < starts[:, None]  # (A, C)
+        live = live[:, None, None, None, :]
+        m_new = jnp.maximum(m, jnp.where(live, s, NEG_INF).max(axis=-1))
+        p = jnp.where(live, jnp.exp(s - m_new[..., None]), 0.0)
+        corr = jnp.exp(m - m_new)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "akgpc,ackd->akgpd", p.astype(vc.dtype), vc,
+            preferred_element_type=jnp.float32,
+        )
+        return acc, m_new, l * corr + p.sum(axis=-1)
+
+    def with_prefix(o_s):
+        stat = (A, kvh, h // kvh, P)
+        acc, m, l = jax.lax.fori_loop(
+            0, (longest + C - 1) // C, chunk,
+            (jnp.zeros(stat + (hd,), jnp.float32),
+             jnp.full(stat, NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32)),
+        )
+        # a row without prefix keeps l = 0, lse_p = NEG_INF: its weight is 0
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_p = (acc / l_safe[..., None]).transpose(0, 3, 1, 2, 4).reshape(A, P, h, hd)
+        lse_p = (m + jnp.log(l_safe)).transpose(0, 3, 1, 2).reshape(A, P, h)
+        lse = jnp.logaddexp(lse_s, lse_p)
+        out = (o_s.astype(jnp.float32) * jnp.exp(lse_s - lse)[..., None]
+               + o_p * jnp.exp(lse_p - lse)[..., None])
+        return out.astype(o_s.dtype)
+
+    # no row with a prefix (every admission without a radix-cache hit):
+    # the suffix part is the answer, no accumulator and no merge
+    out = jax.lax.cond(longest > 0, with_prefix, lambda o_s: o_s, o_s)
+    return out.reshape(A, P, h * hd).astype(cfg.dtype)
+
+
 def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
                       cache, feed, tables, temps, top_ks, top_ps, stop_ids,
                       cfg: LlamaConfig, sampled: bool = True):
@@ -715,9 +799,12 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     block table. P must be a multiple of block_size.
 
     Per layer the body writes EVERY row's suffix K/V before ANY row
-    gathers context, so two same-phase admissions sharing a prefix (the
-    second's table naming blocks the first is filling right now) stay
-    correct: plan order == write order <= read order. Right-pad columns
+    reads a prefix from the pool, so two same-phase admissions sharing a
+    prefix (the second's table naming blocks the first is filling right
+    now) stay correct: plan order == write order <= read order. The
+    attention (_attend_admission) is causal over the row's own suffix
+    plus a loop over its prefix blocks: its work follows starts[n] +
+    lengths[n], never the table span. Right-pad columns
     write into the slot's own reserved (beyond-pos) cells or, past the
     table's edge, the null block. Each row's first output token is
     SAMPLED from its true-last-position logits with a key seeded from
@@ -750,10 +837,11 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
         # phase 1: write all rows' suffix K/V block by block
         def write_row(n, kv):
             def wr(kv):
-                kf, vf = kv
                 s0 = jax.lax.dynamic_index_in_dim(starts, n, keepdims=False) // bs
                 row = jax.lax.dynamic_index_in_dim(adm_tables, n, 0, keepdims=False)
-                for j in range(n_chunks):  # static: P // bs chunks
+
+                def write_block(j, kv):
+                    kf, vf = kv
                     idx = s0 + j
                     blk = jax.lax.dynamic_index_in_dim(
                         row, jnp.minimum(idx, MB - 1), keepdims=False
@@ -765,16 +853,22 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
                         v, (n, j * bs, 0, 0), (1, bs, kvh, hd))[0][None, None]
                     kf = jax.lax.dynamic_update_slice(kf, kc, (li, blk, 0, 0, 0))
                     vf = jax.lax.dynamic_update_slice(vf, vc, (li, blk, 0, 0, 0))
-                return kf, vf
+                    return kf, vf
+
+                # a loop, eight blocks an iteration: spelled out as P // bs
+                # blocks in Python, the (4, 1024) program at 16 layers took
+                # twice as long to lower, to compile (78 s against 32 on a
+                # v5e host, PR 28) and to load from the compile cache
+                return jax.lax.fori_loop(0, n_chunks, write_block, kv,
+                                         unroll=min(8, n_chunks))
 
             return jax.lax.cond(valid[n], wr, lambda kv: kv, kv)
 
         k_full, v_full = jax.lax.fori_loop(0, A, write_row, (k_full, v_full))
-        # phase 2: every row gathers context (sees all phase-1 writes)
+        # phase 2: every row reads its prefix (sees all phase-1 writes)
         k_layer = jax.lax.dynamic_index_in_dim(k_full, li, 0, keepdims=False)
         v_layer = jax.lax.dynamic_index_in_dim(v_full, li, 0, keepdims=False)
-        ctx_k, ctx_v = _gather_block_ctx(k_layer, v_layer, adm_tables)
-        o = _gqa_attend_paged_prefill(q, ctx_k, ctx_v, positions, cfg)
+        o = _attend_admission(q, k, v, k_layer, v_layer, adm_tables, starts, cfg)
         x = x + o @ layer["wo"]
         m = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
         gate = jax.nn.silu((m @ layer["w_gate"]).astype(jnp.float32)).astype(cfg.dtype)
@@ -998,7 +1092,7 @@ def _forward_tokens_paged(params, kv_k, kv_v, tokens, row_tables, base_pos,
         k_layer = jax.lax.dynamic_index_in_dim(k_full, li, 0, keepdims=False)
         v_layer = jax.lax.dynamic_index_in_dim(v_full, li, 0, keepdims=False)
         ctx_k, ctx_v = _gather_block_ctx(k_layer, v_layer, row_tables)
-        o = _gqa_attend_paged_prefill(q, ctx_k, ctx_v, positions, cfg)
+        o = _gqa_attend_span(q, ctx_k, ctx_v, positions, cfg)
         x = x + o @ layer["wo"]
         m = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
         gate = jax.nn.silu((m @ layer["w_gate"]).astype(jnp.float32)).astype(cfg.dtype)

@@ -375,6 +375,14 @@ def _flash_fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k):
     return _fwd_impl(q, k, v, causal, max(block_q, block_k), sm_scale, 0, 0)
 
 
+def flash_attention_fwd(q, k, v, causal: bool = True, sm_scale: Optional[float] = None,
+                        block_q: int = 1024, block_k: int = 1024):
+    """Forward only, outside the custom VJP: (o [B, T, H, D], lse [B, T, H]
+    f32). For callers that merge partial attentions by their log-sum-exp
+    (paged admission: own suffix here, reused prefix from the pool)."""
+    return _flash_fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k)
+
+
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     o, lse = _flash_fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k)
     return o, (q, k, v, o, lse)

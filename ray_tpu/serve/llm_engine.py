@@ -310,11 +310,12 @@ def _dispatch_counts(phases: List[Dict[str, Any]]) -> Dict[str, int]:
     total = sum(ph["steps"] for ph in phases)
     done = 0  # decode steps of this dispatch run so far
     last: Dict[int, int] = {}  # finishing request -> `done` at its last token
-    admissions = prompt_tokens = lane_steps = 0
+    admissions = prompt_tokens = prefix_tokens = lane_steps = 0
     for ph in phases:
         admissions += len(ph["admissions"])
         for _, req in ph["admissions"]:
             prompt_tokens += _suffix_len(req)
+            prefix_tokens += req._start  # > 0: the admission's prefix loop runs
             if req._remaining == 0:
                 last[id(req)] = done  # the prefill's token, unless it decodes
         done += ph["steps"]
@@ -323,7 +324,8 @@ def _dispatch_counts(phases: List[Dict[str, Any]]) -> Dict[str, int]:
             if take and req._remaining == 0:
                 last[id(req)] = done
     return {"phases": len(phases), "steps": total, "admissions": admissions,
-            "prompt_tokens": prompt_tokens, "lane_steps": lane_steps,
+            "prompt_tokens": prompt_tokens, "prefix_tokens": prefix_tokens,
+            "lane_steps": lane_steps,
             "finishing": len(last),
             "finish_wait_steps": sum(total - d for d in last.values())}
 

@@ -258,7 +258,7 @@ def test_finish_wait_steps_on_a_hand_built_plan():
     counts = llm_engine._dispatch_counts(phases)
     assert counts == {
         "phases": 4, "steps": 12, "admissions": 4, "prompt_tokens": 9 + 12 + 12 + 5,
-        "lane_steps": 20, "finishing": 3,
+        "prefix_tokens": 8, "lane_steps": 20, "finishing": 3,
         # a after 2 of 12 steps, c after 8, d after 8 (its phase's 4 steps follow it)
         "finish_wait_steps": 10 + 4 + 4}
     # a plan that finishes nobody waits for nothing
@@ -290,7 +290,8 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
     rng = np.random.default_rng(0)
     prompt = lambda n: rng.integers(0, cfg.vocab_size, n).tolist()  # noqa: E731
     try:
-        eng.generate(prompt(9), 5)  # the loop is up and a program compiled
+        warm = prompt(9)
+        eng.generate(warm, 5)  # the loop is up and a program compiled
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         jax.profiler.start_trace(str(tmp_path), profiler_options=options)
@@ -298,6 +299,8 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
             m0 = eng.metrics()
             lengths, answers = (9, 17, 12, 9, 30), (3, 20, 7, 1, 11)
             reqs = [eng.submit(prompt(n), new) for n, new in zip(lengths, answers)]
+            # one radix-cache hit: the warm-up prompt's first block, reused
+            reqs.append(eng.submit(warm[:8] + prompt(6), 4))
             assert all(r.done.wait(120) for r in reqs)
             assert all(r.error is None for r in reqs)
             m1 = eng.metrics()
@@ -320,11 +323,12 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
             assert any(n == "engine.resolve" and s <= start and end <= e for n, s, e, _ in top)
 
     dispatches = [stats for name, _, _, stats in top if name == "engine.dispatch"]
-    keys = {"seq", "phases", "steps", "admissions", "A", "P", "prompt_tokens", "lane_steps",
-            "finishing", "finish_wait_steps"}
+    keys = {"seq", "phases", "steps", "admissions", "A", "P", "prompt_tokens", "prefix_tokens",
+            "lane_steps", "finishing", "finish_wait_steps"}
     assert all(set(d) == keys for d in dispatches)
     diff = {k: m1[k] - m0[k] for k in ("dispatches", "slot_steps", "useful_slot_steps",
-                                       "prefill_tokens", "requests_completed")}
+                                       "prefill_tokens", "reused_prefix_tokens",
+                                       "requests_completed")}
     assert len(dispatches) == diff["dispatches"] >= 2
     assert [d["seq"] for d in dispatches] == list(
         range(m0["dispatches"], m0["dispatches"] + len(dispatches)))
@@ -332,6 +336,7 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
     assert sum(d["lane_steps"] for d in dispatches) == diff["useful_slot_steps"]
     assert sum(d["admissions"] for d in dispatches) == len(reqs)
     assert sum(d["prompt_tokens"] for d in dispatches) == diff["prefill_tokens"]
+    assert sum(d["prefix_tokens"] for d in dispatches) == diff["reused_prefix_tokens"] == 8
     assert sum(d["finishing"] for d in dispatches) == diff["requests_completed"] == len(reqs)
     assert all(d["P"] in (16, 32) and d["A"] in (1, 2) for d in dispatches)
     resolved = [stats["seq"] for name, _, _, stats in top if name == "engine.resolve"]

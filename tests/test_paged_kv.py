@@ -213,6 +213,198 @@ def test_paged_decode_matches_dense_wrapped_tables():
         feed_d, feed_p = nxt_d, nxt_p
 
 
+# ------------------------------- admission attention: parity and lowering
+def _one_shot_attention(q, k, v, k_layer, v_layer, adm_tables, starts, cfg):
+    """The formulation admission had until PR 28, kept as the reference:
+    gather every row's WHOLE table span from the pool (the suffix K/V are
+    read back from it, so `k`, `v` go unused), build one f32
+    (A, kvh, g, P, span) score, mask it by s <= starts[n] + t, soft-max it
+    in one shot."""
+    import jax
+    import jax.numpy as jnp
+
+    A, P, h, hd = q.shape
+    kvh = cfg.n_kv_heads
+    S = adm_tables.shape[1] * k_layer.shape[1]
+    k_ctx = k_layer[adm_tables].reshape(A, S, kvh, hd)
+    v_ctx = v_layer[adm_tables].reshape(A, S, kvh, hd)
+    positions = starts[:, None] + jnp.arange(P, dtype=jnp.int32)[None, :]
+    qg = q.reshape(A, P, kvh, h // kvh, hd)
+    scores = jnp.einsum("apkgd,askd->akgps", qg, k_ctx,
+                        preferred_element_type=jnp.float32) * (hd ** -0.5)
+    mask = jnp.arange(S)[None, None, :] <= positions[:, :, None]
+    scores = jnp.where(mask[:, None, None, :, :], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("akgps,askd->apkgd", probs.astype(v_ctx.dtype), v_ctx,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(A, P, h * hd).astype(cfg.dtype)
+
+
+_ADM_BS, _ADM_MB = 8, 128  # a table span of 1024: two prefix chunks of 512
+
+# name -> (P, starts, lengths); every case has A = 4 rows
+ADMISSION_CASES = {
+    # (a) no prefix, mixed lengths with right-padding
+    "no-prefix-ragged": (32, [0, 0, 0, 0], [32, 5, 17, 1]),
+    # (b) block-aligned prefixes of different lengths, one row without
+    "prefixes-differ": (32, [24, 0, 8, 64], [9, 32, 20, 3]),
+    # (c) invalid rows (length 0) beside valid ones
+    "invalid-row": (32, [8, 0, 0, 0], [7, 0, 12, 0]),
+    # (d) row 1's prefix is the two blocks row 0 is filling in this phase
+    "shared-blocks-same-phase": (32, [0, 16, 0, 16], [24, 5, 9, 2]),
+    # (e) contexts that end on the chunk edge (504 + 8) and one block past
+    # it (512 + 8); prefixes one block short of, on and past the edge
+    "chunk-edge": (16, [504, 512, 520, 0], [8, 8, 16, 16]),
+}
+
+
+def _admission_case(name, cfg):
+    """Random pool (as if earlier admissions filled it), per-row tables of
+    distinct shuffled blocks; case (d) makes rows 1 and 3 name the first
+    two blocks of rows 0 and 2."""
+    P, starts, lengths = ADMISSION_CASES[name]
+    rng = np.random.default_rng(sorted(ADMISSION_CASES).index(name))
+    A, MB, bs = 4, _ADM_MB, _ADM_BS
+    n_blocks = A * MB + 1
+    tables = (rng.permutation(n_blocks - 1) + 1).reshape(A, MB).astype(np.int32)
+    if name == "shared-blocks-same-phase":
+        tables[1, :2] = tables[0, :2]
+        tables[3, :2] = tables[2, :2]
+    pool = lambda: rng.standard_normal(  # noqa: E731
+        (cfg.n_layers, n_blocks, bs, cfg.n_kv_heads, cfg.head_dim)).astype(np.float32)
+    return (P, np.asarray(starts, np.int32), np.asarray(lengths, np.int32), tables,
+            pool(), pool(), rng)
+
+
+@pytest.mark.parametrize("name", ADMISSION_CASES)
+def test_admission_attention_matches_one_shot(name, monkeypatch):
+    """The admission attention (own suffix by the flash forward, reused
+    prefix in chunks from the pool, merged by log-sum-exp) against the
+    one-shot span formulation: the attention's output on every real query,
+    then a whole admission (pool, positions, first tokens)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as D
+
+    params, cfg = _tiny()
+    P, starts, lengths, tables, pool_k, pool_v, rng = _admission_case(name, cfg)
+    A, bs, S = 4, _ADM_BS, _ADM_MB * _ADM_BS
+    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    assert D.PREFIX_CHUNK == 512 < S
+
+    # --- the attention alone, on a pool that holds the rows' suffixes
+    q = rng.standard_normal((A, P, h, hd)).astype(np.float32)
+    k = rng.standard_normal((A, P, kvh, hd)).astype(np.float32)
+    v = rng.standard_normal((A, P, kvh, hd)).astype(np.float32)
+    k_layer, v_layer = pool_k[0].copy(), pool_v[0].copy()
+    for n in np.flatnonzero(lengths):  # plan order == write order
+        for t in range(P):
+            blk, off = divmod(int(starts[n]) + t, bs)
+            k_layer[tables[n, blk], off] = k[n, t]
+            v_layer[tables[n, blk], off] = v[n, t]
+    args = [jnp.asarray(x) for x in (q, k, v, k_layer, v_layer, tables, starts)]
+    got = np.asarray(D._attend_admission(*args, cfg))
+    want = np.asarray(_one_shot_attention(*args, cfg))
+    real = np.arange(P)[None, :] < lengths[:, None]  # (A, P) real queries
+    assert real.any(axis=1).tolist() == (lengths > 0).tolist()
+    np.testing.assert_allclose(got[real], want[real], rtol=1e-5, atol=1e-5)
+
+    # --- a whole admission, new against the reference patched in
+    prompts = rng.integers(1, cfg.vocab_size, (A, P)).astype(np.int32)
+
+    def admit():
+        cache = D.init_paged_cache(cfg, A, pool_k.shape[1], bs)
+        cache = {**cache, "k": jnp.asarray(pool_k), "v": jnp.asarray(pool_v)}
+        return D.admit_slots_paged(
+            params, jnp.asarray(prompts), jnp.asarray(lengths), jnp.asarray(starts),
+            jnp.arange(A, dtype=jnp.int32), jnp.full(A, 5, jnp.int32),
+            jnp.zeros(A, jnp.uint32), cache, jnp.zeros(A, jnp.int32),
+            jnp.asarray(tables), jnp.zeros(A, jnp.float32), jnp.zeros(A, jnp.int32),
+            jnp.ones(A, jnp.float32), jnp.full((A, 4), -1, jnp.int32), cfg,
+            sampled=False)
+
+    first_new, cache_new, feed_new = admit()
+    monkeypatch.setattr(D, "_attend_admission", _one_shot_attention)
+    first_ref, cache_ref, feed_ref = admit()
+    valid = lengths > 0
+    np.testing.assert_array_equal(np.asarray(first_new)[valid], np.asarray(first_ref)[valid])
+    np.testing.assert_array_equal(np.asarray(feed_new), np.asarray(feed_ref))
+    for key in ("pos", "remaining"):
+        np.testing.assert_array_equal(np.asarray(cache_new[key]), np.asarray(cache_ref[key]))
+    np.testing.assert_array_equal(np.asarray(cache_new["pos"])[valid], (starts + lengths)[valid])
+    for key in ("k", "v"):  # block 0 is the null block: pad columns' dump
+        np.testing.assert_allclose(np.asarray(cache_new[key])[:, 1:],
+                                   np.asarray(cache_ref[key])[:, 1:], rtol=1e-5, atol=1e-5)
+
+
+def _sub_jaxprs(p):
+    import jax
+
+    if isinstance(p, jax.extend.core.ClosedJaxpr):
+        yield p.jaxpr
+    elif isinstance(p, jax.extend.core.Jaxpr):
+        yield p
+    elif isinstance(p, (list, tuple)):
+        for item in p:
+            yield from _sub_jaxprs(item)
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (pjit / scan / cond / while /
+    custom_vjp bodies) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for p in eqn.params.values():
+            for sub in _sub_jaxprs(p):
+                yield from _walk_eqns(sub)
+
+
+def _admission_span_tensors():
+    """(arrays as large as the old score tensor, gathers of a whole span a
+    row) in the jaxpr of admit_slots_paged at (A, P) = (4, 64), span 1024."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as D
+
+    params, cfg = _tiny()
+    A, P, bs, MB = 4, 64, 16, 64
+    span = MB * bs
+    cache = D.init_paged_cache(cfg, A, A * MB + 1, bs)
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    jaxpr = jax.make_jaxpr(
+        lambda *a: D.admit_slots_paged(*a, cfg, sampled=False))(
+        params, i32(A, P), i32(A), i32(A), i32(A), i32(A), jnp.zeros(A, jnp.uint32),
+        cache, i32(A), i32(A, MB), jnp.zeros(A, jnp.float32), i32(A),
+        jnp.ones(A, jnp.float32), i32(A, 4))
+    scores = A * cfg.n_heads * P * span
+    span_ctx = A * span * cfg.n_kv_heads * cfg.head_dim
+    big, gathers = [], []
+    for eqn in _walk_eqns(jaxpr.jaxpr):
+        for var in eqn.outvars:
+            shape = tuple(getattr(var.aval, "shape", ()))
+            if int(np.prod(shape)) >= scores:
+                big.append((eqn.primitive.name, shape))
+            if eqn.primitive.name == "gather" and int(np.prod(shape)) >= span_ctx:
+                gathers.append(shape)
+    return big, gathers
+
+
+def test_admission_lowering_has_no_span_tensor(monkeypatch):
+    """Lint: admission neither builds an array with as many elements as
+    the (A, heads, P, span) scores nor gathers a whole table span a row
+    out of the pool, so the tensor PR 28 removed cannot come back
+    unnoticed. The one-shot reference must trip both detectors."""
+    from ray_tpu.models import llama_decode as D
+
+    big, gathers = _admission_span_tensors()
+    assert not big, f"admission materializes {big}"
+    assert not gathers, f"admission gathers the table span: {gathers}"
+    monkeypatch.setattr(D, "_attend_admission", _one_shot_attention)
+    big, gathers = _admission_span_tensors()
+    assert big and gathers, "the lint failed to flag the one-shot formulation"
+
+
 # ------------------------------------------------- engine-level behavior
 def test_paged_engine_matches_dense_engine_greedy():
     """The paged engine is a pure memory-architecture change for greedy

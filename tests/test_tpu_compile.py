@@ -126,3 +126,31 @@ def test_flash_attention_under_fsdp_tp_compiles_for_four_chips(topo, monkeypatch
     compiled = lowered.compile()
     # the shards' shapes, not the global ones, reach the kernel
     assert "bf16[16,512,128]" in compiled.as_text()
+
+
+# (A, P): the widest admission of the serve cells, and the one-block prompt
+# of the dispatch that admits nothing, whose Pallas block is (16, 128)
+@pytest.mark.parametrize("A,P", [(4, 1024), (1, 16)])
+def test_paged_admission_attention_compiles(one_chip, monkeypatch, A, P):
+    """The admission's attention at the serve cell's widths (32 / 8 heads of
+    128, blocks of 16, a table span of 4096): one Pallas forward for the
+    suffix, and no temporary near the 2.1 GB of (A, 32, P, span) f32 scores
+    the one-shot formulation built at (4, 1024)."""
+    from ray_tpu.models import llama_decode as D
+
+    monkeypatch.setattr(FA, "_on_tpu", lambda: True)
+    cfg = LlamaConfig(vocab_size=32768, d_model=4096, n_layers=1, n_heads=32,
+                      n_kv_heads=8, d_ff=14336, max_seq_len=4096, dtype=jnp.bfloat16)
+    bs, MB = 16, 256
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, kv = arr((A, P, 32, 128)), arr((A, P, 8, 128))
+    pool = arr((4 * MB + 1, bs, 8, 128))
+    lowered = jax.jit(functools.partial(D._attend_admission, cfg=cfg)).lower(
+        q, kv, kv, pool, pool, arr((A, MB), jnp.int32), arr((A,), jnp.int32))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    one_shot_scores = 4 * 32 * 1024 * 4096 * 4  # bytes, f32, at (4, 1024)
+    temp = lowered.compile().memory_analysis().temp_size_in_bytes
+    assert temp < one_shot_scores // 4, temp
