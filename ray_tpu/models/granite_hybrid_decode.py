@@ -1,0 +1,259 @@
+"""The hybrid decoder on the paged serving path: a lane holds recurrent
+state beside its K/V blocks.
+
+The macro-step is models/llama_decode.macro_step_slots_paged, handed this
+module's admission and decode step and this module's cache pytree:
+
+  k, v      (attention layers, n_blocks, bs, kvh * hd)  the block pool, for
+            the few attention layers only; tables are host state as ever.
+            Heads and head size share the minor axis: a minor axis of 64
+            would be padded to 128 on a TPU, twice the pool
+  conv      (Mamba layers, taps - 1, lanes, conv_dim)  each lane's conv tail:
+            the last taps - 1 inputs of the depthwise conv, activation type
+            (lanes on the second-minor axis, not the 3 taps: the same padding)
+  ssm       (Mamba layers, lanes, H, P, N) float32     each lane's SSM state
+  pos, remaining, rng                                   per-lane scalars
+
+Admission computes a row's conv tail and final state from zero and writes
+them to the row's lane (a padded admission row writes nothing); the decode
+step updates the lanes that are active and leaves the others bit for bit
+alone; release needs no device work, the next admission overwrites the row.
+
+Padding is not harmless in a recurrence: past a row's length the step size
+is zeroed (decay 1, input 0), the conv tail is taken from the last real
+positions, and the head is applied at the last real position only.
+
+A lane's state at a block boundary is not kept, so nothing here can resume a
+sequence from blocks alone: there is no gather / import / scatter of blocks
+and no speculative round (serve/llm_engine.py refuses what needs them when
+`state_bytes_per_lane` is not 0).
+"""
+from __future__ import annotations
+
+import functools
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.models import granite_hybrid as G
+from ray_tpu.models import llama_decode as L
+from ray_tpu.models.granite_hybrid import ATTENTION, MAMBA, GraniteHybridConfig
+
+
+def init_paged_cache(cfg: GraniteHybridConfig, n_slots: int, n_blocks: int,
+                     block_size: int) -> Dict[str, Any]:
+    pool = (cfg.n_attn_layers, n_blocks, block_size, cfg.n_kv_heads * cfg.head_dim)
+    return {
+        "k": jnp.zeros(pool, cfg.dtype),
+        "v": jnp.zeros(pool, cfg.dtype),
+        "conv": jnp.zeros((cfg.n_mamba_layers, cfg.mamba_d_conv - 1, n_slots, cfg.conv_dim),
+                          cfg.dtype),
+        "ssm": jnp.zeros((cfg.n_mamba_layers, n_slots, cfg.mamba_n_heads, cfg.mamba_d_head,
+                          cfg.mamba_d_state), jnp.float32),
+        "pos": jnp.zeros((n_slots,), jnp.int32),
+        "remaining": jnp.zeros((n_slots,), jnp.int32),
+        "rng": jnp.zeros((n_slots, 2), jnp.uint32),
+    }
+
+
+def state_bytes_per_lane(cfg: GraniteHybridConfig) -> int:
+    """Bytes of recurrent state a lane holds beside its K/V blocks: the conv
+    tail and the float32 SSM state of every Mamba layer."""
+    conv = (cfg.mamba_d_conv - 1) * cfg.conv_dim * jnp.dtype(cfg.dtype).itemsize
+    ssm = cfg.mamba_n_heads * cfg.mamba_d_head * cfg.mamba_d_state * 4
+    return cfg.n_mamba_layers * (conv + ssm)
+
+
+def _write_lane_rows(full, li, rows, slots, valid, lane_axis: int = 1):
+    """full[li, ..., slots[n], ...] = rows[n] for the valid rows (lanes on
+    `lane_axis` of `full`), one in-place update a row; invalid rows all
+    name lane 0 and write nothing."""
+    rest = rows.shape[1:]
+    shape = (1,) + rest[:lane_axis - 1] + (1,) + rest[lane_axis - 1:]
+
+    def write(n, full):
+        def wr(full):
+            row = jax.lax.dynamic_index_in_dim(rows, n, 0, keepdims=False)
+            at = [0] * full.ndim
+            at[0], at[lane_axis] = li, slots[n]
+            return jax.lax.dynamic_update_slice(
+                full, row.reshape(shape).astype(full.dtype), at)
+
+        return jax.lax.cond(valid[n], wr, lambda full: full, full)
+
+    return jax.lax.fori_loop(0, rows.shape[0], write, full)
+
+
+def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
+                      cache, feed, tables, temps, top_ks, top_ps, stop_ids,
+                      cfg: GraniteHybridConfig, sampled: bool = True):
+    """Fused paged admission of A right-padded prompts (A, P), with
+    llama_decode.admit_slots_paged's arguments and returns. `starts` is all
+    zeros here: without the state at a block boundary no prefix is reused."""
+    A, P = prompts.shape
+    adm_tables = tables[slots]
+    valid = lengths > 0
+
+    def mamba_mixer(layer, mi, a, carry):
+        k_full, v_full, conv, ssm = carry
+        out, tail, h = G.mamba_sequence(layer, a, lengths, cfg)
+        conv = _write_lane_rows(conv, mi, tail, slots, valid, lane_axis=2)
+        ssm = _write_lane_rows(ssm, mi, h, slots, valid)
+        return out, (k_full, v_full, conv, ssm)
+
+    def attn_mixer(layer, ai, a, carry):
+        k_full, v_full, conv, ssm = carry
+        with jax.named_scope(G.SCOPE_ATTN):
+            q, k, v = G.qkv(layer, a, cfg)
+            k_full, v_full = L.write_admission_kv(
+                k_full, v_full, ai, k.reshape(A, P, -1), v.reshape(A, P, -1),
+                adm_tables, starts, valid)
+            out = G.causal_attention(q, k, v, cfg) @ layer["wo"]
+        return out, (k_full, v_full, conv, ssm)
+
+    x, (k_full, v_full, conv, ssm) = G.run_layers(
+        params, G.embed_tokens(params, prompts, cfg),
+        (cache["k"], cache["v"], cache["conv"], cache["ssm"]), cfg,
+        {MAMBA: mamba_mixer, ATTENTION: attn_mixer})
+    # the head at each row's last real position only: all P positions in
+    # float32 over this vocabulary would be gigabytes
+    x_last = jnp.take_along_axis(
+        x, (jnp.maximum(lengths, 1) - 1)[:, None, None], axis=1)[:, 0, :]
+    first, pos, rem, feed, rng = L.finish_admission(
+        G.logits_of(params, x_last, cfg), cache, feed, valid, lengths, starts,
+        slots, rems, seeds, temps, top_ks, top_ps, stop_ids, sampled)
+    cache = {"k": k_full, "v": v_full, "conv": conv, "ssm": ssm,
+             "pos": pos, "remaining": rem, "rng": rng}
+    return first, cache, feed
+
+
+def _attend_flat(q, ctx_k, ctx_v, pos, cfg: GraniteHybridConfig):
+    """One query a lane against its gathered context, on the pool's own
+    layout: q (B, h, hd), ctx_k / ctx_v (B, S, kvh * hd), lane b attends
+    positions [0, pos_b]. Splitting the contexts' minor axis into heads
+    would relayout them (0.65 ms a tensor at 32 x 4096 x 512, measured), so
+    the QUERY is laid out flat instead: each query head's vector in its KV
+    head's columns, zeros elsewhere, and the products run over all kvh * hd
+    columns (kvh times the operations, on a context this size nothing).
+    Returns (B, h * hd)."""
+    B, h, hd = q.shape
+    kvh = cfg.n_kv_heads
+    S = ctx_k.shape[1]
+    own = jnp.eye(kvh, dtype=q.dtype)[None, :, None, :, None]  # head k's columns
+    q_flat = (q.reshape(B, kvh, h // kvh, 1, hd) * own).reshape(B, h, kvh * hd)
+    scores = jnp.einsum("bhc,bsc->bhs", q_flat, ctx_k,
+                        preferred_element_type=jnp.float32) * cfg.attention_multiplier
+    live = jnp.arange(S)[None, None, :] <= pos[:, None, None]
+    probs = jax.nn.softmax(jnp.where(live, scores, -jnp.inf), axis=-1)
+    o_flat = jnp.einsum("bhs,bsc->bhc", probs.astype(ctx_v.dtype), ctx_v,
+                        preferred_element_type=jnp.float32)
+    o = (o_flat.reshape(B, kvh, h // kvh, kvh, hd) * own.astype(jnp.float32)).sum(axis=3)
+    return o.reshape(B, h * hd).astype(cfg.dtype)
+
+
+def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
+                            top_ps, stop_ids, cfg: GraniteHybridConfig,
+                            sampled: bool = True):
+    """One token on every lane, with llama_decode.decode_step_slots_paged's
+    arguments and returns. An inactive lane (remaining == 0) keeps its conv
+    tail and state as they are and aims its K/V write at the null block."""
+    B = tokens.shape[0]
+    pos = cache["pos"]
+    active = cache["remaining"] > 0
+
+    def mamba_mixer(layer, mi, a, carry):
+        k_full, v_full, conv, ssm = carry
+        tail = jax.lax.dynamic_index_in_dim(conv, mi, 0, keepdims=False)
+        h = jax.lax.dynamic_index_in_dim(ssm, mi, 0, keepdims=False)
+        out, new_tail, new_h = G.mamba_token(layer, a, tail, h, cfg)
+        with jax.named_scope(G.SCOPE_UPDATE):
+            new_tail = jnp.where(active[None, :, None], new_tail, tail)
+            new_h = jnp.where(active[:, None, None, None], new_h, h)
+            conv = jax.lax.dynamic_update_index_in_dim(conv, new_tail, mi, 0)
+            ssm = jax.lax.dynamic_update_index_in_dim(ssm, new_h, mi, 0)
+        return out, (k_full, v_full, conv, ssm)
+
+    def attn_mixer(layer, ai, a, carry):
+        k_full, v_full, conv, ssm = carry
+        with jax.named_scope(G.SCOPE_ATTN):
+            q, k, v = G.qkv(layer, a[:, None, :], cfg)
+            k_full, v_full = L.write_decode_kv(
+                k_full, v_full, ai, k.reshape(B, 1, -1), v.reshape(B, 1, -1),
+                tables, pos, active)
+            # each lane's context straight out of layer `ai` of the pool
+            # (slicing the layer off first copies it whole, every step)
+            ctx_k = k_full[ai, tables].reshape(B, -1, k_full.shape[-1])
+            ctx_v = v_full[ai, tables].reshape(B, -1, v_full.shape[-1])
+            out = _attend_flat(q[:, 0], ctx_k, ctx_v, pos, cfg) @ layer["wo"]
+        return out, (k_full, v_full, conv, ssm)
+
+    x, (k_full, v_full, conv, ssm) = G.run_layers(
+        params, G.embed_tokens(params, tokens, cfg),
+        (cache["k"], cache["v"], cache["conv"], cache["ssm"]), cfg,
+        {MAMBA: mamba_mixer, ATTENTION: attn_mixer})
+    logits = G.logits_of(params, x, cfg)
+    nxt, new_pos, remaining, rng = L.finish_decode_step(
+        logits, cache, active, temps, top_ks, top_ps, stop_ids, sampled)
+    cache = {"k": k_full, "v": v_full, "conv": conv, "ssm": ssm,
+             "pos": new_pos, "remaining": remaining, "rng": rng}
+    return logits, nxt, cache
+
+
+@functools.lru_cache(maxsize=16)
+def jitted_macro_step_slots_paged(cfg: GraniteHybridConfig, chunk: int,
+                                  sampled: bool = True):
+    """llama_decode's macro-step skeleton with this model's two halves;
+    the program keeps the skeleton's name."""
+    return jax.jit(
+        L._bind(L.macro_step_slots_paged, chunk=chunk, cfg=cfg, sampled=sampled,
+                admit=admit_slots_paged, decode_step=decode_step_slots_paged),
+        donate_argnums=(1,),
+    )
+
+
+# ------------------------------------------------------- static generation
+_BLOCK = 16
+
+
+def _generate(params, prompt, cfg: GraniteHybridConfig, n_new: int):
+    """Greedy tokens (R, n_new) for prompts (R, T) of one length: one
+    admission and n_new - 1 decode steps through a paged cache that holds
+    exactly these rows."""
+    R, T = prompt.shape
+    mb = -(-(T + n_new) // _BLOCK)
+    P = -(-T // _BLOCK) * _BLOCK
+    cache = init_paged_cache(cfg, R, R * mb + 1, _BLOCK)
+    tables = 1 + jnp.arange(R * mb, dtype=jnp.int32).reshape(R, mb)
+    zeros = jnp.zeros((R,), jnp.int32)
+    plan = dict(temps=jnp.zeros((R,), jnp.float32), top_ks=zeros,
+                top_ps=jnp.ones((R,), jnp.float32),
+                stop_ids=jnp.full((R, 1), -1, jnp.int32))
+    first, cache, feed = admit_slots_paged(
+        params, jnp.pad(prompt, ((0, 0), (0, P - T))), jnp.full((R,), T, jnp.int32), zeros,
+        jnp.arange(R, dtype=jnp.int32), jnp.full((R,), n_new - 1, jnp.int32),
+        zeros.astype(jnp.uint32), cache, zeros, tables, cfg=cfg, sampled=False, **plan)
+
+    def step(carry, _):
+        cache, feed = carry
+        _, nxt, cache = decode_step_slots_paged(
+            params, cache, feed, tables, cfg=cfg, sampled=False, **plan)
+        return (cache, nxt), nxt
+
+    _, rest = jax.lax.scan(step, (cache, feed), None, length=n_new - 1)
+    return jnp.concatenate([first[:, None], rest.T], axis=1)
+
+
+@functools.lru_cache(maxsize=64)
+def _jitted_generate(cfg: GraniteHybridConfig, n_new: int):
+    return jax.jit(L._bind(_generate, cfg=cfg, n_new=n_new))
+
+
+def generate(params, prompt, cfg: GraniteHybridConfig, max_new_tokens: int):
+    """Greedy static generation: prompt (R, T) int32 -> (R, max_new_tokens)
+    int32, one device program."""
+    prompt = jnp.asarray(prompt, jnp.int32)
+    if prompt.shape[1] == 0:
+        raise ValueError("generate() requires a non-empty prompt")
+    return np.asarray(_jitted_generate(cfg, max_new_tokens)(params, prompt))

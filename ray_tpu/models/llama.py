@@ -64,6 +64,20 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    # the modules that run this config: what serve/ asks of any config
+    # object instead of naming a model (imported when asked for)
+    @property
+    def model_module(self):
+        from ray_tpu.models import llama
+
+        return llama
+
+    @property
+    def decode_module(self):
+        from ray_tpu.models import llama_decode
+
+        return llama_decode
+
     @staticmethod
     def llama2_7b(**kw) -> "LlamaConfig":
         return LlamaConfig(**{**dict(

@@ -182,15 +182,16 @@ def init_slot_cache(cfg: LlamaConfig, n_slots: int, max_len: int) -> Dict[str, A
     }
 
 
-def _gqa_attend_slots(q, k_cache, v_cache, pos, cfg: LlamaConfig):
+def _gqa_attend_slots(q, k_cache, v_cache, pos, cfg, scale=None):
     """Per-slot positions: q (B, 1, h, hd), pos (B,) — slot b attends
-    its own [0, pos_b] prefix."""
+    its own [0, pos_b] prefix. `scale` multiplies the scores (hd**-0.5
+    where a model states none)."""
     B, _, h, hd = q.shape
     S = k_cache.shape[1]
     qg = q.reshape(B, cfg.n_kv_heads, h // cfg.n_kv_heads, hd)
     scores = jnp.einsum(
         "bkgd,bskd->bkgs", qg, k_cache, preferred_element_type=jnp.float32
-    ) * (hd**-0.5)
+    ) * (hd**-0.5 if scale is None else scale)
     mask = jnp.arange(S)[None, None, None, :] <= pos[:, None, None, None]
     scores = jnp.where(mask, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -492,6 +493,14 @@ def init_paged_cache(cfg: LlamaConfig, n_slots: int, n_blocks: int,
     }
 
 
+def state_bytes_per_lane(cfg: LlamaConfig) -> int:
+    """Bytes of per-lane recurrent state beside the K/V blocks: none, a
+    lane's whole state is its block table. A decode module whose answer
+    is not 0 makes the engine refuse what needs a state snapshot (prefix
+    reuse, speculation's rollback, migration)."""
+    return 0
+
+
 def copy_kv_blocks(cache: Dict[str, Any], src, dst) -> Dict[str, Any]:
     """Copy-on-write block copies: rows dst[i] <- src[i] across every
     layer, K and V. src/dst are (N,) i32 block ids (host-planned by
@@ -589,6 +598,51 @@ def _gather_block_ctx(k_layer, v_layer, tables):
     return ctx_k, ctx_v
 
 
+def write_decode_kv(k_full, v_full, li, k, v, tables, pos, active):
+    """One decode step's K/V (B, 1, *row) into layer `li` of a pool
+    (L, n_blocks, bs, *row): per-slot write into the slot's CURRENT
+    block at its own offset (same sequential-DMA trick as the dense
+    path: the advanced-index scatter form measured ~25 ms/step on TPU).
+    Inactive lanes write the null block."""
+    B = k.shape[0]
+    bs = k_full.shape[2]
+    row0 = (0,) * (k_full.ndim - 3)
+
+    def write_slot(b, kv):
+        kf, vf = kv
+        kb = jax.lax.dynamic_slice_in_dim(k, b, 1, axis=0)[None]
+        vb = jax.lax.dynamic_slice_in_dim(v, b, 1, axis=0)[None]
+        pb = jax.lax.dynamic_index_in_dim(pos, b, keepdims=False)
+        ab = jax.lax.dynamic_index_in_dim(active, b, keepdims=False)
+        row = jax.lax.dynamic_index_in_dim(tables, b, 0, keepdims=False)
+        blk = jax.lax.dynamic_index_in_dim(row, pb // bs, keepdims=False)
+        blk = jnp.where(ab, blk, 0)  # inactive lanes write the null block
+        off = jnp.where(ab, pb % bs, 0)
+        kf = jax.lax.dynamic_update_slice(kf, kb, (li, blk, off) + row0)
+        vf = jax.lax.dynamic_update_slice(vf, vb, (li, blk, off) + row0)
+        return kf, vf
+
+    return jax.lax.fori_loop(0, B, write_slot, (k_full, v_full))
+
+
+def finish_decode_step(logits, cache, active, temps, top_ks, top_ps, stop_ids,
+                       sampled: bool):
+    """What every model's paged decode step ends with: the next token
+    of each lane from its logits (B, V) f32 and the per-slot scalars
+    after the step (`active` = remaining > 0 before it).
+    Returns (next tokens, pos, remaining, rng)."""
+    if sampled:
+        new_rng, sub = _split_slot_keys(cache["rng"])
+        nxt = sample_tokens(logits, temps, top_ks, top_ps, sub)
+    else:
+        new_rng = cache["rng"]
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    stopped = jnp.any(nxt[:, None] == stop_ids, axis=-1) & active
+    pos = cache["pos"] + active.astype(jnp.int32)
+    remaining = jnp.where(stopped, 0, jnp.maximum(cache["remaining"] - 1, 0))
+    return nxt, pos, remaining, new_rng
+
+
 def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
                             top_ps, stop_ids, cfg: LlamaConfig,
                             sampled: bool = True):
@@ -627,24 +681,8 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
 
-        # per-slot write into the slot's CURRENT block at its own
-        # offset (same sequential-DMA trick as the dense path: the
-        # advanced-index scatter form measured ~25 ms/step on TPU)
-        def write_slot(b, kv):
-            kf, vf = kv
-            kb = jax.lax.dynamic_slice_in_dim(k, b, 1, axis=0)[None]
-            vb = jax.lax.dynamic_slice_in_dim(v, b, 1, axis=0)[None]
-            pb = jax.lax.dynamic_index_in_dim(pos, b, keepdims=False)
-            ab = jax.lax.dynamic_index_in_dim(active, b, keepdims=False)
-            row = jax.lax.dynamic_index_in_dim(tables, b, 0, keepdims=False)
-            blk = jax.lax.dynamic_index_in_dim(row, pb // bs, keepdims=False)
-            blk = jnp.where(ab, blk, 0)  # inactive lanes write the null block
-            off = jnp.where(ab, pb % bs, 0)
-            kf = jax.lax.dynamic_update_slice(kf, kb, (li, blk, off, 0, 0))
-            vf = jax.lax.dynamic_update_slice(vf, vb, (li, blk, off, 0, 0))
-            return kf, vf
-
-        k_full, v_full = jax.lax.fori_loop(0, B, write_slot, (k_full, v_full))
+        k_full, v_full = write_decode_kv(
+            k_full, v_full, li, k, v, tables, pos, active)
         k_layer = jax.lax.dynamic_index_in_dim(k_full, li, 0, keepdims=False)
         v_layer = jax.lax.dynamic_index_in_dim(v_full, li, 0, keepdims=False)
         ctx_k, ctx_v = _gather_block_ctx(k_layer, v_layer, tables)
@@ -663,22 +701,10 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
     )
     x = rms_norm(x[:, 0, :], params["final_norm"], cfg.rms_eps)
     logits = x.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
-    if sampled:
-        new_rng, sub = _split_slot_keys(cache["rng"])
-        nxt = sample_tokens(logits, temps, top_ks, top_ps, sub)
-    else:
-        new_rng = cache["rng"]
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    stopped = jnp.any(nxt[:, None] == stop_ids, axis=-1) & active
-    new_cache = {
-        "k": new_k,
-        "v": new_v,
-        "pos": pos + active.astype(jnp.int32),
-        "remaining": jnp.where(
-            stopped, 0, jnp.maximum(cache["remaining"] - 1, 0)
-        ),
-        "rng": new_rng,
-    }
+    nxt, new_pos, remaining, new_rng = finish_decode_step(
+        logits, cache, active, temps, top_ks, top_ps, stop_ids, sampled)
+    new_cache = {"k": new_k, "v": new_v, "pos": new_pos,
+                 "remaining": remaining, "rng": new_rng}
     return logits, nxt, new_cache
 
 
@@ -787,105 +813,58 @@ def _attend_admission(q, k, v, k_layer, v_layer, adm_tables, starts,
     return out.reshape(A, P, h * hd).astype(cfg.dtype)
 
 
-def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
-                      cache, feed, tables, temps, top_ks, top_ps, stop_ids,
-                      cfg: LlamaConfig, sampled: bool = True):
-    """Fused PAGED admission: prefill A right-padded SUFFIXES (A, P) —
-    `prompts` holds only the tokens after each row's cached prefix of
-    `starts[n]` tokens (block-aligned; 0 for a cache miss) — and land
-    rows with length > 0 in their target `slots`. The radix-prefix-hit
-    prefill skip happens exactly here: reused blocks are never
-    recomputed, the suffix attends to them read-only through the slot's
-    block table. P must be a multiple of block_size.
-
-    Per layer the body writes EVERY row's suffix K/V before ANY row
-    reads a prefix from the pool, so two same-phase admissions sharing a
-    prefix (the second's table naming blocks the first is filling right
-    now) stay correct: plan order == write order <= read order. The
-    attention (_attend_admission) is causal over the row's own suffix
-    plus a loop over its prefix blocks: its work follows starts[n] +
-    lengths[n], never the table span. Right-pad columns
-    write into the slot's own reserved (beyond-pos) cells or, past the
-    table's edge, the null block. Each row's first output token is
-    SAMPLED from its true-last-position logits with a key seeded from
-    `seeds[n]`; the carried key lands in the slot's rng state.
-    Returns (first tokens (A,), cache, feed)."""
-    A, P = prompts.shape
-    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    bs = cache["k"].shape[2]
-    MB = tables.shape[1]
-    S = MB * bs
+def write_admission_kv(k_full, v_full, li, k, v, adm_tables, starts, valid):
+    """Every valid admission row's K/V (A, P, *row) into layer `li` of
+    a pool (L, n_blocks, bs, *row), block by block from block
+    starts[n] // bs of the row's table; blocks past the table's edge go
+    to the null block."""
+    A, P = k.shape[:2]
+    row = k.shape[2:]
+    row0 = (0,) * len(row)
+    bs = k_full.shape[2]
+    MB = adm_tables.shape[1]
     n_chunks = P // bs
-    adm_tables = tables[slots]  # (A, MB)
-    valid = lengths > 0
-    x = params["embed"][prompts].astype(cfg.dtype)
-    cos, sin = rope_frequencies(hd, S, cfg.rope_theta)
-    positions = starts[:, None] + jnp.broadcast_to(
-        jnp.arange(P, dtype=jnp.int32)[None, :], (A, P)
-    )
 
-    def body(carry, layer_and_idx):
-        x, k_full, v_full = carry
-        layer, li = layer_and_idx
-        a = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q = (a @ layer["wq"]).reshape(A, P, h, hd)
-        k = (a @ layer["wk"]).reshape(A, P, kvh, hd)
-        v = (a @ layer["wv"]).reshape(A, P, kvh, hd)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
+    def write_row(n, kv):
+        def wr(kv):
+            s0 = jax.lax.dynamic_index_in_dim(starts, n, keepdims=False) // bs
+            table = jax.lax.dynamic_index_in_dim(adm_tables, n, 0, keepdims=False)
 
-        # phase 1: write all rows' suffix K/V block by block
-        def write_row(n, kv):
-            def wr(kv):
-                s0 = jax.lax.dynamic_index_in_dim(starts, n, keepdims=False) // bs
-                row = jax.lax.dynamic_index_in_dim(adm_tables, n, 0, keepdims=False)
+            def write_block(j, kv):
+                kf, vf = kv
+                idx = s0 + j
+                blk = jax.lax.dynamic_index_in_dim(
+                    table, jnp.minimum(idx, MB - 1), keepdims=False
+                )
+                blk = jnp.where(idx < MB, blk, 0)  # pad overshoot -> null
+                kc = jax.lax.dynamic_slice(
+                    k, (n, j * bs) + row0, (1, bs) + row)[0][None, None]
+                vc = jax.lax.dynamic_slice(
+                    v, (n, j * bs) + row0, (1, bs) + row)[0][None, None]
+                kf = jax.lax.dynamic_update_slice(kf, kc, (li, blk, 0) + row0)
+                vf = jax.lax.dynamic_update_slice(vf, vc, (li, blk, 0) + row0)
+                return kf, vf
 
-                def write_block(j, kv):
-                    kf, vf = kv
-                    idx = s0 + j
-                    blk = jax.lax.dynamic_index_in_dim(
-                        row, jnp.minimum(idx, MB - 1), keepdims=False
-                    )
-                    blk = jnp.where(idx < MB, blk, 0)  # pad overshoot -> null
-                    kc = jax.lax.dynamic_slice(
-                        k, (n, j * bs, 0, 0), (1, bs, kvh, hd))[0][None, None]
-                    vc = jax.lax.dynamic_slice(
-                        v, (n, j * bs, 0, 0), (1, bs, kvh, hd))[0][None, None]
-                    kf = jax.lax.dynamic_update_slice(kf, kc, (li, blk, 0, 0, 0))
-                    vf = jax.lax.dynamic_update_slice(vf, vc, (li, blk, 0, 0, 0))
-                    return kf, vf
+            # a loop, eight blocks an iteration: spelled out as P // bs
+            # blocks in Python, the (4, 1024) program at 16 layers took
+            # twice as long to lower, to compile (78 s against 32 on a
+            # v5e host, PR 28) and to load from the compile cache
+            return jax.lax.fori_loop(0, n_chunks, write_block, kv,
+                                     unroll=min(8, n_chunks))
 
-                # a loop, eight blocks an iteration: spelled out as P // bs
-                # blocks in Python, the (4, 1024) program at 16 layers took
-                # twice as long to lower, to compile (78 s against 32 on a
-                # v5e host, PR 28) and to load from the compile cache
-                return jax.lax.fori_loop(0, n_chunks, write_block, kv,
-                                         unroll=min(8, n_chunks))
+        return jax.lax.cond(valid[n], wr, lambda kv: kv, kv)
 
-            return jax.lax.cond(valid[n], wr, lambda kv: kv, kv)
+    return jax.lax.fori_loop(0, A, write_row, (k_full, v_full))
 
-        k_full, v_full = jax.lax.fori_loop(0, A, write_row, (k_full, v_full))
-        # phase 2: every row reads its prefix (sees all phase-1 writes)
-        k_layer = jax.lax.dynamic_index_in_dim(k_full, li, 0, keepdims=False)
-        v_layer = jax.lax.dynamic_index_in_dim(v_full, li, 0, keepdims=False)
-        o = _attend_admission(q, k, v, k_layer, v_layer, adm_tables, starts, cfg)
-        x = x + o @ layer["wo"]
-        m = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
-        gate = jax.nn.silu((m @ layer["w_gate"]).astype(jnp.float32)).astype(cfg.dtype)
-        x = x + (gate * (m @ layer["w_up"])) @ layer["w_down"]
-        return (x, k_full, v_full), None
 
-    (x, k_big, v_big), _ = jax.lax.scan(
-        body,
-        (x, cache["k"], cache["v"]),
-        (params["layers"], jnp.arange(cfg.n_layers)),
-        unroll=True,
-    )
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits_all = x.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
-    last = jnp.take_along_axis(
-        logits_all, (jnp.maximum(lengths, 1) - 1)[:, None, None], axis=1
-    )[:, 0, :]
+def finish_admission(last, cache, feed, valid, lengths, starts, slots, rems,
+                     seeds, temps, top_ks, top_ps, stop_ids, sampled: bool):
+    """What every model's paged admission ends with: each row's first
+    output token from its true-last-position logits `last` (A, V) f32
+    (sampled with a key seeded from `seeds[n]`), and the per-slot
+    scalars armed for the `valid` rows (length > 0). Returns (first
+    tokens, pos, remaining, feed, rng)."""
+    A = last.shape[0]
     if sampled:
         row_keys = jax.vmap(jax.random.PRNGKey)(seeds)
         carried, sub = _split_slot_keys(row_keys)
@@ -914,6 +893,80 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
         0, A, write_one,
         (cache["pos"], cache["remaining"], feed, cache["rng"]),
     )
+    return first, pos, rem, feed, rng
+
+
+def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
+                      cache, feed, tables, temps, top_ks, top_ps, stop_ids,
+                      cfg: LlamaConfig, sampled: bool = True):
+    """Fused PAGED admission: prefill A right-padded SUFFIXES (A, P) —
+    `prompts` holds only the tokens after each row's cached prefix of
+    `starts[n]` tokens (block-aligned; 0 for a cache miss) — and land
+    rows with length > 0 in their target `slots`. The radix-prefix-hit
+    prefill skip happens exactly here: reused blocks are never
+    recomputed, the suffix attends to them read-only through the slot's
+    block table. P must be a multiple of block_size.
+
+    Per layer the body writes EVERY row's suffix K/V before ANY row
+    reads a prefix from the pool, so two same-phase admissions sharing a
+    prefix (the second's table naming blocks the first is filling right
+    now) stay correct: plan order == write order <= read order. The
+    attention (_attend_admission) is causal over the row's own suffix
+    plus a loop over its prefix blocks: its work follows starts[n] +
+    lengths[n], never the table span. Right-pad columns
+    write into the slot's own reserved (beyond-pos) cells or, past the
+    table's edge, the null block. Each row's first output token is
+    SAMPLED from its true-last-position logits with a key seeded from
+    `seeds[n]`; the carried key lands in the slot's rng state.
+    Returns (first tokens (A,), cache, feed)."""
+    A, P = prompts.shape
+    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    S = tables.shape[1] * cache["k"].shape[2]
+    adm_tables = tables[slots]  # (A, MB)
+    valid = lengths > 0
+    x = params["embed"][prompts].astype(cfg.dtype)
+    cos, sin = rope_frequencies(hd, S, cfg.rope_theta)
+    positions = starts[:, None] + jnp.broadcast_to(
+        jnp.arange(P, dtype=jnp.int32)[None, :], (A, P)
+    )
+
+    def body(carry, layer_and_idx):
+        x, k_full, v_full = carry
+        layer, li = layer_and_idx
+        a = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+        q = (a @ layer["wq"]).reshape(A, P, h, hd)
+        k = (a @ layer["wk"]).reshape(A, P, kvh, hd)
+        v = (a @ layer["wv"]).reshape(A, P, kvh, hd)
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
+
+        # phase 1: write all rows' suffix K/V block by block
+        k_full, v_full = write_admission_kv(
+            k_full, v_full, li, k, v, adm_tables, starts, valid)
+        # phase 2: every row reads its prefix (sees all phase-1 writes)
+        k_layer = jax.lax.dynamic_index_in_dim(k_full, li, 0, keepdims=False)
+        v_layer = jax.lax.dynamic_index_in_dim(v_full, li, 0, keepdims=False)
+        o = _attend_admission(q, k, v, k_layer, v_layer, adm_tables, starts, cfg)
+        x = x + o @ layer["wo"]
+        m = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
+        gate = jax.nn.silu((m @ layer["w_gate"]).astype(jnp.float32)).astype(cfg.dtype)
+        x = x + (gate * (m @ layer["w_up"])) @ layer["w_down"]
+        return (x, k_full, v_full), None
+
+    (x, k_big, v_big), _ = jax.lax.scan(
+        body,
+        (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(cfg.n_layers)),
+        unroll=True,
+    )
+    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    logits_all = x.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
+    last = jnp.take_along_axis(
+        logits_all, (jnp.maximum(lengths, 1) - 1)[:, None, None], axis=1
+    )[:, 0, :]
+    first, pos, rem, feed, rng = finish_admission(
+        last, cache, feed, valid, lengths, starts, slots, rems, seeds, temps,
+        top_ks, top_ps, stop_ids, sampled)
     cache = {"k": k_big, "v": v_big, "pos": pos, "remaining": rem, "rng": rng}
     return first, cache, feed
 
@@ -921,9 +974,14 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
 def macro_step_slots_paged(params, cache, feed, steps, has_admit, prompts,
                            lengths, starts, slots, rems, seeds, tables, temps,
                            top_ks, top_ps, stop_ids, chunk: int,
-                           cfg: LlamaConfig, sampled: bool = True):
+                           cfg, sampled: bool = True, admit=None,
+                           decode_step=None):
     """Paged macro-step: the macro_step_slots plan shape extended with
-    the paged/sampling plan arrays, still ONE jitted dispatch. Extra
+    the paged/sampling plan arrays, still ONE jitted dispatch. The phase
+    and step skeleton is every model's: `admit` and `decode_step` are
+    the model's own admission and one-token step over its own cache
+    pytree, with the signatures of admit_slots_paged and
+    decode_step_slots_paged (the defaults, Llama's). Extra
     per-phase arrays (K phases, B slots, A admission lanes, MB table
     width, NS stop width):
       starts   (K, A)        cached-prefix length per admission row
@@ -948,6 +1006,8 @@ def macro_step_slots_paged(params, cache, feed, steps, has_admit, prompts,
     pipeline. Returns (toks (K, chunk, B), firsts (K, A), feed,
     cache)."""
     A = prompts.shape[1]
+    admit = admit or admit_slots_paged
+    decode_step = decode_step or decode_step_slots_paged
 
     def phase(carry, xs):
         cache, feed = carry
@@ -957,7 +1017,7 @@ def macro_step_slots_paged(params, cache, feed, steps, has_admit, prompts,
         def do_admit(op):
             c, fd = op
             with jax.named_scope(ADMIT_SCOPE):
-                return admit_slots_paged(
+                return admit(
                     params, prompts_k, lengths_k, starts_k, slots_k, rems_k,
                     seeds_k, c, fd, tables_k, temps_k, topk_k, topp_k, stop_k,
                     cfg, sampled=sampled,
@@ -973,7 +1033,7 @@ def macro_step_slots_paged(params, cache, feed, steps, has_admit, prompts,
             def run(op):
                 cc, fd = op
                 with jax.named_scope(DECODE_SCOPE):
-                    _, nxt, cc = decode_step_slots_paged(
+                    _, nxt, cc = decode_step(
                         params, cc, fd, tables_k, temps_k, topk_k, topp_k,
                         stop_k, cfg, sampled=sampled,
                     )

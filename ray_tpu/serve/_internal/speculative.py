@@ -82,18 +82,21 @@ def resolve_draft_model(draft_model: Any, params, cfg) -> Tuple[Any, Any]:
             f"string draft_model must be 'self' or 'self:N', "
             f"got {draft_model!r}"
         )
-    from ray_tpu.models import llama
+
+    def is_config(obj) -> bool:
+        # a model config object names its own modules (models/*.py)
+        return hasattr(obj, "model_module")
 
     seed = 0
-    if isinstance(draft_model, llama.LlamaConfig):
+    if is_config(draft_model):
         draft_cfg = draft_model
         draft_params = None
     elif isinstance(draft_model, dict):
         body = dict(draft_model)
         draft_cfg = body.pop("cfg", None)
-        if not isinstance(draft_cfg, llama.LlamaConfig):
+        if not is_config(draft_cfg):
             raise ValueError(
-                "dict draft_model must carry a 'cfg' LlamaConfig "
+                "dict draft_model must carry a 'cfg' model config "
                 f"(got {type(draft_cfg).__name__})"
             )
         draft_params = body.pop("params", None)
@@ -107,7 +110,7 @@ def resolve_draft_model(draft_model: Any, params, cfg) -> Tuple[Any, Any]:
             draft_params = load_pytree_from_checkpoint(ckpt)
     else:
         raise ValueError(
-            "draft_model must be None, 'self', a LlamaConfig, or a dict "
+            "draft_model must be None, 'self', a model config, or a dict "
             f"(got {type(draft_model).__name__})"
         )
     if draft_cfg.vocab_size != cfg.vocab_size:
@@ -119,7 +122,8 @@ def resolve_draft_model(draft_model: Any, params, cfg) -> Tuple[Any, Any]:
     if draft_params is None:
         import jax
 
-        draft_params = llama.init_params(jax.random.PRNGKey(seed), draft_cfg)
+        draft_params = draft_cfg.model_module.init_params(
+            jax.random.PRNGKey(seed), draft_cfg)
     return draft_params, draft_cfg
 
 

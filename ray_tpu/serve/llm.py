@@ -88,14 +88,16 @@ class _LLMServer:
                  digest_prefix_len: int = 32):
         import jax
 
-        from ray_tpu.models import llama
-
         if pool is not None and not continuous:
             raise ValueError(
                 "pool roles require the continuous engine "
                 "(llm_deployment(continuous=True, pools=...))")
 
-        self.cfg = cfg or llama.LlamaConfig.tiny()
+        if cfg is None:
+            from ray_tpu.models.llama import LlamaConfig
+
+            cfg = LlamaConfig.tiny()
+        self.cfg = cfg
         if params is not None:
             self.params = params
         elif checkpoint_dir is not None:
@@ -103,7 +105,9 @@ class _LLMServer:
 
             self.params = load_pytree_from_checkpoint(checkpoint_dir)
         else:
-            self.params = llama.init_params(jax.random.PRNGKey(seed), self.cfg)
+            # the config's own model module (llama, granite_hybrid, ...)
+            self.params = cfg.model_module.init_params(
+                jax.random.PRNGKey(seed), cfg)
         self.max_new_tokens = max_new_tokens
         self.engine = None
         self.pool = pool
@@ -351,8 +355,6 @@ class _LLMServer:
 
     @batch(max_batch_size=32, batch_wait_timeout_s=0.02)
     def _generate(self, prompts: List[List[int]]) -> List[List[int]]:
-        from ray_tpu.models import llama_decode
-
         # group by prompt length: each group is one static-shape
         # prefill + one device-side decode scan
         groups: Dict[int, List[int]] = {}
@@ -361,7 +363,7 @@ class _LLMServer:
         out: List[Any] = [None] * len(prompts)
         for length, idxs in groups.items():
             arr = np.asarray([prompts[i] for i in idxs], np.int32)
-            toks = llama_decode.generate(
+            toks = self.cfg.decode_module.generate(
                 self.params, arr, self.cfg, max_new_tokens=self.max_new_tokens
             )
             for row, i in enumerate(idxs):

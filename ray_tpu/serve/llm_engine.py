@@ -5,8 +5,18 @@ inside replicas (continuous batching + paged KV); there is no TPU
 engine to wrap, so this is the green-field TPU-native equivalent
 (SURVEY §7 step 10). Design:
 
-- A fixed pool of KV-cache SLOTS (models/llama_decode.py per-slot
-  machinery): each slot is an independent sequence at its own position.
+- A fixed pool of SLOTS (lanes): each is an independent sequence at its
+  own position. What a lane holds is the model's: the decode module the
+  config object names (`cfg.decode_module`: models/llama_decode.py,
+  models/granite_hybrid_decode.py) owns the cache pytree and the two
+  halves of the macro-step. For an attention-only model a lane is a block
+  table into the K/V pool and a few scalars. For a model with recurrent
+  layers a lane ALSO owns a row of per-layer state (conv tail, SSM state)
+  that admission overwrites, the decode step updates while the lane is
+  live, and release abandons. Blocks alone cannot resume such a lane, so
+  whatever needs a state snapshot (prefix reuse, speculation's rollback,
+  migration and the cluster cache) is refused for it by name, with the
+  reason, rather than switched off.
 - PAGED KV (paged=True, the serving default path): KV memory is a
   global pool of fixed-size blocks instead of slots x max_len stripes —
   a host-side BlockAllocator (serve/_internal/kv_blocks.py) plans
@@ -73,6 +83,7 @@ legacy per-chunk loop survives behind macro_phases=0 for A/B testing.
 """
 from __future__ import annotations
 
+import gc
 import logging
 import queue
 import threading
@@ -296,7 +307,8 @@ def _suffix_len(req: "_Request") -> int:
     return len(req.prompt) - req._start
 
 
-def _dispatch_counts(phases: List[Dict[str, Any]]) -> Dict[str, int]:
+def _dispatch_counts(phases: List[Dict[str, Any]],
+                     recurrent: bool = False) -> Dict[str, int]:
     """What one macro dispatch carries, from the plan alone: the keyword
     arguments of its `engine.dispatch` span (host integers; nothing is
     read from the device). `_plan` leaves every request at its
@@ -306,7 +318,10 @@ def _dispatch_counts(phases: List[Dict[str, Any]]) -> Dict[str, int]:
     still runs after that token exists: what a finished answer waits on
     the device before the host can see it. A speculative plan holds
     estimates and decrements nothing, so only requests that owe no decode
-    step at admission count there."""
+    step at admission count there. `state_lanes` is the state rows the
+    dispatch's decode steps have to move: each live lane-step of a model
+    whose lanes hold recurrent state (`recurrent`); no other model's
+    dispatch carries the key."""
     total = sum(ph["steps"] for ph in phases)
     done = 0  # decode steps of this dispatch run so far
     last: Dict[int, int] = {}  # finishing request -> `done` at its last token
@@ -323,11 +338,14 @@ def _dispatch_counts(phases: List[Dict[str, Any]]) -> Dict[str, int]:
             lane_steps += take
             if take and req._remaining == 0:
                 last[id(req)] = done
-    return {"phases": len(phases), "steps": total, "admissions": admissions,
-            "prompt_tokens": prompt_tokens, "prefix_tokens": prefix_tokens,
-            "lane_steps": lane_steps,
-            "finishing": len(last),
-            "finish_wait_steps": sum(total - d for d in last.values())}
+    counts = {"phases": len(phases), "steps": total, "admissions": admissions,
+              "prompt_tokens": prompt_tokens, "prefix_tokens": prefix_tokens,
+              "lane_steps": lane_steps,
+              "finishing": len(last),
+              "finish_wait_steps": sum(total - d for d in last.values())}
+    if recurrent:
+        counts["state_lanes"] = lane_steps
+    return counts
 
 
 def _finish(req: "_Request", error: Optional[str] = None,
@@ -363,6 +381,34 @@ def _finish(req: "_Request", error: Optional[str] = None,
     return True
 
 
+def _refuse_for_recurrent_state(paged, **asked) -> None:
+    """A model whose lanes hold recurrent state cannot resume a sequence
+    from K/V blocks: the state at a block boundary is not kept. Every
+    option that needs such a snapshot is refused by name (never silently
+    switched off) until one exists."""
+    why = ("the model's lanes hold recurrent state, and the state at a "
+           "block boundary is not kept: ")
+    if not paged:
+        raise ValueError(
+            why + "only the paged engine (paged=True) holds a lane's state "
+            "row; the dense slot cache has none")
+    no_rollback = ("rejected speculative tokens cannot be rolled back out "
+                   "of a recurrence")
+    reasons = {
+        "prefix_cache": "a block-aligned prefix hit is useless without the "
+                        "state at that boundary (pass prefix_cache=False)",
+        "draft_model": no_rollback,
+        "num_speculative_tokens": no_rollback,
+        "role": "a migrated request's blocks cannot resume without its "
+                "lane's state (disaggregated pools)",
+        "cluster_cache": "a peer's prefix blocks are useless without the "
+                         "state at their boundary",
+    }
+    for name, reason in reasons.items():
+        if asked.get(name):
+            raise ValueError(f"{name}={asked[name]!r} is refused: " + why + reason)
+
+
 class ContinuousBatchingEngine:
     def __init__(self, params, cfg, n_slots: int = 8, max_len: int = 0,
                  chunk: int = 8, macro_phases: int = 8, name: str = "default",
@@ -375,7 +421,16 @@ class ContinuousBatchingEngine:
                  digest_prefix_len: int = 32):
         import jax
 
-        from ray_tpu.models import llama_decode as D
+        # the model's decode module: cache pytree, macro-step halves
+        D = cfg.decode_module
+        # bytes of recurrent state a lane holds beside its K/V blocks
+        self.state_bytes = int(D.state_bytes_per_lane(cfg))
+        if self.state_bytes:
+            _refuse_for_recurrent_state(
+                paged=paged, prefix_cache=prefix_cache,
+                draft_model=draft_model,
+                num_speculative_tokens=num_speculative_tokens, role=role,
+                cluster_cache=cluster_cache)
 
         if role not in (None, "prefill", "decode"):
             raise ValueError(
@@ -480,9 +535,12 @@ class ContinuousBatchingEngine:
                 "(separate draft KV cannot migrate across replicas)")
         # memoized per (cfg, chunk): same-geometry engines share one jit
         # wrapper, so engine construction never recompiles warm programs
-        self._prefill_slots = D.jitted_prefill_into_slots(cfg)
-        self._chunk_fn = D.jitted_decode_chunk_slots(cfg, chunk)
-        self._macro_fn = D.jitted_macro_step_slots(cfg, chunk)
+        # (the dense programs: a model with recurrent state is paged-only)
+        self._prefill_slots = self._chunk_fn = self._macro_fn = None
+        if not self.state_bytes:
+            self._prefill_slots = D.jitted_prefill_into_slots(cfg)
+            self._chunk_fn = D.jitted_decode_chunk_slots(cfg, chunk)
+            self._macro_fn = D.jitted_macro_step_slots(cfg, chunk)
         self._slots: List[Optional[_Request]] = [None] * n_slots
         import jax.numpy as jnp
 
@@ -545,7 +603,10 @@ class ContinuousBatchingEngine:
                    "draft_accepted_tokens": 0, "migrations_out": 0,
                    "migrations_in": 0, "migrated_blocks_out": 0,
                    "migrated_blocks_in": 0, "prefix_exports": 0,
-                   "prefix_imports": 0, "requests_completed": 0}
+                   "prefix_imports": 0, "requests_completed": 0,
+                   # recurrent-state rows the decode steps had to move:
+                   # live lane-steps of a model whose lanes hold state
+                   "state_lane_steps": 0}
         shared = _engine_metrics()
         self._tags = {"engine": name}
         self._ttft = _LatencyHist(_TTFT_BOUNDS, shared["ttft"], self._tags)
@@ -559,6 +620,9 @@ class ContinuousBatchingEngine:
         self._tel = _get_tel(f"llm_dispatch:{name}") or StepTelemetry(
             f"llm_dispatch:{name}", kind="serve")
         self._jit_cache_sizes: Dict[int, int] = {}
+        # a dispatch that traced and compiled a program leaves a large
+        # young heap behind: it is collected the next time the loop idles
+        self._collect_when_idle = False
         self._t_snapshot = 0.0
         self._pub_marker: Optional[tuple] = None
         self._wake = threading.Event()
@@ -823,6 +887,15 @@ class ContinuousBatchingEngine:
             except Exception as e:  # noqa: BLE001 — job errors go to the caller
                 fut.set_exception(e)
 
+    def _refuse_block_transfer(self, what: str) -> None:
+        """K/V blocks shipped between replicas resume nothing where a
+        lane also holds recurrent state."""
+        if self.state_bytes:
+            raise ValueError(
+                f"{what} is refused: the model's lanes hold recurrent "
+                "state, and K/V blocks without the state at their "
+                "boundary cannot resume or seed a sequence")
+
     def submit_resumed(self, prompt: List[int], first_token: int,
                        max_new_tokens: int, k, v, n_data_blocks: int,
                        on_done=None, sampling=None, rid: Optional[str] = None,
@@ -836,6 +909,7 @@ class ContinuousBatchingEngine:
         mid-migration would discard finished prefill work)."""
         from ray_tpu.serve._internal.sampling import SamplingParams
 
+        self._refuse_block_transfer("submit_resumed")
         if self._dead is not None:
             raise RuntimeError(f"engine is dead: {self._dead}")
         if not self.paged:
@@ -883,6 +957,8 @@ class ContinuousBatchingEngine:
         caller must hold until importers are done) or None on miss."""
         from ray_tpu.serve._internal import kv_plane
 
+        self._refuse_block_transfer("export_prefix")
+
         def job():
             if self._kv_inv is None:
                 return None
@@ -922,6 +998,7 @@ class ContinuousBatchingEngine:
         ANOTHER replica. Opportunistic — pool exhaustion drops the
         import silently (it's a cache fill, not a request). Returns
         blocks newly committed."""
+        self._refuse_block_transfer("import_prefix")
 
         def job():
             if self._prefix is None:
@@ -968,6 +1045,7 @@ class ContinuousBatchingEngine:
         with self._m_lock:
             m = dict(self._m)
         m["queue_depth"] = self.load()  # live gauge, not a counter
+        m["state_bytes"] = self.state_bytes  # a constant: bytes a lane
         toks = max(1, m["tokens_out"])
         m["dispatches_per_token"] = round(m["dispatches"] / toks, 4)
         m["lane_occupancy_pct"] = round(
@@ -1615,8 +1693,11 @@ class ContinuousBatchingEngine:
         )
         self._m["dispatches"] += 1
         for ph in phases:
+            live = sum(t for _, _, t in ph["takes"])
             self._m["slot_steps"] += ph["steps"] * self.n_slots
-            self._m["useful_slot_steps"] += sum(t for _, _, t in ph["takes"])
+            self._m["useful_slot_steps"] += live
+            if self.state_bytes:
+                self._m["state_lane_steps"] += live
         self._pending.append(("macro", toks_dev, firsts_dev, phases, seq))
 
     def _shed_expired(self) -> None:
@@ -1667,6 +1748,19 @@ class ContinuousBatchingEngine:
             self._waiting = deque(
                 r for r in self._waiting if not r.done.is_set())
 
+    def _collect_after_compiles(self) -> None:
+        """Run the cyclic collector now, with nothing waiting and nothing
+        in flight, if a program was traced and compiled since the last
+        time. Tracing leaves hundreds of thousands of objects for the
+        collector; left to its own counters it takes its full pass (50-70
+        ms over this process's heap, every thread stopped: my chip runs,
+        PR 29) whenever the next allocations cross its threshold, which is
+        as traffic arrives. After an explicit pass the next full one needs
+        a quarter more long-lived objects, so serving sees none."""
+        if self._collect_when_idle and not self._wake.is_set():
+            self._collect_when_idle = False
+            gc.collect()
+
     def _resolve_next(self) -> None:
         """Resolve the oldest dispatch in flight, under its span."""
         entry = self._pending.popleft()
@@ -1692,6 +1786,7 @@ class ContinuousBatchingEngine:
                     self._repair()
                     self._maybe_publish(time.perf_counter())
                 with span(_SPAN_IDLE):
+                    self._collect_after_compiles()
                     self._wake.wait(timeout=0.05)
                     self._wake.clear()
                 continue
@@ -1699,7 +1794,7 @@ class ContinuousBatchingEngine:
                 phases = self._plan()
                 if phases:
                     A, P = self._variant(phases)
-                    counts = _dispatch_counts(phases)
+                    counts = _dispatch_counts(phases, bool(self.state_bytes))
             if phases:
                 with span(_SPAN_DISPATCH, seq=self._m["dispatches"], A=A, P=P,
                           **counts):
@@ -1782,6 +1877,7 @@ class ContinuousBatchingEngine:
                 while self._pending:
                     self._resolve(self._pending.popleft())
                 self._maybe_publish(time.perf_counter())
+                self._collect_after_compiles()
                 self._wake.wait(timeout=0.05)
                 self._wake.clear()
                 continue
@@ -1833,6 +1929,7 @@ class ContinuousBatchingEngine:
                 seen = self._jit_cache_sizes.get(key, 0)
                 compiled = n > seen
                 self._jit_cache_sizes[key] = max(n, seen)
+                self._collect_when_idle |= compiled
             ctxs, seen_spans = [], set()
             for r in reqs:
                 c = r._trace_ctx

@@ -154,3 +154,38 @@ def test_paged_admission_attention_compiles(one_chip, monkeypatch, A, P):
     one_shot_scores = 4 * 32 * 1024 * 4096 * 4  # bytes, f32, at (4, 1024)
     temp = lowered.compile().memory_analysis().temp_size_in_bytes
     assert temp < one_shot_scores // 4, temp
+
+
+def test_hybrid_macro_step_keeps_state_and_pool_in_place(one_chip):
+    """The hybrid decoder's paged macro-step at granite-4.0-h-micro's widths,
+    32 lanes, the dispatch that admits nothing: 9.9 GB of weights, recurrent
+    state and K/V pool go in, and the program's temporaries stay a few
+    hundred MB. They were 4.1 GB (compiled only, PR 29) while a stack's minor
+    axis was no multiple of 128: the one 8,512-column input projection, the
+    pool's head size of 64 and the conv tail's 3 taps each took a relayout
+    copy of the whole stack in every dispatch, and the tied head a float32
+    copy of the embedding in every step."""
+    from ray_tpu.models import granite_hybrid as G
+    from ray_tpu.models import granite_hybrid_decode as D
+    from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
+
+    cfg = G.GraniteHybridConfig(max_seq_len=4096)
+    B, bs, K, A, P = 32, 16, 8, 1, 16
+    MB = cfg.max_seq_len // bs
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: arr(x.shape, x.dtype), tree)
+
+    params = shaped(jax.eval_shape(lambda: G.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
+    compiled = D.jitted_macro_step_slots_paged(cfg, 8, sampled=False).lower(
+        params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
+        arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
+        arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
+        arr((K, B, MAX_STOP_TOKENS))).compile()
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes > 9.8e9 and m.alias_size_in_bytes > 3.5e9  # cache donated
+    assert m.temp_size_in_bytes < 0.6e9, m.temp_size_in_bytes
