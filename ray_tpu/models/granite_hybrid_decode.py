@@ -129,36 +129,15 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     return first, cache, feed
 
 
-def _attend_flat(q, ctx_k, ctx_v, pos, cfg: GraniteHybridConfig):
-    """One query a lane against its gathered context, on the pool's own
-    layout: q (B, h, hd), ctx_k / ctx_v (B, S, kvh * hd), lane b attends
-    positions [0, pos_b]. Splitting the contexts' minor axis into heads
-    would relayout them (0.65 ms a tensor at 32 x 4096 x 512, measured), so
-    the QUERY is laid out flat instead: each query head's vector in its KV
-    head's columns, zeros elsewhere, and the products run over all kvh * hd
-    columns (kvh times the operations, on a context this size nothing).
-    Returns (B, h * hd)."""
-    B, h, hd = q.shape
-    kvh = cfg.n_kv_heads
-    S = ctx_k.shape[1]
-    own = jnp.eye(kvh, dtype=q.dtype)[None, :, None, :, None]  # head k's columns
-    q_flat = (q.reshape(B, kvh, h // kvh, 1, hd) * own).reshape(B, h, kvh * hd)
-    scores = jnp.einsum("bhc,bsc->bhs", q_flat, ctx_k,
-                        preferred_element_type=jnp.float32) * cfg.attention_multiplier
-    live = jnp.arange(S)[None, None, :] <= pos[:, None, None]
-    probs = jax.nn.softmax(jnp.where(live, scores, -jnp.inf), axis=-1)
-    o_flat = jnp.einsum("bhs,bsc->bhc", probs.astype(ctx_v.dtype), ctx_v,
-                        preferred_element_type=jnp.float32)
-    o = (o_flat.reshape(B, kvh, h // kvh, kvh, hd) * own.astype(jnp.float32)).sum(axis=3)
-    return o.reshape(B, h * hd).astype(cfg.dtype)
-
-
 def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
                             top_ps, stop_ids, cfg: GraniteHybridConfig,
                             sampled: bool = True):
     """One token on every lane, with llama_decode.decode_step_slots_paged's
     arguments and returns. An inactive lane (remaining == 0) keeps its conv
-    tail and state as they are and aims its K/V write at the null block."""
+    tail and state as they are and aims its K/V write at the null block. The
+    attention layers read the lanes' contexts out of the flat pool in place,
+    through llama_decode.attend_decode_paged: work follows the longest live
+    lane, not the table span."""
     B = tokens.shape[0]
     pos = cache["pos"]
     active = cache["remaining"] > 0
@@ -182,11 +161,9 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
             k_full, v_full = L.write_decode_kv(
                 k_full, v_full, ai, k.reshape(B, 1, -1), v.reshape(B, 1, -1),
                 tables, pos, active)
-            # each lane's context straight out of layer `ai` of the pool
-            # (slicing the layer off first copies it whole, every step)
-            ctx_k = k_full[ai, tables].reshape(B, -1, k_full.shape[-1])
-            ctx_v = v_full[ai, tables].reshape(B, -1, v_full.shape[-1])
-            out = _attend_flat(q[:, 0], ctx_k, ctx_v, pos, cfg) @ layer["wo"]
+            out = L.attend_decode_paged(
+                q[:, 0], k_full, v_full, ai, tables, pos, active,
+                cfg.attention_multiplier) @ layer["wo"]
         return out, (k_full, v_full, conv, ssm)
 
     x, (k_full, v_full, conv, ssm) = G.run_layers(

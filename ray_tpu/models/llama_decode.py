@@ -182,16 +182,15 @@ def init_slot_cache(cfg: LlamaConfig, n_slots: int, max_len: int) -> Dict[str, A
     }
 
 
-def _gqa_attend_slots(q, k_cache, v_cache, pos, cfg, scale=None):
+def _gqa_attend_slots(q, k_cache, v_cache, pos, cfg):
     """Per-slot positions: q (B, 1, h, hd), pos (B,) — slot b attends
-    its own [0, pos_b] prefix. `scale` multiplies the scores (hd**-0.5
-    where a model states none)."""
+    its own [0, pos_b] prefix of the dense slot cache."""
     B, _, h, hd = q.shape
     S = k_cache.shape[1]
     qg = q.reshape(B, cfg.n_kv_heads, h // cfg.n_kv_heads, hd)
     scores = jnp.einsum(
         "bkgd,bskd->bkgs", qg, k_cache, preferred_element_type=jnp.float32
-    ) * (hd**-0.5 if scale is None else scale)
+    ) * (hd**-0.5)
     mask = jnp.arange(S)[None, None, None, :] <= pos[:, None, None, None]
     scores = jnp.where(mask, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -478,7 +477,10 @@ def macro_step_slots(params, cache, feed, steps, has_admit, prompts, lengths,
 def init_paged_cache(cfg: LlamaConfig, n_slots: int, n_blocks: int,
                      block_size: int) -> Dict[str, Any]:
     """Paged decode state: the block pool plus per-slot scalars. Block
-    tables are NOT device state — the host allocator owns them."""
+    tables are NOT device state — the host allocator owns them. The pool
+    never exists in (n_slots, max_len) form, nor does a lane's context
+    outside it: the decode step and the admission read it in place, a
+    chunk of blocks at a time (attend_decode_paged, _attend_admission)."""
     shape = (cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
     return {
         "k": jnp.zeros(shape, cfg.dtype),
@@ -587,15 +589,111 @@ def sample_tokens(logits, temps, top_ks, top_ps, keys):
 
 
 def _gather_block_ctx(k_layer, v_layer, tables):
-    """Materialize each slot's context from the pool: k_layer
-    (n_blocks, bs, kvh, hd), tables (B, MB) -> (B, MB*bs, kvh, hd).
-    The transient per-layer gather workspace — the pool itself never
-    exists in (n_slots, max_len) form."""
+    """Materialize each row's whole table span from a layer's pool:
+    k_layer (n_blocks, bs, kvh, hd), tables (R, MB) -> (R, MB*bs, kvh, hd).
+    Only _forward_tokens_paged (speculation and the draft pool's mirror)
+    still reads a context this way; the decode step and the admission
+    read the pool a chunk of blocks at a time."""
     B, MB = tables.shape
     bs = k_layer.shape[1]
     ctx_k = k_layer[tables].reshape(B, MB * bs, *k_layer.shape[2:])
     ctx_v = v_layer[tables].reshape(B, MB * bs, *v_layer.shape[2:])
     return ctx_k, ctx_v
+
+
+def _online_softmax_update(carry, s, live, vc, pv: str):
+    """One chunk of an online softmax: carry (acc, m, l) in f32, the
+    chunk's scores s (..., C) f32 with `live` (broadcastable to s) marking
+    the positions that count, its values vc, and the einsum `pv` of
+    probabilities (cast to the value dtype) with values."""
+    acc, m, l = carry
+    m_new = jnp.maximum(m, jnp.where(live, s, NEG_INF).max(axis=-1))
+    p = jnp.where(live, jnp.exp(s - m_new[..., None]), 0.0)
+    corr = jnp.exp(m - m_new)
+    acc = acc * corr[..., None] + jnp.einsum(
+        pv, p.astype(vc.dtype), vc, preferred_element_type=jnp.float32)
+    return acc, m_new, l * corr + p.sum(axis=-1)
+
+
+# positions of context one iteration of the decode attention's loop reads
+# from the pool for every lane (rounded to whole blocks). Chosen once on a
+# v5e (PR 30, the attention alone, ms a layer at a longest context of 560 /
+# 4096): 32 lanes of flat 512-column rows 0.17 / 1.04 at 128, 0.22 / 1.13 at
+# 256, 0.28 / 1.08 at 512 (a chunk's gather is its bytes three times over, so
+# whole chunks past the longest lane cost); 4 lanes of (8, 128) rows 0.035 /
+# 0.22, 0.040 / 0.20, 0.043 / 0.16: an iteration's own overhead is small
+DECODE_CHUNK = 128
+
+
+def decode_chunk_positions(block_size: int, max_blocks: int) -> int:
+    """Positions a chunk of attend_decode_paged covers, given the pool's
+    block size and the tables' width: what the engine's `ctx_chunks` and
+    `span_chunks` count in."""
+    return min(max(DECODE_CHUNK // block_size, 1), max_blocks) * block_size
+
+
+def attend_decode_paged(q, k_full, v_full, li, tables, pos, active, scale):
+    """Decode attention in proportion to the context the lanes hold: one
+    query a lane, q (B, h, hd), lane b attending positions [0, pos[b]] of
+    layer `li` of the pools AFTER the step's own K/V write; tables
+    (B, MB), active (B,) bool. Returns (B, h * hd) in q's dtype.
+
+    The context is read straight out of the pool, a chunk of blocks at a
+    time under an online softmax (the arithmetic of _attend_admission's
+    prefix loop with one query a row), for ceil((longest live context) /
+    chunk) iterations: a trip count that is data in the program, so the
+    step's attention follows what the lanes hold and never the table
+    span. The gather carries the layer index (slicing the layer off the
+    pool first copies it whole, every step). An inactive lane does not
+    lengthen the loop; its output is whatever the live lanes' chunks
+    covered of it (all zeros when no lane is live) and is discarded by
+    the caller. bf16 operands, f32 scores, softmax and accumulation,
+    probabilities cast to the value dtype for the PV product.
+
+    Both pool layouts: rows of (kvh, hd), pools of rank 5, take the GQA
+    products; flat rows of kvh * hd columns, rank 4 (a head size under
+    128 would be padded to it on a TPU), keep the gathered chunk as it
+    lies and lay the QUERY out flat instead: each query head's vector in
+    its KV head's columns, zeros elsewhere, the products over all
+    kvh * hd columns (kvh times the operations, on one query nothing;
+    splitting the chunk's minor axis into heads would relayout it)."""
+    B, h, hd = q.shape
+    bs, MB = k_full.shape[2], tables.shape[1]
+    row = k_full.shape[3:]
+    flat = len(row) == 1
+    kvh = row[0] // hd if flat else row[0]
+    if flat:
+        own = jnp.eye(kvh, dtype=q.dtype)[None, :, None, :, None]  # head k's columns
+        qx = (q.reshape(B, kvh, h // kvh, 1, hd) * own).reshape(B, h, kvh * hd)
+        qk, pv = "bhc,bsc->bhs", "bhs,bsc->bhc"
+    else:
+        qx = q.reshape(B, kvh, h // kvh, hd)
+        qk, pv = "bkgd,bskd->bkgs", "bkgs,bskd->bkgd"
+    stat = qx.shape[:-1]
+    C = decode_chunk_positions(bs, MB)
+    cb = C // bs  # blocks a chunk
+    # whole chunks only: the tail names the null block and is never live
+    chunked = jnp.pad(tables, ((0, 0), (0, -MB % cb)))
+    longest = jnp.max(jnp.where(active, pos + 1, 0))
+
+    def chunk(i, carry):
+        blocks = jax.lax.dynamic_slice_in_dim(chunked, i * cb, cb, axis=1)
+        kc = k_full[li, blocks].reshape((B, C) + row)
+        vc = v_full[li, blocks].reshape((B, C) + row)
+        s = jnp.einsum(qk, qx, kc, preferred_element_type=jnp.float32) * scale
+        live = (i * C + jnp.arange(C))[None, :] <= pos[:, None]  # (B, C)
+        live = live.reshape((B,) + (1,) * (len(stat) - 1) + (C,))
+        return _online_softmax_update(carry, s, live, vc, pv)
+
+    acc, _, l = jax.lax.fori_loop(
+        0, (longest + C - 1) // C, chunk,
+        (jnp.zeros(qx.shape, jnp.float32), jnp.full(stat, NEG_INF, jnp.float32),
+         jnp.zeros(stat, jnp.float32)),
+    )
+    o = acc / jnp.where(l == 0.0, 1.0, l)[..., None]  # no chunk ran: zeros
+    if flat:
+        o = (o.reshape(B, kvh, h // kvh, kvh, hd) * own.astype(jnp.float32)).sum(axis=3)
+    return o.reshape(B, h * hd).astype(q.dtype)
 
 
 def write_decode_kv(k_full, v_full, li, k, v, tables, pos, active):
@@ -651,7 +749,10 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
     top_ps are the per-slot sampling plan; stop_ids (B, NS) i32 are
     -1-padded stop sets. Inactive lanes (remaining == 0) aim their KV
     write at the null block — their old blocks may already belong to a
-    later-phase admission of the same macro plan. Returns
+    later-phase admission of the same macro plan. Each layer attends the
+    lanes' contexts in place (attend_decode_paged): as many chunks of
+    the pool as the longest live lane holds, no copy of the layer, no
+    gather of the table span. Returns
     (logits, next_tokens, cache); a sampled stop token zeroes the
     slot's `remaining` device-side (the host observes it one macro-step
     later and repairs its speculative plan).
@@ -683,11 +784,9 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
 
         k_full, v_full = write_decode_kv(
             k_full, v_full, li, k, v, tables, pos, active)
-        k_layer = jax.lax.dynamic_index_in_dim(k_full, li, 0, keepdims=False)
-        v_layer = jax.lax.dynamic_index_in_dim(v_full, li, 0, keepdims=False)
-        ctx_k, ctx_v = _gather_block_ctx(k_layer, v_layer, tables)
-        o = _gqa_attend_slots(q, ctx_k, ctx_v, pos, cfg) @ layer["wo"]
-        x = x + o
+        o = attend_decode_paged(
+            q[:, 0], k_full, v_full, li, tables, pos, active, hd**-0.5)
+        x = x + o[:, None, :] @ layer["wo"]
         m = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
         gate = jax.nn.silu((m @ layer["w_gate"]).astype(jnp.float32)).astype(cfg.dtype)
         x = x + (gate * (m @ layer["w_up"])) @ layer["w_down"]
@@ -773,7 +872,6 @@ def _attend_admission(q, k, v, k_layer, v_layer, adm_tables, starts,
     longest = jnp.max(starts)
 
     def chunk(i, carry):
-        acc, m, l = carry
         blocks = jax.lax.dynamic_slice_in_dim(chunked, i * cb, cb, axis=1)
         kc = k_layer[blocks].reshape(A, C, kvh, hd)
         vc = v_layer[blocks].reshape(A, C, kvh, hd)
@@ -782,14 +880,7 @@ def _attend_admission(q, k, v, k_layer, v_layer, adm_tables, starts,
         ) * (hd**-0.5)
         live = (i * C + jnp.arange(C))[None, :] < starts[:, None]  # (A, C)
         live = live[:, None, None, None, :]
-        m_new = jnp.maximum(m, jnp.where(live, s, NEG_INF).max(axis=-1))
-        p = jnp.where(live, jnp.exp(s - m_new[..., None]), 0.0)
-        corr = jnp.exp(m - m_new)
-        acc = acc * corr[..., None] + jnp.einsum(
-            "akgpc,ackd->akgpd", p.astype(vc.dtype), vc,
-            preferred_element_type=jnp.float32,
-        )
-        return acc, m_new, l * corr + p.sum(axis=-1)
+        return _online_softmax_update(carry, s, live, vc, "akgpc,ackd->akgpd")
 
     def with_prefix(o_s):
         stat = (A, kvh, h // kvh, P)

@@ -307,8 +307,8 @@ def _suffix_len(req: "_Request") -> int:
     return len(req.prompt) - req._start
 
 
-def _dispatch_counts(phases: List[Dict[str, Any]],
-                     recurrent: bool = False) -> Dict[str, int]:
+def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
+                     ctx_chunk: int = 0) -> Dict[str, int]:
     """What one macro dispatch carries, from the plan alone: the keyword
     arguments of its `engine.dispatch` span (host integers; nothing is
     read from the device). `_plan` leaves every request at its
@@ -321,7 +321,14 @@ def _dispatch_counts(phases: List[Dict[str, Any]],
     step at admission count there. `state_lanes` is the state rows the
     dispatch's decode steps have to move: each live lane-step of a model
     whose lanes hold recurrent state (`recurrent`); no other model's
-    dispatch carries the key."""
+    dispatch carries the key. `ctx_chunks` is the trip counts of the paged
+    decode attention's loop summed over the dispatch's decode steps: each
+    step reads ceil(longest planned live context / `ctx_chunk`) chunks of
+    `ctx_chunk` positions, a live lane's context at a step being its
+    prompt, the tokens it has decoded and the one it feeds. Only an engine
+    whose decode steps run that loop gives `ctx_chunk` (paged, no draft
+    model); the device's own count is smaller where a sampled stop ends a
+    lane before its plan does."""
     total = sum(ph["steps"] for ph in phases)
     done = 0  # decode steps of this dispatch run so far
     last: Dict[int, int] = {}  # finishing request -> `done` at its last token
@@ -345,7 +352,27 @@ def _dispatch_counts(phases: List[Dict[str, Any]],
               "finish_wait_steps": sum(total - d for d in last.values())}
     if recurrent:
         counts["state_lanes"] = lane_steps
+    if ctx_chunk:
+        counts["ctx_chunks"] = _ctx_chunks(phases, ctx_chunk)
     return counts
+
+
+def _ctx_chunks(phases: List[Dict[str, Any]], ctx_chunk: int) -> int:
+    """`ctx_chunks` of `_dispatch_counts`. The plan leaves a request at its
+    post-dispatch `_remaining`, so its steps owed before a phase are that
+    plus what this phase and the later ones take, walking the phases
+    backwards; a lane that owes r steps holds its prompt and
+    max_new_tokens - 1 - r decoded tokens, and every live lane of a phase
+    takes all of its steps."""
+    owed: Dict[int, int] = {}  # request -> steps owed after the phase at hand
+    chunks = 0
+    for ph in reversed(phases):
+        longest = 0  # live context at the phase's first step
+        for _, req, take in ph["takes"]:
+            before = owed[id(req)] = owed.get(id(req), req._remaining) + take
+            longest = max(longest, len(req.prompt) + req.max_new_tokens - before)
+        chunks += sum(-(-(longest + t) // ctx_chunk) for t in range(ph["steps"]))
+    return chunks
 
 
 def _finish(req: "_Request", error: Optional[str] = None,
@@ -490,6 +517,13 @@ class ContinuousBatchingEngine:
         # stay None and the engine never traces a program containing a
         # single draft parameter (lint-enforced)
         self.n_spec = int(num_speculative_tokens)
+        # positions a chunk of the paged decode attention covers; 0 where
+        # no decode step runs that loop (dense cache, speculative rounds)
+        self._ctx_chunk = 0
+        if self.paged and draft_model is None:
+            from ray_tpu.models.llama_decode import decode_chunk_positions
+
+            self._ctx_chunk = decode_chunk_positions(block_size, self._mb)
         self.draft_params = None
         self.draft_cfg = None
         self.draft_cache = None
@@ -606,7 +640,11 @@ class ContinuousBatchingEngine:
                    "prefix_imports": 0, "requests_completed": 0,
                    # recurrent-state rows the decode steps had to move:
                    # live lane-steps of a model whose lanes hold state
-                   "state_lane_steps": 0}
+                   "state_lane_steps": 0,
+                   # chunks of context the paged decode attention's loop
+                   # was planned to read, and what reading every step's
+                   # whole table span would have been
+                   "ctx_chunks": 0, "span_chunks": 0}
         shared = _engine_metrics()
         self._tags = {"engine": name}
         self._ttft = _LatencyHist(_TTFT_BOUNDS, shared["ttft"], self._tags)
@@ -1698,6 +1736,10 @@ class ContinuousBatchingEngine:
             self._m["useful_slot_steps"] += live
             if self.state_bytes:
                 self._m["state_lane_steps"] += live
+        if self._ctx_chunk:
+            self._m["ctx_chunks"] += _ctx_chunks(phases, self._ctx_chunk)
+            self._m["span_chunks"] += sum(ph["steps"] for ph in phases) * -(
+                -self._mb * self.block_size // self._ctx_chunk)
         self._pending.append(("macro", toks_dev, firsts_dev, phases, seq))
 
     def _shed_expired(self) -> None:
@@ -1794,7 +1836,8 @@ class ContinuousBatchingEngine:
                 phases = self._plan()
                 if phases:
                     A, P = self._variant(phases)
-                    counts = _dispatch_counts(phases, bool(self.state_bytes))
+                    counts = _dispatch_counts(phases, bool(self.state_bytes),
+                                              self._ctx_chunk)
             if phases:
                 with span(_SPAN_DISPATCH, seq=self._m["dispatches"], A=A, P=P,
                           **counts):

@@ -240,14 +240,16 @@ def test_names_and_scopes_are_metadata_only(name, monkeypatch):
 
 
 # ------------------------------------------------- the plan's counts (d)
-def _req(prompt_len, remaining, start=0):
-    return types.SimpleNamespace(prompt=[0] * prompt_len, _start=start, _remaining=remaining)
+def _req(prompt_len, remaining, start=0, max_new=1):
+    return types.SimpleNamespace(prompt=[0] * prompt_len, _start=start, _remaining=remaining,
+                                 max_new_tokens=max_new)
 
 
 def test_finish_wait_steps_on_a_hand_built_plan():
     """Two lanes, chunk 4, four phases; `_remaining` is the state `_plan`
     leaves behind (0 = the request's last token is in this dispatch)."""
-    a, b, c, d = _req(9, 0), _req(12, 7), _req(20, 0, start=8), _req(5, 0)
+    a, b, c, d = (_req(9, 0, max_new=3), _req(12, 7, max_new=20),
+                  _req(20, 0, start=8, max_new=7), _req(5, 0))
     phases = [
         {"steps": 2, "admissions": [(0, a), (1, b)], "takes": [(0, a, 2), (1, b, 2)]},
         {"steps": 4, "admissions": [(0, c)], "takes": [(0, c, 4), (1, b, 4)]},
@@ -264,6 +266,75 @@ def test_finish_wait_steps_on_a_hand_built_plan():
     # a plan that finishes nobody waits for nothing
     assert llm_engine._dispatch_counts([{"steps": 4, "admissions": [], "takes": [(1, b, 4)]}])[
         "finish_wait_steps"] == 0
+    # chunks of 8 positions the decode attention reads, a step: the longest
+    # live context is b's 13, 14; c's 21..24; c's 25, 26; b's 21..24
+    with_ctx = llm_engine._dispatch_counts(phases, ctx_chunk=8)
+    assert with_ctx == {**counts, "ctx_chunks": 2 * 2 + 4 * 3 + 2 * 4 + 4 * 3}
+
+
+def _drive(eng, prompts_and_answers):
+    """Run requests through a paged engine whose loop is stopped, one plan at
+    a time: -> (requests, per dispatch its phases' (steps, [(request, take)])
+    and its counts)."""
+    reqs = [eng.submit(p, n) for p, n in prompts_and_answers]
+    eng._drain_queue()
+    seen = []
+    while eng._waiting or any(r is not None for r in eng._slots):
+        phases = eng._plan()
+        seen.append(([(ph["steps"], [(r, t) for _, r, t in ph["takes"]]) for ph in phases],
+                     llm_engine._dispatch_counts(phases, False, eng._ctx_chunk)))
+        eng._dispatch_macro(phases)
+    while eng._pending:
+        eng._resolve(eng._pending.popleft())
+    assert all(r.done.is_set() and r.error is None for r in reqs)
+    return reqs, seen
+
+
+def test_ctx_chunks_follow_the_planned_contexts():
+    """`ctx_chunks` is the decode attention's trip counts: per decode step
+    ceil(longest live context / chunk), where a lane's context is its prompt,
+    what it has decoded and the token it feeds. Recomputed forwards from the
+    requests' lengths (the engine walks its plan backwards from
+    `_remaining`); the metric is the spans' sum and never passes
+    `span_chunks`, every step reading the whole table span."""
+    cfg, params = _cfg_params()
+    eng = ContinuousBatchingEngine(params, cfg, paged=True, n_slots=2, chunk=4, macro_phases=4,
+                                   max_len=1024, block_size=16, prefix_cache=False)
+    eng.shutdown()  # the plans below are made on this thread
+    C = D.decode_chunk_positions(16, 1024 // 16)
+    assert eng._ctx_chunk == C == 128
+    rng = np.random.default_rng(1)
+    prompt = lambda n: rng.integers(0, cfg.vocab_size, n).tolist()  # noqa: E731
+
+    # one request alone: 11 decode steps on contexts of 251..261 positions,
+    # six of them inside the second chunk, five reaching into the third
+    _, seen = _drive(eng, [(prompt(250), 12)])
+    assert sum(c["steps"] for _, c in seen) == 11
+    assert sum(c["ctx_chunks"] for _, c in seen) == 6 * 2 + 5 * 3
+    m = eng.metrics()
+    assert (m["ctx_chunks"], m["span_chunks"]) == (27, 11 * 8)
+
+    # lanes of unequal length that come and go: the longest live lane decides
+    reqs, seen = _drive(eng, [(prompt(250), 12), (prompt(30), 9), (prompt(505), 10),
+                              (prompt(17), 5)])
+    decoded = {id(r): 0 for r in reqs}
+    want = steps = 0
+    for phases, counts in seen:
+        mine = 0
+        for n, takes in phases:
+            assert all(t == n for _, t in takes)
+            longest = max((len(r.prompt) + decoded[id(r)] + 1 for r, _ in takes), default=0)
+            mine += sum(-(-(longest + t) // C) for t in range(n))
+            for r, t in takes:
+                decoded[id(r)] += t
+            steps += n
+        assert counts["ctx_chunks"] == mine <= counts["steps"] * 8
+        want += mine
+    assert [decoded[id(r)] for r in reqs] == [11, 8, 9, 4]
+    assert steps < want < 8 * steps  # some steps read one chunk, none the whole span
+    m2 = eng.metrics()
+    assert m2["ctx_chunks"] - m["ctx_chunks"] == want
+    assert m2["span_chunks"] - m["span_chunks"] == 8 * steps
 
 
 # ---------------------------------------------- spans in a real trace (c)
@@ -324,11 +395,11 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
 
     dispatches = [stats for name, _, _, stats in top if name == "engine.dispatch"]
     keys = {"seq", "phases", "steps", "admissions", "A", "P", "prompt_tokens", "prefix_tokens",
-            "lane_steps", "finishing", "finish_wait_steps"}
+            "lane_steps", "finishing", "finish_wait_steps", "ctx_chunks"}
     assert all(set(d) == keys for d in dispatches)
     diff = {k: m1[k] - m0[k] for k in ("dispatches", "slot_steps", "useful_slot_steps",
                                        "prefill_tokens", "reused_prefix_tokens",
-                                       "requests_completed")}
+                                       "requests_completed", "ctx_chunks", "span_chunks")}
     assert len(dispatches) == diff["dispatches"] >= 2
     assert [d["seq"] for d in dispatches] == list(
         range(m0["dispatches"], m0["dispatches"] + len(dispatches)))
@@ -337,6 +408,9 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
     assert sum(d["admissions"] for d in dispatches) == len(reqs)
     assert sum(d["prompt_tokens"] for d in dispatches) == diff["prefill_tokens"]
     assert sum(d["prefix_tokens"] for d in dispatches) == diff["reused_prefix_tokens"] == 8
+    # a span of 64 positions is one chunk: every step reads it, no more
+    assert (sum(d["ctx_chunks"] for d in dispatches) == diff["ctx_chunks"]
+            == diff["span_chunks"] == sum(d["steps"] for d in dispatches))
     assert sum(d["finishing"] for d in dispatches) == diff["requests_completed"] == len(reqs)
     assert all(d["P"] in (16, 32) and d["A"] in (1, 2) for d in dispatches)
     resolved = [stats["seq"] for name, _, _, stats in top if name == "engine.resolve"]

@@ -338,7 +338,7 @@ def test_admission_attention_matches_one_shot(name, monkeypatch):
 
 
 def _sub_jaxprs(p):
-    import jax
+    import jax.extend  # not loaded by `import jax` alone
 
     if isinstance(p, jax.extend.core.ClosedJaxpr):
         yield p.jaxpr
@@ -403,6 +403,151 @@ def test_admission_lowering_has_no_span_tensor(monkeypatch):
     monkeypatch.setattr(D, "_attend_admission", _one_shot_attention)
     big, gathers = _admission_span_tensors()
     assert big and gathers, "the lint failed to flag the one-shot formulation"
+
+
+# ---------------------------- decode attention: parity and lowering (PR 30)
+def _one_shot_decode_attention(q, k_full, v_full, li, tables, pos, active, scale):
+    """The formulation the paged decode step had until PR 30, kept as the
+    reference for both pool layouts: slice layer `li` off the pools, gather
+    every lane's WHOLE table span from it, build one f32 (B, heads, span)
+    score, mask it by s <= pos[b] and soft-max it in one shot. `active`
+    goes unused: a dead lane attends its stale positions like a live one."""
+    import jax
+    import jax.numpy as jnp
+
+    B, h, hd = q.shape
+    k_layer = jax.lax.dynamic_index_in_dim(k_full, li, 0, keepdims=False)
+    v_layer = jax.lax.dynamic_index_in_dim(v_full, li, 0, keepdims=False)
+    S = tables.shape[1] * k_layer.shape[1]
+    ctx_k = k_layer[tables].reshape(B, S, -1, hd)  # heads split, whichever the layout
+    ctx_v = v_layer[tables].reshape(B, S, -1, hd)
+    kvh = ctx_k.shape[2]
+    qg = q.reshape(B, kvh, h // kvh, hd)
+    scores = jnp.einsum("bkgd,bskd->bkgs", qg, ctx_k,
+                        preferred_element_type=jnp.float32) * scale
+    mask = jnp.arange(S)[None, None, None, :] <= pos[:, None, None, None]
+    probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+    out = jnp.einsum("bkgs,bskd->bkgd", probs.astype(ctx_v.dtype), ctx_v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, h * hd).astype(q.dtype)
+
+
+_DEC_BS, _DEC_MB = 16, 64  # a table span of 1024: eight chunks of 128
+
+# name -> (pos, active) of B = 4 lanes; a lane's context is pos + 1 positions
+DECODE_CASES = {
+    # lanes of unequal length, one of them of length 1
+    "unequal-and-length-1": ([0, 37, 300, 700], [1, 1, 1, 1]),
+    # contexts that are exact multiples of the chunk, and one past it
+    "chunk-multiples": ([127, 511, 128, 767], [1, 1, 1, 1]),
+    # the span's last position: the loop runs every chunk
+    "span-end": ([1023, 5, 1022, 511], [1, 1, 1, 1]),
+    # dead lanes, one holding more than any live lane, beside live ones
+    "inactive-beside-live": ([900, 40, 0, 260], [0, 1, 0, 1]),
+    # nothing live: no iteration, every output discarded
+    "no-active-lane": ([900, 40, 0, 260], [0, 0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("layout", ["rows-kvh-hd", "rows-flat"])
+@pytest.mark.parametrize("name", DECODE_CASES)
+def test_decode_attention_matches_one_shot(name, layout, dtype):
+    """The chunked decode attention against the one-shot formulation on
+    every live lane, for Mistral's pool rows (kvh, hd) and the hybrid's flat
+    rows of kvh * hd columns, out of a random pool through shuffled tables.
+    float32 within 1e-5; bfloat16 within 2e-2 (the two round their
+    probabilities to 8 bits of mantissa at different scales before PV)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as D
+
+    pos, active = (np.asarray(x, np.int32) for x in DECODE_CASES[name])
+    B, bs, MB, h, kvh, hd, li = 4, _DEC_BS, _DEC_MB, 4, 2, 16, 1
+    C = D.decode_chunk_positions(bs, MB)
+    assert C == 128 and MB * bs == 8 * C
+    rng = np.random.default_rng(sorted(DECODE_CASES).index(name))
+    n_blocks = B * MB + 1
+    tables = (rng.permutation(n_blocks - 1) + 1).reshape(B, MB).astype(np.int32)
+    row = (kvh * hd,) if layout == "rows-flat" else (kvh, hd)
+    jdt = jnp.float32 if dtype == "f32" else jnp.bfloat16
+    k_full, v_full = (jnp.asarray(rng.standard_normal((3, n_blocks, bs) + row), jdt)
+                      for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((B, h, hd)), jdt)
+    args = (q, k_full, v_full, jnp.int32(li), jnp.asarray(tables), jnp.asarray(pos),
+            jnp.asarray(active.astype(bool)), 0.2)
+    got = np.asarray(D.attend_decode_paged(*args), np.float32)
+    want = np.asarray(_one_shot_decode_attention(*args), np.float32)
+    assert got.shape == want.shape == (B, h * hd) and np.isfinite(got).all()
+    live = active.astype(bool)
+    tol = 1e-5 if dtype == "f32" else 2e-2
+    np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+    if not live.any():
+        assert not got.any()  # the loop ran no chunk
+
+
+def _decode_step_jaxpr(model):
+    """(jaxpr of the model's paged decode step, its pool-layer shape prefix,
+    lanes, span) at 4 lanes, blocks of 16, a span of 1024."""
+    import jax
+    import jax.numpy as jnp
+
+    B, bs, MB = 4, _DEC_BS, _DEC_MB
+    n_blocks = B * MB + 1
+    if model == "llama":
+        from ray_tpu.models import llama_decode as D
+
+        params, cfg = _tiny()
+    else:
+        from ray_tpu.models import granite_hybrid as G
+        from ray_tpu.models import granite_hybrid_decode as D
+
+        cfg = G.GraniteHybridConfig.tiny(dtype=jnp.float32)
+        params = G.init_params(jax.random.PRNGKey(0), cfg)
+    cache = D.init_paged_cache(cfg, B, n_blocks, bs)
+    assert cache["k"].shape[0] != n_blocks  # a layer's shape is not the pool's
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    jaxpr = jax.make_jaxpr(
+        lambda *a: D.decode_step_slots_paged(*a, cfg, sampled=False))(
+        params, cache, i32(B), i32(B, MB), jnp.zeros(B, jnp.float32), i32(B),
+        jnp.ones(B, jnp.float32), i32(B, 4))
+    return jaxpr, cache["k"].shape[1:], B, MB * bs
+
+
+def _decode_span_tensors(model):
+    """(arrays of a whole pool layer; gathers of every lane's whole span and
+    arrays with the span on an axis) that the model's paged decode step
+    produces."""
+    jaxpr, layer, B, span = _decode_step_jaxpr(model)
+    span_ctx = B * span * int(np.prod(layer[2:]))
+    layers, spans = [], []
+    for eqn in _walk_eqns(jaxpr.jaxpr):
+        for var in eqn.outvars:
+            shape = tuple(getattr(var.aval, "shape", ()))
+            name = eqn.primitive.name
+            if shape == layer:
+                layers.append((name, shape))
+            if (name == "gather" and int(np.prod(shape)) >= span_ctx) or (
+                    len(shape) > 2 and shape[0] == B and span in shape[1:]):
+                spans.append((name, shape))
+    return layers, spans
+
+
+@pytest.mark.parametrize("model", ["llama", "granite-hybrid"])
+def test_decode_lowering_has_no_layer_copy_and_no_span_gather(model, monkeypatch):
+    """Lint: neither model's paged decode step produces an array of a whole
+    pool layer (n_blocks, bs, ...) nor one of a lane's whole table span
+    (B, MB * bs, ...): gathered context, scores or probabilities. The
+    one-shot reference must trip both detectors."""
+    from ray_tpu.models import llama_decode as D
+
+    layers, spans = _decode_span_tensors(model)
+    assert not layers, f"the decode step copies a pool layer: {layers}"
+    assert not spans, f"the decode step builds a table span: {spans}"
+    monkeypatch.setattr(D, "attend_decode_paged", _one_shot_decode_attention)
+    layers, spans = _decode_span_tensors(model)
+    assert layers and spans, "the lint failed to flag the one-shot formulation"
+    assert any(name == "gather" for name, _ in spans)
 
 
 # ------------------------------------------------- engine-level behavior

@@ -156,6 +156,44 @@ def test_paged_admission_attention_compiles(one_chip, monkeypatch, A, P):
     assert temp < one_shot_scores // 4, temp
 
 
+def test_paged_decode_step_copies_no_pool_layer(one_chip):
+    """Llama's paged decode step at the serve cell's widths (4 lanes, blocks
+    of 16, a table span of 4096, the default pool of 1,025 blocks), two
+    layers deep: the attention reads chunks of 16 blocks a lane straight out
+    of the pool, and no instruction of the optimized program has a whole
+    layer of the pool, `bf16[1025,16,8,128]`, for its output. Until PR 30
+    `dynamic_index_in_dim(k_full, li)` made two such copies a layer (33.6 MB
+    each) in every decode step, and gathered the span from them."""
+    import re
+
+    from ray_tpu.models import llama
+    from ray_tpu.models import llama_decode as D
+
+    cfg = LlamaConfig(vocab_size=32768, d_model=4096, n_layers=2, n_heads=32,
+                      n_kv_heads=8, d_ff=14336, max_seq_len=4096, dtype=jnp.bfloat16)
+    B, bs, MB = 4, 16, 256
+    n_blocks = B * MB + 1
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: arr(x.shape, x.dtype), tree)
+
+    params = shaped(jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, n_blocks, bs)))
+    text = jax.jit(functools.partial(D.decode_step_slots_paged, cfg=cfg, sampled=False),
+                   donate_argnums=(1,)).lower(
+        params, cache, arr((B,)), arr((B, MB)), arr((B,), jnp.float32), arr((B,)),
+        arr((B,), jnp.float32), arr((B, 4))).compile().as_text()
+    outputs = re.findall(r"= (?:\()?(bf16\[[\d,]+\])", text)
+    assert f"bf16[{cfg.n_layers},{n_blocks},{bs},8,128]" in outputs  # the pool, updated in place
+    chunk = D.decode_chunk_positions(bs, MB) // bs
+    assert f"bf16[{B},{chunk},{bs},8,128]" in outputs                  # a chunk's gather
+    assert f"bf16[{n_blocks},{bs},8,128]" not in outputs, "a pool layer is copied"
+    assert f"bf16[{B},{MB},{bs},8,128]" not in outputs, "the table span is gathered"
+
+
 def test_hybrid_macro_step_keeps_state_and_pool_in_place(one_chip):
     """The hybrid decoder's paged macro-step at granite-4.0-h-micro's widths,
     32 lanes, the dispatch that admits nothing: 9.9 GB of weights, recurrent
@@ -188,4 +226,6 @@ def test_hybrid_macro_step_keeps_state_and_pool_in_place(one_chip):
         arr((K, B, MAX_STOP_TOKENS))).compile()
     m = compiled.memory_analysis()
     assert m.argument_size_in_bytes > 9.8e9 and m.alias_size_in_bytes > 3.5e9  # cache donated
-    assert m.temp_size_in_bytes < 0.6e9, m.temp_size_in_bytes
+    # 0.354 GB while the decode step gathered every lane's whole span, 0.150
+    # with the chunked decode attention (compiled only, PR 30)
+    assert m.temp_size_in_bytes < 0.25e9, m.temp_size_in_bytes
