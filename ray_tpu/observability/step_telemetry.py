@@ -3,7 +3,9 @@
 The host-side planes (tasks, actors, RPC spans, the shm arena) have had
 continuous observability since the seed; the DEVICE hot paths — the
 llama train step, the MoE dispatch, the macro-step decode engine — were
-observable only by re-running bench.py. Production TPU fleets live on
+observable only by re-running a benchmark (today `benchmark/run.py`:
+`pretrain-4k` for the train step, the four serve cells for the
+engine; the MoE dispatch is in no cell: not measured). Production TPU fleets live on
 per-step telemetry (MegaScale attributes most of its recovered MFU to
 always-on step/compile/straggler monitoring), so this layer wraps any
 jitted callable and records, with near-zero host overhead:

@@ -47,7 +47,8 @@ the step survives a slice dying mid-run:
               generation, and optionally warms its programs back up.
   accounting— every phase (detect / regang / restore / recompile) is
               billed to a GoodputMeter (train/goodput.py) surfaced via
-              /api/training and bench.py's elastic section.
+              /api/training (on the chip: not measured, no cell of
+              benchmark/run.py loses a slice).
 
 Within a slice, rank-level failures remain the ElasticCoordinator's
 job (train/elastic.py): each slice's host gang regangs ranks behind

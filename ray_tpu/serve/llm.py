@@ -21,9 +21,9 @@ engine defaults) or a dict carrying per-request SamplingParams fields:
 deployment, the handle hashes it (or the prompt prefix) so a session's
 repeat traffic lands on the replica whose radix cache is hot.
 
-temperature/top-k/top-p sampling and stop tokens require the paged
-engine (`paged=True`, the default for `continuous=True`) — they run
-device-side inside the decode scan (models/llama_decode.sample_tokens).
+temperature/top-k/top-p sampling and stop tokens need the engine
+(`continuous=True`): they run device-side inside the decode scan
+(models/llama_decode.sample_tokens).
 """
 from __future__ import annotations
 
@@ -79,9 +79,9 @@ class _LLMServer:
     def __init__(self, cfg=None, params=None, max_new_tokens: int = 32,
                  checkpoint_dir: Optional[str] = None, seed: int = 0,
                  continuous: bool = False, n_slots: int = 8, chunk: int = 8,
-                 macro_phases: int = 8, paged: Optional[bool] = None,
-                 block_size: int = 16, n_blocks: int = 0,
-                 prefix_cache: bool = True, max_queue: Optional[int] = None,
+                 macro_phases: int = 8, block_size: int = 16,
+                 n_blocks: int = 0, prefix_cache: bool = True,
+                 max_queue: Optional[int] = None,
                  draft_model=None, num_speculative_tokens: int = 0,
                  pool: Optional[str] = None,
                  cluster_cache: Optional[bool] = None,
@@ -122,24 +122,16 @@ class _LLMServer:
         self._prefetch_memo: Dict[str, float] = {}
         if continuous:
             # continuous batching: requests admit/evict per decode chunk,
-            # with macro-step scheduling batching K chunks per dispatch;
-            # paged (default) decouples KV memory from slots x max_len
-            # and unlocks sampling + stop tokens + prefix reuse
-            from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
-
-            if paged is None:
-                # auto: paged whenever the macro scheduler runs; the
-                # legacy per-chunk path (macro_phases=0) stays dense.
-                # An EXPLICIT paged=True with macro_phases=0 is a config
-                # error the engine raises loudly — never a silent
-                # downgrade to dense.
-                paged = macro_phases > 0
+            # with macro-step scheduling batching K chunks per dispatch
+            # over a paged K/V pool (sampling, stop tokens, prefix reuse)
             import os
+
+            from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
 
             self.engine = ContinuousBatchingEngine(
                 self.params, self.cfg, n_slots=n_slots, chunk=chunk,
-                macro_phases=macro_phases, paged=paged,
-                block_size=block_size, n_blocks=n_blocks,
+                macro_phases=macro_phases, block_size=block_size,
+                n_blocks=n_blocks,
                 prefix_cache=prefix_cache, max_queue=max_queue,
                 # lossless draft-model speculation: draft_model is None
                 # (off — the engine compiles the exact pre-speculation
@@ -480,8 +472,8 @@ def llm_deployment(num_replicas: int = 1, max_new_tokens: int = 32,
                    cfg=None, checkpoint_dir: Optional[str] = None,
                    continuous: bool = False, n_slots: int = 8,
                    chunk: int = 8, macro_phases: int = 8,
-                   paged: Optional[bool] = None, block_size: int = 16,
-                   n_blocks: int = 0, prefix_cache: bool = True,
+                   block_size: int = 16, n_blocks: int = 0,
+                   prefix_cache: bool = True,
                    max_queue: Optional[int] = None, draft_model=None,
                    num_speculative_tokens: int = 0,
                    pools: Optional[Dict[str, int]] = None,
@@ -503,7 +495,7 @@ def llm_deployment(num_replicas: int = 1, max_new_tokens: int = 32,
     retryable error instead of queueing unboundedly).
 
     `draft_model` + `num_speculative_tokens` turn on LOSSLESS
-    draft-model speculative decoding (paged engine only): a small draft
+    draft-model speculative decoding (continuous=True only): a small draft
     model proposes num_speculative_tokens tokens per lane each round
     and the target verifies them all in one batched dispatch, emitting
     every accepted token plus one correction/bonus token. Greedy output
@@ -519,7 +511,7 @@ def llm_deployment(num_replicas: int = 1, max_new_tokens: int = 32,
     FLOPs — speculation off costs nothing.
 
     `pools={"prefill": P, "decode": D}` turns on DISAGGREGATED serving
-    (continuous paged engine only): the deployment runs P prefill
+    (continuous=True only): the deployment runs P prefill
     replicas (admission + prompt pass, compute-bound) and D decode
     replicas (the token loop, bandwidth-bound); finished prefills ship
     their KV blocks to a decode replica over the object plane and the
@@ -543,11 +535,7 @@ def llm_deployment(num_replicas: int = 1, max_new_tokens: int = 32,
         if not continuous:
             raise ValueError(
                 "pools= requires continuous=True (disaggregated serving "
-                "runs on the continuous paged engine)")
-        if paged is False or macro_phases <= 0:
-            raise ValueError(
-                "pools= requires the paged macro-step engine "
-                "(macro_phases > 0 and paged != False)")
+                "runs on the continuous-batching engine)")
         deploy_kw["pool_config"] = dict(pools)
     dep = deployment(
         _LLMServer, name="LLMServer", num_replicas=num_replicas, **deploy_kw
@@ -555,7 +543,7 @@ def llm_deployment(num_replicas: int = 1, max_new_tokens: int = 32,
     return dep.bind(cfg=cfg, max_new_tokens=max_new_tokens,
                     checkpoint_dir=checkpoint_dir, continuous=continuous,
                     n_slots=n_slots, chunk=chunk, macro_phases=macro_phases,
-                    paged=paged, block_size=block_size, n_blocks=n_blocks,
+                    block_size=block_size, n_blocks=n_blocks,
                     prefix_cache=prefix_cache, max_queue=max_queue,
                     draft_model=draft_model,
                     num_speculative_tokens=num_speculative_tokens,

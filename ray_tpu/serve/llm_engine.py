@@ -1,11 +1,11 @@
-"""Continuous-batching LLM engine with macro-step scheduling.
+"""Continuous-batching LLM engine: a paged K/V pool driven by macro-steps.
 
 The reference's Serve LLM stack delegates the decode loop to vLLM
 inside replicas (continuous batching + paged KV); there is no TPU
 engine to wrap, so this is the green-field TPU-native equivalent
-(SURVEY §7 step 10). Design:
+(SURVEY §7 step 10). There is one engine and one mode of it:
 
-- A fixed pool of SLOTS (lanes): each is an independent sequence at its
+- LANES. A fixed number of slots, each an independent sequence at its
   own position. What a lane holds is the model's: the decode module the
   config object names (`cfg.decode_module`: models/llama_decode.py,
   models/granite_hybrid_decode.py) owns the cache pytree and the two
@@ -17,69 +17,64 @@ engine to wrap, so this is the green-field TPU-native equivalent
   whatever needs a state snapshot (prefix reuse, speculation's rollback,
   migration and the cluster cache) is refused for it by name, with the
   reason, rather than switched off.
-- PAGED KV (paged=True, the serving default path): KV memory is a
-  global pool of fixed-size blocks instead of slots x max_len stripes —
-  a host-side BlockAllocator (serve/_internal/kv_blocks.py) plans
-  refcounted per-slot block tables that ride each dispatch as i32
-  program arguments, a radix prefix cache
-  (serve/_internal/prefix_cache.py) lets admissions that share a
-  committed prompt prefix reuse its blocks and prefill only the
-  suffix, and REAL SAMPLING (temperature/top-k/top-p, per-request
-  seeds, device-side stop-token detection) runs inside the decode scan.
-- PLAN-AND-REPAIR replaces the old greedy-only invariant: with
-  sampling, token values CAN end a sequence early (stop tokens), so
-  the host keeps planning K phases ahead speculatively from counters,
-  the device zeroes a stopped slot's `remaining` the moment it samples
-  a stop, and the host repairs its plan when the resolved tokens
-  reveal it — truncating delivery at the stop, freeing the slot and
-  its blocks at the next plan boundary, and billing the discarded
-  planned steps as `plan_repair_waste_pct` (alias
-  `speculative_waste_pct`). Block reuse under
-  speculation is safe by construction: tables are PER-DISPATCH host
-  plans, so a zombie lane (stopped or cancelled but still riding
-  already-planned phases) only ever writes blocks it owned at dispatch
-  time — every later dispatch points it at the null block, and a new
-  owner's admission prefill (always a later dispatch, device programs
+- THE POOL. K/V memory is one pool of fixed-size blocks: a host-side
+  BlockAllocator (serve/_internal/kv_blocks.py) plans refcounted
+  per-lane block tables that ride each dispatch as i32 program
+  arguments, and a radix prefix cache (serve/_internal/prefix_cache.py)
+  lets admissions that share a committed prompt prefix reuse its blocks
+  and prefill only the suffix. Sampling (temperature/top-k/top-p,
+  per-request seeds) and stop-token detection run on the device, inside
+  the decode scan.
+- COUNTERS ONLY. Scheduling never reads a token VALUE: admission,
+  eviction and chunk sizing are decided from host-side counters (a
+  request's length, the steps it still owes, the blocks it holds). For
+  a greedy request without stop tokens the plan is exact. A stop token
+  CAN end a sequence early, so there the plan is speculative: the
+  device zeroes a stopped lane's `remaining` the moment it emits a
+  stop, and the host repairs its plan when the resolved tokens reveal
+  it, truncating delivery at the stop, freeing the lane and its blocks
+  at the next plan boundary (`_repair`), and billing the discarded
+  planned steps as `plan_repair_waste_pct`. Block reuse under such a
+  plan is safe by construction: tables are PER-DISPATCH host plans, so
+  a zombie lane (stopped or cancelled but still riding already-planned
+  phases) only ever writes blocks it owned at dispatch time; every
+  later dispatch points it at the null block, and a new owner's
+  admission prefill (always a later dispatch, device programs
   serialize) overwrites before any read.
-- KEY INVARIANT (greedy requests — and the legacy dense mode's only
-  mode): greedy decode to a requested length means scheduling never
-  depends on token VALUES — admission, eviction and chunk sizing are
-  all decidable from host-side counters alone; a stop-free plan needs
-  zero repair.
-- MACRO-STEP SCHEDULING exploits that invariant to collapse dispatch
-  count: the host plans K phases of admissions/evictions ahead, then
-  executes the WHOLE plan as one jitted dispatch
-  (llama_decode.macro_step_slots — a lax.scan over the plan whose
-  phases run a fused admission prefill + a decode chunk device-side).
-  Prompts ride along as program arguments, so admission costs zero
-  extra dispatches.
-- ADAPTIVE CHUNKS: each phase decodes exactly to the next scheduling
-  event — min(chunk, min remaining over live slots) — so a freed slot
-  is re-admitted at the very next phase instead of idling to a fixed
-  chunk boundary; phases beyond their planned steps are skipped via
+- PHASES. The host plans up to `macro_phases` phases of admissions and
+  evictions ahead (`_plan`; `_plan_spec` for verify rounds of a draft
+  model) and ships the WHOLE plan as one jitted dispatch
+  (`_dispatch_macro`: the decode module's macro_step_slots_paged, a
+  lax.scan over the plan whose phases run a fused admission prefill
+  and a decode chunk). Prompts ride along as program arguments, so an
+  admission is no dispatch of its own. One program is compiled per
+  (admission lanes, padded prompt width) pair, both powers of two
+  (`_variant`), times greedy / sampled.
+- ADAPTIVE CHUNKS. Each phase decodes exactly to the next scheduling
+  event, min(chunk, least steps owed over the live lanes), so a freed
+  lane is re-admitted at the very next phase and does not idle to a
+  fixed chunk boundary; a phase's unused steps are skipped with
   lax.cond, so a shrunk phase costs only its real steps.
-- ASYNC PIPELINE: tokens are fetched ONE MACRO-STEP BEHIND the
-  dispatch frontier — while macro-step N executes, the host plans and
-  dispatches N+1 from counters, then resolves N's tokens overlapped
-  with N+1's compute.
+- ONE BEHIND. Tokens are fetched one macro-step behind the dispatch
+  frontier: while macro-step N executes, the host plans and dispatches
+  N+1 from counters, then resolves N's tokens (the only blocking reads,
+  `_resolve_inner`) overlapped with N+1's compute. A request is handed
+  back when its last token has been resolved, not before (ROADMAP W2).
+- SPANS. Every stretch of a loop iteration runs under one of
+  `observability.ENGINE_SPANS` (`engine.idle`, `engine.intake`,
+  `engine.plan`, `engine.dispatch`, `engine.resolve`, and
+  `engine.fetch` inside the last), written on the profiler's clock
+  beside the device's events; `engine.dispatch` carries the plan's
+  counts (`_dispatch_counts`). What the host costs the device is read
+  from those, by the benchmark: `engine.starved_idle_pct` (device idle
+  time under any span but `engine.idle`) and `engine.deliver_lag_ms`
+  (end of a dispatch's execution to the end of its resolve), per cell
+  in PERF_LEDGER.jsonl and PERF.md section 5. `metrics()` keeps the
+  counters: dispatches per token, lane occupancy, TTFT / TPOT
+  percentiles, block utilisation.
 
-Dispatch-cost math (why macro-stepping wins): with per-chunk
-dispatching, serving G tokens through B slots at chunk C costs
-~G/(B*C) chunk dispatches + one prefill dispatch per admission bucket;
-every dispatch pays a fixed host cost D, and where D is large against
-the step time per-chunk dispatching loses to static batching's
-one-scan-per-group even though continuous batching wastes far fewer
-lanes at mixed lengths. How large D is on a directly attached chip:
-not measured (ROADMAP S2 is to trace it). Macro-stepping divides the chunk
-dispatches by K and folds the prefill dispatches into the same
-program, so total dispatch overhead drops ~K*(1 + prefills/chunks)x —
-an order of magnitude at K=8 — while the lane-efficiency win of
-iteration-level scheduling is kept (and sharpened by adaptive chunks).
-`metrics()` reports dispatches/token, lane occupancy and TTFT/TPOT
-percentiles so bench.py can track the regime per round.
-
-Static batching (llama_decode.generate) remains the one-shot path; the
-legacy per-chunk loop survives behind macro_phases=0 for A/B testing.
+Static batching (the decode module's `generate`) remains the one-shot
+path of `serve/llm.py` with `continuous=False`.
 """
 from __future__ import annotations
 
@@ -235,7 +230,7 @@ class _LatencyHist:
 
 class _Request:
     __slots__ = ("prompt", "max_new_tokens", "tokens", "done", "error",
-                 "exc", "on_done", "sampling", "finish_reason", "_first_dev",
+                 "exc", "on_done", "sampling", "finish_reason",
                  "_remaining", "_rounds_est", "_rounds_inflight",
                  "_t_submit", "_t_first", "_t_done",
                  "_trace_ctx", "_start", "_blocks", "_blocks_freed",
@@ -261,8 +256,8 @@ class _Request:
         # completion is a cross-thread event (engine loop delivers,
         # caller threads cancel): _finish's test-and-set runs under this
         self._done_lock = threading.Lock()
-        self._start = 0            # reused-prefix tokens (paged admissions)
-        self._blocks: List[int] = []   # KV blocks owned (paged mode)
+        self._start = 0            # reused-prefix tokens of the admission
+        self._blocks: List[int] = []   # KV blocks owned
         self._blocks_freed = False
         # completion callback, fired (once) from the engine loop thread
         # right after done.set() — the serve direct-transport path
@@ -275,7 +270,6 @@ class _Request:
         # completion raise so the taxonomy survives the process boundary
         # (error stays the human-readable string form)
         self.exc: Optional[BaseException] = None
-        self._first_dev = None   # device scalar: prefill's first token (legacy path)
         self._remaining = 0      # host-side plan counter (decode steps owed)
         # KV-plane state: _migrate marks a prefill-pool request that
         # hands off after its first token; export holds the exporter's
@@ -326,8 +320,8 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
     step reads ceil(longest planned live context / `ctx_chunk`) chunks of
     `ctx_chunk` positions, a live lane's context at a step being its
     prompt, the tokens it has decoded and the one it feeds. Only an engine
-    whose decode steps run that loop gives `ctx_chunk` (paged, no draft
-    model); the device's own count is smaller where a sampled stop ends a
+    whose decode steps run that loop gives `ctx_chunk` (no draft model);
+    the device's own count is smaller where a sampled stop ends a
     lane before its plan does."""
     total = sum(ph["steps"] for ph in phases)
     done = 0  # decode steps of this dispatch run so far
@@ -408,17 +402,13 @@ def _finish(req: "_Request", error: Optional[str] = None,
     return True
 
 
-def _refuse_for_recurrent_state(paged, **asked) -> None:
+def _refuse_for_recurrent_state(**asked) -> None:
     """A model whose lanes hold recurrent state cannot resume a sequence
     from K/V blocks: the state at a block boundary is not kept. Every
     option that needs such a snapshot is refused by name (never silently
     switched off) until one exists."""
     why = ("the model's lanes hold recurrent state, and the state at a "
            "block boundary is not kept: ")
-    if not paged:
-        raise ValueError(
-            why + "only the paged engine (paged=True) holds a lane's state "
-            "row; the dense slot cache has none")
     no_rollback = ("rejected speculative tokens cannot be rolled back out "
                    "of a recurrence")
     reasons = {
@@ -439,8 +429,8 @@ def _refuse_for_recurrent_state(paged, **asked) -> None:
 class ContinuousBatchingEngine:
     def __init__(self, params, cfg, n_slots: int = 8, max_len: int = 0,
                  chunk: int = 8, macro_phases: int = 8, name: str = "default",
-                 paged: bool = False, block_size: int = 16,
-                 n_blocks: int = 0, prefix_cache: bool = True,
+                 block_size: int = 16, n_blocks: int = 0,
+                 prefix_cache: bool = True,
                  max_queue: Optional[int] = None, draft_model=None,
                  num_speculative_tokens: int = 0,
                  role: Optional[str] = None,
@@ -454,8 +444,7 @@ class ContinuousBatchingEngine:
         self.state_bytes = int(D.state_bytes_per_lane(cfg))
         if self.state_bytes:
             _refuse_for_recurrent_state(
-                paged=paged, prefix_cache=prefix_cache,
-                draft_model=draft_model,
+                prefix_cache=prefix_cache, draft_model=draft_model,
                 num_speculative_tokens=num_speculative_tokens, role=role,
                 cluster_cache=cluster_cache)
 
@@ -463,11 +452,13 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"engine role must be None, 'prefill' or 'decode', got "
                 f"{role!r}")
-        if role is not None and not paged:
-            raise ValueError(
-                "disaggregated pool roles require the paged engine "
-                "(paged=True) — KV migration is block-granular")
         self.role = role
+        if macro_phases < 1:
+            raise ValueError(
+                f"macro_phases must be >= 1, got {macro_phases}: every "
+                "dispatch is a plan of at least one phase")
+        if block_size & (block_size - 1) or block_size < 1:
+            raise ValueError(f"block_size must be a power of two, got {block_size}")
 
         self._jax = jax
         # a span on the profiler's own clock, beside the device's events in
@@ -479,48 +470,35 @@ class ContinuousBatchingEngine:
         self.n_slots = n_slots
         self.max_len = max_len or cfg.max_seq_len
         self.chunk = chunk
-        self.macro_phases = macro_phases  # 0 => legacy per-chunk dispatching
-        self.paged = bool(paged)
-        self._alloc = None
-        self._prefix = None
-        if self.paged:
-            if macro_phases < 1:
-                raise ValueError("paged KV requires macro_phases >= 1")
-            if block_size & (block_size - 1) or block_size < 1:
-                raise ValueError(f"block_size must be a power of two, got {block_size}")
-            from ray_tpu.serve._internal.kv_blocks import BlockAllocator
-            from ray_tpu.serve._internal.prefix_cache import RadixPrefixCache
+        self.macro_phases = macro_phases
+        from ray_tpu.serve._internal.kv_blocks import BlockAllocator
+        from ray_tpu.serve._internal.prefix_cache import RadixPrefixCache
 
-            self.block_size = block_size
-            # table width: blocks to cover max_len (per-slot ceiling)
-            self._mb = -(-self.max_len // block_size)
-            # default pool: same KV budget as the dense slots x max_len
-            # cache (+1 for the reserved null block) — paged wins by
-            # serving MORE slots from the SAME budget, not more memory
-            self.n_blocks = n_blocks or n_slots * self._mb + 1
-            self._alloc = BlockAllocator(self.n_blocks, block_size)
-            if prefix_cache:
-                self._prefix = RadixPrefixCache(self._alloc)
-            self.cache = D.init_paged_cache(cfg, n_slots, self.n_blocks,
-                                            block_size)
-            # greedy variant prebound; the sampled twin resolves lazily
-            # at the first plan that actually contains a sampled request
-            # (two static variants — all-greedy traffic must not pay the
-            # per-step sort/softmax/rng sampling pipeline)
-            self._macro_paged_fn = D.jitted_macro_step_slots_paged(
-                cfg, chunk, sampled=False)
-        else:
-            self.cache = D.init_slot_cache(cfg, n_slots, self.max_len)
-        # draft-model speculative decoding (paged-only): the spec macro
-        # program is a THIRD static variant family beside the PR-7
-        # greedy/sampled pair — with speculation off these attributes
-        # stay None and the engine never traces a program containing a
-        # single draft parameter (lint-enforced)
+        self.block_size = block_size
+        # table width: blocks to cover max_len (per-slot ceiling)
+        self._mb = -(-self.max_len // block_size)
+        # default pool: the K/V budget of slots x max_len stripes (+1 for
+        # the reserved null block), shared by however many lanes fit
+        self.n_blocks = n_blocks or n_slots * self._mb + 1
+        self._alloc = BlockAllocator(self.n_blocks, block_size)
+        self._prefix = RadixPrefixCache(self._alloc) if prefix_cache else None
+        self.cache = D.init_paged_cache(cfg, n_slots, self.n_blocks,
+                                        block_size)
+        # greedy variant prebound; _dispatch_macro rebinds it per plan (two
+        # static variants: all-greedy traffic must not pay the per-step
+        # sort/softmax/rng sampling pipeline)
+        self._macro_paged_fn = D.jitted_macro_step_slots_paged(
+            cfg, chunk, sampled=False)
+        # draft-model speculative decoding: the spec macro program is a
+        # THIRD static variant family beside the greedy/sampled pair. With
+        # speculation off these attributes stay None and the engine never
+        # traces a program containing a single draft parameter
+        # (lint-enforced)
         self.n_spec = int(num_speculative_tokens)
         # positions a chunk of the paged decode attention covers; 0 where
-        # no decode step runs that loop (dense cache, speculative rounds)
+        # no decode step runs that loop (speculative rounds)
         self._ctx_chunk = 0
-        if self.paged and draft_model is None:
+        if draft_model is None:
             from ray_tpu.models.llama_decode import decode_chunk_positions
 
             self._ctx_chunk = decode_chunk_positions(block_size, self._mb)
@@ -528,10 +506,6 @@ class ContinuousBatchingEngine:
         self.draft_cfg = None
         self.draft_cache = None
         if draft_model is not None:
-            if not self.paged:
-                raise ValueError(
-                    "speculative decoding requires the paged engine "
-                    "(paged=True)")
             if self.n_spec < 1:
                 raise ValueError(
                     "draft_model requires num_speculative_tokens >= 1, "
@@ -567,9 +541,12 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 "disaggregated pools require a shared-pool draft model "
                 "(separate draft KV cannot migrate across replicas)")
-        # memoized per (cfg, chunk): same-geometry engines share one jit
-        # wrapper, so engine construction never recompiles warm programs
-        # (the dense programs: a model with recurrent state is paged-only)
+        # The one wart this engine keeps: the wrappers of the dense slot
+        # programs, bound and NEVER called. The Mistral benchmark driver
+        # counts their compilations by these names
+        # (benchmark/drivers/serve.py:55, `bench_compiles`); they go with
+        # ROADMAP B7 (the driver drops the three keys) and D2b (the
+        # programs themselves). A model with recurrent state has none.
         self._prefill_slots = self._chunk_fn = self._macro_fn = None
         if not self.state_bytes:
             self._prefill_slots = D.jitted_prefill_into_slots(cfg)
@@ -593,7 +570,7 @@ class ContinuousBatchingEngine:
         self._qtok_lock = threading.Lock()
         self._queued_prefill_tokens = 0
         self._kv_inv = None
-        if self.paged and self._prefix is not None:
+        if self._prefix is not None:
             from ray_tpu.serve._internal.kv_plane import (
                 PrefixInventory, cluster_cache_enabled)
 
@@ -718,8 +695,7 @@ class ContinuousBatchingEngine:
         if self._dead is not None:
             raise RuntimeError(f"engine is dead: {self._dead}")
         if len(prompt) == 0:
-            # length 0 is the macro plan's padding-row sentinel (and the
-            # legacy prefill's last-position logits would be garbage)
+            # length 0 is the macro plan's padding-row sentinel
             raise ValueError("prompt must be non-empty")
         if max_new_tokens < 1:
             raise ValueError(f"max_new_tokens must be >= 1, got {max_new_tokens}")
@@ -738,25 +714,17 @@ class ContinuousBatchingEngine:
 
             sampling = _dc.replace(
                 sampling, seed=int.from_bytes(_os.urandom(4), "little"))
-        if not self.paged and (not sampling.greedy or sampling.stop):
-            # dense mode has no device-side sampling/stop detection —
-            # its macro program is the greedy-invariant one
-            raise ValueError(
-                "temperature sampling and stop tokens require the paged "
-                "engine (paged=True)"
-            )
         # prefill-pool requests hand off after their first token, so
         # they reserve blocks for the PROMPT only (admission writes
         # prompt positions; the decode pool reserves the full span)
         will_migrate = self.role == "prefill" and max_new_tokens > 1
-        if self.paged:
-            span = len(prompt) if will_migrate else len(prompt) + max_new_tokens
-            need = self._alloc.blocks_for_tokens(span)
-            if need > self.n_blocks - 1:
-                raise ValueError(
-                    f"request needs {need} KV blocks, pool only has "
-                    f"{self.n_blocks - 1}"
-                )
+        span = len(prompt) if will_migrate else len(prompt) + max_new_tokens
+        need = self._alloc.blocks_for_tokens(span)
+        if need > self.n_blocks - 1:
+            raise ValueError(
+                f"request needs {need} KV blocks, pool only has "
+                f"{self.n_blocks - 1}"
+            )
         try:
             self._check_admission(sampling)
         except Exception as e:
@@ -800,7 +768,7 @@ class ContinuousBatchingEngine:
         req = self.submit(prompt, max_new_tokens, sampling=sampling, rid=rid)
         if not req.done.wait(timeout):
             # CANCEL, don't abandon: a timed-out request left live would
-            # keep burning decode steps and (paged) holding KV blocks
+            # keep burning decode steps and holding KV blocks
             # forever — cancellation frees the slot and its blocks at
             # the engine's next plan boundary
             self.cancel(req, "cancelled: generation timed out")
@@ -950,8 +918,6 @@ class ContinuousBatchingEngine:
         self._refuse_block_transfer("submit_resumed")
         if self._dead is not None:
             raise RuntimeError(f"engine is dead: {self._dead}")
-        if not self.paged:
-            raise ValueError("KV resume requires the paged engine")
         if len(prompt) + max_new_tokens > self.max_len:
             raise ValueError(
                 f"prompt+generation ({len(prompt)}+{max_new_tokens}) exceeds "
@@ -1091,13 +1057,11 @@ class ContinuousBatchingEngine:
         )
         # plan-and-repair bill: % of PLANNED useful steps whose tokens
         # were discarded (early stop / cancellation revealed after the
-        # speculative plan shipped). Historically named
-        # speculative_waste_pct — kept as an alias now that draft-model
-        # speculation has its own, distinct rejection metric below.
+        # speculative plan shipped); draft-model speculation has its
+        # own, distinct rejection metric below
         m["plan_repair_waste_pct"] = round(
             100.0 * m["wasted_steps"] / max(1, m["useful_slot_steps"]), 2
         )
-        m["speculative_waste_pct"] = m["plan_repair_waste_pct"]
         # draft-model speculation ledger: % of proposed draft tokens the
         # target rejected, and the headline win — verified tokens per
         # verify round (= accepted drafts + the correction/bonus token;
@@ -1116,17 +1080,16 @@ class ContinuousBatchingEngine:
         m["shed_requests"] = m["shed_queue_full"] + m["shed_eta"]
         m["avg_service_ms"] = round(self._ema_service_s * 1e3, 1)
         m["admission_eta_ms"] = round(self.eta_s() * 1e3, 1)
-        if self.paged:
-            total = self.n_blocks - 1  # block 0 is the reserved null
-            m["kv_blocks_total"] = total
-            m["kv_blocks_in_use"] = self._alloc.used_blocks
-            # peak utilization over the workload — the snapshot of record
-            # (in_use drains to the cache-pinned floor between requests)
-            m["kv_blocks_utilization_pct"] = round(
-                100.0 * m["kv_blocks_peak_in_use"] / max(1, total), 1
-            )
-            if self._prefix is not None:
-                m.update(self._prefix.stats())
+        total = self.n_blocks - 1  # block 0 is the reserved null
+        m["kv_blocks_total"] = total
+        m["kv_blocks_in_use"] = self._alloc.used_blocks
+        # peak utilization over the workload — the snapshot of record
+        # (in_use drains to the cache-pinned floor between requests)
+        m["kv_blocks_utilization_pct"] = round(
+            100.0 * m["kv_blocks_peak_in_use"] / max(1, total), 1
+        )
+        if self._prefix is not None:
+            m.update(self._prefix.stats())
         for key, hist in (("ttft", self._ttft), ("tpot", self._tpot),
                           ("migration", self._mig)):
             p50, p95, p99 = hist.percentiles_ms()
@@ -1186,23 +1149,13 @@ class ContinuousBatchingEngine:
                 setattr(self._prefix, c, 0)
 
     # ------------------------------------------------------------ engine
-    def _bucket(self, n: int) -> int:
-        """Power-of-two padded prompt width, clamped to max_len: with a
-        non-power-of-two max_len (e.g. 768) the raw bucket can exceed
-        the cache depth and crash prefill at trace time; submit()
-        already guarantees the prompt itself fits."""
-        b = 16
-        while b < n:
-            b *= 2
-        return min(b, self.max_len)
-
     # ---- macro-step scheduling ----------------------------------------
     def _free_request_blocks(self, req: _Request) -> None:
         """Return a request's KV blocks to the pool (idempotent — a
         request can be planned-evicted AND repaired in either order).
         Blocks the prefix cache committed stay pinned by its reference
         until cache eviction."""
-        if not self.paged or req._blocks_freed:
+        if req._blocks_freed:
             return
         req._blocks_freed = True
         self._alloc.decref(req._blocks)
@@ -1463,18 +1416,16 @@ class ContinuousBatchingEngine:
         allocations/frees."""
         if self.draft_params is not None:
             return self._plan_spec()
-        if self.paged:
-            self._admit_resumes()
+        self._admit_resumes()
         phases = []
         while len(phases) < self.macro_phases:
             admissions = []
             free = [i for i, r in enumerate(self._slots) if r is None]
             while free and self._waiting:
                 req = self._waiting[0]
-                if self.paged and not self._try_admit_paged(req):
+                if not self._try_admit_paged(req):
                     break  # pool exhausted: stays queued, FIFO order kept
                 self._waiting.popleft()
-                self._dec_qtok(req)
                 slot = free.pop(0)
                 # migrating requests are prefill-only: zero decode steps
                 # owed here, so the slot frees this very phase and the
@@ -1487,7 +1438,7 @@ class ContinuousBatchingEngine:
                     if r is not None and r._remaining > 0]
             if not live and not admissions:
                 break
-            snapshot = self._snapshot_phase() if self.paged else {}
+            snapshot = self._snapshot_phase()
             # adaptive chunk: decode exactly to the next scheduling event
             # (a slot finishing) so the freed lane re-admits immediately
             steps = min([self.chunk] + [r._remaining for _, r in live]) if live else 0
@@ -1587,28 +1538,25 @@ class ContinuousBatchingEngine:
         A = 1
         while A < max(1, max_admit):
             A *= 2
-        if self.paged:
-            P = self._bucket_paged(max(
-                (_suffix_len(r) for p in phases for _, r in p["admissions"]),
-                default=1,
-            ))
-        else:
-            P = self._bucket(max(
-                (len(r.prompt) for p in phases for _, r in p["admissions"]),
-                default=1,
-            ))
+        P = self._bucket_paged(max(
+            (_suffix_len(r) for p in phases for _, r in p["admissions"]),
+            default=1,
+        ))
         return A, P
 
     def _dispatch_macro(self, phases: List[Dict[str, Any]]) -> None:
         """Ship the plan as ONE jitted dispatch and append the result to
-        the fetch frontier (resolved one macro-step behind). In paged
-        mode admission rows carry only each prompt's SUFFIX beyond its
-        reused prefix, and the per-phase block tables + sampling plan
-        ride along as extra program arguments."""
+        the fetch frontier (resolved one macro-step behind). Admission
+        rows carry only each prompt's SUFFIX beyond its reused prefix,
+        and the per-phase block tables + sampling plan ride along as
+        extra program arguments."""
         import jax.numpy as jnp
+
+        from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
 
         K = self.macro_phases
         A, P = self._variant(phases)
+        B, MB = self.n_slots, self._mb
         seq = self._m["dispatches"]
         steps = np.zeros(K, np.int32)
         has_admit = np.zeros(K, bool)
@@ -1618,117 +1566,70 @@ class ContinuousBatchingEngine:
         rems = np.zeros((K, A), np.int32)
         starts = np.zeros((K, A), np.int32)
         seeds = np.zeros((K, A), np.uint32)
+        tables = np.zeros((K, B, MB), np.int32)
+        temps = np.zeros((K, B), np.float32)
+        top_ks = np.zeros((K, B), np.int32)
+        top_ps = np.ones((K, B), np.float32)
+        stops = np.full((K, B, MAX_STOP_TOKENS), -1, np.int32)
         for k, ph in enumerate(phases):
             steps[k] = ph["steps"]
+            tables[k] = ph["tables"]
+            temps[k] = ph["temps"]
+            top_ks[k] = ph["top_ks"]
+            top_ps[k] = ph["top_ps"]
+            stops[k] = ph["stops"]
             for a, (slot, req) in enumerate(ph["admissions"]):
                 has_admit[k] = True
-                if self.paged:
-                    suffix = req.prompt[req._start:]
-                    prompts[k, a, : len(suffix)] = suffix
-                    lengths[k, a] = len(suffix)
-                    starts[k, a] = req._start
-                    # greedy rows never consume their key; submit()
-                    # materialized a real seed for every sampled row
-                    seeds[k, a] = np.uint32(
-                        (req.sampling.seed or 0) & 0xFFFFFFFF)
-                else:
-                    prompts[k, a, : len(req.prompt)] = req.prompt
-                    lengths[k, a] = len(req.prompt)
+                suffix = req.prompt[req._start:]
+                prompts[k, a, : len(suffix)] = suffix
+                lengths[k, a] = len(suffix)
+                starts[k, a] = req._start
+                # greedy rows never consume their key; submit()
+                # materialized a real seed for every sampled row
+                seeds[k, a] = np.uint32((req.sampling.seed or 0) & 0xFFFFFFFF)
                 slots[k, a] = slot
                 # migrating rows arm ZERO decode steps: the admission
                 # prefill still samples their first token, then the lane
                 # goes inactive (writes aim at the null block) — decode
                 # happens on the importing replica
                 rems[k, a] = 0 if req._migrate else req.max_new_tokens - 1
+        riders = ([r for p in phases for _, r in p["admissions"]]
+                  + [r for p in phases for _, r, _ in p["takes"]])
+        # static variant selection: only pay the device sampling
+        # pipeline when a sampled request actually rides the plan
+        plan_sampled = any(not r.sampling.greedy for r in riders)
         t0 = time.perf_counter()
         try:
-            if self.paged:
-                from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
-
-                # static variant selection: only pay the device sampling
-                # pipeline when a sampled request actually rides the plan
-                plan_sampled = any(
-                    not r.sampling.greedy
-                    for p in phases
-                    for r in ([r for _, r in p["admissions"]]
-                              + [r for _, r, _ in p["takes"]])
-                )
+            plan_args = [jnp.asarray(x) for x in (
+                steps, has_admit, prompts, lengths, starts, slots, rems,
+                seeds, tables, temps, top_ks, top_ps, stops)]
+            if self.draft_params is not None:
+                # third static variant family: the speculative macro
+                # program (drafts + batched verification per round)
+                self._macro_paged_fn = self._D.jitted_macro_step_slots_spec(
+                    self.cfg, self.draft_cfg, self.chunk, self.n_spec,
+                    sampled=plan_sampled)
+                (toks_dev, counts_dev, firsts_dev, self._next_dev,
+                 self.cache, self.draft_cache) = self._macro_paged_fn(
+                    self.params, self.draft_params, self.cache,
+                    self.draft_cache, self._next_dev, *plan_args)
+                entry = ("spec", (toks_dev, counts_dev), firsts_dev, phases,
+                         seq)
+            else:
                 self._macro_paged_fn = self._D.jitted_macro_step_slots_paged(
                     self.cfg, self.chunk, sampled=plan_sampled)
-                B, MB = self.n_slots, self._mb
-                tables = np.zeros((K, B, MB), np.int32)
-                temps = np.zeros((K, B), np.float32)
-                top_ks = np.zeros((K, B), np.int32)
-                top_ps = np.ones((K, B), np.float32)
-                stops = np.full((K, B, MAX_STOP_TOKENS), -1, np.int32)
-                for k, ph in enumerate(phases):
-                    tables[k] = ph["tables"]
-                    temps[k] = ph["temps"]
-                    top_ks[k] = ph["top_ks"]
-                    top_ps[k] = ph["top_ps"]
-                    stops[k] = ph["stops"]
-                if self.draft_params is not None:
-                    # third static variant family: the speculative macro
-                    # program (drafts + batched verification per round)
-                    self._macro_paged_fn = self._D.jitted_macro_step_slots_spec(
-                        self.cfg, self.draft_cfg, self.chunk, self.n_spec,
-                        sampled=plan_sampled)
-                    (toks_dev, counts_dev, firsts_dev, self._next_dev,
-                     self.cache, self.draft_cache) = self._macro_paged_fn(
-                        self.params, self.draft_params, self.cache,
-                        self.draft_cache, self._next_dev,
-                        jnp.asarray(steps), jnp.asarray(has_admit),
-                        jnp.asarray(prompts), jnp.asarray(lengths),
-                        jnp.asarray(starts), jnp.asarray(slots),
-                        jnp.asarray(rems), jnp.asarray(seeds),
-                        jnp.asarray(tables), jnp.asarray(temps),
-                        jnp.asarray(top_ks), jnp.asarray(top_ps),
-                        jnp.asarray(stops),
-                    )
-                    self._record_dispatch(
-                        t0, time.perf_counter(), self._macro_paged_fn,
-                        [r for p in phases for _, r in p["admissions"]]
-                        + [r for p in phases for _, r, _ in p["takes"]],
-                    )
-                    self._m["dispatches"] += 1
-                    for ph in phases:
-                        self._m["slot_steps"] += ph["steps"] * self.n_slots
-                        self._m["useful_slot_steps"] += sum(
-                            t for _, _, t in ph["takes"])
-                    self._pending.append(
-                        ("spec", (toks_dev, counts_dev), firsts_dev, phases,
-                         seq))
-                    return
                 toks_dev, firsts_dev, self._next_dev, self.cache = (
-                    self._macro_paged_fn(
-                        self.params, self.cache, self._next_dev,
-                        jnp.asarray(steps), jnp.asarray(has_admit),
-                        jnp.asarray(prompts), jnp.asarray(lengths),
-                        jnp.asarray(starts), jnp.asarray(slots),
-                        jnp.asarray(rems), jnp.asarray(seeds),
-                        jnp.asarray(tables), jnp.asarray(temps),
-                        jnp.asarray(top_ks), jnp.asarray(top_ps),
-                        jnp.asarray(stops),
-                    )
-                )
-            else:
-                toks_dev, firsts_dev, self._next_dev, self.cache = self._macro_fn(
-                    self.params, self.cache, self._next_dev,
-                    jnp.asarray(steps), jnp.asarray(has_admit), jnp.asarray(prompts),
-                    jnp.asarray(lengths), jnp.asarray(slots), jnp.asarray(rems),
-                )
+                    self._macro_paged_fn(self.params, self.cache,
+                                         self._next_dev, *plan_args))
+                entry = ("macro", toks_dev, firsts_dev, phases, seq)
         except Exception:
             # park the plan so _die can fail requests whose ONLY remaining
             # reference is this plan (admitted AND fully planned-out slots
             # are already evicted from the host bookkeeping)
             self._pending.append(("macro", None, None, phases, seq))
             raise
-        self._record_dispatch(
-            t0, time.perf_counter(),
-            self._macro_paged_fn if self.paged else self._macro_fn,
-            [r for p in phases for _, r in p["admissions"]]
-            + [r for p in phases for _, r, _ in p["takes"]],
-        )
+        self._record_dispatch(t0, time.perf_counter(), self._macro_paged_fn,
+                              riders)
         self._m["dispatches"] += 1
         for ph in phases:
             live = sum(t for _, _, t in ph["takes"])
@@ -1740,7 +1641,7 @@ class ContinuousBatchingEngine:
             self._m["ctx_chunks"] += _ctx_chunks(phases, self._ctx_chunk)
             self._m["span_chunks"] += sum(ph["steps"] for ph in phases) * -(
                 -self._mb * self.block_size // self._ctx_chunk)
-        self._pending.append(("macro", toks_dev, firsts_dev, phases, seq))
+        self._pending.append(entry)
 
     def _shed_expired(self) -> None:
         """Deadline shed at plan boundaries: a QUEUED request whose
@@ -1856,106 +1757,6 @@ class ContinuousBatchingEngine:
                 with span(_SPAN_IDLE):
                     self._wake.wait(timeout=0.01)
                     self._wake.clear()
-
-    # ---- legacy per-chunk path (macro_phases=0): kept for A/B tests ----
-    def _admit(self) -> None:
-        """Move queued requests into free slots. Admissions are BATCHED:
-        requests bucket by power-of-two padded prompt length and each
-        bucket prefills in ONE dispatch (prefill_into_slots), not one
-        per sequence (a dispatch's cost on a directly attached chip:
-        not measured)."""
-        import jax.numpy as jnp
-
-        free = [i for i, r in enumerate(self._slots) if r is None]
-        batch: List[tuple] = []
-        while free and self._waiting:
-            slot, req = free.pop(0), self._waiting.popleft()
-            self._dec_qtok(req)
-            # claim the slot BEFORE the prefill dispatch so a failed
-            # dispatch still leaves the request reachable by _die
-            self._slots[slot] = req
-            batch.append((slot, req))
-        if not batch:
-            return
-        buckets: Dict[int, List[tuple]] = {}
-        for slot, req in batch:
-            buckets.setdefault(self._bucket(len(req.prompt)), []).append((slot, req))
-        for tb, members in buckets.items():
-            prompts = np.zeros((len(members), tb), np.int32)
-            lengths = np.zeros(len(members), np.int32)
-            slots = np.zeros(len(members), np.int32)
-            for n, (slot, req) in enumerate(members):
-                prompts[n, : len(req.prompt)] = req.prompt
-                lengths[n] = len(req.prompt)
-                slots[n] = slot
-            t0 = time.perf_counter()
-            firsts, self.cache = self._prefill_slots(
-                self.params, jnp.asarray(prompts), jnp.asarray(lengths),
-                jnp.asarray(slots), self.cache,
-            )
-            self._record_dispatch(t0, time.perf_counter(), self._prefill_slots,
-                                  [req for _, req in members])
-            self._m["dispatches"] += 1
-            rem_updates = np.zeros(len(members), np.int32)
-            for n, (_slot, req) in enumerate(members):
-                req._first_dev = firsts[n]
-                req._remaining = req.max_new_tokens - 1
-                rem_updates[n] = req._remaining
-            self.cache["remaining"] = self.cache["remaining"].at[
-                jnp.asarray(slots)
-            ].set(jnp.asarray(rem_updates))
-            live = [n for n, (_s, r) in enumerate(members) if r._remaining > 0]
-            if live:
-                idx = jnp.asarray(slots[live])
-                self._next_dev = self._next_dev.at[idx].set(firsts[jnp.asarray(live)])
-
-    def _loop_chunked(self) -> None:
-        while self._running:
-            self._drain_queue()
-            self._shed_expired()
-            self._repair()  # timeout/cancel: free the slot before admitting
-            self._admit()
-            active = [(s, r) for s, r in enumerate(self._slots) if r is not None]
-            if not active:
-                while self._pending:
-                    self._resolve(self._pending.popleft())
-                self._maybe_publish(time.perf_counter())
-                self._collect_after_compiles()
-                self._wake.wait(timeout=0.05)
-                self._wake.clear()
-                continue
-            # prefill-only requests resolve without a decode chunk
-            takes = []
-            for slot, req in active:
-                if req._remaining == 0:
-                    takes.append((slot, req, 0))
-                    self._slots[slot] = None
-            if len(takes) == len(active):
-                self._pending.append(("chunk", None, takes))
-                continue
-            # dispatch the next chunk fed from device-side tokens (no sync)
-            t0 = time.perf_counter()
-            toks_dev, self.cache = self._chunk_fn(self.params, self.cache, self._next_dev)
-            self._next_dev = toks_dev[:, -1]
-            self._record_dispatch(t0, time.perf_counter(), self._chunk_fn,
-                                  [r for _, r in active])
-            self._m["dispatches"] += 1
-            self._m["slot_steps"] += self.chunk * self.n_slots
-            # deterministic bookkeeping: plan takes + evictions from
-            # host counters — token values never gate scheduling
-            for slot, req in active:
-                if req._remaining == 0:
-                    continue
-                take = min(req._remaining, self.chunk)
-                req._remaining -= take
-                self._m["useful_slot_steps"] += take
-                takes.append((slot, req, take))
-                if req._remaining == 0:
-                    self._slots[slot] = None  # evict: freed for next admit
-            self._pending.append(("chunk", toks_dev, takes))
-            # fetch one chunk BEHIND: overlaps the chunk just dispatched
-            while len(self._pending) > 1:
-                self._resolve(self._pending.popleft())
 
     # ---- shared plumbing ----------------------------------------------
     def _record_dispatch(self, t0: float, t1: float, jit_fn, reqs) -> None:
@@ -2104,7 +1905,7 @@ class ContinuousBatchingEngine:
             self._migrate_out(req)
 
     def _resolve(self, entry) -> None:
-        """Fetch one macro-step's (or legacy chunk's) tokens — the only
+        """Fetch one macro-step's tokens — the only
         host sync, one dispatch behind the frontier — and deliver them
         to requests according to the plan. Dispatch is async, so a
         poisoned device program often surfaces HERE (at the blocking
@@ -2161,26 +1962,16 @@ class ContinuousBatchingEngine:
                             est = max(1, est)
                         req._rounds_est = max(0, est)
             return
-        if entry[0] == "macro":
-            _, toks_dev, firsts_dev, phases, _seq = entry
-            with self._span(_SPAN_FETCH):
-                toks = np.asarray(toks_dev)
-                firsts = np.asarray(firsts_dev)
-            for k, ph in enumerate(phases):
-                for a, (_slot, req) in enumerate(ph["admissions"]):
-                    self._deliver(req, [int(firsts[k, a])])
-                for slot, req, take in ph["takes"]:
-                    if take:
-                        self._deliver(req, [int(t) for t in toks[k, :take, slot]])
-            return
-        _, toks_dev, takes = entry
-        toks = np.asarray(toks_dev) if toks_dev is not None else None
-        for slot, req, take in takes:
-            if req._first_dev is not None:
-                self._deliver(req, [int(np.asarray(req._first_dev))])
-                req._first_dev = None
-            if take and toks is not None:
-                self._deliver(req, [int(t) for t in toks[slot, :take]])
+        _, toks_dev, firsts_dev, phases, _seq = entry
+        with self._span(_SPAN_FETCH):
+            toks = np.asarray(toks_dev)
+            firsts = np.asarray(firsts_dev)
+        for k, ph in enumerate(phases):
+            for a, (_slot, req) in enumerate(ph["admissions"]):
+                self._deliver(req, [int(firsts[k, a])])
+            for slot, req, take in ph["takes"]:
+                if take:
+                    self._deliver(req, [int(t) for t in toks[k, :take, slot]])
 
     def _die(self, msg: str) -> None:
         """Fail every in-flight and queued request with a diagnostic and
@@ -2200,12 +1991,9 @@ class ContinuousBatchingEngine:
         self._dead = msg
         doomed = set()
         for entry in self._pending:
-            if entry[0] in ("macro", "spec"):
-                for ph in entry[3]:
-                    doomed.update(r for _, r in ph["admissions"])
-                    doomed.update(r for _, r, _ in ph["takes"])
-            else:
-                doomed.update(r for _, r, _ in entry[2])
+            for ph in entry[3]:
+                doomed.update(r for _, r in ph["admissions"])
+                doomed.update(r for _, r, _ in ph["takes"])
         self._pending.clear()
         doomed.update(r for r in self._slots if r is not None)
         self._slots = [None] * self.n_slots
@@ -2242,10 +2030,7 @@ class ContinuousBatchingEngine:
 
     def _loop(self) -> None:
         try:
-            if self.macro_phases > 0:
-                self._loop_macro()
-            else:
-                self._loop_chunked()
+            self._loop_macro()
             while self._pending:  # clean shutdown: drain the frontier
                 self._resolve(self._pending.popleft())
         except Exception as e:  # noqa: BLE001 — anything device-side

@@ -15,9 +15,10 @@ The breakdown is what makes the bill actionable: a fat `restore` says
 ship Gemini-style peer state transfer, a fat `recompile` says persist
 the compilation cache, a fat `detect` says tighten probe timeouts.
 
-`summary()` feeds `/api/training` (via observability.publish_snapshot)
-and bench.py's elastic section; the ROADMAP bench gate is
-goodput ≥ 95% under injected preemptions.
+`summary()` feeds `/api/training` (via observability.publish_snapshot).
+Goodput under injected preemptions on the chip: not measured (no cell
+of benchmark/run.py saves, loses a slice or resumes; PERF.md section
+7, `pretrain-resume`).
 """
 from __future__ import annotations
 

@@ -15,31 +15,29 @@ import pytest
 from ray_tpu.serve import llm_engine as E
 
 
-def _engine(model: str, macro_phases: int):
+def _engine(model: str):
     if model == "hybrid":
         from ray_tpu.models import granite_hybrid as M
 
         cfg = M.GraniteHybridConfig.tiny(dtype=jnp.float32)
-        kw = dict(paged=True, block_size=16, prefix_cache=False, max_len=128)
+        kw = dict(block_size=16, prefix_cache=False, max_len=128)
     else:
         from ray_tpu.models import llama as M
 
         cfg = M.LlamaConfig.tiny(dtype=jnp.float32, attn_impl="blockwise", remat=False)
-        kw = dict(paged=True, block_size=8, max_len=64) if macro_phases else dict(
-            paged=False, max_len=64)
+        kw = dict(block_size=8, max_len=64)
     params = M.init_params(jax.random.PRNGKey(0), cfg)
     return E.ContinuousBatchingEngine(params, cfg, n_slots=2, chunk=4,
-                                      macro_phases=macro_phases, **kw)
+                                      macro_phases=4, **kw)
 
 
-@pytest.mark.parametrize("model,macro_phases", [("hybrid", 4), ("llama", 4), ("llama", 0)],
-                         ids=["hybrid-macro", "llama-macro", "llama-chunked"])
-def test_loop_collects_once_it_idles_after_a_compile(monkeypatch, model, macro_phases):
+@pytest.mark.parametrize("model", ["hybrid", "llama"], ids=["hybrid-macro", "llama-macro"])
+def test_loop_collects_once_it_idles_after_a_compile(monkeypatch, model):
     """One explicit pass after a dispatch that compiled, none after a dispatch
     of a program that is warm."""
     passes = []
     monkeypatch.setattr(E.gc, "collect", lambda *a: passes.append(time.perf_counter()))
-    eng = _engine(model, macro_phases)
+    eng = _engine(model)
 
     def serve_then_idle():
         eng.generate([5, 6, 7], 3)

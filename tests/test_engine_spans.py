@@ -298,7 +298,7 @@ def test_ctx_chunks_follow_the_planned_contexts():
     `_remaining`); the metric is the spans' sum and never passes
     `span_chunks`, every step reading the whole table span."""
     cfg, params = _cfg_params()
-    eng = ContinuousBatchingEngine(params, cfg, paged=True, n_slots=2, chunk=4, macro_phases=4,
+    eng = ContinuousBatchingEngine(params, cfg, n_slots=2, chunk=4, macro_phases=4,
                                    max_len=1024, block_size=16, prefix_cache=False)
     eng.shutdown()  # the plans below are made on this thread
     C = D.decode_chunk_positions(16, 1024 // 16)
@@ -356,7 +356,7 @@ def _engine_events(trace_dir):
 
 def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
     cfg, params = _cfg_params()
-    eng = ContinuousBatchingEngine(params, cfg, paged=True, n_slots=2, chunk=4,
+    eng = ContinuousBatchingEngine(params, cfg, n_slots=2, chunk=4,
                                    macro_phases=4, max_len=64, block_size=8)
     rng = np.random.default_rng(0)
     prompt = lambda n: rng.integers(0, cfg.vocab_size, n).tolist()  # noqa: E731

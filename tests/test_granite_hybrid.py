@@ -243,7 +243,7 @@ def test_a_lane_is_untouched_by_the_others():
 def _engine(**kw):
     cfg, _, params = _model()
     return ContinuousBatchingEngine(params, cfg, **{**dict(
-        paged=True, n_slots=3, chunk=4, macro_phases=4, max_len=128, block_size=BLOCK,
+        n_slots=3, chunk=4, macro_phases=4, max_len=128, block_size=BLOCK,
         prefix_cache=False), **kw})
 
 
@@ -291,7 +291,6 @@ REFUSED_AT_CONSTRUCTION = {
     "num_speculative_tokens": dict(num_speculative_tokens=2),
     "role": dict(role="decode"),
     "cluster_cache": dict(cluster_cache=True),
-    "paged=True": dict(paged=False, macro_phases=0),  # the dense slot cache
 }
 
 

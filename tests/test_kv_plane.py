@@ -37,7 +37,6 @@ def _tiny_engine(**kw):
     kw.setdefault("n_slots", 2)
     kw.setdefault("chunk", 4)
     kw.setdefault("macro_phases", 4)
-    kw.setdefault("paged", True)
     kw.setdefault("block_size", 8)
     kw.setdefault("n_blocks", 64)
     return ContinuousBatchingEngine(params, cfg, **kw), params, cfg
@@ -217,17 +216,16 @@ def test_deployment_rejects_pool_autoscaling_without_pools():
         D.options(pool_config={"decode": 1})
 
 
-def test_llm_deployment_pools_requires_continuous_paged():
+def test_llm_deployment_pools_requires_continuous():
     from ray_tpu.serve.llm import llm_deployment
 
     with pytest.raises(ValueError, match="continuous"):
         llm_deployment(pools={"prefill": 1, "decode": 1})
-    with pytest.raises(ValueError, match="paged"):
-        llm_deployment(pools={"prefill": 1, "decode": 1}, continuous=True,
-                       macro_phases=0)
 
 
-def test_engine_role_requires_paged_and_shared_draft():
+def test_engine_role_is_validated_and_needs_a_shared_draft():
+    import dataclasses
+
     import jax
 
     from ray_tpu.models import llama
@@ -235,11 +233,13 @@ def test_engine_role_requires_paged_and_shared_draft():
 
     cfg = llama.LlamaConfig.tiny()
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
-    with pytest.raises(ValueError, match="paged"):
-        ContinuousBatchingEngine(params, cfg, macro_phases=0, paged=False,
-                                 role="prefill")
     with pytest.raises(ValueError, match="role"):
         ContinuousBatchingEngine(params, cfg, role="verify")
+    # a draft pool of its own cannot follow a migration
+    with pytest.raises(ValueError, match="shared-pool draft"):
+        ContinuousBatchingEngine(
+            params, cfg, role="prefill", num_speculative_tokens=2,
+            draft_model=dataclasses.replace(cfg, n_layers=1))
 
 
 # ------------------------------------------------- role routing (fakes)
@@ -595,7 +595,7 @@ def test_pooled_deployment_end_to_end(_cleanup_serve):
     prompt = _prompt(19)
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
     ref = ContinuousBatchingEngine(params, cfg, n_slots=2, chunk=4,
-                                   macro_phases=4, paged=True, block_size=8,
+                                   macro_phases=4, block_size=8,
                                    n_blocks=64)
     try:
         want = ref.generate(prompt, 8, timeout=180)

@@ -240,7 +240,7 @@ def test_non_speculative_program_has_zero_draft_flops():
 
         eng = ContinuousBatchingEngine(
             params, cfg, n_slots=N_SLOTS, chunk=CHUNK, macro_phases=2,
-            max_len=MAX_LEN, paged=True, block_size=BLOCK)
+            max_len=MAX_LEN, block_size=BLOCK)
         assert eng._macro_paged_fn is D.jitted_macro_step_slots_paged(
             cfg, CHUNK, sampled=False)
         assert eng.draft_params is None and eng.draft_cache is None
@@ -262,7 +262,7 @@ def test_engine_block_leak_audit_mixed_workload():
                                  remat=False)
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
     eng = ContinuousBatchingEngine(params, cfg, n_slots=3, chunk=4,
-                                   macro_phases=4, max_len=64, paged=True,
+                                   macro_phases=4, max_len=64,
                                    block_size=8)
     try:
         rng = np.random.default_rng(0)
@@ -294,3 +294,27 @@ def test_engine_block_leak_audit_mixed_workload():
         eng.shutdown()
     eng._prefix.clear()
     assert eng._alloc.check_zero(), eng._alloc.leaked()
+
+
+def _engine_mode_findings(source: str):
+    """What the one-engine lint holds serve/llm_engine.py to: no dense slot
+    mode (`self.paged`, `init_slot_cache`), no per-chunk loop, no call of a
+    dense slot program, and one loop over `self._running`."""
+    found = [word for word in ("self.paged", "_loop_chunked", "init_slot_cache",
+                               "_chunk_fn(", "_prefill_slots(", "_macro_fn(")
+             if word in source]
+    loops = source.count("while self._running")
+    return found + ([f"{loops} loops over self._running"] if loops != 1 else [])
+
+
+def test_engine_source_has_one_mode_and_one_loop():
+    import inspect
+
+    from ray_tpu.serve import llm_engine
+
+    assert _engine_mode_findings(inspect.getsource(llm_engine)) == []
+    # the lint flags what it is there to keep out
+    two_modes = ("while self._running:\n  if self.paged: x = self._macro_fn(y)\n"
+                 "while self._running: pass")
+    assert _engine_mode_findings(two_modes) == [
+        "self.paged", "_macro_fn(", "2 loops over self._running"]

@@ -25,7 +25,7 @@ def _paged_engine(params, cfg, **kw):
     kw.setdefault("macro_phases", 4)
     kw.setdefault("max_len", 64)
     kw.setdefault("block_size", 8)
-    return ContinuousBatchingEngine(params, cfg, paged=True, **kw)
+    return ContinuousBatchingEngine(params, cfg, **kw)
 
 
 # --------------------------------------------------------------- allocator
@@ -551,30 +551,6 @@ def test_decode_lowering_has_no_layer_copy_and_no_span_gather(model, monkeypatch
 
 
 # ------------------------------------------------- engine-level behavior
-def test_paged_engine_matches_dense_engine_greedy():
-    """The paged engine is a pure memory-architecture change for greedy
-    requests: identical tokens to the dense macro engine."""
-    from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
-
-    params, cfg = _tiny()
-    prompts = [[1, 2, 3], [4, 5], [6, 7, 8, 9], [10], [11, 12], [13, 14, 15]]
-    lens = [7, 2, 11, 1, 5, 4]
-    outs = {}
-    for paged in (False, True):
-        eng = ContinuousBatchingEngine(
-            params, cfg, n_slots=2, chunk=4, macro_phases=4, max_len=64,
-            paged=paged, block_size=8)
-        try:
-            reqs = [eng.submit(p, n) for p, n in zip(prompts, lens)]
-            for r in reqs:
-                assert r.done.wait(180), "engine request timed out"
-                assert r.error is None, r.error
-            outs[paged] = [r.tokens for r in reqs]
-        finally:
-            eng.shutdown()
-    assert outs[False] == outs[True]
-
-
 def test_paged_oversubscription_same_kv_budget():
     """THE paging win: 2x the dense config's concurrent sequences served
     to completion from the SAME KV budget. Dense budget = 2 slots x 64
@@ -744,7 +720,7 @@ def test_stop_token_truncates_through_macro_repair():
         assert req.tokens == w[:cut], (req.tokens, w, stop_tok)
         assert req.finish_reason == "stop"
         m = eng.metrics()
-        assert m["speculative_waste_pct"] > 0
+        assert m["plan_repair_waste_pct"] > 0
         # the repaired slot is reusable: a follow-up runs fine
         again = eng.generate([5, 6, 7], 4)
         assert again == w[:4]
@@ -788,20 +764,30 @@ def test_timeout_cancels_and_frees_blocks():
     assert eng._alloc.check_zero(), eng._alloc.leaked()
 
 
-def test_dense_engine_rejects_sampling():
-    """The dense macro program is the greedy-invariant one: sampling and
-    stop tokens must be refused up front, not silently ignored."""
-    from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
+def test_default_engine_samples_and_stops():
+    """`ContinuousBatchingEngine(params, cfg)` with no further argument is
+    the engine every deployment runs: it has a block allocator, and it
+    serves a seeded sampled request and a stop-token request."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as D
     from ray_tpu.serve._internal.sampling import SamplingParams
+    from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
 
     params, cfg = _tiny()
-    eng = ContinuousBatchingEngine(params, cfg, n_slots=2, chunk=4,
-                                   macro_phases=4, max_len=64, paged=False)
+    greedy = D.generate(params, jnp.asarray([[1, 2]], jnp.int32), cfg,
+                        max_new_tokens=6)[0].tolist()
+    eng = ContinuousBatchingEngine(params, cfg)
     try:
-        with pytest.raises(ValueError, match="paged"):
-            eng.submit([1, 2], 4, sampling=SamplingParams(temperature=0.5))
-        with pytest.raises(ValueError, match="paged"):
-            eng.submit([1, 2], 4, sampling=SamplingParams(stop=(3,)))
+        assert eng._alloc is not None and eng.metrics()["kv_blocks_total"] > 0
+        sampled = [eng.generate([1, 2], 6, sampling=SamplingParams(
+            temperature=0.9, seed=7)) for _ in range(2)]
+        assert sampled[0] == sampled[1] and len(sampled[0]) == 6
+        assert all(0 <= t < cfg.vocab_size for t in sampled[0])
+        req = eng.submit([1, 2], 6, sampling=SamplingParams(stop=(greedy[2],)))
+        assert req.done.wait(180) and req.error is None, req.error
+        assert req.tokens == greedy[: greedy.index(greedy[2])]
+        assert req.finish_reason == "stop"
     finally:
         eng.shutdown()
 

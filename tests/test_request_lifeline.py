@@ -44,7 +44,6 @@ def _tiny_engine(**kw):
     kw.setdefault("n_slots", 2)
     kw.setdefault("chunk", 4)
     kw.setdefault("macro_phases", 4)
-    kw.setdefault("paged", True)
     kw.setdefault("block_size", 8)
     kw.setdefault("n_blocks", 64)
     return ContinuousBatchingEngine(params, cfg, **kw)

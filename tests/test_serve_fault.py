@@ -297,7 +297,7 @@ def _engine(**kw):
     kw.setdefault("macro_phases", 2)
     kw.setdefault("max_len", 64)
     kw.setdefault("block_size", 8)
-    return ContinuousBatchingEngine(params, cfg, paged=True, **kw)
+    return ContinuousBatchingEngine(params, cfg, **kw)
 
 
 def test_engine_sheds_on_queue_bound():
@@ -567,8 +567,8 @@ def test_chaos_kill_tiny_engine_zero_lost(_cleanup_serve):
     one retry — zero lost. (Slow tier: two replica processes compile
     the macro programs, ~1 min on the 2-core sandbox; the tier-1 chaos
     smoke pins the same kill→detect→redispatch→restart machinery on a
-    cheap deployment in <20s, and bench.py's serve_fault section runs
-    this gate per round.)"""
+    cheap deployment in <20s; on the chip the gate is not measured: no
+    cell of benchmark/run.py kills a replica.)"""
     import jax.numpy as jnp
 
     from ray_tpu.models import llama

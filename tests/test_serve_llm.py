@@ -31,31 +31,41 @@ def test_llm_deployment_batched_generation(ray_start_regular):
         serve.delete("llm_app")
 
 
-def test_continuous_engine_eviction_correctness():
+_SIX = ([[1, 2, 3], [4, 5], [6, 7, 8, 9], [10], [11, 12], [13, 14, 15]],
+        [7, 2, 11, 1, 5, 4])
+# name -> (engine options, prompts, generation lengths): 2 slots under 5 or
+# 6 requests of mixed prompt and generation lengths force queueing,
+# mid-chunk finishes, eviction and slot reuse
+EVICTION_CASES = {
+    "defaults": (dict(n_slots=2, chunk=4),
+                 [[1, 2, 3], [4, 5], [6, 7, 8, 9], [10], [11, 12]], [6, 3, 9, 1, 5]),
+    "plans-of-4-phases": (dict(n_slots=2, chunk=4, macro_phases=4), *_SIX),
+    "blocks-of-8": (dict(n_slots=2, chunk=4, macro_phases=4, max_len=64,
+                         block_size=8), *_SIX),
+}
+
+
+@pytest.mark.parametrize("case", EVICTION_CASES)
+def test_continuous_engine_eviction_correctness(case):
     """Mixed-length sequences decoded concurrently through the
     continuous-batching engine must produce EXACTLY the tokens the
     static path produces for each prompt alone — admission, chunked
-    decode, mid-chunk freezing, eviction and slot reuse change nothing
-    (reference: vLLM-style iteration-level scheduling; here the
-    TPU-native engine in serve/llm_engine.py)."""
-    import jax
+    decode, mid-chunk freezing, eviction and slot reuse change nothing,
+    whatever the plan's length and the pool's block size (reference:
+    vLLM-style iteration-level scheduling; here the TPU-native engine
+    in serve/llm_engine.py)."""
     import jax.numpy as jnp
 
-    from ray_tpu.models import llama, llama_decode
-    from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
+    from ray_tpu.models import llama_decode
 
-    cfg = llama.LlamaConfig.tiny(dtype=jnp.float32, attn_impl="blockwise", remat=False)
-    params = llama.init_params(jax.random.PRNGKey(0), cfg)
-    # 2 slots + 5 requests of mixed prompt lengths and generation
-    # lengths: forces queueing, mid-chunk finishes, eviction + reuse
-    engine = ContinuousBatchingEngine(params, cfg, n_slots=2, chunk=4)
+    options, prompts, lens = EVICTION_CASES[case]
+    engine, params, cfg = _tiny_engine(**options)
     try:
-        prompts = [[1, 2, 3], [4, 5], [6, 7, 8, 9], [10], [11, 12]]
-        lens = [6, 3, 9, 1, 5]
         reqs = [engine.submit(p, n) for p, n in zip(prompts, lens)]
         outs = []
         for r in reqs:
             assert r.done.wait(180), "engine request timed out"
+            assert r.error is None, r.error
             outs.append(r.tokens)
         for p, n, got in zip(prompts, lens, outs):
             want = llama_decode.generate(
@@ -78,23 +88,25 @@ def _tiny_engine(**kw):
     return ContinuousBatchingEngine(params, cfg, **kw), params, cfg
 
 
-@pytest.mark.parametrize("macro_phases", [0, 4])
-def test_engine_non_power_of_two_max_len(macro_phases):
-    """A prompt whose power-of-two bucket exceeds a non-power-of-two
-    max_len must decode correctly instead of crashing the engine thread
-    at prefill trace time (bucket 64 > cache depth 48)."""
+@pytest.mark.parametrize("block_size", [8, 16])
+def test_engine_non_power_of_two_max_len(block_size):
+    """A prompt whose power-of-two bucket exceeds a table span that is no
+    power of two (6 blocks of 8, 3 of 16) must decode correctly instead
+    of crashing the engine thread at prefill trace time (bucket 64 >
+    span 48: `_bucket_paged` clamps to the span)."""
     import jax.numpy as jnp
 
     from ray_tpu.models import llama_decode
 
     engine, params, cfg = _tiny_engine(n_slots=2, chunk=4, max_len=48,
-                                       macro_phases=macro_phases)
+                                       macro_phases=4, block_size=block_size)
     try:
         # empty prompts are rejected up front (length 0 is the macro
-        # plan's padding sentinel; the prefill logits would be garbage)
+        # plan's padding sentinel)
         with pytest.raises(ValueError, match="non-empty"):
             engine.submit([], 4)
         prompt = list(range(1, 34))  # len 33: buckets to 64 without the clamp
+        assert engine._bucket_paged(len(prompt)) == 48
         got = engine.generate(prompt, 6, timeout=120)
         want = llama_decode.generate(
             params, jnp.asarray([prompt], jnp.int32), cfg, max_new_tokens=6
@@ -104,20 +116,36 @@ def test_engine_non_power_of_two_max_len(macro_phases):
         engine.shutdown()
 
 
-@pytest.mark.parametrize("macro_phases", [0, 4])
-def test_engine_poisoned_dispatch_fails_fast(macro_phases):
-    """A poisoned device program must surface a diagnostic error on every
-    in-flight request and kill the engine — not N generic 120s timeouts."""
-    engine, _, _ = _tiny_engine(n_slots=2, chunk=4, macro_phases=macro_phases)
-    try:
-        def boom(*a, **k):
-            raise ValueError("poisoned device program")
+def _poison_macro_step(monkeypatch, engine, wrap):
+    """Put `wrap(real_program, sampled)` where the engine takes its program
+    from: `_dispatch_macro` asks the decode module for the greedy or the
+    sampled variant at every dispatch."""
+    real = engine._D.jitted_macro_step_slots_paged
+    monkeypatch.setattr(
+        engine._D, "jitted_macro_step_slots_paged",
+        lambda cfg, chunk, sampled: wrap(real(cfg, chunk, sampled=sampled), sampled))
 
-        engine._macro_fn = boom
-        engine._chunk_fn = boom
-        engine._prefill_slots = boom
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
+def test_engine_poisoned_dispatch_fails_fast(monkeypatch, temperature):
+    """A poisoned device program (either variant the dispatch binds) must
+    surface a diagnostic error on every in-flight request and kill the
+    engine — not N generic 120s timeouts."""
+    engine, _, _ = _tiny_engine(n_slots=2, chunk=4, macro_phases=4)
+    try:
+        bound = []
+
+        def wrap(_real, sampled):
+            def boom(*a, **k):
+                bound.append(sampled)
+                raise ValueError("poisoned device program")
+            return boom
+
+        _poison_macro_step(monkeypatch, engine, wrap)
         with pytest.raises(RuntimeError, match="poisoned device program"):
-            engine.generate([1, 2, 3], 6, timeout=30)
+            engine.generate([1, 2, 3], 6, timeout=30,
+                            sampling={"temperature": temperature, "seed": 3})
+        assert bound == [temperature > 0]
         # engine is dead: submit refuses immediately with the diagnostic
         with pytest.raises(RuntimeError, match="engine is dead"):
             engine.submit([4, 5], 3)
@@ -125,7 +153,7 @@ def test_engine_poisoned_dispatch_fails_fast(macro_phases):
         engine.shutdown()
 
 
-def test_engine_poisoned_fetch_fails_fast():
+def test_engine_poisoned_fetch_fails_fast(monkeypatch):
     """Dispatch is async, so device faults usually surface at the
     blocking token FETCH, one macro-step behind — requests referenced
     only by the in-flight plan must still get the diagnostic."""
@@ -135,13 +163,13 @@ def test_engine_poisoned_fetch_fails_fast():
 
     engine, _, _ = _tiny_engine(n_slots=2, chunk=4, macro_phases=4)
     try:
-        real_fn = engine._macro_fn
+        def wrap(real_fn, _sampled):
+            def corrupting(*a, **k):
+                toks, firsts, feed, cache = real_fn(*a, **k)
+                return _Boom(), firsts, feed, cache
+            return corrupting
 
-        def corrupting(*a, **k):
-            toks, firsts, feed, cache = real_fn(*a, **k)
-            return _Boom(), firsts, feed, cache
-
-        engine._macro_fn = corrupting
+        _poison_macro_step(monkeypatch, engine, wrap)
         with pytest.raises(RuntimeError, match="poisoned device buffer"):
             engine.generate([1, 2, 3], 6, timeout=30)
         with pytest.raises(RuntimeError, match="engine is dead"):
@@ -150,24 +178,29 @@ def test_engine_poisoned_fetch_fails_fast():
         engine.shutdown()
 
 
-def test_macro_matches_single_chunk_path():
-    """The macro-step scheduler is a pure dispatch-count optimization:
-    identical requests produce identical tokens to the legacy
-    one-dispatch-per-chunk path."""
-    prompts = [[1, 2, 3], [4, 5], [6, 7, 8, 9], [10], [11, 12], [13, 14, 15]]
-    lens = [7, 2, 11, 1, 5, 4]
-    outs = {}
-    for mp in (0, 4):
-        engine, _, _ = _tiny_engine(n_slots=2, chunk=4, macro_phases=mp)
-        try:
-            reqs = [engine.submit(p, n) for p, n in zip(prompts, lens)]
-            for r in reqs:
-                assert r.done.wait(180), "engine request timed out"
-                assert r.error is None, r.error
-            outs[mp] = [r.tokens for r in reqs]
-        finally:
-            engine.shutdown()
-    assert outs[0] == outs[4]
+def test_removed_engine_modes_are_errors():
+    """The dense slot mode and the per-chunk loop are gone, not defaulted
+    away: `paged=` is an unknown argument on all three signatures, and
+    a plan of no phases is refused by name."""
+    import jax
+
+    from ray_tpu.models import llama
+    from ray_tpu.serve.llm import _LLMServer, llm_deployment
+    from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
+
+    cfg = llama.LlamaConfig.tiny()
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    for paged in (False, True):
+        with pytest.raises(TypeError, match="paged"):
+            ContinuousBatchingEngine(params, cfg, paged=paged)
+        with pytest.raises(TypeError, match="paged"):
+            _LLMServer(cfg=cfg, params=params, continuous=True, paged=paged)
+        with pytest.raises(TypeError, match="paged"):
+            llm_deployment(cfg=cfg, continuous=True, paged=paged)
+    with pytest.raises(ValueError, match="macro_phases"):
+        ContinuousBatchingEngine(params, cfg, macro_phases=0)
+    with pytest.raises(ValueError, match="macro_phases"):
+        _LLMServer(cfg=cfg, params=params, continuous=True, macro_phases=0)
 
 
 def test_adaptive_chunk_bookkeeping_skewed():
@@ -204,8 +237,7 @@ def test_adaptive_chunk_bookkeeping_skewed():
 
 def test_macro_dispatch_amortization_smoke():
     """CI smoke invariant: the macro-step engine issues <= 1 dispatch per
-    K chunks (driven synchronously so the count is deterministic), and
-    the legacy per-chunk path pays >= 5x more on the same workload."""
+    K chunks (driven synchronously so the count is deterministic)."""
     import math
 
     engine, _, _ = _tiny_engine(n_slots=2, chunk=4, macro_phases=4)
@@ -221,15 +253,6 @@ def test_macro_dispatch_amortization_smoke():
     steps_total = m["slot_steps"] // engine.n_slots
     chunks = math.ceil(steps_total / engine.chunk)
     assert m["dispatches"] <= max(1, math.ceil(chunks / engine.macro_phases)), m
-
-    legacy, _, _ = _tiny_engine(n_slots=2, chunk=4, macro_phases=0)
-    try:
-        lreqs = [legacy.submit([1 + i, 2 + i, 3 + i], 8) for i in range(4)]
-        for r in lreqs:
-            assert r.done.wait(180)
-        assert legacy.metrics()["dispatches"] >= 5 * m["dispatches"]
-    finally:
-        legacy.shutdown()
 
 
 def test_continuous_llm_deployment(ray_start_regular):

@@ -39,7 +39,7 @@ def _engine(params, cfg, draft="self", **kw):
     kw.setdefault("block_size", 8)
     if draft is not None:
         kw.setdefault("num_speculative_tokens", N_SPEC)
-    return ContinuousBatchingEngine(params, cfg, paged=True,
+    return ContinuousBatchingEngine(params, cfg,
                                     draft_model=draft, **kw)
 
 
@@ -241,17 +241,14 @@ def test_reference_acceptance_math():
 
 
 def test_speculation_config_validation():
-    """Config errors are loud: speculation needs the paged engine, a
-    positive token count, and a vocab-matched draft."""
+    """Config errors are loud: speculation needs a positive token count
+    and a vocab-matched draft."""
     import dataclasses
 
     from ray_tpu.models import llama
     from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
 
     cfg, params = _tiny()
-    with pytest.raises(ValueError, match="paged"):
-        ContinuousBatchingEngine(params, cfg, macro_phases=0, paged=False,
-                                 draft_model="self", num_speculative_tokens=2)
     with pytest.raises(ValueError, match="num_speculative_tokens"):
         _engine(params, cfg, num_speculative_tokens=0)
     with pytest.raises(ValueError, match="draft_model"):
