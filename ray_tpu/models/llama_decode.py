@@ -741,6 +741,37 @@ def finish_decode_step(logits, cache, active, temps, top_ks, top_ps, stop_ids,
     return nxt, pos, remaining, new_rng
 
 
+def _qkv(a, layer, cfg: LlamaConfig):
+    """The paged programs' q / k / v projections: a (..., d_model) against
+    one layer's `wq`, `wk`, `wv`, split into heads AFTER the products.
+    Returns q (..., h, hd), k and v (..., kvh, hd), each `a @ w` bit for bit.
+
+    The barrier keeps the head split out of the product. Without it the TPU
+    compiler folds `.reshape(..., h, hd)` into the matmul and reads the
+    weight as (head, head_dim, d_model); to feed that it copies every
+    layer's slice out of the stacked parameter in EVERY decode step and
+    transposes the three whole stacks in every dispatch. Compiled for a
+    v5e at Mistral-7B's widths, 16 layers, 4 lanes (compiled only, PR 32;
+    tests/test_tpu_compile.py holds it): three multi-output fusions a step
+    that write 16 x bf16[1,4096,4096] + 2 x 16 x bf16[1,4096,1024], 805 MB
+    (2.22 ms of an 11.69 ms step on the chip, PR 30's trace, segment
+    `slice`), three to six copies of a bf16[16,4096,*] stack a dispatch,
+    and 1.56 / 1.87 GB of temporaries at (A, P) = (1, 16) / (4, 512); with
+    it the product reads `params["layers"]["wq"]` where it lies, as `wo`
+    and the MLP's do, and the temporaries are 0.002 / 0.27 GB. The barrier
+    alone is NOT enough: with the sixteen layers unrolled every form that
+    drops the stack copies makes the compiler copy the whole K pool
+    (bf16[16,1025,16,8,128], 537 MB) twice a decode step, so the decode
+    step and the admission walk their layers in a rolled scan. Do not
+    simplify either away without running that compile test."""
+    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    lead = a.shape[:-1]
+    q, k, v = jax.lax.optimization_barrier(
+        (a @ layer["wq"], a @ layer["wk"], a @ layer["wv"]))
+    return (q.reshape(*lead, h, hd), k.reshape(*lead, kvh, hd),
+            v.reshape(*lead, kvh, hd))
+
+
 def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
                             top_ps, stop_ids, cfg: LlamaConfig,
                             sampled: bool = True):
@@ -762,8 +793,7 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
     no vocab sort/softmax/cumsum, no rng splits — so an all-greedy
     workload pays exactly the pre-sampling per-step cost. Stop-token
     detection stays (greedy requests may carry stop ids)."""
-    B = tokens.shape[0]
-    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    hd = cfg.head_dim
     bs = cache["k"].shape[2]
     S = tables.shape[1] * bs
     pos = cache["pos"]
@@ -776,9 +806,7 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
         x, k_full, v_full = carry
         layer, li = layer_and_idx
         a = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q = (a @ layer["wq"]).reshape(B, 1, h, hd)
-        k = (a @ layer["wk"]).reshape(B, 1, kvh, hd)
-        v = (a @ layer["wv"]).reshape(B, 1, kvh, hd)
+        q, k, v = _qkv(a, layer, cfg)
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
 
@@ -792,11 +820,12 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
         x = x + (gate * (m @ layer["w_up"])) @ layer["w_down"]
         return (x, k_full, v_full), None
 
+    # rolled: one layer body in the program, `li` a run-time value (see _qkv
+    # for what the compiler does to sixteen unrolled layers)
     (x, new_k, new_v), _ = jax.lax.scan(
         body,
         (x, cache["k"], cache["v"]),
         (params["layers"], jnp.arange(cfg.n_layers)),
-        unroll=True,
     )
     x = rms_norm(x[:, 0, :], params["final_norm"], cfg.rms_eps)
     logits = x.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
@@ -1011,7 +1040,7 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     `seeds[n]`; the carried key lands in the slot's rng state.
     Returns (first tokens (A,), cache, feed)."""
     A, P = prompts.shape
-    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    hd = cfg.head_dim
     S = tables.shape[1] * cache["k"].shape[2]
     adm_tables = tables[slots]  # (A, MB)
     valid = lengths > 0
@@ -1025,9 +1054,7 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
         x, k_full, v_full = carry
         layer, li = layer_and_idx
         a = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q = (a @ layer["wq"]).reshape(A, P, h, hd)
-        k = (a @ layer["wk"]).reshape(A, P, kvh, hd)
-        v = (a @ layer["wv"]).reshape(A, P, kvh, hd)
+        q, k, v = _qkv(a, layer, cfg)
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
 
@@ -1044,11 +1071,10 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
         x = x + (gate * (m @ layer["w_up"])) @ layer["w_down"]
         return (x, k_full, v_full), None
 
-    (x, k_big, v_big), _ = jax.lax.scan(
+    (x, k_big, v_big), _ = jax.lax.scan(  # rolled, as the decode step's
         body,
         (x, cache["k"], cache["v"]),
         (params["layers"], jnp.arange(cfg.n_layers)),
-        unroll=True,
     )
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits_all = x.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
@@ -1199,7 +1225,7 @@ def _forward_tokens_paged(params, kv_k, kv_v, tokens, row_tables, base_pos,
     as T sequential decode steps would — the speculative verify kernel.
     Returns (logits (R, T, V) f32 or None, kv_k, kv_v)."""
     R, T = tokens.shape
-    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    kvh, hd = cfg.n_kv_heads, cfg.head_dim
     bs = kv_k.shape[2]
     MB = row_tables.shape[1]
     S = MB * bs
@@ -1214,9 +1240,7 @@ def _forward_tokens_paged(params, kv_k, kv_v, tokens, row_tables, base_pos,
         x, k_full, v_full = carry
         layer, li = layer_and_idx
         a = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q = (a @ layer["wq"]).reshape(R, T, h, hd)
-        k = (a @ layer["wk"]).reshape(R, T, kvh, hd)
-        v = (a @ layer["wv"]).reshape(R, T, kvh, hd)
+        q, k, v = _qkv(a, layer, cfg)
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
 

@@ -550,6 +550,174 @@ def test_decode_lowering_has_no_layer_copy_and_no_span_gather(model, monkeypatch
     assert any(name == "gather" for name, _ in spans)
 
 
+# --------------- q / k / v projections: the head split stays out of the product (PR 32)
+def _qkv_folded(a, layer, cfg):
+    """The formulation every paged program had until PR 32, kept as the
+    lint's reference: the head reshape applied straight to the product, which
+    the TPU compiler folds into the matmul (tests/test_tpu_compile.py)."""
+    lead = a.shape[:-1]
+    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    return ((a @ layer["wq"]).reshape(*lead, h, hd),
+            (a @ layer["wk"]).reshape(*lead, kvh, hd),
+            (a @ layer["wv"]).reshape(*lead, kvh, hd))
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("rows", [(4, 1), (4, 512)])
+def test_qkv_helper_equals_plain_products(rows, dtype):
+    """_qkv is `a @ w` bit for bit, split into heads afterwards, for a decode
+    step's 4 x 1 rows and an admission's 4 x 512."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+    from ray_tpu.models import llama_decode as D
+
+    jdt = jnp.float32 if dtype == "f32" else jnp.bfloat16
+    cfg = llama.LlamaConfig.tiny(dtype=jdt)
+    layer = jax.tree.map(lambda x: x[1], llama.init_params(jax.random.PRNGKey(3), cfg)["layers"])
+    a = jnp.asarray(np.random.default_rng(0).standard_normal(rows + (cfg.d_model,)), jdt)
+    got = jax.jit(lambda a, layer: D._qkv(a, layer, cfg))(a, layer)
+    for out, name, heads in zip(got, ("wq", "wk", "wv"),
+                                (cfg.n_heads, cfg.n_kv_heads, cfg.n_kv_heads)):
+        want = jax.jit(jnp.matmul)(a, layer[name])
+        assert out.dtype == want.dtype == jdt
+        assert out.shape == rows + (heads, cfg.head_dim)
+        np.testing.assert_array_equal(
+            np.asarray(out, np.float32).reshape(want.shape), np.asarray(want, np.float32))
+
+
+def test_layer_index_traced_matches_static():
+    """The rolled layer scans hand `li` to the pool's writers and to the
+    decode attention as a run-time value: each gives, for every layer, what
+    it gives for the same index as a Python constant."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as D
+
+    L, B, bs, MB, h, kvh, hd, P = 3, 2, 8, 4, 4, 2, 16, 16
+    n_blocks = B * MB + 1
+    rng = np.random.default_rng(5)
+    arr = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)  # noqa: E731
+    k_full, v_full = arr(L, n_blocks, bs, kvh, hd), arr(L, n_blocks, bs, kvh, hd)
+    tables = jnp.asarray((rng.permutation(n_blocks - 1) + 1).reshape(B, MB), jnp.int32)
+    pos, active = jnp.asarray([9, 20], jnp.int32), jnp.asarray([True, True])
+    k1, v1, q = arr(B, 1, kvh, hd), arr(B, 1, kvh, hd), arr(B, h, hd)
+    kP, vP = arr(B, P, kvh, hd), arr(B, P, kvh, hd)
+    starts, valid = jnp.asarray([0, 8], jnp.int32), jnp.asarray([True, True])
+    programs = {
+        "write_decode_kv": lambda li: D.write_decode_kv(
+            k_full, v_full, li, k1, v1, tables, pos, active),
+        "attend_decode_paged": lambda li: D.attend_decode_paged(
+            q, k_full, v_full, li, tables, pos, active, 0.25),
+        "write_admission_kv": lambda li: D.write_admission_kv(
+            k_full, v_full, li, kP, vP, tables, starts, valid),
+    }
+    for name, f in programs.items():
+        traced = jax.jit(f)
+        for li in range(L):
+            for got, want in zip(jax.tree.leaves(traced(jnp.int32(li))),
+                                 jax.tree.leaves(f(li))):
+                np.testing.assert_array_equal(np.asarray(got), np.asarray(want), err_msg=name)
+
+
+def _head_splits_of_products(program):
+    """(reshapes that split the last axis of a bare dot_general's output into
+    (heads, head_dim); optimization barriers over dot_generals) in the jaxpr
+    of one of Llama's three paged programs, at the tiny widths."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama_decode as D
+
+    params, cfg = _tiny()
+    B, bs, MB, T = 4, 16, 8, 16
+    cache = D.init_paged_cache(cfg, B, B * MB + 1, bs)
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    sampling = (jnp.zeros(B, jnp.float32), i32(B), jnp.ones(B, jnp.float32), i32(B, 4))
+    if program == "decode_step_slots_paged":
+        jaxpr = jax.make_jaxpr(lambda *a: D.decode_step_slots_paged(*a, cfg, sampled=False))(
+            params, cache, i32(B), i32(B, MB), *sampling)
+    elif program == "admit_slots_paged":
+        jaxpr = jax.make_jaxpr(lambda *a: D.admit_slots_paged(*a, cfg, sampled=False))(
+            params, i32(B, T), i32(B), i32(B), i32(B), i32(B), jnp.zeros(B, jnp.uint32),
+            cache, i32(B), i32(B, MB), *sampling)
+    else:
+        jaxpr = jax.make_jaxpr(lambda *a: D._forward_tokens_paged(*a, cfg))(
+            params, cache["k"], cache["v"], i32(B, T), i32(B, MB), i32(B),
+            jnp.ones(B, bool))
+    heads = {(cfg.n_heads, cfg.head_dim), (cfg.n_kv_heads, cfg.head_dim)}
+    eqns = list(_walk_eqns(jaxpr.jaxpr))
+    made_by = {id(v): e.primitive.name for e in eqns for v in e.outvars}
+    folded = [tuple(e.outvars[0].aval.shape) for e in eqns
+              if e.primitive.name == "reshape"
+              and tuple(e.outvars[0].aval.shape[-2:]) in heads
+              and made_by.get(id(e.invars[0])) == "dot_general"]
+    barriers = [e for e in eqns if e.primitive.name == "optimization_barrier"
+                and all(made_by.get(id(v)) == "dot_general" for v in e.invars)]
+    return folded, barriers
+
+
+@pytest.mark.parametrize("program", ["decode_step_slots_paged", "admit_slots_paged",
+                                     "_forward_tokens_paged"])
+def test_paged_programs_split_heads_after_a_barrier(program, monkeypatch):
+    """Lint: in each of Llama's paged programs no bare dot_general feeds a
+    head reshape; the three projections pass one optimization barrier first
+    (llama_decode._qkv says what the compiler does otherwise). The
+    formulation they had until PR 32 must trip the detector."""
+    from ray_tpu.models import llama_decode as D
+
+    folded, barriers = _head_splits_of_products(program)
+    assert not folded, f"{program} reshapes a product into heads: {folded}"
+    assert len(barriers) == 1 and len(barriers[0].invars) == 3
+    monkeypatch.setattr(D, "_qkv", _qkv_folded)
+    folded, barriers = _head_splits_of_products(program)
+    assert len(folded) == 3 and not barriers, "the lint failed to flag the folded formulation"
+
+
+def test_hybrid_macro_step_lowers_without_llamas_halves(monkeypatch):
+    """The hybrid decoder brings its own two halves and its own projections:
+    its lowered macro-step holds no optimization barrier and is the same
+    text, byte for byte, with Llama's halves and their helper taken away
+    (PR 32 changed those and nothing the hybrid lowers; compared once with
+    the parent commit's text, PERF.md section 6)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import granite_hybrid as G
+    from ray_tpu.models import granite_hybrid_decode as GD
+    from ray_tpu.models import llama_decode as D
+    from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
+
+    cfg = G.GraniteHybridConfig.tiny(dtype=jnp.float32)
+    params = G.init_params(jax.random.PRNGKey(0), cfg)
+    B, bs, K, A, P, MB = 4, 16, 2, 1, 16, 8
+    cache = GD.init_paged_cache(cfg, B, B * MB + 1, bs)
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    f32 = lambda *shape: jnp.zeros(shape, jnp.float32)  # noqa: E731
+
+    def lowered():
+        # a fresh jit each time: the memoized one would hand back its trace
+        step = jax.jit(lambda *a: D.macro_step_slots_paged(
+            *a, chunk=2, cfg=cfg, sampled=False, admit=GD.admit_slots_paged,
+            decode_step=GD.decode_step_slots_paged))
+        return step.lower(
+            params, cache, i32(B), i32(K), jnp.zeros(K, bool), i32(K, A, P), i32(K, A),
+            i32(K, A), i32(K, A), i32(K, A), jnp.zeros((K, A), jnp.uint32), i32(K, B, MB),
+            f32(K, B), i32(K, B), f32(K, B), i32(K, B, MAX_STOP_TOKENS)).as_text()
+
+    text = lowered()
+    assert "optimization_barrier" not in text
+
+    def gone(*a, **kw):
+        raise AssertionError("the hybrid's macro-step traced one of Llama's halves")
+
+    for name in ("_qkv", "decode_step_slots_paged", "admit_slots_paged"):
+        monkeypatch.setattr(D, name, gone)
+    assert lowered() == text
+
+
 # ------------------------------------------------- engine-level behavior
 def test_paged_oversubscription_same_kv_budget():
     """THE paging win: 2x the dense config's concurrent sequences served

@@ -54,6 +54,18 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _shapes_on(sharding):
+    """(arr, shaped): a ShapeDtypeStruct on `sharding` (int32 unless told),
+    and a pytree of arrays or shapes turned into such."""
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: arr(x.shape, x.dtype), tree)
+
+    return arr, shaped
+
+
 def _qkv(shape, sharding):
     B, T, H, KVH, D = shape
     q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=sharding)
@@ -174,12 +186,7 @@ def test_paged_decode_step_copies_no_pool_layer(one_chip):
     B, bs, MB = 4, 16, 256
     n_blocks = B * MB + 1
 
-    def arr(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    def shaped(tree):
-        return jax.tree.map(lambda x: arr(x.shape, x.dtype), tree)
-
+    arr, shaped = _shapes_on(one_chip)
     params = shaped(jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0), cfg)))
     cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, n_blocks, bs)))
     text = jax.jit(functools.partial(D.decode_step_slots_paged, cfg=cfg, sampled=False),
@@ -192,6 +199,94 @@ def test_paged_decode_step_copies_no_pool_layer(one_chip):
     assert f"bf16[{B},{chunk},{bs},8,128]" in outputs                  # a chunk's gather
     assert f"bf16[{n_blocks},{bs},8,128]" not in outputs, "a pool layer is copied"
     assert f"bf16[{B},{MB},{bs},8,128]" not in outputs, "the table span is gathered"
+
+
+def _mistral_macro_step(one_chip, A, P):
+    """Llama's paged macro-step as the Mistral serve cells run it (16 layers
+    at the published widths, 4 lanes, blocks of 16, a table span of 4096, the
+    default pool of 1,025 blocks, 8 phases of 8 steps, greedy, cache
+    donated), compiled for the described chip at the (A, P) variant."""
+    from ray_tpu.models import llama
+    from ray_tpu.models import llama_decode as D
+    from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
+
+    cfg = LlamaConfig(vocab_size=32768, d_model=4096, n_layers=16, n_heads=32,
+                      n_kv_heads=8, d_ff=14336, max_seq_len=4096, dtype=jnp.bfloat16)
+    B, bs, K = 4, 16, 8
+    MB = cfg.max_seq_len // bs
+
+    arr, shaped = _shapes_on(one_chip)
+    params = shaped(jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
+    # a jit of its own: the memoized one would hand a patched helper's trace on
+    step = jax.jit(D._bind(D.macro_step_slots_paged, chunk=8, cfg=cfg, sampled=False),
+                   donate_argnums=(1,))
+    return step.lower(
+        params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
+        arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
+        arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
+        arr((K, B, MAX_STOP_TOKENS))).compile()
+
+
+def _weight_and_pool_copies(text):
+    """What the folded q / k / v products cost, among the instructions of an
+    optimized module that are operations of their own (they carry
+    `estimated_cycles`; a fused computation's inner instructions do not):
+    (outputs that are one layer's whole wq / wk / wv, transposed or fused
+    forms included; copies of a whole stack of weights; copies of the whole
+    K or V pool)."""
+    import re
+
+    slices, stacks, pools = [], [], []
+    line = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(")
+    for ln in text.splitlines():
+        m = line.match(ln)
+        if not m or '"estimated_cycles"' not in ln:
+            continue
+        name, out, op = m.groups()
+        shapes = set(re.findall(r"bf16\[[\d,]+\]", out))
+        slices += [(name, s) for s in shapes if re.fullmatch(
+            r"bf16\[1,4096,(4096|1024|6144)\]|bf16\[1,(1024|6144),4096\]", s)]
+        if op == "copy":
+            stacks += [(name, s) for s in shapes if re.fullmatch(r"bf16\[16,\d{4,},\d{4,}\]", s)]
+            pools += [(name, s) for s in shapes if s == "bf16[16,1025,16,8,128]"]
+    return slices, stacks, pools
+
+
+def test_paged_macro_step_reads_projection_weights_in_place(one_chip, monkeypatch):
+    """Mistral's macro-step at the serve cells' size, the dispatch that
+    admits nothing, (1, 16), and the chat cells' widest, (4, 512): the
+    q / k / v products read the stacked parameters where they lie. No
+    operation outputs a layer's whole projection matrix, none copies a
+    stack of weights, none copies the K or V pool, and the temporaries
+    stay under 0.9 GB.
+
+    Until PR 32 the head reshape sat on the product, the compiler folded it
+    into the matmul and fed that from copies: three fusions in every decode
+    step that write each layer's wq / wk / wv out of the stack (805 MB a
+    step), three to six transposed copies of the stacks a dispatch, 1.56 /
+    1.87 GB of temporaries. A barrier alone is not enough: with the sixteen
+    layers unrolled the compiler then copies the whole K pool, 537 MB, twice
+    a decode step (it does not at 2 layers, hence the depth here). The
+    folded products (tests/test_paged_kv.py keeps them) must trip the detector."""
+    from ray_tpu.models import llama_decode as D
+    from tests.test_paged_kv import _qkv_folded
+
+    monkeypatch.setattr(FA, "_on_tpu", lambda: True)
+    for A, P in ((1, 16), (4, 512)):
+        compiled = _mistral_macro_step(one_chip, A, P)
+        slices, stacks, pools = _weight_and_pool_copies(compiled.as_text())
+        assert not slices, f"({A}, {P}): a layer's projection weights are copied: {slices[:4]}"
+        assert not stacks, f"({A}, {P}): a stack of weights is copied: {stacks}"
+        assert not pools, f"({A}, {P}): the whole pool is copied: {pools}"
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 0.9e9, (A, P, temp)
+
+    monkeypatch.setattr(D, "_qkv", _qkv_folded)
+    compiled = _mistral_macro_step(one_chip, 1, 16)
+    slices, stacks, _ = _weight_and_pool_copies(compiled.as_text())
+    assert slices and stacks, "the detector failed to flag the folded products"
+    assert compiled.memory_analysis().temp_size_in_bytes > 0.7e9
 
 
 def test_hybrid_macro_step_keeps_state_and_pool_in_place(one_chip):
@@ -211,12 +306,7 @@ def test_hybrid_macro_step_keeps_state_and_pool_in_place(one_chip):
     B, bs, K, A, P = 32, 16, 8, 1, 16
     MB = cfg.max_seq_len // bs
 
-    def arr(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    def shaped(tree):
-        return jax.tree.map(lambda x: arr(x.shape, x.dtype), tree)
-
+    arr, shaped = _shapes_on(one_chip)
     params = shaped(jax.eval_shape(lambda: G.init_params(jax.random.PRNGKey(0), cfg)))
     cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
     compiled = D.jitted_macro_step_slots_paged(cfg, 8, sampled=False).lower(
