@@ -66,26 +66,6 @@ def state_bytes_per_lane(cfg: GraniteHybridConfig) -> int:
     return cfg.n_mamba_layers * (conv + ssm)
 
 
-def _write_lane_rows(full, li, rows, slots, valid, lane_axis: int = 1):
-    """full[li, ..., slots[n], ...] = rows[n] for the valid rows (lanes on
-    `lane_axis` of `full`), one in-place update a row; invalid rows all
-    name lane 0 and write nothing."""
-    rest = rows.shape[1:]
-    shape = (1,) + rest[:lane_axis - 1] + (1,) + rest[lane_axis - 1:]
-
-    def write(n, full):
-        def wr(full):
-            row = jax.lax.dynamic_index_in_dim(rows, n, 0, keepdims=False)
-            at = [0] * full.ndim
-            at[0], at[lane_axis] = li, slots[n]
-            return jax.lax.dynamic_update_slice(
-                full, row.reshape(shape).astype(full.dtype), at)
-
-        return jax.lax.cond(valid[n], wr, lambda full: full, full)
-
-    return jax.lax.fori_loop(0, rows.shape[0], write, full)
-
-
 def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
                       cache, feed, tables, temps, top_ks, top_ps, stop_ids,
                       cfg: GraniteHybridConfig, sampled: bool = True):
@@ -99,8 +79,8 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     def mamba_mixer(layer, mi, a, carry):
         k_full, v_full, conv, ssm = carry
         out, tail, h = G.mamba_sequence(layer, a, lengths, cfg)
-        conv = _write_lane_rows(conv, mi, tail, slots, valid, lane_axis=2)
-        ssm = _write_lane_rows(ssm, mi, h, slots, valid)
+        conv = L.write_lane_rows(conv, mi, tail, slots, valid, lane_axis=2)
+        ssm = L.write_lane_rows(ssm, mi, h, slots, valid)
         return out, (k_full, v_full, conv, ssm)
 
     def attn_mixer(layer, ai, a, carry):
@@ -191,35 +171,9 @@ def jitted_macro_step_slots_paged(cfg: GraniteHybridConfig, chunk: int,
 
 
 # ------------------------------------------------------- static generation
-_BLOCK = 16
-
-
 def _generate(params, prompt, cfg: GraniteHybridConfig, n_new: int):
-    """Greedy tokens (R, n_new) for prompts (R, T) of one length: one
-    admission and n_new - 1 decode steps through a paged cache that holds
-    exactly these rows."""
-    R, T = prompt.shape
-    mb = -(-(T + n_new) // _BLOCK)
-    P = -(-T // _BLOCK) * _BLOCK
-    cache = init_paged_cache(cfg, R, R * mb + 1, _BLOCK)
-    tables = 1 + jnp.arange(R * mb, dtype=jnp.int32).reshape(R, mb)
-    zeros = jnp.zeros((R,), jnp.int32)
-    plan = dict(temps=jnp.zeros((R,), jnp.float32), top_ks=zeros,
-                top_ps=jnp.ones((R,), jnp.float32),
-                stop_ids=jnp.full((R, 1), -1, jnp.int32))
-    first, cache, feed = admit_slots_paged(
-        params, jnp.pad(prompt, ((0, 0), (0, P - T))), jnp.full((R,), T, jnp.int32), zeros,
-        jnp.arange(R, dtype=jnp.int32), jnp.full((R,), n_new - 1, jnp.int32),
-        zeros.astype(jnp.uint32), cache, zeros, tables, cfg=cfg, sampled=False, **plan)
-
-    def step(carry, _):
-        cache, feed = carry
-        _, nxt, cache = decode_step_slots_paged(
-            params, cache, feed, tables, cfg=cfg, sampled=False, **plan)
-        return (cache, nxt), nxt
-
-    _, rest = jax.lax.scan(step, (cache, feed), None, length=n_new - 1)
-    return jnp.concatenate([first[:, None], rest.T], axis=1)
+    return L.generate_through_paged_cache(
+        init_paged_cache, admit_slots_paged, decode_step_slots_paged, params, prompt, cfg, n_new)
 
 
 @functools.lru_cache(maxsize=64)

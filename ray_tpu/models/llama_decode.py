@@ -1585,6 +1585,57 @@ def macro_step_slots_spec(params, draft_params, cache, draft_cache, feed,
     return toks, counts, firsts, feed, cache, draft_cache
 
 
+def write_lane_rows(full, li, rows, slots, valid, lane_axis: int = 1):
+    """full[li, ..., slots[n], ...] = rows[n] for the valid rows (lanes on
+    `lane_axis` of `full`), one in-place update a row; invalid rows all
+    name lane 0 and write nothing."""
+    rest = rows.shape[1:]
+    shape = (1,) + rest[:lane_axis - 1] + (1,) + rest[lane_axis - 1:]
+
+    def write(n, full):
+        def wr(full):
+            row = jax.lax.dynamic_index_in_dim(rows, n, 0, keepdims=False)
+            at = [0] * full.ndim
+            at[0], at[lane_axis] = li, slots[n]
+            return jax.lax.dynamic_update_slice(
+                full, row.reshape(shape).astype(full.dtype), at)
+
+        return jax.lax.cond(valid[n], wr, lambda full: full, full)
+
+    return jax.lax.fori_loop(0, rows.shape[0], write, full)
+
+
+def generate_through_paged_cache(init_cache, admit, decode_step, params, prompt,
+                                 cfg, n_new: int, block: int = 16):
+    """Greedy tokens (R, n_new) for prompts (R, T) of one length, for a
+    model that has only the paged halves: one admission and n_new - 1
+    decode steps through a paged cache that holds exactly these rows
+    (`init_cache`, `admit`, `decode_step`: the model's init_paged_cache,
+    admit_slots_paged and decode_step_slots_paged)."""
+    R, T = prompt.shape
+    mb = -(-(T + n_new) // block)
+    P = -(-T // block) * block
+    cache = init_cache(cfg, R, R * mb + 1, block)
+    tables = 1 + jnp.arange(R * mb, dtype=jnp.int32).reshape(R, mb)
+    zeros = jnp.zeros((R,), jnp.int32)
+    plan = dict(temps=jnp.zeros((R,), jnp.float32), top_ks=zeros,
+                top_ps=jnp.ones((R,), jnp.float32),
+                stop_ids=jnp.full((R, 1), -1, jnp.int32))
+    first, cache, feed = admit(
+        params, jnp.pad(prompt, ((0, 0), (0, P - T))), jnp.full((R,), T, jnp.int32), zeros,
+        jnp.arange(R, dtype=jnp.int32), jnp.full((R,), n_new - 1, jnp.int32),
+        zeros.astype(jnp.uint32), cache, zeros, tables, cfg=cfg, sampled=False, **plan)
+
+    def step(carry, _):
+        cache, feed = carry
+        _, nxt, cache = decode_step(
+            params, cache, feed, tables, cfg=cfg, sampled=False, **plan)
+        return (cache, nxt), nxt
+
+    _, rest = jax.lax.scan(step, (cache, feed), None, length=n_new - 1)
+    return jnp.concatenate([first[:, None], rest.T], axis=1)
+
+
 def _bind(f, **static):
     """`functools.partial(f, **static)` under `f`'s own name. `jax.jit`
     names a program after its function's `__name__`, and a bare partial

@@ -64,7 +64,9 @@ def blockwise_attention(
     return o
 
 
-def _fwd_impl(q, k, v, causal, block_size, sm_scale, q_offset, kv_offset):
+def _fwd_impl(q, k, v, causal, block_size, sm_scale, q_offset, kv_offset, window=None):
+    """`window` (forward only, with `causal`): query i attends key j with
+    0 <= i - j < window."""
     B, T, H, D = q.shape
     S = k.shape[1]
     scale = sm_scale if sm_scale is not None else D ** -0.5
@@ -90,7 +92,10 @@ def _fwd_impl(q, k, v, causal, block_size, sm_scale, q_offset, kv_offset):
         if causal:
             q_ids = q_offset + jax.lax.broadcasted_iota(jnp.int32, (T, blk), 0)
             kv_ids = kv_offset + base + jax.lax.broadcasted_iota(jnp.int32, (T, blk), 1)
-            bias = jnp.where(kv_ids <= q_ids, 0.0, NEG_INF)
+            seen = kv_ids <= q_ids
+            if window is not None:
+                seen = seen & (kv_ids > q_ids - window)
+            bias = jnp.where(seen, 0.0, NEG_INF)
             s = s + bias[None, :, None, :]
         if pad:
             kv_ids2 = base + jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1)

@@ -28,7 +28,8 @@ from ray_tpu.ops.blockwise_attention import _broadcast_kv, _bwd as _blockwise_bw
 NEG_INF = -1e30
 
 
-def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *, scale, causal, bq, bk, nk):
+def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *, scale, causal, bq, bk, nk,
+               window=None):
     j = pl.program_id(2)
     i = pl.program_id(1)
 
@@ -42,6 +43,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *, scale, cau
     run = True
     if causal:
         run = j * bk <= i * bq + bq - 1
+    if window is not None:
+        # ... and those wholly behind the window of the block's first row
+        run = jnp.logical_and(run, j * bk + bk - 1 > i * bq - window)
 
     @pl.when(run)
     def _():
@@ -60,6 +64,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *, scale, cau
             rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
             s = jnp.where(cols <= rows, s, NEG_INF)
+            if window is not None:  # row i attends j with 0 <= i - j < window
+                s = jnp.where(cols > rows - window, s, NEG_INF)
         m_prev = m_s[:]                                    # [bq, 1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)                             # [bq, bk] f32
@@ -83,7 +89,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *, scale, cau
         lse_ref[0, 0] = jnp.transpose(m_s[:] + jnp.log(l_safe), (1, 0))
 
 
-def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None):
     B, T, H, D = q.shape
     S = k.shape[1]
     scale = sm_scale if sm_scale is not None else D ** -0.5
@@ -99,7 +105,7 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     vr = v.transpose(0, 2, 1, 3).reshape(B * H, S, D)
 
     kernel = functools.partial(
-        _fa_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nk=nk
+        _fa_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nk=nk, window=window
     )
     o, lse = pl.pallas_call(
         kernel,
@@ -364,23 +370,29 @@ def kernel_supported(seq_q: int, seq_k: int, head_dim: int, block_q: int = 1024,
     )
 
 
-def _flash_fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k):
+def _flash_fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k, window=None):
     T, S = q.shape[1], k.shape[1]
     if _on_tpu() and kernel_supported(T, S, q.shape[3], block_q, block_k):
         return _flash_fwd_pallas(
             q, k, v, causal, sm_scale, _fit_block(T, block_q), _fit_block(S, block_k),
-            interpret=False,
+            interpret=False, window=window,
         )
     # XLA fallback (CPU tests, odd shapes)
-    return _fwd_impl(q, k, v, causal, max(block_q, block_k), sm_scale, 0, 0)
+    return _fwd_impl(q, k, v, causal, max(block_q, block_k), sm_scale, 0, 0, window)
 
 
 def flash_attention_fwd(q, k, v, causal: bool = True, sm_scale: Optional[float] = None,
-                        block_q: int = 1024, block_k: int = 1024):
+                        block_q: int = 1024, block_k: int = 1024,
+                        window: Optional[int] = None):
     """Forward only, outside the custom VJP: (o [B, T, H, D], lse [B, T, H]
     f32). For callers that merge partial attentions by their log-sum-exp
-    (paged admission: own suffix here, reused prefix from the pool)."""
-    return _flash_fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k)
+    (paged admission: own suffix here, reused prefix from the pool).
+    `window` (with `causal`): position i attends j with 0 <= i - j < window,
+    and key blocks wholly behind a query block's window are skipped; the
+    training path has no windowed backward and does not take it."""
+    if window is not None and not causal:
+        raise ValueError("a window is a causal mask's other edge: it needs causal=True")
+    return _flash_fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k, window)
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):

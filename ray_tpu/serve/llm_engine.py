@@ -302,7 +302,7 @@ def _suffix_len(req: "_Request") -> int:
 
 
 def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
-                     ctx_chunk: int = 0) -> Dict[str, int]:
+                     ctx_chunk: int = 0, window: int = 0) -> Dict[str, int]:
     """What one macro dispatch carries, from the plan alone: the keyword
     arguments of its `engine.dispatch` span (host integers; nothing is
     read from the device). `_plan` leaves every request at its
@@ -322,7 +322,10 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
     prompt, the tokens it has decoded and the one it feeds. Only an engine
     whose decode steps run that loop gives `ctx_chunk` (no draft model);
     the device's own count is smaller where a sampled stop ends a
-    lane before its plan does."""
+    lane before its plan does. `past_window_lane_steps` is the live
+    lane-steps whose context is longer than `window`, for a model with
+    sliding-window layers (their rings are full there, and the window's
+    mask cuts something off); no other model's dispatch carries the key."""
     total = sum(ph["steps"] for ph in phases)
     done = 0  # decode steps of this dispatch run so far
     last: Dict[int, int] = {}  # finishing request -> `done` at its last token
@@ -348,7 +351,25 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
         counts["state_lanes"] = lane_steps
     if ctx_chunk:
         counts["ctx_chunks"] = _ctx_chunks(phases, ctx_chunk)
+    if window:
+        counts["past_window_lane_steps"] = _past_window_lane_steps(phases, window)
     return counts
+
+
+def _past_window_lane_steps(phases: List[Dict[str, Any]], window: int) -> int:
+    """`past_window_lane_steps` of `_dispatch_counts`, by `_ctx_chunks`'
+    walk: a lane that owes r steps before a phase holds its prompt and
+    max_new_tokens - r positions at the phase's first step, one more each
+    step after."""
+    owed: Dict[int, int] = {}
+    past = 0
+    for ph in reversed(phases):
+        for _, req, take in ph["takes"]:
+            before = owed[id(req)] = owed.get(id(req), req._remaining) + take
+            first = len(req.prompt) + req.max_new_tokens - before
+            # its contexts in this phase: first .. first + take - 1
+            past += max(0, first + take - max(first, window + 1))
+    return past
 
 
 def _ctx_chunks(phases: List[Dict[str, Any]], ctx_chunk: int) -> int:
@@ -403,12 +424,14 @@ def _finish(req: "_Request", error: Optional[str] = None,
 
 
 def _refuse_for_recurrent_state(**asked) -> None:
-    """A model whose lanes hold recurrent state cannot resume a sequence
-    from K/V blocks: the state at a block boundary is not kept. Every
-    option that needs such a snapshot is refused by name (never silently
-    switched off) until one exists."""
-    why = ("the model's lanes hold recurrent state, and the state at a "
-           "block boundary is not kept: ")
+    """A model whose lanes hold rows of their own beside their K/V blocks
+    (recurrent state, a window layer's ring of its last positions) cannot
+    resume a sequence from the blocks: what those rows held at a block
+    boundary is not kept. Every option that needs such a snapshot is
+    refused by name (never silently switched off) until one exists."""
+    why = ("the model's lanes hold recurrent state (or a window layer's "
+           "ring) beside their blocks, and the state at a block boundary "
+           "is not kept: ")
     no_rollback = ("rejected speculative tokens cannot be rolled back out "
                    "of a recurrence")
     reasons = {
@@ -498,6 +521,13 @@ class ContinuousBatchingEngine:
         # positions a chunk of the paged decode attention covers; 0 where
         # no decode step runs that loop (speculative rounds)
         self._ctx_chunk = 0
+        # the sliding window of a model that has window layers, else 0
+        self._window = int(getattr(cfg, "sliding_window", 0) or 0)
+        # what the model's macro-step counts on the device and hands back
+        # beside its tokens (a (n,) int32 fifth return), by name: none for
+        # a model whose decode module names none. They steer nothing: the
+        # planner reads counters of its own only
+        self._device_counters = tuple(getattr(D, "DEVICE_COUNTERS", ()))
         if draft_model is None:
             from ray_tpu.models.llama_decode import decode_chunk_positions
 
@@ -621,7 +651,11 @@ class ContinuousBatchingEngine:
                    # chunks of context the paged decode attention's loop
                    # was planned to read, and what reading every step's
                    # whole table span would have been
-                   "ctx_chunks": 0, "span_chunks": 0}
+                   "ctx_chunks": 0, "span_chunks": 0,
+                   # planned live lane-steps whose context passes the
+                   # model's sliding window (0 for a model without one)
+                   "past_window_lane_steps": 0,
+                   **dict.fromkeys(self._device_counters, 0)}
         shared = _engine_metrics()
         self._tags = {"engine": name}
         self._ttft = _LatencyHist(_TTFT_BOUNDS, shared["ttft"], self._tags)
@@ -895,12 +929,13 @@ class ContinuousBatchingEngine:
 
     def _refuse_block_transfer(self, what: str) -> None:
         """K/V blocks shipped between replicas resume nothing where a
-        lane also holds recurrent state."""
+        lane also holds rows of its own."""
         if self.state_bytes:
             raise ValueError(
                 f"{what} is refused: the model's lanes hold recurrent "
-                "state, and K/V blocks without the state at their "
-                "boundary cannot resume or seed a sequence")
+                "state (or a window layer's ring) beside their blocks, "
+                "and K/V blocks without the state at their boundary "
+                "cannot resume or seed a sequence")
 
     def submit_resumed(self, prompt: List[int], first_token: int,
                        max_new_tokens: int, k, v, n_data_blocks: int,
@@ -1618,10 +1653,11 @@ class ContinuousBatchingEngine:
             else:
                 self._macro_paged_fn = self._D.jitted_macro_step_slots_paged(
                     self.cfg, self.chunk, sampled=plan_sampled)
-                toks_dev, firsts_dev, self._next_dev, self.cache = (
-                    self._macro_paged_fn(self.params, self.cache,
-                                         self._next_dev, *plan_args))
-                entry = ("macro", toks_dev, firsts_dev, phases, seq)
+                (toks_dev, firsts_dev, self._next_dev, self.cache,
+                 *counted_dev) = self._macro_paged_fn(
+                    self.params, self.cache, self._next_dev, *plan_args)
+                entry = ("macro", toks_dev, firsts_dev, phases, seq,
+                         *counted_dev)
         except Exception:
             # park the plan so _die can fail requests whose ONLY remaining
             # reference is this plan (admitted AND fully planned-out slots
@@ -1637,6 +1673,9 @@ class ContinuousBatchingEngine:
             self._m["useful_slot_steps"] += live
             if self.state_bytes:
                 self._m["state_lane_steps"] += live
+        if self._window:
+            self._m["past_window_lane_steps"] += _past_window_lane_steps(
+                phases, self._window)
         if self._ctx_chunk:
             self._m["ctx_chunks"] += _ctx_chunks(phases, self._ctx_chunk)
             self._m["span_chunks"] += sum(ph["steps"] for ph in phases) * -(
@@ -1707,8 +1746,10 @@ class ContinuousBatchingEngine:
     def _resolve_next(self) -> None:
         """Resolve the oldest dispatch in flight, under its span."""
         entry = self._pending.popleft()
-        with self._span(_SPAN_RESOLVE, seq=entry[4]):
-            self._resolve(entry)
+        with self._span(_SPAN_RESOLVE, seq=entry[4]) as span:
+            counted = self._resolve(entry)
+            if counted:  # the dispatch's device counters, as the span's stats
+                span.set_metadata(**counted)
 
     def _loop_macro(self) -> None:
         # every stretch of an iteration runs under one of ENGINE_SPANS, so
@@ -1738,7 +1779,7 @@ class ContinuousBatchingEngine:
                 if phases:
                     A, P = self._variant(phases)
                     counts = _dispatch_counts(phases, bool(self.state_bytes),
-                                              self._ctx_chunk)
+                                              self._ctx_chunk, self._window)
             if phases:
                 with span(_SPAN_DISPATCH, seq=self._m["dispatches"], A=A, P=P,
                           **counts):
@@ -1910,9 +1951,10 @@ class ContinuousBatchingEngine:
         to requests according to the plan. Dispatch is async, so a
         poisoned device program often surfaces HERE (at the blocking
         fetch), after the entry already left _pending — re-park it so
-        _die can still reach its requests."""
+        _die can still reach its requests. Returns the dispatch's
+        device counters by name (none for most models)."""
         try:
-            self._resolve_inner(entry)
+            return self._resolve_inner(entry)
         except Exception:
             self._pending.appendleft(entry)
             raise
@@ -1962,16 +2004,21 @@ class ContinuousBatchingEngine:
                             est = max(1, est)
                         req._rounds_est = max(0, est)
             return
-        _, toks_dev, firsts_dev, phases, _seq = entry
+        _, toks_dev, firsts_dev, phases, _seq, *counted_dev = entry
         with self._span(_SPAN_FETCH):
             toks = np.asarray(toks_dev)
             firsts = np.asarray(firsts_dev)
+            counted = {name: int(v) for dev in counted_dev for name, v in
+                       zip(self._device_counters, np.asarray(dev))}
+        for name, v in counted.items():
+            self._m[name] += v
         for k, ph in enumerate(phases):
             for a, (_slot, req) in enumerate(ph["admissions"]):
                 self._deliver(req, [int(firsts[k, a])])
             for slot, req, take in ph["takes"]:
                 if take:
                     self._deliver(req, [int(t) for t in toks[k, :take, slot]])
+        return counted
 
     def _die(self, msg: str) -> None:
         """Fail every in-flight and queued request with a diagnostic and

@@ -319,3 +319,54 @@ def test_hybrid_macro_step_keeps_state_and_pool_in_place(one_chip):
     # 0.354 GB while the decode step gathered every lane's whole span, 0.150
     # with the chunked decode attention (compiled only, PR 30)
     assert m.temp_size_in_bytes < 0.25e9, m.temp_size_in_bytes
+
+
+def test_flash_forward_kernel_with_a_window_compiles(one_chip):
+    """The admission of a prompt longer than the sliding window: 4096
+    positions, 32 / 4 heads of 128, a window of 2048, blocks of 1024."""
+    shape = (1, 4096, 32, 4, 128)
+    bq, bk = _blocks(shape)
+    fwd = functools.partial(FA._flash_fwd_pallas, causal=True, sm_scale=None,
+                            block_q=bq, block_k=bk, interpret=False, window=2048)
+    lowered = jax.jit(fwd).lower(*_qkv(shape, one_chip))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    lowered.compile()
+
+
+def test_afmoe_macro_step_reads_the_expert_stacks_in_place(one_chip, monkeypatch):
+    """The AFMoE decoder's paged macro-step at `trinity-mini.serve`'s widths
+    (one dense and four expert layers of 128 experts, 8 lanes, a table span
+    of 8192), the dispatch that admits nothing: 8.75 GB of weights, pool and
+    rings go in, and no operation outputs one layer's experts (a
+    bf16[128, 2048, 1024] or its transpose, 537 MB). It did, three times a
+    layer and decode step, 0.83 GB of temporaries, while `expert_ffn` was
+    handed a layer's experts sliced out of the stack: a ragged product is a
+    kernel and no slice fuses into its operand (compiled only, PR 33). With
+    the layer folded into the group axis the temporaries are 0.18 GB."""
+    import re
+
+    from ray_tpu.models import afmoe as M
+    from ray_tpu.models import afmoe_decode as D
+    from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
+
+    monkeypatch.setattr(FA, "_on_tpu", lambda: True)
+    cfg = M.AfmoeConfig(layer_types=(M.SLIDING,) * 4 + (M.FULL,), n_dense_layers=1,
+                        max_seq_len=8192)
+    B, bs, K, A, P = 8, 16, 8, 1, 16
+    MB = cfg.max_seq_len // bs
+
+    arr, shaped = _shapes_on(one_chip)
+    params = shaped(jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
+    compiled = D.jitted_macro_step_slots_paged(cfg, 8, sampled=False).lower(
+        params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
+        arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
+        arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
+        arr((K, B, MAX_STOP_TOKENS))).compile()
+    m = compiled.memory_analysis()
+    assert 8.7e9 < m.argument_size_in_bytes < 8.8e9 and m.alias_size_in_bytes > 0.26e9
+    assert m.temp_size_in_bytes < 0.3e9, m.temp_size_in_bytes
+    whole_layer = re.compile(r"bf16\[(1,)?128,(2048,1024|1024,2048)\]")
+    copied = [ln.split(" = ")[0].strip() for ln in compiled.as_text().splitlines()
+              if '"estimated_cycles"' in ln and whole_layer.search(ln.split(" = ")[1].split("(")[0])]
+    assert not copied, copied
