@@ -15,9 +15,8 @@ paired execution the plan's `steps`, `lane_steps`, `prompt_tokens` and
 decode steps and expert layers).
 
 What `program_spans` already reads (the window mark, the engine's spans, the
-macro-step's executions and their pairing with `engine.dispatch`) is taken
-from there; this file adds one more pass over the same `.xplane.pb` for the
-operations' name stacks, as `hybrid_spans` does. The readers
+macro-step's executions and their pairing with `engine.dispatch`, every
+operation's name stack and half) is taken from there. The readers
 `programs.moe_share_pct`, `kernels.moe_decode_roofline_pct`,
 `kernels.moe_prefill_roofline_pct` and `programs.attn_share_pct` are a few
 lines each on top of `afmoe_view`. A program without these scopes gives zeros,
@@ -27,18 +26,16 @@ The ragged products themselves carry NO scope in a trace: the TPU compiler
 turns each into a kernel of its own making and names it itself (`tf_op`
 `ragged-dot-none`, as it does the `ragged-dot-metadata` before it), so the
 name stack the program gave the product is gone (my chip run, PR 33: 1.5 of
-2.5 traced seconds lay under neither half). `scoped` gives such an operation
-the half of the last operation before it that had one (the sort and the gather
-of rows that feed the product run just before it, and the device runs one
-operation at a time) and the scope `moe_experts`.
+2.5 traced seconds lay under neither half). `program_spans.halves` gives such
+an operation the half of the last operation before it that had one, and
+`scoped` the scope `moe_experts`: these are the program's only ragged products.
 """
 from __future__ import annotations
 
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-from benchmark import program_spans, trace_reduce
-from benchmark.program_spans import ADMIT, DECODE
-from benchmark.trace_reduce import DEVICE_PLANE, OP_LINE
+from benchmark import program_spans
+from benchmark.program_spans import ADMIT, COMPILER_NAMED, DECODE
 
 ROUTE, EXPERTS, SHARED, WINDOW, FULL = (
     "moe_route", "moe_experts", "moe_shared", "attn_window", "attn_full")
@@ -47,7 +44,6 @@ MOE = (ROUTE, EXPERTS, SHARED)
 ALL = "all"  # every operation of a half, whatever its scope
 DEVICE_COUNTERS = ("expert_rows", "experts_hit", "expert_rows_max")
 KEYS = tuple((half, scope) for half in (ADMIT, DECODE) for scope in SCOPES + (ALL,))
-COMPILER_NAMED = "ragged-dot"  # in the HLO name of a kernel the compiler made of a ragged product
 
 ScopedOp = Tuple[float, float, str, str]  # start_s, duration_s, half, scope ("" = none)
 
@@ -62,47 +58,17 @@ def scope_of(text: str) -> str:
     return best
 
 
-def scoped(raw: Sequence[Tuple[float, float, str, str]]) -> List[ScopedOp]:
+def scoped(raw: Sequence[program_spans.NamedOp]) -> List[ScopedOp]:
     """(start_s, duration_s, HLO name, name stack) of every device operation
-    -> ScopedOps, sorted; a kernel the compiler named itself inherits from
-    the operation before it."""
-    ops: List[ScopedOp] = []
-    last = ("", "")
-    for start, dur, name, text in sorted(raw):
-        here = (program_spans.scope_of(text), scope_of(text))
-        if here[0]:
-            last = here
-        elif COMPILER_NAMED in name:
-            # the half of the operation before it; the scope is known, these
-            # are the program's only ragged products (a fusion before one may
-            # carry a neighbouring scope's name: it reads 0.1 s of the
-            # admission's kernels under no scope, my chip run, PR 33)
-            here = (last[0], EXPERTS)
-        ops.append((start, dur) + here)
-    return ops
-
-
-def scoped_ops(path: str) -> List[ScopedOp]:
-    """Every device operation of the trace file with the macro-step half and
-    the scope it lies in, sorted."""
-    from jax.profiler import ProfileData
-
-    with open(path, "rb") as f:
-        xspace = f.read()
-    stacks = program_spans.name_stacks(xspace)
-    raw = []
-    for plane in ProfileData.from_serialized_xspace(xspace).planes:
-        if not DEVICE_PLANE.match(plane.name):
-            continue
-        stack = stacks.get(plane.name, {})
-        for line in plane.lines:
-            if line.name != OP_LINE:
-                continue
-            for ev in line.events:
-                if not trace_reduce.is_container(ev.name):  # its time is its bodies'
-                    raw.append((ev.start_ns * 1e-9, ev.duration_ns * 1e-9, ev.name,
-                                stack.get(ev.name, "")))
-    return scoped(raw)
+    -> ScopedOps, sorted. A kernel the compiler named itself has the half
+    `program_spans.halves` gives it and the scope `moe_experts` (a fusion
+    before one may carry a neighbouring scope's name: it reads 0.1 s of the
+    admission's kernels under no scope, my chip run, PR 33)."""
+    raw = sorted(raw)
+    return [(start, dur, half,
+             EXPERTS if COMPILER_NAMED in name and not program_spans.scope_of(text)
+             else scope_of(text))
+            for (start, dur, name, text), half in zip(raw, program_spans.halves(raw))]
 
 
 def by_execution(ops: Sequence[ScopedOp], executions: Sequence[Tuple[float, float]]):
@@ -135,9 +101,7 @@ def view(trace: Dict[str, Any], ops: Sequence[ScopedOp]) -> Optional[Dict[str, A
     inside = lambda s, d: lo <= s + d / 2 <= hi  # noqa: E731
     pairs, _, _ = program_spans.pair_dispatches(
         [s for s in spans if s[0] == program_spans.DISPATCH], executions)
-    # the last execution a trace holds is cut by the profiler's stop: its
-    # dispatch plans more steps than the trace shows operations of (B2)
-    pairs = [(dsp, ex) for dsp, ex in pairs if inside(*ex) and ex != executions[-1]]
+    pairs = program_spans.whole_in_window(pairs, executions, window)
     resolves = {int(st["seq"]): st for n, _, _, st in spans
                 if n == program_spans.RESOLVE and "seq" in st and "experts_hit" in st}
     counted = [(dsp, ex, resolves[int(dsp[3]["seq"])]) for dsp, ex in pairs
@@ -165,5 +129,5 @@ def afmoe_view(facts: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     if trace is None:
         return None
     if "afmoe_view" not in trace:
-        trace["afmoe_view"] = view(trace, scoped_ops(trace["path"]))
+        trace["afmoe_view"] = view(trace, scoped(trace["named_ops"]))
     return trace["afmoe_view"]

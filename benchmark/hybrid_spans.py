@@ -8,9 +8,8 @@ macro-step's `admit_prefill` and `decode_chunk`:
   attn_mix    the attention layers' mixers, both halves
 
 What `program_spans` already reads (the window mark, the engine's spans, the
-macro-step's executions and their pairing with `engine.dispatch`) is taken
-from there; this file adds one more pass over the same `.xplane.pb` for the
-operations' name stacks. The readers `programs.ssm_share_pct`,
+macro-step's executions and their pairing with `engine.dispatch`, every
+operation's name stack) is taken from there. The readers `programs.ssm_share_pct`,
 `kernels.ssm_update_roofline_pct` and `kernels.ssm_scan_roofline_pct` are a
 few lines each on top of `hybrid_view`. A program without these scopes gives
 zeros, and every reader then returns None.
@@ -19,8 +18,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-from benchmark import program_spans, trace_reduce
-from benchmark.trace_reduce import DEVICE_PLANE, OP_LINE
+from benchmark import program_spans
 
 SCAN, UPDATE, PROJ, ATTN = "ssm_scan", "ssm_update", "ssm_proj", "attn_mix"
 SCOPES = (SCAN, UPDATE, PROJ, ATTN)
@@ -38,26 +36,9 @@ def scope_of(text: str) -> str:
     return best
 
 
-def scoped_ops(path: str) -> List[ScopedOp]:
-    """Every device operation of the trace file with its scope, sorted."""
-    from jax.profiler import ProfileData
-
-    with open(path, "rb") as f:
-        xspace = f.read()
-    stacks = program_spans.name_stacks(xspace)
-    ops: List[ScopedOp] = []
-    for plane in ProfileData.from_serialized_xspace(xspace).planes:
-        if not DEVICE_PLANE.match(plane.name):
-            continue
-        stack = stacks.get(plane.name, {})
-        for line in plane.lines:
-            if line.name != OP_LINE:
-                continue
-            for ev in line.events:
-                if not trace_reduce.is_container(ev.name):  # its time is its bodies'
-                    ops.append((ev.start_ns * 1e-9, ev.duration_ns * 1e-9,
-                                scope_of(stack.get(ev.name, ""))))
-    return sorted(ops)
+def scoped_ops(trace: Dict[str, Any]) -> List[ScopedOp]:
+    """Every device operation of the trace with its scope, sorted."""
+    return [(s, d, scope_of(text)) for s, d, _, text in trace["named_ops"]]
 
 
 def by_execution(ops: Sequence[ScopedOp], executions: Sequence[Tuple[float, float]]):
@@ -86,7 +67,7 @@ def view(trace: Dict[str, Any], ops: Sequence[ScopedOp]) -> Optional[Dict[str, A
     inside = lambda s, d: lo <= s + d / 2 <= hi  # noqa: E731
     pairs, _, _ = program_spans.pair_dispatches(
         [s for s in spans if s[0] == program_spans.DISPATCH], executions)
-    pairs = [(dsp, ex) for dsp, ex in pairs if inside(*ex)]
+    pairs = program_spans.whole_in_window(pairs, executions, window)
     in_window = [ex for ex in executions if inside(*ex)]
     per = by_execution(ops, executions)
     total = lambda execs: {k: sum(per[ex][k] for ex in execs) for k in SCOPES}  # noqa: E731
@@ -105,5 +86,5 @@ def hybrid_view(facts: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     if trace is None:
         return None
     if "hybrid_view" not in trace:
-        trace["hybrid_view"] = view(trace, scoped_ops(trace["path"]))
+        trace["hybrid_view"] = view(trace, scoped_ops(trace))
     return trace["hybrid_view"]

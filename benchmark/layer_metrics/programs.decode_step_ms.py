@@ -1,7 +1,8 @@
 """Device time of one decode step of the macro-step: the time of the
 operations under the `decode_chunk` scope over the decode steps dispatched
 (`steps` of the `engine.dispatch` spans), both over the window's executions
-that could be paired with their dispatch."""
+that could be paired with their dispatch and that the trace holds whole (its
+last one is cut by the profiler's stop: `program_spans.whole_in_window`)."""
 from benchmark import program_spans
 
 

@@ -3,10 +3,9 @@ under `moe_route` (scores, choice, weights), `moe_experts` (the routed
 experts' products) and `moe_shared` (the shared expert), in both halves, over
 the device time of the window's macro-step executions. Printed beside it:
 seconds under each scope and in all of each half (the expert products'
-kernels counted where they run: `afmoe_spans.scoped`), and from those the
-whole decode step and the admission's share, which `programs.decode_step_ms`
-and `programs.prefill_share_pct` under-read in this cell for want of that
-rule (PERF.md section 7)."""
+kernels counted where they run: `program_spans.halves`), and from those the
+whole decode step and the admission's share, as `programs.decode_step_ms` and
+`programs.prefill_share_pct` read them since PR 35."""
 from benchmark import afmoe_spans
 
 

@@ -54,10 +54,15 @@ IDLE, DISPATCH, RESOLVE, FETCH = "engine.idle", "engine.dispatch", "engine.resol
 MACRO_STEP = re.compile(r"^jit_macro_step_slots")
 ADMIT, DECODE = "admit_prefill", "decode_chunk"
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkdv")
+# in the HLO name of a kernel the TPU compiler makes of a ragged product and
+# names itself (`tf_op` `ragged-dot-none`, and the `ragged-dot-metadata`
+# before it): the name stack the program gave the product is gone (PR 33)
+COMPILER_NAMED = "ragged-dot"
 
 Span = Tuple[str, float, float, Dict[str, Any]]  # name, start_s, duration_s, stats
 Exec = Tuple[float, float]                       # start_s, duration_s of one macro-step execution
 Op = Tuple[float, float, str]                    # start_s, duration_s, scope ("" = neither)
+NamedOp = Tuple[float, float, str, str]          # start_s, duration_s, HLO name, name stack
 Interval = Tuple[float, float]
 
 
@@ -70,6 +75,24 @@ def scope_of(text: str) -> str:
     if a < 0 and d < 0:
         return ""
     return ADMIT if a > d else DECODE
+
+
+def halves(named: Sequence[NamedOp]) -> List[str]:
+    """The macro-step half of each operation of one device, given in the
+    order they ran. A kernel the compiler named itself carries no name stack:
+    it takes the half of the last operation before it that had one (the sort
+    and the gather of rows that feed a ragged product run just before it, and
+    the device runs one operation at a time). Other operations without a
+    stack (the compiler's own copies) stay under neither."""
+    out, last = [], ""
+    for _, _, name, text in named:
+        half = scope_of(text)
+        if half:
+            last = half
+        elif COMPILER_NAMED in name:
+            half = last
+        out.append(half)
+    return out
 
 
 def kernel_of(hlo_line: str) -> Optional[str]:
@@ -161,7 +184,7 @@ def load(trace_dir: str) -> Dict[str, Any]:
     marks: List[Interval] = []
     modules: List[Tuple[str, float, float]] = []
     busy: List[Interval] = []
-    ops: List[Op] = []
+    ops: List[Tuple[float, float, str, str, str]] = []  # an Op, then its HLO name and name stack
     kernels: Dict[str, List[float]] = {}
     devices = 0
     for plane in ProfileData.from_serialized_xspace(xspace).planes:
@@ -182,19 +205,25 @@ def load(trace_dir: str) -> Dict[str, Any]:
                     modules += [(trace_reduce.module_name(ev.name), ev.start_ns * 1e-9,
                                  ev.duration_ns * 1e-9) for ev in line.events]
                 elif line.name == OP_LINE:
+                    named: List[NamedOp] = []
                     for ev in line.events:
                         start, dur = ev.start_ns * 1e-9, ev.duration_ns * 1e-9
                         busy.append((start, start + dur))
                         if trace_reduce.is_container(ev.name):
                             continue  # its time is its bodies'
-                        ops.append((start, dur, scope_of(stack.get(ev.name, ""))))
+                        named.append((start, dur, ev.name, stack.get(ev.name, "")))
                         kernel = kernel_of(ev.name)
                         if kernel:
                             kernels.setdefault(kernel, []).append(dur)
+                    named.sort()
+                    ops += [(s, d, half, name, text)
+                            for (s, d, name, text), half in zip(named, halves(named))]
+    ops.sort()
     window = max(marks, key=lambda m: m[1] - m[0]) if marks else None
     return {"path": path, "devices": devices, "window": window,
             "spans": sorted(spans, key=lambda s: s[1]), "modules": modules, "busy": busy,
-            "ops": sorted(ops), "kernels": kernels}
+            "ops": [op[:3] for op in ops], "named_ops": [op[:2] + op[3:] for op in ops],
+            "kernels": kernels}
 
 
 def run_trace(facts: Dict[str, Any]) -> Optional[Dict[str, Any]]:
@@ -260,6 +289,17 @@ def pair_dispatches(dispatches: Sequence[Span], executions: Sequence[Exec]):
     return pairs, lone_exec, list(dispatches[j:])
 
 
+def whole_in_window(pairs, executions: Sequence[Exec], window: Interval):
+    """The pairs whose execution lies in the window (counted whole by its
+    middle, as `trace_reduce` does) and is whole in the trace: the last
+    execution a trace holds is cut by the profiler's stop, and its dispatch
+    plans more steps than the trace shows operations of. Counted, it made
+    `programs.decode_step_ms` read 18.1 for 21.1 (B2, PR 30 to PR 35)."""
+    lo, hi = window
+    return [(dsp, ex) for dsp, ex in pairs
+            if lo <= ex[0] + ex[1] / 2 <= hi and ex != executions[-1]]
+
+
 def idle_by_span(busy: Sequence[Interval], spans: Sequence[Span],
                  window: Interval) -> Dict[str, Any]:
     """The window's device idle time (no operation running), split by the
@@ -296,7 +336,7 @@ def serve_view(trace: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     inside = lambda s, d: lo <= s + d / 2 <= hi  # noqa: E731  (counted whole by its middle, as trace_reduce does)
     pairs, lone_exec, lone_dispatch = pair_dispatches(
         [s for s in spans if s[0] == DISPATCH], executions)
-    pairs = [(dsp, ex) for dsp, ex in pairs if inside(*ex)]
+    pairs = whole_in_window(pairs, executions, window)
     in_window = [ex for ex in executions if inside(*ex)]
 
     # device time of each execution's operations by scope, in one pass over both sorted lists

@@ -169,6 +169,9 @@ def main() -> int:
                                               for k, v in reduced["ops"].items()),
                                              key=lambda t: -t[1])[:60]}, f, indent=1)
         result["device"] = result_device
+        # every number compared beside its limit: last in the line, and last on standard error
+        result["checks"] = {c["name"]: {"value": c["value"], "limit": c["limit"], "ok": c["ok"]}
+                            for c in checks}
     except BaseException as e:  # the one boundary: say why, exit non-zero, no result line
         traceback.print_exc(file=sys.stdout)
         try:
@@ -179,6 +182,9 @@ def main() -> int:
         return 1
     finally:
         sweep_dead_rings()
+    for name, c in result["checks"].items():
+        print(f"check {name}: {c['value']} (limit {c['limit']}) {'ok' if c['ok'] else 'NOT OK'}",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

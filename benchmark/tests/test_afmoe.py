@@ -101,8 +101,8 @@ def test_the_cell_and_its_traffic_are_the_issues():
             "kernels.moe_prefill_roofline_pct", "programs.attn_share_pct",
             "programs.macro_step_ms", "device.idle_pct.serve"} <= names
     assert "programs.serve_roofline_pct" not in names  # Llama's arithmetic
-    # they sum by scope alone, and the ragged products' kernels carry none (afmoe_spans.scoped)
-    assert not {"programs.decode_step_ms", "programs.prefill_share_pct"} & names
+    # a ragged product's kernel carries no scope; since PR 35 `program_spans.halves` gives it its half
+    assert {"programs.decode_step_ms", "programs.prefill_share_pct"} <= names
     for m in cell["per_layer"]:
         assert os.path.isfile(f"{common.BENCH_DIR}/layer_metrics/{m['name']}.py")
 

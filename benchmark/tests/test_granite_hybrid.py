@@ -222,25 +222,29 @@ def test_scope_of_takes_the_innermost():
 
 
 def _recorded():
-    """Two macro-step executions inside a 1 s window (and one before it),
-    each paired with a dispatch; operations of 10 ms each."""
+    """Two macro-step executions inside a 1 s window (one before it, and one
+    after it that the trace's end cuts), each paired with a dispatch;
+    operations of 10 ms each."""
     dispatch = lambda t, seq, **kw: ("engine.dispatch", t, 0.001, {"seq": seq, **kw})  # noqa: E731
     trace = {
         "window": (1.0, 2.0),
         "spans": [dispatch(0.40, 0, steps=8, state_lanes=100, prompt_tokens=300),
                   dispatch(1.05, 1, steps=10, state_lanes=300, prompt_tokens=500),
-                  dispatch(1.50, 2, steps=12, state_lanes=340, prompt_tokens=0)],
+                  dispatch(1.50, 2, steps=12, state_lanes=340, prompt_tokens=0),
+                  dispatch(1.90, 3, steps=64, state_lanes=2048, prompt_tokens=0)],
         "modules": [("jit_macro_step_slots_paged", 0.5, 0.2),
                     ("jit_macro_step_slots_paged", 1.1, 0.3),
                     ("jit_other", 1.45, 0.01),
-                    ("jit_macro_step_slots_paged", 1.6, 0.2)],
+                    ("jit_macro_step_slots_paged", 1.6, 0.2),
+                    ("jit_macro_step_slots_paged", 1.95, 0.2)],
     }
     ops = sorted([(0.55, 0.01, "ssm_update"),                       # before the window
                   (1.10, 0.01, "ssm_scan"), (1.12, 0.01, "ssm_scan"), (1.14, 0.01, "ssm_proj"),
                   (1.20, 0.01, "ssm_update"), (1.22, 0.01, "attn_mix"), (1.24, 0.01, ""),
                   (1.455, 0.01, "ssm_update"),                      # not in a macro-step
                   (1.60, 0.01, "ssm_update"), (1.62, 0.01, "ssm_update"),
-                  (1.64, 0.01, "ssm_proj")])
+                  (1.64, 0.01, "ssm_proj"),
+                  (1.96, 0.01, "ssm_update")])                     # after the window
     return trace, ops
 
 
@@ -254,6 +258,12 @@ def test_view_sums_scopes_over_the_windows_executions():
     assert v["paired"] == v["window"]
     assert (v["paired_state_lanes"], v["paired_steps"], v["paired_prompt_tokens"]) == (640, 22, 500)
     assert hybrid_spans.view({**trace, "window": None}, ops) is None
+    # the trace's last execution is cut by the profiler's stop: its dispatch's
+    # 12 steps and 340 lane-steps are not counted over the operations it shows (B2)
+    cut = hybrid_spans.view({**trace, "modules": trace["modules"][:-1]}, ops)
+    assert (cut["executions"], cut["paired_executions"]) == (2, 1)
+    assert (cut["paired_state_lanes"], cut["paired_steps"]) == (300, 10)
+    assert cut["paired"]["ssm_update"] == pytest.approx(0.01) and cut["window"] == v["window"]
 
 
 @pytest.mark.parametrize("metric", ["programs.ssm_share_pct", "kernels.ssm_update_roofline_pct",

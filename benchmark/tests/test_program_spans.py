@@ -173,17 +173,71 @@ def test_idle_is_split_by_the_span_that_covers_it():
 
 def test_serve_view_on_the_recorded_list():
     v = ps.serve_view(_recorded())
-    assert (v["executions"], v["paired"], v["unpaired_executions"], v["unpaired_dispatches"]) == (4, 3, 1, 0)
+    # e3 is the trace's last execution: paired with its dispatch, but not among `paired`
+    assert (v["executions"], v["paired"], v["unpaired_executions"], v["unpaired_dispatches"]) == (4, 2, 1, 0)
     assert v["macro_step_s"] == pytest.approx(7.2)
     assert v["admit_s"] == pytest.approx(2.7) and v["decode_s"] == pytest.approx(4.4)
     assert v["unscoped_ops_s"] == pytest.approx(0.1) and v["neither_s"] == pytest.approx(0.1)
     # decode time and steps over the PAIRED executions only: e0 has no dispatch to count steps by
-    assert v["paired_decode_s"] == pytest.approx(3.2) and v["paired_steps"] == 35
-    assert ps.decode_step_ms(v) == pytest.approx(3200 / 35)
-    assert v["paired_prompt_tokens"] == 400
+    assert v["paired_decode_s"] == pytest.approx(1.7) and v["paired_steps"] == 30
+    assert ps.decode_step_ms(v) == pytest.approx(1700 / 30)
+    assert v["paired_prompt_tokens"] == 100
     assert v["deliver_lag_s"] == [pytest.approx(0.03), pytest.approx(0.02)]   # seq 7 never resolved
     assert v["fetch_lag_s"] == [pytest.approx(0.01), pytest.approx(0.01)]
     assert (v["dispatches"], v["finishing"], v["finish_wait_steps"]) == (3, 3, 14)
+
+
+def test_the_traces_last_execution_is_clipped_and_counts_no_steps():
+    """B2. The profiler stops in the middle of the last macro-step: its
+    dispatch span says all 64 planned steps, the trace holds the operations
+    of 20 of them. Counted among `paired` it makes a 20 ms step read 15.3."""
+    step = 0.020
+    spans = [("engine.dispatch", 0.9 + 1.3 * k, 0.01, {"seq": k, "steps": 64}) for k in range(3)]
+    execs = [(1.0, 1.28), (2.3, 1.28), (3.6, 0.4)]  # the third is cut at 4.0, where the trace ends
+    ops = [(s + step * i, step, DECODE) for s, d in execs for i in range(round(d / step))]
+    trace = {"devices": 1, "window": (0.95, 4.0), "spans": spans, "kernels": {},
+             "modules": [("jit_macro_step_slots_paged", s, d) for s, d in execs],
+             "busy": [(s, s + d) for s, d, _ in ops], "ops": ops}
+    v = ps.serve_view(trace)
+    assert (v["executions"], v["paired"], v["paired_steps"]) == (3, 2, 128)
+    assert ps.decode_step_ms(v) == pytest.approx(20.0)
+    assert v["decode_s"] == pytest.approx(2.96)  # the window's device time still holds the clipped one
+    assert 1e3 * v["decode_s"] / (3 * 64) == pytest.approx(15.4, abs=0.05)  # what it read before
+    pairs, _, _ = ps.pair_dispatches(spans, execs)
+    assert [ex for _, ex in ps.whole_in_window(pairs, execs, (0.95, 4.0))] == execs[:2]
+    assert [ex for _, ex in ps.whole_in_window(pairs, execs, (2.0, 4.0))] == execs[1:2]
+
+
+def test_a_kernel_the_compiler_named_takes_the_half_of_the_operation_before_it(tmp_path):
+    """PERF.md section 7 (e), PR 33: a ragged product reaches the trace as a
+    kernel named `ragged-dot-none` with no name stack of the program's. It
+    lies between two scoped operations and counts under the first one's half;
+    the compiler's own copy after it stays under neither."""
+    base = "jit(macro_step_slots_paged)/while/body/"
+    named = [(0.10, 0.01, "%fusion.1 = ...", base + "admit_prefill/while/body/moe_experts/sort"),
+             (0.12, 0.01, "%ragged-dot-metadata.2 = ... custom-call", "ragged-dot-metadata"),
+             (0.13, 0.05, "%ragged-dot-none.7 = bf16[32768,1024] custom-call", "ragged-dot-none"),
+             (0.20, 0.01, "%copy-done.3 = ...", ""),
+             (0.30, 0.01, "%fusion.10 = ...", base + "decode_chunk/while/body/moe_experts/gather"),
+             (0.32, 0.02, "%ragged-dot-none.1 = bf16[64,1024] custom-call", "ragged-dot-none"),
+             (0.40, 0.01, "%fusion.11 = ...", base + "decode_chunk/dot_general")]
+    assert ps.halves(named) == [ADMIT, ADMIT, ADMIT, "", DECODE, DECODE, DECODE]
+    assert ps.halves(named[1:3]) == ["", ""]  # nothing before it had a half: none to take
+
+    # and through `load`: fusion.2 (decode_chunk), then a compiler-named kernel in place of the copy
+    from jax.profiler import ProfileData
+
+    where = tmp_path / "plugins" / "profile" / "now"
+    where.mkdir(parents=True)
+    ragged = XSPACE.replace('"%copy.3 = bf16[8] copy(%p)"',
+                            '"%ragged-dot-none.3 = bf16[8] custom-call(%p)"')
+    (where / "host.xplane.pb").write_bytes(ProfileData.text_proto_to_serialized_xspace(ragged))
+    t = ps.load(str(tmp_path))
+    assert [(round(d, 3), scope) for _, d, scope in t["ops"]] == [
+        (1.0, ADMIT), (1.5, DECODE), (0.4, DECODE), (0.1, "")]
+    assert [(name.split(" ")[0], text.rsplit("/", 1)[-1]) for _, _, name, text in t["named_ops"]] == [
+        ("%fusion.1", "dot_general:"), ("%fusion.2", "add:"), ("%ragged-dot-none.3", ""),
+        ("%flash_bwd_dq.12", "pallas_call:")]
 
 
 def test_interval_helpers():
@@ -220,11 +274,11 @@ def test_serve_readers_on_hand_made_facts(monkeypatch):
     assert lag["check_at_most_one_unpaired_at_each_end"]
     wait = _read("engine.finish_wait_steps", ctx)
     assert wait["value"] == pytest.approx(14 / 3)
-    assert wait["wait_ms_at_decode_step_ms"] == pytest.approx(14 / 3 * 3200 / 35)
+    assert wait["wait_ms_at_decode_step_ms"] == pytest.approx(14 / 3 * 1700 / 30)
     share = _read("programs.prefill_share_pct", ctx)
-    assert share["value"] == pytest.approx(37.5) and share["prompt_tokens"] == 400
+    assert share["value"] == pytest.approx(37.5) and share["prompt_tokens"] == 100
     assert share["neither_s"] == pytest.approx(0.1) and share["check_neither_under_5pct"]
-    assert _read("programs.decode_step_ms", ctx)["value"] == pytest.approx(3200 / 35)
+    assert _read("programs.decode_step_ms", ctx)["value"] == pytest.approx(1700 / 30)
 
 
 @pytest.mark.parametrize("name", SERVE_READERS + KERNEL_READERS)
