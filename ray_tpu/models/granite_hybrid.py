@@ -13,7 +13,9 @@ residual multiplier `r`; `layer_types` says which mixer a layer has:
   a scalar a head; `rmsnorm(y * silu(z)) * w` over all features; `out_proj`.
   The recurrence has two forms here: `ssd_chunked` over a whole sequence
   (within a chunk of `mamba_chunk_size` the masked `C B^T` form on the matrix
-  unit, between chunks the carried state) and `ssm_step` for one token.
+  unit, between chunks the carried state) and `ssm_step` for one token
+  (`ssm_step_stacked` where the rows live in the cache's stacked state: on a
+  TPU the Pallas kernel of ops/ssm_update.py, one pass over each live row).
 - `attention`: grouped-query attention with NO position term (`nope`),
   scores times `attention_multiplier`.
 
@@ -369,15 +371,47 @@ def ssd_chunked(x, dt, A, B, C, D, chunk: int):
 
 
 def ssm_step(h, x, dt, A, B, C, D):
-    """The recurrence for one position. h (R, H, P, N) float32; x (R, H, P);
-    dt (R, H) float32; B, C (R, N). Elementwise in float32 throughout (no
-    matrix unit: its float32 products would round to bfloat16).
-    Returns (y (R, H, P) float32, new state)."""
+    """The recurrence for one position: the definition, the path off the
+    TPU and the tests' oracle (`ssm_step_stacked` is what a decode step
+    calls). h (R, H, P, N) float32; x (R, H, P); dt (R, H) float32; B, C
+    (R, N). Elementwise in float32 throughout (no matrix unit: its float32
+    products would round to bfloat16). Returns (y (R, H, P) float32, new
+    state)."""
     xf, Bf, Cf = x.astype(F32), B.astype(F32), C.astype(F32)
     h = (jnp.exp(dt * A)[:, :, None, None] * h
          + (dt[:, :, None] * xf)[..., None] * Bf[:, None, None, :])
     y = jnp.sum(h * Cf[:, None, None, :], axis=-1) + D[None, :, None] * xf
     return y, h
+
+
+def live_rows(active):
+    """What `ssm_step_stacked` wants to know of the rows' flags (R,) bool,
+    made once a step for all its layers: (the flags, the live rows' indices
+    in rising order with the last of them repeated to the end (R,) int32
+    (row 0 where none is live), their number (1,) int32)."""
+    n_live = jnp.sum(active, dtype=jnp.int32)
+    order = jnp.argsort(~active, stable=True).astype(jnp.int32)
+    last = order[jnp.maximum(n_live - 1, 0)]
+    return active, jnp.where(jnp.arange(active.shape[0]) < n_live, order, last), n_live[None]
+
+
+def ssm_step_stacked(ssm, mi, live, x, dt, A, B, C, D):
+    """`ssm_step` on layer `mi` of the cache's stacked state (layers, R, H,
+    P, N), for the rows that are live (`live` is their `live_rows`); a row
+    that is not live and every other layer stay bit for bit. The path
+    follows what can be seen here: on a TPU, for shapes its tiles take, the
+    kernel of ops/ssm_update.py reads each live row once, writes it back in
+    place and takes `h . C` in the same pass; elsewhere `ssm_step` on the
+    layer, a select and the write. Returns (y (R, H, P) float32, meaningless
+    on a row that is not live; the stack)."""
+    from ray_tpu.ops import ssm_update  # Pallas: imported where it is traced
+
+    if ssm_update.engages(*ssm.shape[2:]):
+        return ssm_update.update_stacked_state(ssm, mi, live, x, dt, A, B, C, D)
+    h = jax.lax.dynamic_index_in_dim(ssm, mi, 0, keepdims=False)
+    y, new_h = ssm_step(h, x, dt, A, B, C, D)
+    new_h = jnp.where(live[0][:, None, None, None], new_h, h)
+    return y, jax.lax.dynamic_update_index_in_dim(ssm, new_h, mi, 0)
 
 
 def gated_norm(y, z, layer, cfg: GraniteHybridConfig):
@@ -406,21 +440,23 @@ def mamba_sequence(layer, a, lengths, cfg: GraniteHybridConfig):
     return out, tail, h
 
 
-def mamba_token(layer, a, tail, h, cfg: GraniteHybridConfig):
+def mamba_token(layer, mi, a, tail, ssm, live, cfg: GraniteHybridConfig):
     """The Mamba mixer for one position of each row: a (R, d), the rows'
-    conv tails (K-1, R, conv_dim) and states. Returns (out (R, d), new
-    tails, new states)."""
+    conv tails (K-1, R, conv_dim), the stacked state of all Mamba layers, of
+    which this is layer `mi`, and the rows' `live_rows`. Returns (out
+    (R, d), new tails for every row, the stack with the live rows' states
+    stepped)."""
     R = a.shape[0]
     with jax.named_scope(SCOPE_PROJ):
         z, xBC, dt = in_proj(a, layer, cfg)
     with jax.named_scope(SCOPE_UPDATE):
         xBC, tail = conv_step(tail, xBC, layer)
         x, B, C = split_xbc(xBC, cfg)
-        y, h = ssm_step(h, x, step_sizes(dt, layer), -jnp.exp(layer["A_log"]), B, C,
-                        layer["D"])
+        y, ssm = ssm_step_stacked(ssm, mi, live, x, step_sizes(dt, layer),
+                                  -jnp.exp(layer["A_log"]), B, C, layer["D"])
     with jax.named_scope(SCOPE_PROJ):
         out = gated_norm(y.reshape(R, cfg.d_inner), z, layer, cfg) @ layer["out_proj"]
-    return out, tail, h
+    return out, tail, ssm
 
 
 # ---------------------------------------------------------- attention mixer

@@ -11,13 +11,18 @@ module's admission and decode step and this module's cache pytree:
   conv      (Mamba layers, taps - 1, lanes, conv_dim)  each lane's conv tail:
             the last taps - 1 inputs of the depthwise conv, activation type
             (lanes on the second-minor axis, not the 3 taps: the same padding)
-  ssm       (Mamba layers, lanes, H, P, N) float32     each lane's SSM state
+  ssm       (Mamba layers, lanes, H, P, N) float32     each lane's SSM state,
+            stepped in place in the stack: on a TPU by the kernel of
+            ops/ssm_update.py, which is handed the whole stack, the layer's
+            index and the live lanes; no layer is sliced out or written back
   pos, remaining, rng                                   per-lane scalars
 
 Admission computes a row's conv tail and final state from zero and writes
 them to the row's lane (a padded admission row writes nothing); the decode
 step updates the lanes that are active and leaves the others bit for bit
-alone; release needs no device work, the next admission overwrites the row.
+alone (the kernel makes no pass over an inactive lane's state; off the TPU
+`ssm_step` runs on every lane and a select keeps the inactive ones);
+release needs no device work, the next admission overwrites the row.
 
 Padding is not harmless in a recurrence: past a row's length the step size
 is zeroed (decay 1, input 0), the conv tail is taken from the last real
@@ -114,24 +119,23 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
                             sampled: bool = True):
     """One token on every lane, with llama_decode.decode_step_slots_paged's
     arguments and returns. An inactive lane (remaining == 0) keeps its conv
-    tail and state as they are and aims its K/V write at the null block. The
+    tail and state as they are, aims its K/V write at the null block, and
+    its logits mean nothing (its mixer output is not computed on a TPU). The
     attention layers read the lanes' contexts out of the flat pool in place,
     through llama_decode.attend_decode_paged: work follows the longest live
     lane, not the table span."""
     B = tokens.shape[0]
     pos = cache["pos"]
     active = cache["remaining"] > 0
+    live = G.live_rows(active)  # one list for the step's every layer
 
     def mamba_mixer(layer, mi, a, carry):
         k_full, v_full, conv, ssm = carry
         tail = jax.lax.dynamic_index_in_dim(conv, mi, 0, keepdims=False)
-        h = jax.lax.dynamic_index_in_dim(ssm, mi, 0, keepdims=False)
-        out, new_tail, new_h = G.mamba_token(layer, a, tail, h, cfg)
+        out, new_tail, ssm = G.mamba_token(layer, mi, a, tail, ssm, live, cfg)
         with jax.named_scope(G.SCOPE_UPDATE):
             new_tail = jnp.where(active[None, :, None], new_tail, tail)
-            new_h = jnp.where(active[:, None, None, None], new_h, h)
             conv = jax.lax.dynamic_update_index_in_dim(conv, new_tail, mi, 0)
-            ssm = jax.lax.dynamic_update_index_in_dim(ssm, new_h, mi, 0)
         return out, (k_full, v_full, conv, ssm)
 
     def attn_mixer(layer, ai, a, carry):

@@ -20,11 +20,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from benchmark import reference_granite_hybrid as R
 from benchmark import weights_granite_hybrid as W
 from ray_tpu.models import granite_hybrid as G
 from ray_tpu.models import granite_hybrid_decode as D
+from ray_tpu.ops import ssm_update as SU
 from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
 
 F32_RTOL = 1e-4
@@ -32,9 +34,13 @@ BF16_ATOL = 0.1
 BLOCK = 16
 
 
-@functools.lru_cache(maxsize=4)
-def _model(dtype=jnp.float32, seed=2**31 + 29):
-    cfg = G.GraniteHybridConfig.tiny(dtype=dtype)
+# Mamba widths the decode-side kernel's tiles take (ops/ssm_update.supported)
+KERNEL_WIDTHS = (("mamba_n_heads", 16), ("mamba_d_state", 128))
+
+
+@functools.lru_cache(maxsize=8)
+def _model(dtype=jnp.float32, seed=2**31 + 29, widths=()):
+    cfg = G.GraniteHybridConfig.tiny(dtype=dtype, **dict(widths))
     key = W.seed_key(seed)
     return cfg, key, W.init_params(key, cfg)
 
@@ -92,6 +98,19 @@ def test_ssd_chunked_is_ssm_step_iterated(T):
 
 
 # ------------------------------ the paged cache driven by hand (c, d, e)
+@pytest.fixture(params=["xla", "kernel"])
+def update_path(request, monkeypatch):
+    """The two paths of a decode step's state update, as the config's widths
+    to take: `ssm_step` + select + write (what the CPU runs), and the Pallas
+    kernel a TPU runs, here in the TPU interpret mode at widths it takes."""
+    if request.param == "xla":
+        yield ()
+        return
+    monkeypatch.setattr(SU, "_on_tpu", lambda: True)
+    with pltpu.force_tpu_interpret_mode():
+        yield KERNEL_WIDTHS
+
+
 @functools.lru_cache(maxsize=4)
 def _jitted_halves(cfg):
     return (jax.jit(functools.partial(D.admit_slots_paged, cfg=cfg, sampled=False)),
@@ -140,11 +159,11 @@ class Lanes:
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-def test_admission_then_decode_matches_the_reference_at_every_position(dtype):
+def test_admission_then_decode_matches_the_reference_at_every_position(dtype, update_path):
     """(c) prefill then decode through the cache against the reference's
     full forward over prompt + emitted tokens, logits at every emitted
     position (the first token's too, through the state it leaves)."""
-    cfg, key, params = _model(dtype)
+    cfg, key, params = _model(dtype, widths=update_path)
     lanes = Lanes(cfg, params, n=2)
     prompts = [_tokens(1, 19, seed=3)[0], _tokens(1, 7, seed=4)[0]]
     n_new = 9
@@ -213,11 +232,12 @@ def test_padding_changes_nothing_through_admission(bucket, beside):
         assert np.abs(w - g).max() <= 1e-5 * np.abs(w).max()
 
 
-def test_a_lane_is_untouched_by_the_others():
+def test_a_lane_is_untouched_by_the_others(update_path):
     """(e) a lane's state is unchanged by other lanes' admissions (padding
     rows included) and by steps taken while it is inactive; a lane reused by
     a second request gives what a fresh cache gives."""
-    cfg, _, params = _model()
+    cfg, _, params = _model(widths=update_path)
+    assert SU.supported(cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state) == bool(update_path)
     lanes = Lanes(cfg, params)
     a, b, c = (_tokens(1, n, seed=s)[0] for n, s in ((13, 8), (21, 9), (9, 10)))
     lanes.admit([(1, a)], 16, new=3)                 # lane 1 owes 2 decode steps

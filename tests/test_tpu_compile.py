@@ -289,17 +289,18 @@ def test_paged_macro_step_reads_projection_weights_in_place(one_chip, monkeypatc
     assert compiled.memory_analysis().temp_size_in_bytes > 0.7e9
 
 
-def test_hybrid_macro_step_keeps_state_and_pool_in_place(one_chip):
-    """The hybrid decoder's paged macro-step at granite-4.0-h-micro's widths,
-    32 lanes, the dispatch that admits nothing: 9.9 GB of weights, recurrent
-    state and K/V pool go in, and the program's temporaries stay a few
-    hundred MB. They were 4.1 GB (compiled only, PR 29) while a stack's minor
-    axis was no multiple of 128: the one 8,512-column input projection, the
-    pool's head size of 64 and the conv tail's 3 taps each took a relayout
-    copy of the whole stack in every dispatch, and the tied head a float32
-    copy of the embedding in every step."""
+@functools.lru_cache(maxsize=2)
+def _hybrid_macro_step(one_chip, kernel: bool = True):
+    """The optimized text and the memory analysis of the hybrid decoder's
+    paged macro-step at granite-4.0-h-micro's widths and
+    `batch-generate-wide`'s 32 lanes, the dispatch that admits nothing,
+    (1, 16), compiled for the chip; the state update through its Pallas
+    kernel, as the chip runs it, or through plain XLA."""
+    from unittest import mock
+
     from ray_tpu.models import granite_hybrid as G
     from ray_tpu.models import granite_hybrid_decode as D
+    from ray_tpu.ops import ssm_update as SU
     from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
 
     cfg = G.GraniteHybridConfig(max_seq_len=4096)
@@ -309,16 +310,103 @@ def test_hybrid_macro_step_keeps_state_and_pool_in_place(one_chip):
     arr, shaped = _shapes_on(one_chip)
     params = shaped(jax.eval_shape(lambda: G.init_params(jax.random.PRNGKey(0), cfg)))
     cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
-    compiled = D.jitted_macro_step_slots_paged(cfg, 8, sampled=False).lower(
-        params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
-        arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
-        arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
-        arr((K, B, MAX_STOP_TOKENS))).compile()
-    m = compiled.memory_analysis()
+    # not the memoized jit: it would hand the other path's trace on
+    with mock.patch.object(SU, "_on_tpu", lambda: kernel):
+        compiled = D.jitted_macro_step_slots_paged.__wrapped__(cfg, 8, sampled=False).lower(
+            params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
+            arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
+            arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
+            arr((K, B, MAX_STOP_TOKENS))).compile()
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+def _state_update_kernels(text):
+    return [ln for ln in text.splitlines() if "custom-call(" in ln and " %ssm_update" in ln]
+
+
+def _state_passes(text):
+    """Operations of an optimized hybrid macro-step, other than the state
+    update's kernel, that put out the whole stacked SSM state or a whole
+    layer of it: (those of a decode step, by their `decode_chunk` scope;
+    plain copies anywhere). The admission's write of one lane's row is an
+    in-place dynamic-update-slice under `admit_prefill` and is neither."""
+    import re
+
+    state = re.compile(r"f32\[(36,|1,)?32,64,64,128\]")
+    line = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(")
+    in_decode, copies = [], []
+    for ln in text.splitlines():
+        m = line.match(ln)
+        if not m or not state.search(m.group(2)):
+            continue
+        name, _, op = m.groups()
+        if op == "copy":
+            copies.append(name)
+        elif '"estimated_cycles"' in ln and "decode_chunk" in ln and op != "custom-call":
+            in_decode.append(name)
+    return in_decode, copies
+
+
+def test_hybrid_macro_step_keeps_state_and_pool_in_place(one_chip):
+    """The hybrid decoder's paged macro-step at granite-4.0-h-micro's widths,
+    32 lanes, the dispatch that admits nothing: 9.9 GB of weights, recurrent
+    state and K/V pool go in, and the program's temporaries stay a few
+    hundred MB. They were 4.1 GB (compiled only, PR 29) while a stack's minor
+    axis was no multiple of 128: the one 8,512-column input projection, the
+    pool's head size of 64 and the conv tail's 3 taps each took a relayout
+    copy of the whole stack in every dispatch, and the tied head a float32
+    copy of the embedding in every step.
+
+    With the state update's kernel (PR 36) no operation of a decode step
+    puts out the stacked state or a layer of it but the kernel, whose second
+    result is the stack it was given; nothing copies either. The plain XLA
+    path (`ssm_step`, a select over all lanes, the layer written back: five
+    `select_dynamic-update-slice` fusions, one a run of Mamba layers) must
+    trip the detector."""
+    text, m = _hybrid_macro_step(one_chip)
     assert m.argument_size_in_bytes > 9.8e9 and m.alias_size_in_bytes > 3.5e9  # cache donated
     # 0.354 GB while the decode step gathered every lane's whole span, 0.150
-    # with the chunked decode attention (compiled only, PR 30)
+    # with the chunked decode attention (compiled only, PR 30), 0.133 with
+    # the state update's kernel (PR 36)
     assert m.temp_size_in_bytes < 0.25e9, m.temp_size_in_bytes
+    in_decode, copies = _state_passes(text)
+    assert not in_decode, f"a decode step passes over the state outside the kernel: {in_decode}"
+    assert not copies, f"the state is copied: {copies}"
+    kernels = _state_update_kernels(text)
+    assert kernels and all("output_to_operand_aliasing={{1}: (7, {})}" in ln for ln in kernels)
+
+    in_decode, _ = _state_passes(_hybrid_macro_step(one_chip, kernel=False)[0])
+    assert len(in_decode) >= 5, "the detector failed to flag the select over all lanes"
+
+
+def test_hybrid_state_update_kernel_compiles_and_the_layers_stay_rolled(one_chip):
+    """The kernel of ops/ssm_update.py at the cell's shapes (36 layers x 32
+    lanes x 64 heads x 64 x 128 float32, 2.4 GB; whole lanes of 64 heads a
+    block) compiles for the chip with the stack aliased and nothing beside
+    it; and in the macro-step the layer scans stayed rolled: as many kernel
+    calls as there are runs of Mamba layers, five (9, 9, 9, 5, 4), not
+    thirty-six. Every (A, P) variant of the macro-step is built in warm-up,
+    so a kernel a layer would be paid thirteen times over in `setup_s`."""
+    from ray_tpu.models import granite_hybrid as G
+    from ray_tpu.ops import ssm_update as SU
+
+    cfg = G.GraniteHybridConfig()
+    L, H, P, N = 32, cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+    assert SU.supported(H, P, N) and SU.heads_per_block(H, P, N) == H
+    arr, _ = _shapes_on(one_chip)
+    f32 = functools.partial(arr, dtype=jnp.float32)
+    stack = f32((cfg.n_mamba_layers, L, H, P, N))
+    compiled = jax.jit(SU._ssm_update_pallas, donate_argnums=(0,)).lower(
+        stack, arr(()), arr((L,)), arr((1,)), f32((L, H)), f32((L, H, P)), f32((L, N)),
+        f32((L, N))).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 4 * cfg.n_mamba_layers * L * H * P * N
+    assert m.temp_size_in_bytes < 1e6, m.temp_size_in_bytes
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+    calls = _state_update_kernels(_hybrid_macro_step(one_chip)[0])
+    assert len(calls) == sum(kind == G.MAMBA for kind, *_ in cfg.runs) == 5
+    assert all("decode_chunk" in ln and "/ssm_update/" in ln for ln in calls)
 
 
 def test_flash_forward_kernel_with_a_window_compiles(one_chip):
