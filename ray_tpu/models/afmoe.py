@@ -136,6 +136,12 @@ class AfmoeConfig:
         return tuple(tuple(r) for r in out)
 
     @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the router's experts whose weights this
+        program holds (`expert_ffn`): all of them."""
+        return 0, self.n_experts
+
+    @property
     def model_module(self):
         from ray_tpu.models import afmoe
 
@@ -195,7 +201,7 @@ def make_moe(k, cfg: AfmoeConfig) -> Dict[str, Any]:
     k_r, k_e, k_s = jax.random.split(k, 3)
     return {"router": _dense(k_r, (d, E), d, cfg.dtype),
             "bias": jnp.zeros((E,), F32),
-            "experts": make_swiglu(k_e, d, f, cfg.dtype, (E,)),
+            "experts": make_swiglu(k_e, d, f, cfg.dtype, (cfg.held_experts[1],)),
             "shared": make_swiglu(k_s, d, f * cfg.n_shared_experts, cfg.dtype)}
 
 
@@ -250,6 +256,15 @@ def expert_ffn(u, chosen, w, experts, at, cfg: AfmoeConfig, live=None):
     their pairs sort behind every expert's and belong to no group, so
     they cost no product and no expert's weights are read for them.
 
+    The program may hold a PART of the router's experts: `cfg.held_experts`
+    is (first, count) of the router's `cfg.n_experts`, and the stacks have
+    `count` experts a layer (one chip's share where a layer's experts are
+    divided over chips). A pair whose expert is not held is out as a row
+    that is not live is: behind every group, in none, no product, no weight
+    read, and nothing added for it. `w` stays the router's, normalised over
+    all the chosen, held or not: what the other chips' experts would add
+    is left out and nothing stands in for it.
+
     `experts` is the STACK of every expert layer's experts, (layers, E, ...),
     and `at` says which layer's are meant: the layer index is folded into
     the group axis (layers * E groups, all but this layer's E empty), so
@@ -257,11 +272,15 @@ def expert_ffn(u, chosen, w, experts, at, cfg: AfmoeConfig, live=None):
     layer's experts out first is a copy of all of them (1.6 GB a layer at
     Trinity-Mini's widths) in every decode step: a ragged product is a
     kernel, and no slice fuses into a kernel's operand.
-    Returns (out (N, d), rows an expert (E,) int32)."""
+    Returns (out (N, d), rows a held expert (E,) int32)."""
     N, k = chosen.shape
-    E = cfg.n_experts
+    first, E = cfg.held_experts
     n_layers = experts["w_gate"].shape[0]
     pair_expert = chosen.reshape(-1)
+    held = None
+    if (first, E) != (0, cfg.n_experts):
+        held = (pair_expert >= first) & (pair_expert < first + E)
+        pair_expert = jnp.where(held, pair_expert - first, E)
     if live is not None:
         pair_expert = jnp.where(jnp.repeat(live, k), pair_expert, E)
     order = jnp.argsort(pair_expert, stable=True)
@@ -269,6 +288,7 @@ def expert_ffn(u, chosen, w, experts, at, cfg: AfmoeConfig, live=None):
     groups = jax.lax.dynamic_update_slice(
         jnp.zeros((n_layers * E,), jnp.int32), sizes, (at * E,))
     stack = lambda name: experts[name].reshape((n_layers * E,) + experts[name].shape[2:])  # noqa: E731
+
     rows = u[order // k]                                    # (N * k, d), sorted by expert
     gate = jax.nn.silu(grouped_matmul(rows, stack("w_gate"), groups).astype(F32))
     act = gate.astype(cfg.dtype) * grouped_matmul(rows, stack("w_up"), groups)
@@ -279,6 +299,8 @@ def expert_ffn(u, chosen, w, experts, at, cfg: AfmoeConfig, live=None):
     y = y[back].reshape(N, k, -1)
     if live is not None:
         y = jnp.where(live[:, None, None], y, 0)
+    if held is not None:
+        y = jnp.where(held.reshape(N, k, 1), y, 0)
     out = jnp.einsum("nkd,nk->nd", y, w.astype(F32), preferred_element_type=F32)
     return out.astype(cfg.dtype), sizes
 

@@ -632,7 +632,8 @@ def decode_chunk_positions(block_size: int, max_blocks: int) -> int:
     return min(max(DECODE_CHUNK // block_size, 1), max_blocks) * block_size
 
 
-def attend_decode_paged(q, k_full, v_full, li, tables, pos, active, scale):
+def attend_decode_paged(q, k_full, v_full, li, tables, pos, active, scale,
+                        v_cols: int = 0):
     """Decode attention in proportion to the context the lanes hold: one
     query a lane, q (B, h, hd), lane b attending positions [0, pos[b]] of
     layer `li` of the pools AFTER the step's own K/V write; tables
@@ -656,12 +657,19 @@ def attend_decode_paged(q, k_full, v_full, li, tables, pos, active, scale):
     lies and lay the QUERY out flat instead: each query head's vector in
     its KV head's columns, zeros elsewhere, the products over all
     kvh * hd columns (kvh times the operations, on one query nothing;
-    splitting the chunk's minor axis into heads would relayout it)."""
+    splitting the chunk's minor axis into heads would relayout it).
+
+    The single-pool form, `v_full` None: the pool's rows are ONE key a
+    position, as wide as a query (flat, one "KV head"), and a position's
+    value is the first `v_cols` columns of its own key row (a latent cache:
+    [c | rotary part], values c). A chunk is gathered once and read twice;
+    the return is (B, h * v_cols)."""
     B, h, hd = q.shape
     bs, MB = k_full.shape[2], tables.shape[1]
     row = k_full.shape[3:]
     flat = len(row) == 1
     kvh = row[0] // hd if flat else row[0]
+    hv = v_cols or hd  # a head's value columns
     if flat:
         own = jnp.eye(kvh, dtype=q.dtype)[None, :, None, :, None]  # head k's columns
         qx = (q.reshape(B, kvh, h // kvh, 1, hd) * own).reshape(B, h, kvh * hd)
@@ -679,7 +687,7 @@ def attend_decode_paged(q, k_full, v_full, li, tables, pos, active, scale):
     def chunk(i, carry):
         blocks = jax.lax.dynamic_slice_in_dim(chunked, i * cb, cb, axis=1)
         kc = k_full[li, blocks].reshape((B, C) + row)
-        vc = v_full[li, blocks].reshape((B, C) + row)
+        vc = kc[..., :v_cols] if v_full is None else v_full[li, blocks].reshape((B, C) + row)
         s = jnp.einsum(qk, qx, kc, preferred_element_type=jnp.float32) * scale
         live = (i * C + jnp.arange(C))[None, :] <= pos[:, None]  # (B, C)
         live = live.reshape((B,) + (1,) * (len(stat) - 1) + (C,))
@@ -687,13 +695,13 @@ def attend_decode_paged(q, k_full, v_full, li, tables, pos, active, scale):
 
     acc, _, l = jax.lax.fori_loop(
         0, (longest + C - 1) // C, chunk,
-        (jnp.zeros(qx.shape, jnp.float32), jnp.full(stat, NEG_INF, jnp.float32),
-         jnp.zeros(stat, jnp.float32)),
+        (jnp.zeros(stat + (qx.shape[-1] // hd * hv,), jnp.float32),
+         jnp.full(stat, NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32)),
     )
     o = acc / jnp.where(l == 0.0, 1.0, l)[..., None]  # no chunk ran: zeros
     if flat:
-        o = (o.reshape(B, kvh, h // kvh, kvh, hd) * own.astype(jnp.float32)).sum(axis=3)
-    return o.reshape(B, h * hd).astype(q.dtype)
+        o = (o.reshape(B, kvh, h // kvh, kvh, hv) * own.astype(jnp.float32)).sum(axis=3)
+    return o.reshape(B, h * hv).astype(q.dtype)
 
 
 def write_decode_kv(k_full, v_full, li, k, v, tables, pos, active):
@@ -701,26 +709,34 @@ def write_decode_kv(k_full, v_full, li, k, v, tables, pos, active):
     (L, n_blocks, bs, *row): per-slot write into the slot's CURRENT
     block at its own offset (same sequential-DMA trick as the dense
     path: the advanced-index scatter form measured ~25 ms/step on TPU).
-    Inactive lanes write the null block."""
+    Inactive lanes write the null block. A cache of ONE pool (a latent
+    row a position) passes `v_full` and `v` None and gets None back."""
     B = k.shape[0]
     bs = k_full.shape[2]
     row0 = (0,) * (k_full.ndim - 3)
+    pools, new = _pools(k_full, v_full), _pools(k, v)
 
-    def write_slot(b, kv):
-        kf, vf = kv
-        kb = jax.lax.dynamic_slice_in_dim(k, b, 1, axis=0)[None]
-        vb = jax.lax.dynamic_slice_in_dim(v, b, 1, axis=0)[None]
+    def write_slot(b, pools):
+        rows = [jax.lax.dynamic_slice_in_dim(n, b, 1, axis=0)[None] for n in new]
         pb = jax.lax.dynamic_index_in_dim(pos, b, keepdims=False)
         ab = jax.lax.dynamic_index_in_dim(active, b, keepdims=False)
         row = jax.lax.dynamic_index_in_dim(tables, b, 0, keepdims=False)
         blk = jax.lax.dynamic_index_in_dim(row, pb // bs, keepdims=False)
         blk = jnp.where(ab, blk, 0)  # inactive lanes write the null block
         off = jnp.where(ab, pb % bs, 0)
-        kf = jax.lax.dynamic_update_slice(kf, kb, (li, blk, off) + row0)
-        vf = jax.lax.dynamic_update_slice(vf, vb, (li, blk, off) + row0)
-        return kf, vf
+        return tuple(jax.lax.dynamic_update_slice(f, r, (li, blk, off) + row0)
+                     for f, r in zip(pools, rows))
 
-    return jax.lax.fori_loop(0, B, write_slot, (k_full, v_full))
+    return _k_and_v(jax.lax.fori_loop(0, B, write_slot, pools))
+
+
+def _pools(k, v):
+    """(k, v), or (k,) for a cache of one pool."""
+    return (k,) if v is None else (k, v)
+
+
+def _k_and_v(pools):
+    return pools if len(pools) == 2 else (pools[0], None)
 
 
 def finish_decode_step(logits, cache, active, temps, top_ks, top_ps, stop_ids,
@@ -937,13 +953,15 @@ def write_admission_kv(k_full, v_full, li, k, v, adm_tables, starts, valid):
     """Every valid admission row's K/V (A, P, *row) into layer `li` of
     a pool (L, n_blocks, bs, *row), block by block from block
     starts[n] // bs of the row's table; blocks past the table's edge go
-    to the null block."""
+    to the null block. `v_full` and `v` None: a cache of one pool, as
+    write_decode_kv takes it."""
     A, P = k.shape[:2]
     row = k.shape[2:]
     row0 = (0,) * len(row)
     bs = k_full.shape[2]
     MB = adm_tables.shape[1]
     n_chunks = P // bs
+    new = _pools(k, v)
 
     def write_row(n, kv):
         def wr(kv):
@@ -951,19 +969,15 @@ def write_admission_kv(k_full, v_full, li, k, v, adm_tables, starts, valid):
             table = jax.lax.dynamic_index_in_dim(adm_tables, n, 0, keepdims=False)
 
             def write_block(j, kv):
-                kf, vf = kv
                 idx = s0 + j
                 blk = jax.lax.dynamic_index_in_dim(
                     table, jnp.minimum(idx, MB - 1), keepdims=False
                 )
                 blk = jnp.where(idx < MB, blk, 0)  # pad overshoot -> null
-                kc = jax.lax.dynamic_slice(
-                    k, (n, j * bs) + row0, (1, bs) + row)[0][None, None]
-                vc = jax.lax.dynamic_slice(
-                    v, (n, j * bs) + row0, (1, bs) + row)[0][None, None]
-                kf = jax.lax.dynamic_update_slice(kf, kc, (li, blk, 0) + row0)
-                vf = jax.lax.dynamic_update_slice(vf, vc, (li, blk, 0) + row0)
-                return kf, vf
+                cs = [jax.lax.dynamic_slice(
+                    r, (n, j * bs) + row0, (1, bs) + row)[0][None, None] for r in new]
+                return tuple(jax.lax.dynamic_update_slice(f, c, (li, blk, 0) + row0)
+                             for f, c in zip(kv, cs))
 
             # a loop, eight blocks an iteration: spelled out as P // bs
             # blocks in Python, the (4, 1024) program at 16 layers took
@@ -974,7 +988,7 @@ def write_admission_kv(k_full, v_full, li, k, v, adm_tables, starts, valid):
 
         return jax.lax.cond(valid[n], wr, lambda kv: kv, kv)
 
-    return jax.lax.fori_loop(0, A, write_row, (k_full, v_full))
+    return _k_and_v(jax.lax.fori_loop(0, A, write_row, _pools(k_full, v_full)))
 
 
 def finish_admission(last, cache, feed, valid, lengths, starts, slots, rems,

@@ -39,9 +39,11 @@ ENGINE_SPANS = (
     "engine.plan",      # `_plan()`: admissions, block tables and decode chunks for up to `macro_phases` phases
     "engine.dispatch",  # `_dispatch_macro()`: plan arrays built and the macro-step enqueued; stats: seq, phases,
                         # steps, admissions, A, P, prompt_tokens, lane_steps, finishing, finish_wait_steps, ctx_chunks,
+                        # ctx_tokens, prompt_pairs (what the decode steps' and the admissions' attention has to do),
                         # past_window_lane_steps (a model with sliding-window layers only)
     "engine.resolve",   # `_resolve()` of dispatch `seq`: the fetch, then delivery of its tokens to the requests; stats:
-                        # seq, and the dispatch's device counters where the model's decode module names any
+                        # seq, the plan counts of its dispatch over again (a trace that starts after a dispatch
+                        # still holds them), and the dispatch's device counters where the decode module names any
     "engine.fetch",     # inside resolve: the blocking device-to-host reads of the dispatch's tokens
 )
 

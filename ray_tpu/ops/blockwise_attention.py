@@ -79,7 +79,8 @@ def _fwd_impl(q, k, v, causal, block_size, sm_scale, q_offset, kv_offset, window
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     kb = k.reshape(B, nblocks, blk, H, D).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(B, nblocks, blk, H, D).transpose(1, 0, 2, 3, 4)
+    Dv = v.shape[3]  # the forward takes a value size of its own
+    vb = v.reshape(B, nblocks, blk, H, Dv).transpose(1, 0, 2, 3, 4)
 
     qf = q.astype(jnp.float32) * scale
 
@@ -109,7 +110,7 @@ def _fwd_impl(q, k, v, causal, block_size, sm_scale, q_offset, kv_offset, window
         )
         return (acc_new, m_new, l_new), None
 
-    acc0 = jnp.zeros((B, T, H, D), jnp.float32)
+    acc0 = jnp.zeros((B, T, H, Dv), jnp.float32)
     m0 = jnp.full((B, T, H), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, T, H), jnp.float32)
     (acc, m, l), _ = jax.lax.scan(

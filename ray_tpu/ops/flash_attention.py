@@ -28,8 +28,12 @@ from ray_tpu.ops.blockwise_attention import _broadcast_kv, _bwd as _blockwise_bw
 NEG_INF = -1e30
 
 
-def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *, scale, causal, bq, bk, nk,
-               window=None):
+def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk, nk, window=None, shared=False):
+    # with `shared`, two more operands: a second part of every query head's
+    # vector and ONE second key part for all heads (its index map ignores the
+    # head); the score is the sum of the two products
+    q2_ref, k2_ref = rest[:2] if shared else (None, None)
+    o_ref, lse_ref, acc, m_s, l_s = rest[2:] if shared else rest
     j = pl.program_id(2)
     i = pl.program_id(1)
 
@@ -59,7 +63,12 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *, scale, cau
         v = v_ref[0]                                       # [bk, D] bf16
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                          # [bq, bk] f32
+        )
+        if shared:
+            s = s + jax.lax.dot_general(
+                q2_ref[0], k2_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        s = s * scale                                      # [bq, bk] f32
         if causal:
             rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -89,9 +98,11 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_s, l_s, *, scale, cau
         lse_ref[0, 0] = jnp.transpose(m_s[:] + jnp.log(l_safe), (1, 0))
 
 
-def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None):
+def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k, interpret, window=None,
+                      q_shared=None, k_shared=None):
     B, T, H, D = q.shape
     S = k.shape[1]
+    Dv = v.shape[3]  # the value size may differ from the query / key size
     scale = sm_scale if sm_scale is not None else D ** -0.5
     k = _broadcast_kv(k, H)
     v = _broadcast_kv(v, H)
@@ -102,36 +113,47 @@ def _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k, interpret, wi
 
     qr = q.transpose(0, 2, 1, 3).reshape(B * H, T, D)
     kr = k.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-    vr = v.transpose(0, 2, 1, 3).reshape(B * H, S, D)
+    vr = v.transpose(0, 2, 1, 3).reshape(B * H, S, Dv)
+    operands = [qr, kr, vr]
+    in_specs = [
+        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
+        pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b, j, 0)),
+    ]
+    shared = q_shared is not None
+    if shared:  # q_shared [B, T, H, D2]; k_shared [B, S, D2], never copied a head
+        D2 = q_shared.shape[3]
+        operands += [q_shared.transpose(0, 2, 1, 3).reshape(B * H, T, D2), k_shared]
+        in_specs += [
+            pl.BlockSpec((1, bq, D2), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bk, D2), lambda b, i, j: (b // H, j, 0)),
+        ]
 
     kernel = functools.partial(
-        _fa_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nk=nk, window=window
+        _fa_kernel, scale=scale, causal=causal, bq=bq, bk=bk, nk=nk, window=window,
+        shared=shared,
     )
     o, lse = pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, 1, bq), lambda b, i, j: (b, i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, T, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, nq, 1, bq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
-    )(qr, kr, vr)
-    o = o.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    )(*operands)
+    o = o.reshape(B, H, T, Dv).transpose(0, 2, 1, 3)
     lse = lse.reshape(B, H, T).transpose(0, 2, 1)  # [B, T, H] (from (BH, nq, 1, bq))
     return o, lse
 
@@ -358,41 +380,61 @@ def _fit_block(seq: int, block: int) -> int:
     return b if b >= 128 and seq % b == 0 else 0
 
 
-def kernel_supported(seq_q: int, seq_k: int, head_dim: int, block_q: int = 1024, block_k: int = 1024) -> bool:
+def kernel_supported(seq_q: int, seq_k: int, head_dim: int, block_q: int = 1024, block_k: int = 1024,
+                     *more_dims: int) -> bool:
     """True iff these shapes dispatch to the pallas kernel on a TPU backend.
     head_dim 64 (validated on-chip; covers most small models) or a
-    128-multiple (MXU-native); seq lengths must be divisible by SOME
-    power-of-two block >= 128 (the dispatch shrinks blocks to fit)."""
+    128-multiple (MXU-native), and so every one of `more_dims` (a value size
+    of its own, the shared second part of a key); seq lengths must be
+    divisible by SOME power-of-two block >= 128 (the dispatch shrinks blocks
+    to fit)."""
     return (
         _fit_block(seq_q, block_q) > 0
         and _fit_block(seq_k, block_k) > 0
-        and (head_dim == 64 or head_dim % 128 == 0)
+        and all(d == 64 or d % 128 == 0 for d in (head_dim,) + more_dims)
     )
 
 
-def _flash_fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k, window=None):
+def _flash_fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k, window=None,
+                        q_shared=None, k_shared=None):
     T, S = q.shape[1], k.shape[1]
-    if _on_tpu() and kernel_supported(T, S, q.shape[3], block_q, block_k):
+    more = (v.shape[3],) + (() if q_shared is None else (q_shared.shape[3],))
+    if _on_tpu() and kernel_supported(T, S, q.shape[3], block_q, block_k, *more):
         return _flash_fwd_pallas(
             q, k, v, causal, sm_scale, _fit_block(T, block_q), _fit_block(S, block_k),
-            interpret=False, window=window,
+            interpret=False, window=window, q_shared=q_shared, k_shared=k_shared,
         )
-    # XLA fallback (CPU tests, odd shapes)
+    # XLA fallback (CPU tests, odd shapes): the two products as one, the
+    # shared key part beside every head's own
+    if q_shared is not None:
+        q = jnp.concatenate([q, q_shared], axis=-1)
+        k = jnp.concatenate(
+            [k, jnp.broadcast_to(k_shared[:, :, None, :], k.shape[:3] + k_shared.shape[2:])], axis=-1)
     return _fwd_impl(q, k, v, causal, max(block_q, block_k), sm_scale, 0, 0, window)
 
 
 def flash_attention_fwd(q, k, v, causal: bool = True, sm_scale: Optional[float] = None,
                         block_q: int = 1024, block_k: int = 1024,
-                        window: Optional[int] = None):
-    """Forward only, outside the custom VJP: (o [B, T, H, D], lse [B, T, H]
+                        window: Optional[int] = None, q_shared=None, k_shared=None):
+    """Forward only, outside the custom VJP: (o [B, T, H, Dv], lse [B, T, H]
     f32). For callers that merge partial attentions by their log-sum-exp
     (paged admission: own suffix here, reused prefix from the pool).
     `window` (with `causal`): position i attends j with 0 <= i - j < window,
     and key blocks wholly behind a query block's window are skipped; the
-    training path has no windowed backward and does not take it."""
+    training path has no windowed backward and does not take it.
+
+    v's head size may differ from q's and k's. `q_shared` [B, T, H, D2] with
+    `k_shared` [B, S, D2] adds a second product to every score, q_shared .
+    k_shared, whose key part is ONE vector a position for all heads (latent
+    attention's rotary part: 64 wide beside a 128-wide own part, where one
+    192-wide key would need the shared part copied to every head and padded
+    to 256); `sm_scale` is then the caller's to give."""
     if window is not None and not causal:
         raise ValueError("a window is a causal mask's other edge: it needs causal=True")
-    return _flash_fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k, window)
+    if (q_shared is None) != (k_shared is None) or (q_shared is not None and sm_scale is None):
+        raise ValueError("q_shared and k_shared come together, with the sm_scale of the whole key")
+    return _flash_fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k, window,
+                               q_shared, k_shared)
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):

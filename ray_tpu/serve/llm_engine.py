@@ -8,9 +8,15 @@ engine to wrap, so this is the green-field TPU-native equivalent
 - LANES. A fixed number of slots, each an independent sequence at its
   own position. What a lane holds is the model's: the decode module the
   config object names (`cfg.decode_module`: models/llama_decode.py,
-  models/granite_hybrid_decode.py) owns the cache pytree and the two
+  models/granite_hybrid_decode.py, models/afmoe_decode.py,
+  models/sarvam_mla_decode.py) owns the cache pytree and the two
   halves of the macro-step. For an attention-only model a lane is a block
-  table into the K/V pool and a few scalars. For a model with recurrent
+  table into the K/V pool and a few scalars; where the pool holds ONE
+  latent row a position in place of keys and values (a decode module
+  with `LATENT_POOL`), the tables, the allocator and the planner are the
+  same, and what copies or ships a K and a V pool (prefix reuse,
+  speculation, migration, the cluster cache) is refused by name until it
+  knows that pool. For a model with recurrent
   layers a lane ALSO owns a row of per-layer state (conv tail, SSM state)
   that admission overwrites, the decode step updates while the lane is
   live, and release abandons. Blocks alone cannot resume such a lane, so
@@ -325,7 +331,13 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
     lane before its plan does. `past_window_lane_steps` is the live
     lane-steps whose context is longer than `window`, for a model with
     sliding-window layers (their rings are full there, and the window's
-    mask cuts something off); no other model's dispatch carries the key."""
+    mask cuts something off); no other model's dispatch carries the key.
+    `ctx_tokens` is the positions the dispatch's decode steps attend, summed
+    over steps and live lanes (a lane at position pos attends pos + 1), and
+    `prompt_pairs` the (query, key) pairs of its admissions' causal
+    attention, n (n + 1) / 2 for a prompt of n tokens (and n times its
+    reused prefix): what the attention of each half has to do whatever
+    does it, for every model."""
     total = sum(ph["steps"] for ph in phases)
     done = 0  # decode steps of this dispatch run so far
     last: Dict[int, int] = {}  # finishing request -> `done` at its last token
@@ -346,7 +358,8 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
               "prompt_tokens": prompt_tokens, "prefix_tokens": prefix_tokens,
               "lane_steps": lane_steps,
               "finishing": len(last),
-              "finish_wait_steps": sum(total - d for d in last.values())}
+              "finish_wait_steps": sum(total - d for d in last.values()),
+              "ctx_tokens": _ctx_tokens(phases), "prompt_pairs": _prompt_pairs(phases)}
     if recurrent:
         counts["state_lanes"] = lane_steps
     if ctx_chunk:
@@ -370,6 +383,26 @@ def _past_window_lane_steps(phases: List[Dict[str, Any]], window: int) -> int:
             # its contexts in this phase: first .. first + take - 1
             past += max(0, first + take - max(first, window + 1))
     return past
+
+
+def _prompt_pairs(phases: List[Dict[str, Any]]) -> int:
+    """`prompt_pairs` of `_dispatch_counts`."""
+    new = [(_suffix_len(req), req._start) for ph in phases for _, req in ph["admissions"]]
+    return sum(n * start + n * (n + 1) // 2 for n, start in new)
+
+
+def _ctx_tokens(phases: List[Dict[str, Any]]) -> int:
+    """`ctx_tokens` of `_dispatch_counts`, by `_ctx_chunks`' walk: a lane
+    that owes r steps before a phase attends its prompt and max_new_tokens
+    - r positions at the phase's first step, one more each step after."""
+    owed: Dict[int, int] = {}
+    tokens = 0
+    for ph in reversed(phases):
+        for _, req, take in ph["takes"]:
+            before = owed[id(req)] = owed.get(id(req), req._remaining) + take
+            first = len(req.prompt) + req.max_new_tokens - before
+            tokens += take * first + take * (take - 1) // 2
+    return tokens
 
 
 def _ctx_chunks(phases: List[Dict[str, Any]], ctx_chunk: int) -> int:
@@ -423,27 +456,46 @@ def _finish(req: "_Request", error: Optional[str] = None,
     return True
 
 
-def _refuse_for_recurrent_state(**asked) -> None:
-    """A model whose lanes hold rows of their own beside their K/V blocks
-    (recurrent state, a window layer's ring of its last positions) cannot
-    resume a sequence from the blocks: what those rows held at a block
-    boundary is not kept. Every option that needs such a snapshot is
-    refused by name (never silently switched off) until one exists."""
-    why = ("the model's lanes hold recurrent state (or a window layer's "
-           "ring) beside their blocks, and the state at a block boundary "
-           "is not kept: ")
-    no_rollback = ("rejected speculative tokens cannot be rolled back out "
-                   "of a recurrence")
-    reasons = {
-        "prefix_cache": "a block-aligned prefix hit is useless without the "
-                        "state at that boundary (pass prefix_cache=False)",
-        "draft_model": no_rollback,
-        "num_speculative_tokens": no_rollback,
-        "role": "a migrated request's blocks cannot resume without its "
-                "lane's state (disaggregated pools)",
-        "cluster_cache": "a peer's prefix blocks are useless without the "
-                         "state at their boundary",
-    }
+def _refuse_what_reuses_kv_blocks(latent_pool: bool, **asked) -> None:
+    """Every option that resumes, copies or ships a sequence by its K/V
+    blocks is refused by name (never silently switched off) for a model
+    those blocks do not describe, for one of two reasons. Lanes that hold
+    rows of their own beside their blocks (recurrent state, a window
+    layer's ring of its last positions): what those rows held at a block
+    boundary is not kept, so the blocks cannot resume a sequence. A
+    `latent_pool` (one pool of latent rows, no keys and no values): the
+    blocks are a lane's whole state, but the programs that copy, gather,
+    import and re-read blocks, and the speculative ones, are written for a
+    K pool and a V pool."""
+    if latent_pool:
+        why = ("the model's cache is one pool of latent rows, and what reuses "
+               "blocks is written for a K pool and a V pool: ")
+        needs_kv = "the speculative programs read and write cache['k'] and cache['v']"
+        reasons = {
+            "prefix_cache": "the admission's prefix loop and the copy-on-write of a "
+                            "shared block read K and V blocks (pass prefix_cache=False)",
+            "draft_model": needs_kv,
+            "num_speculative_tokens": needs_kv,
+            "role": "the KV plane gathers and imports a K and a V array a "
+                    "migration (disaggregated pools)",
+            "cluster_cache": "a peer's prefix blocks arrive as a K and a V array",
+        }
+    else:
+        why = ("the model's lanes hold recurrent state (or a window layer's "
+               "ring) beside their blocks, and the state at a block boundary "
+               "is not kept: ")
+        no_rollback = ("rejected speculative tokens cannot be rolled back out "
+                       "of a recurrence")
+        reasons = {
+            "prefix_cache": "a block-aligned prefix hit is useless without the "
+                            "state at that boundary (pass prefix_cache=False)",
+            "draft_model": no_rollback,
+            "num_speculative_tokens": no_rollback,
+            "role": "a migrated request's blocks cannot resume without its "
+                    "lane's state (disaggregated pools)",
+            "cluster_cache": "a peer's prefix blocks are useless without the "
+                             "state at their boundary",
+        }
     for name, reason in reasons.items():
         if asked.get(name):
             raise ValueError(f"{name}={asked[name]!r} is refused: " + why + reason)
@@ -465,9 +517,11 @@ class ContinuousBatchingEngine:
         D = cfg.decode_module
         # bytes of recurrent state a lane holds beside its K/V blocks
         self.state_bytes = int(D.state_bytes_per_lane(cfg))
-        if self.state_bytes:
-            _refuse_for_recurrent_state(
-                prefix_cache=prefix_cache, draft_model=draft_model,
+        # one pool of latent rows where other models hold a K and a V pool
+        latent_pool = bool(getattr(D, "LATENT_POOL", False))
+        if self.state_bytes or latent_pool:
+            _refuse_what_reuses_kv_blocks(
+                latent_pool, prefix_cache=prefix_cache, draft_model=draft_model,
                 num_speculative_tokens=num_speculative_tokens, role=role,
                 cluster_cache=cluster_cache)
 
@@ -576,9 +630,9 @@ class ContinuousBatchingEngine:
         # counts their compilations by these names
         # (benchmark/drivers/serve.py:55, `bench_compiles`); they go with
         # ROADMAP B7 (the driver drops the three keys) and D2b (the
-        # programs themselves). A model with recurrent state has none.
+        # programs themselves). Only Llama's decode module has them.
         self._prefill_slots = self._chunk_fn = self._macro_fn = None
-        if not self.state_bytes:
+        if hasattr(D, "jitted_macro_step_slots"):
             self._prefill_slots = D.jitted_prefill_into_slots(cfg)
             self._chunk_fn = D.jitted_decode_chunk_slots(cfg, chunk)
             self._macro_fn = D.jitted_macro_step_slots(cfg, chunk)
@@ -589,6 +643,7 @@ class ContinuousBatchingEngine:
         self._queue: "queue.Queue[_Request]" = queue.Queue()
         self._waiting: deque = deque()       # planner-side FIFO (loop thread only)
         self._pending: deque = deque()       # fetch frontier: tagged entries
+        self._planned: Dict[int, Dict[str, int]] = {}  # seq -> plan counts, until resolved
         # KV-plane plumbing: inbound migrations (fetched payloads
         # awaiting a slot), cross-thread jobs the loop executes at plan
         # boundaries (allocator/trie mutation stays loop-thread-only),
@@ -652,6 +707,9 @@ class ContinuousBatchingEngine:
                    # was planned to read, and what reading every step's
                    # whole table span would have been
                    "ctx_chunks": 0, "span_chunks": 0,
+                   # positions the planned decode steps attend, and (query,
+                   # key) pairs of the planned admissions' attention
+                   "ctx_tokens": 0, "prompt_pairs": 0,
                    # planned live lane-steps whose context passes the
                    # model's sliding window (0 for a model without one)
                    "past_window_lane_steps": 0,
@@ -1579,12 +1637,14 @@ class ContinuousBatchingEngine:
         ))
         return A, P
 
-    def _dispatch_macro(self, phases: List[Dict[str, Any]]) -> None:
+    def _dispatch_macro(self, phases: List[Dict[str, Any]],
+                        counts: Dict[str, int]) -> None:
         """Ship the plan as ONE jitted dispatch and append the result to
         the fetch frontier (resolved one macro-step behind). Admission
         rows carry only each prompt's SUFFIX beyond its reused prefix,
         and the per-phase block tables + sampling plan ride along as
-        extra program arguments."""
+        extra program arguments. `counts` is the plan's
+        `_dispatch_counts`, kept for the dispatch's `engine.resolve`."""
         import jax.numpy as jnp
 
         from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
@@ -1676,6 +1736,9 @@ class ContinuousBatchingEngine:
         if self._window:
             self._m["past_window_lane_steps"] += _past_window_lane_steps(
                 phases, self._window)
+        self._m["ctx_tokens"] += counts["ctx_tokens"]
+        self._m["prompt_pairs"] += counts["prompt_pairs"]
+        self._planned[seq] = counts
         if self._ctx_chunk:
             self._m["ctx_chunks"] += _ctx_chunks(phases, self._ctx_chunk)
             self._m["span_chunks"] += sum(ph["steps"] for ph in phases) * -(
@@ -1746,7 +1809,10 @@ class ContinuousBatchingEngine:
     def _resolve_next(self) -> None:
         """Resolve the oldest dispatch in flight, under its span."""
         entry = self._pending.popleft()
-        with self._span(_SPAN_RESOLVE, seq=entry[4]) as span:
+        # the span repeats its dispatch's plan counts: a trace that starts
+        # after a dispatch still knows what its execution was planned to do
+        with self._span(_SPAN_RESOLVE, seq=entry[4],
+                        **self._planned.pop(entry[4], {})) as span:
             counted = self._resolve(entry)
             if counted:  # the dispatch's device counters, as the span's stats
                 span.set_metadata(**counted)
@@ -1783,7 +1849,7 @@ class ContinuousBatchingEngine:
             if phases:
                 with span(_SPAN_DISPATCH, seq=self._m["dispatches"], A=A, P=P,
                           **counts):
-                    self._dispatch_macro(phases)
+                    self._dispatch_macro(phases, counts)
                 # fetch one macro-step BEHIND: overlaps the one just
                 # dispatched
                 while len(self._pending) > 1:

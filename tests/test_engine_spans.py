@@ -262,7 +262,11 @@ def test_finish_wait_steps_on_a_hand_built_plan():
         "phases": 4, "steps": 12, "admissions": 4, "prompt_tokens": 9 + 12 + 12 + 5,
         "prefix_tokens": 8, "lane_steps": 20, "finishing": 3,
         # a after 2 of 12 steps, c after 8, d after 8 (its phase's 4 steps follow it)
-        "finish_wait_steps": 10 + 4 + 4}
+        "finish_wait_steps": 10 + 4 + 4,
+        # a attends 10, 11; b 13..24; c 21..26: what the decode steps read
+        "ctx_tokens": 21 + 222 + 141,
+        # n (n + 1) / 2 a prompt, and c's 12 new tokens over its 8 reused
+        "prompt_pairs": 45 + 78 + (96 + 78) + 15}
     # a plan that finishes nobody waits for nothing
     assert llm_engine._dispatch_counts([{"steps": 4, "admissions": [], "takes": [(1, b, 4)]}])[
         "finish_wait_steps"] == 0
@@ -283,7 +287,7 @@ def _drive(eng, prompts_and_answers):
         phases = eng._plan()
         seen.append(([(ph["steps"], [(r, t) for _, r, t in ph["takes"]]) for ph in phases],
                      llm_engine._dispatch_counts(phases, False, eng._ctx_chunk)))
-        eng._dispatch_macro(phases)
+        eng._dispatch_macro(phases, seen[-1][1])
     while eng._pending:
         eng._resolve(eng._pending.popleft())
     assert all(r.done.is_set() and r.error is None for r in reqs)
@@ -395,11 +399,13 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
 
     dispatches = [stats for name, _, _, stats in top if name == "engine.dispatch"]
     keys = {"seq", "phases", "steps", "admissions", "A", "P", "prompt_tokens", "prefix_tokens",
-            "lane_steps", "finishing", "finish_wait_steps", "ctx_chunks"}
+            "lane_steps", "finishing", "finish_wait_steps", "ctx_chunks", "ctx_tokens",
+            "prompt_pairs"}
     assert all(set(d) == keys for d in dispatches)
     diff = {k: m1[k] - m0[k] for k in ("dispatches", "slot_steps", "useful_slot_steps",
                                        "prefill_tokens", "reused_prefix_tokens",
-                                       "requests_completed", "ctx_chunks", "span_chunks")}
+                                       "requests_completed", "ctx_chunks", "span_chunks",
+                                       "ctx_tokens", "prompt_pairs")}
     assert len(dispatches) == diff["dispatches"] >= 2
     assert [d["seq"] for d in dispatches] == list(
         range(m0["dispatches"], m0["dispatches"] + len(dispatches)))
@@ -412,6 +418,12 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
     assert (sum(d["ctx_chunks"] for d in dispatches) == diff["ctx_chunks"]
             == diff["span_chunks"] == sum(d["steps"] for d in dispatches))
     assert sum(d["finishing"] for d in dispatches) == diff["requests_completed"] == len(reqs)
+    # what the attention of each half has to do, for every model (PR 39)
+    assert sum(d["ctx_tokens"] for d in dispatches) == diff["ctx_tokens"] > diff["useful_slot_steps"]
+    assert sum(d["prompt_pairs"] for d in dispatches) == diff["prompt_pairs"] > diff["prefill_tokens"]
     assert all(d["P"] in (16, 32) and d["A"] in (1, 2) for d in dispatches)
-    resolved = [stats["seq"] for name, _, _, stats in top if name == "engine.resolve"]
-    assert resolved == [d["seq"] for d in dispatches]
+    # each resolve repeats its dispatch's plan counts: an execution whose
+    # dispatch lies before a trace's start is still counted (PR 39)
+    resolved = [stats for name, _, _, stats in top if name == "engine.resolve"]
+    plan = keys - {"A", "P"}
+    assert [{k: r[k] for k in plan} for r in resolved] == [{k: d[k] for k in plan} for d in dispatches]

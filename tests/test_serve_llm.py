@@ -240,12 +240,15 @@ def test_macro_dispatch_amortization_smoke():
     K chunks (driven synchronously so the count is deterministic)."""
     import math
 
+    from ray_tpu.serve.llm_engine import _dispatch_counts
+
     engine, _, _ = _tiny_engine(n_slots=2, chunk=4, macro_phases=4)
     engine.shutdown()  # drive the scheduler synchronously below
     reqs = [engine.submit([1 + i, 2 + i, 3 + i], 8) for i in range(4)]
     engine._drain_queue()
     while engine._waiting or any(r is not None for r in engine._slots):
-        engine._dispatch_macro(engine._plan())
+        phases = engine._plan()
+        engine._dispatch_macro(phases, _dispatch_counts(phases))
     while engine._pending:
         engine._resolve(engine._pending.popleft())
     assert all(r.done.is_set() and len(r.tokens) == 8 for r in reqs)

@@ -228,23 +228,30 @@ def _mistral_macro_step(one_chip, A, P):
         arr((K, B, MAX_STOP_TOKENS))).compile()
 
 
+def _outputs_of_own_operations(text):
+    """[(name, op, output shapes)] of an optimized module's instructions that
+    are operations of their own (they carry `estimated_cycles`; a fused
+    computation's inner instructions do not)."""
+    import re
+
+    line = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(")
+    out = []
+    for ln in text.splitlines():
+        m = line.match(ln)
+        if m and '"estimated_cycles"' in ln:
+            out.append((m.group(1), m.group(3), set(re.findall(r"(?:bf16|f32)\[[\d,]+\]", m.group(2)))))
+    return out
+
+
 def _weight_and_pool_copies(text):
-    """What the folded q / k / v products cost, among the instructions of an
-    optimized module that are operations of their own (they carry
-    `estimated_cycles`; a fused computation's inner instructions do not):
-    (outputs that are one layer's whole wq / wk / wv, transposed or fused
-    forms included; copies of a whole stack of weights; copies of the whole
-    K or V pool)."""
+    """What the folded q / k / v products cost, among the operations of an
+    optimized module (`_outputs_of_own_operations`): (outputs that are one
+    layer's whole wq / wk / wv, transposed or fused forms included; copies of
+    a whole stack of weights; copies of the whole K or V pool)."""
     import re
 
     slices, stacks, pools = [], [], []
-    line = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(")
-    for ln in text.splitlines():
-        m = line.match(ln)
-        if not m or '"estimated_cycles"' not in ln:
-            continue
-        name, out, op = m.groups()
-        shapes = set(re.findall(r"bf16\[[\d,]+\]", out))
+    for name, op, shapes in _outputs_of_own_operations(text):
         slices += [(name, s) for s in shapes if re.fullmatch(
             r"bf16\[1,4096,(4096|1024|6144)\]|bf16\[1,(1024|6144),4096\]", s)]
         if op == "copy":
@@ -458,3 +465,90 @@ def test_afmoe_macro_step_reads_the_expert_stacks_in_place(one_chip, monkeypatch
     copied = [ln.split(" = ")[0].strip() for ln in compiled.as_text().splitlines()
               if '"estimated_cycles"' in ln and whole_layer.search(ln.split(" = ")[1].split("(")[0])]
     assert not copied, copied
+
+
+def test_flash_forward_kernel_with_a_shared_key_part_compiles(one_chip):
+    """Latent attention's admission: 64 heads whose keys are a 128-wide part
+    of their own and ONE 64-wide rotary part for all heads (192 together),
+    values 128 wide, 4096 positions, blocks of 1024: the two score products
+    in the kernel, the shared part's index map ignoring the head."""
+    B, T, H = 2, 4096, 64
+    assert FA.kernel_supported(T, T, 128, 1024, 1024, 128, 64)
+    assert not FA.kernel_supported(T, T, 192)  # one 192-wide key is no kernel shape
+    arr, _ = _shapes_on(one_chip)
+    q, q2 = arr((B, T, H, 128), jnp.bfloat16), arr((B, T, H, 64), jnp.bfloat16)
+    fwd = functools.partial(FA._flash_fwd_pallas, causal=True, sm_scale=0.135, block_q=1024,
+                            block_k=1024, interpret=False)
+    lowered = jax.jit(lambda q, k, v, q2, k2: fwd(q, k, v, q_shared=q2, k_shared=k2)).lower(
+        q, q, q, q2, arr((B, T, 64), jnp.bfloat16))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    lowered.compile()
+
+
+@functools.lru_cache(maxsize=2)
+def _mla_macro_step(one_chip, A, P):
+    """The latent-attention decoder's paged macro-step at
+    `sarvam-105b.serve`'s widths (one dense and four expert layers holding 32
+    of the router's 128 experts, a 65,536-row vocabulary, 8 lanes, a table
+    span of 8192), compiled for the described chip at the (A, P) variant."""
+    from ray_tpu.models import sarvam_mla as M
+    from ray_tpu.models import sarvam_mla_decode as D
+    from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
+
+    cfg = M.SarvamMlaConfig(vocab_size=65536, n_layers=5, held_count=32, max_seq_len=8192)
+    B, bs, K = 8, 16, 8
+    MB = cfg.max_seq_len // bs
+    arr, shaped = _shapes_on(one_chip)
+    params = shaped(jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
+    return D.jitted_macro_step_slots_paged(cfg, 8, sampled=False).lower(
+        params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
+        arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
+        arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
+        arr((K, B, MAX_STOP_TOKENS))).compile()
+
+
+def test_mla_macro_step_reads_pool_latent_weights_and_experts_in_place(one_chip, monkeypatch):
+    """The dispatch that admits nothing, (1, 16): 9.07 GB of weights and a
+    0.42 GB latent pool go in (the pool donated). No operation outputs a
+    layer of the pool or copies the pool (a 576-column row did: two relayout
+    copies of the whole pool a dispatch, 0.42 GB of temporaries; the row is
+    padded to 640 for that, compiled only, PR 39), none outputs a layer's
+    W_uk / W_uv in another layout or the stack of them, none a layer's held
+    experts (bf16[32, 4096, 2048], 537 MB): the temporaries are 0.03 GB."""
+    import re
+
+    monkeypatch.setattr(FA, "_on_tpu", lambda: True)
+    compiled = _mla_macro_step(one_chip, 1, 16)
+    m = compiled.memory_analysis()
+    assert 9.45e9 < m.argument_size_in_bytes < 9.55e9 and m.alias_size_in_bytes > 0.41e9
+    assert m.temp_size_in_bytes < 0.1e9, m.temp_size_in_bytes
+    ops = _outputs_of_own_operations(compiled.as_text())
+    pool = re.compile(r"bf16\[(5|1),4097,16,(640|576)\]")
+    copies = [(n, s) for n, op, shapes in ops for s in shapes
+              if pool.fullmatch(s) and (op == "copy" or s.startswith("bf16[1,"))]
+    assert not copies, copies
+    assert sum(1 for _, _, shapes in ops if "bf16[5,4097,16,640]" in shapes) <= 2  # the in-place writes
+    moved = re.compile(r"bf16\[(5,|1,)?(64,128,512|64,512,128|512,64,128|128,64,512|32,4096,2048|"
+                       r"32,2048,4096)\]")
+    assert not [(n, s) for n, _, shapes in ops for s in shapes if moved.fullmatch(s)]
+
+
+def test_mla_widest_admission_fits_the_chip_and_attends_through_the_kernel(one_chip, monkeypatch):
+    """(A, P) = (8, 4096), 32,768 admitted tokens: the admission's attention
+    is the flash kernel (one call in each of the two layer loops), two rows
+    at a time (`sarvam_mla.ATTN_TOKENS`), and arguments + temporaries stay
+    under 13.5 GB of the chip's 16 (12.77 compiled only, PR 39: 9.49 GB of
+    arguments, 3.28 GB of temporaries)."""
+    monkeypatch.setattr(FA, "_on_tpu", lambda: True)
+    compiled = _mla_macro_step(one_chip, 8, 4096)
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+    print(f"memory_analysis (8, 4096): arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, total {total / 1e9:.2f} GB")
+    assert total < 13.5e9, total
+    import re
+
+    # 2 rows x 64 heads a call, values 128 wide: one call in each layer loop
+    kernels = re.findall(r"%flash_fwd[.\d]* = \((bf16\[[\d,]+\])[^=]*custom-call\(", compiled.as_text())
+    assert kernels == ["bf16[128,4096,128]"] * 2, kernels
