@@ -59,10 +59,9 @@ DENSE, MOE = "dense", "moe"
 # inside the macro-step's admit_prefill / decode_chunk and name neither
 SCOPE_ROUTE, SCOPE_EXPERTS, SCOPE_SHARED, SCOPE_WINDOW, SCOPE_FULL = (
     "moe_route", "moe_experts", "moe_shared", "attn_window", "attn_full")
-# rows of one pass of the expert layer: a longer input goes through in
-# pieces of this many, so that the sorted copies of its rows (top_k a row)
-# stay a few hundred MB whatever the admission's width
-MOE_ROWS = 4096
+# (row, expert) pairs of one pass of `expert_ffn`'s products: more (an
+# admission) go through in chunks of this many, fewer (a decode step) at once
+PAIR_CHUNK = 4096
 
 _MINI_LAYERS = tuple(FULL if i % 4 == 3 else SLIDING for i in range(32))
 
@@ -248,7 +247,7 @@ def route(u, router, bias, cfg: AfmoeConfig):
     return chosen.astype(jnp.int32), w * cfg.route_scale
 
 
-def expert_ffn(u, chosen, w, experts, at, cfg: AfmoeConfig, live=None):
+def expert_ffn(u, chosen, w, experts, at, cfg: AfmoeConfig, live=None, chunk: int = PAIR_CHUNK):
     """sum_e w_e SwiGLU_e(u) over each row's chosen experts: u (N, d),
     chosen and w (N, top_k). The N * top_k (row, expert) pairs are sorted
     by expert and each expert multiplies its own rows and no others: three
@@ -272,6 +271,10 @@ def expert_ffn(u, chosen, w, experts, at, cfg: AfmoeConfig, live=None):
     layer's experts out first is a copy of all of them (1.6 GB a layer at
     Trinity-Mini's widths) in every decode step: a ragged product is a
     kernel, and no slice fuses into a kernel's operand.
+
+    More than `chunk` pairs (an admission; a decode step has a few dozen)
+    go through `_expert_ffn_in_chunks`: the same sums, and what belongs to
+    no group is sorted and nothing else.
     Returns (out (N, d), rows a held expert (E,) int32)."""
     N, k = chosen.shape
     first, E = cfg.held_experts
@@ -285,14 +288,22 @@ def expert_ffn(u, chosen, w, experts, at, cfg: AfmoeConfig, live=None):
         pair_expert = jnp.where(jnp.repeat(live, k), pair_expert, E)
     order = jnp.argsort(pair_expert, stable=True)
     sizes = jnp.sum(pair_expert[:, None] == jnp.arange(E)[None, :], axis=0, dtype=jnp.int32)
-    groups = jax.lax.dynamic_update_slice(
-        jnp.zeros((n_layers * E,), jnp.int32), sizes, (at * E,))
     stack = lambda name: experts[name].reshape((n_layers * E,) + experts[name].shape[2:])  # noqa: E731
 
-    rows = u[order // k]                                    # (N * k, d), sorted by expert
-    gate = jax.nn.silu(grouped_matmul(rows, stack("w_gate"), groups).astype(F32))
-    act = gate.astype(cfg.dtype) * grouped_matmul(rows, stack("w_up"), groups)
-    y = grouped_matmul(act, stack("w_down"), groups)
+    def products(pairs, sizes):
+        """SwiGLU of each pair's expert for `pairs` (indices into the N *
+        top_k), sorted by group; `sizes` (E,) pairs a group of layer `at`."""
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_layers * E,), jnp.int32), sizes, (at * E,))
+        rows = u[pairs // k]
+        gate = jax.nn.silu(grouped_matmul(rows, stack("w_gate"), groups).astype(F32))
+        act = gate.astype(cfg.dtype) * grouped_matmul(rows, stack("w_up"), groups)
+        return grouped_matmul(act, stack("w_down"), groups)
+
+    if N * k > chunk:
+        out = _expert_ffn_in_chunks(w, order, sizes, products, u.shape[1], chunk)
+        return out.astype(cfg.dtype), sizes
+    y = products(order, sizes)                              # (N * k, d), sorted by expert
     # back to the rows' order, each pair beside its weight; a row of a
     # ragged product past the last group holds nothing meaningful
     back = jnp.argsort(order)
@@ -303,6 +314,35 @@ def expert_ffn(u, chosen, w, experts, at, cfg: AfmoeConfig, live=None):
         y = jnp.where(held.reshape(N, k, 1), y, 0)
     out = jnp.einsum("nkd,nk->nd", y, w.astype(F32), preferred_element_type=F32)
     return out.astype(cfg.dtype), sizes
+
+
+def _expert_ffn_in_chunks(w, order, sizes, products, d: int, chunk: int):
+    """`expert_ffn`'s sum for any number of pairs, `chunk` sorted pairs at a
+    time and ONLY as far as the pairs in a group reach (the sort puts them
+    in front): each pass gathers its pairs' rows of u, multiplies them with
+    the group sizes clipped to the chunk (a group that a chunk's edge cuts
+    is read on both sides of it), weights each result by its pair's w and
+    adds it to its row of a float32 (N, d) sum. Nothing top_k times as long
+    as u is built: a pair in no group costs its sort key. `products(pairs,
+    sizes)` is `expert_ffn`'s, d its width; returns the sum (N, d) float32."""
+    N, k = w.shape
+    # a whole last chunk, so that no slice starts early
+    order = jnp.pad(order, (0, -(N * k) % chunk))
+    w = w.astype(F32).reshape(-1)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    n_in = ends[-1]
+
+    def add_chunk(c, total):
+        lo = c * chunk
+        pairs = jax.lax.dynamic_slice(order, (lo,), (chunk,))
+        y = products(pairs, jnp.clip(ends - lo, 0, chunk) - jnp.clip(starts - lo, 0, chunk))
+        # a row of a ragged product past the last group holds nothing
+        # meaningful: it is sent to row N, which there is not
+        rows = jnp.where(lo + jnp.arange(chunk) < n_in, pairs // k, N)
+        return total.at[rows].add(y.astype(F32) * w[pairs][:, None], mode="drop")
+
+    return jax.lax.fori_loop(0, -(-n_in // chunk), add_chunk, jnp.zeros((N, d), F32))
 
 
 def moe_ffn(m, p, cfg: AfmoeConfig, live=None):
@@ -317,17 +357,6 @@ def moe_ffn(m, p, cfg: AfmoeConfig, live=None):
     with jax.named_scope(SCOPE_SHARED):
         out = out + swiglu(m, p["shared"], cfg)
     return out, sizes
-
-
-def moe_ffn_in_pieces(m, p, cfg: AfmoeConfig):
-    """`moe_ffn` over any number of rows, MOE_ROWS at a time."""
-    N, d = m.shape
-    if N <= MOE_ROWS:
-        return moe_ffn(m, p, cfg)[0]
-    pad = -N % MOE_ROWS
-    pieces = jnp.pad(m, ((0, pad), (0, 0))).reshape(-1, MOE_ROWS, d)
-    out = jax.lax.map(lambda piece: moe_ffn(piece, p, cfg)[0], pieces)
-    return out.reshape(-1, d)[:N]
 
 
 # ------------------------------------------------------- the attention half
@@ -374,12 +403,12 @@ def run_layers(params, x, carry, cfg: AfmoeConfig, mixers: Dict[str, Callable],
     """x (..., d) through every layer in order. `mixers[attention kind]
     (layer, index among its kind, normed x, carry) -> (attention output,
     carry)`; `experts(expert layer's params, normed rows (N, d), carry) ->
-    (FFN output, carry)`, by default the expert layer MOE_ROWS rows at a
-    time. The block around them (four norms, two residuals, the dense FFN)
-    is the same for every caller: the full forward, admission and the
-    decode step."""
+    (FFN output, carry)`, by default the expert layer over every row. The
+    block around them (four norms, two residuals, the dense FFN) is the
+    same for every caller: the full forward, admission and the decode
+    step."""
     if experts is None:
-        experts = lambda p, m, carry: (moe_ffn_in_pieces(m, p, cfg), carry)  # noqa: E731
+        experts = lambda p, m, carry: (moe_ffn(m, p, cfg)[0], carry)  # noqa: E731
     eps = cfg.rms_eps
 
     for attn, ffn, g0, a0, f0, n in cfg.runs:

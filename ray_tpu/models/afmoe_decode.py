@@ -143,6 +143,10 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     valid = lengths > 0
     cos, sin = M.rope_tables(cfg, P)
     window = cfg.sliding_window
+    # the rows that are a prompt's: a padded row chooses no expert (what it
+    # leaves is read by nothing: attention is causal, the head reads each
+    # row's last real position, the pool's padded positions are masked)
+    real = (jnp.arange(P)[None, :] < lengths[:, None]).reshape(-1)
 
     def window_mixer(layer, wi, a, carry):
         k_full, v_full, wk, wv = carry
@@ -169,7 +173,8 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     x, (k_full, v_full, wk, wv) = M.run_layers(
         params, M.embed_tokens(params, prompts, cfg),
         (cache["k"], cache["v"], cache["wk"], cache["wv"]), cfg,
-        {SLIDING: window_mixer, FULL: full_mixer})
+        {SLIDING: window_mixer, FULL: full_mixer},
+        lambda p, m, carry: (M.moe_ffn(m, p, cfg, live=real)[0], carry))
     # the head at each row's last real position only: all P positions in
     # float32 over this vocabulary would be gigabytes
     x_last = jnp.take_along_axis(

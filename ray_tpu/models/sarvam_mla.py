@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.afmoe import (  # the FFN half is that model's, called not copied
-    DENSE, MOE, _dense, _layer_at, logits_of, make_moe, make_swiglu, moe_ffn_in_pieces, swiglu)
+    DENSE, MOE, _dense, _layer_at, logits_of, make_moe, make_swiglu, moe_ffn, swiglu)
 from ray_tpu.ops.normalization import rms_norm
 from ray_tpu.ops.rope import apply_rope, yarn_frequencies, yarn_mscale
 
@@ -280,10 +280,10 @@ def run_layers(params, x, carry, cfg: SarvamMlaConfig, mixer: Callable,
     """x (..., d) through every layer in order. `mixer(layer, index, normed
     x, carry) -> (attention output, carry)`; `experts(expert layer's params,
     normed rows (N, d), carry) -> (FFN output, carry)`, by default the expert
-    layer afmoe.MOE_ROWS rows at a time. The block around them is the same
-    for the full forward, the admission and the decode step."""
+    layer over every row. The block around them is the same for the full
+    forward, the admission and the decode step."""
     if experts is None:
-        experts = lambda p, m, carry: (moe_ffn_in_pieces(m, p, cfg), carry)  # noqa: E731
+        experts = lambda p, m, carry: (moe_ffn(m, p, cfg)[0], carry)  # noqa: E731
 
     def body(c, i, ffn, g0):
         x, carry = c

@@ -87,6 +87,10 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     adm_tables = tables[slots]
     valid = lengths > 0
     cos, sin = M.rope_tables(cfg, P)
+    # the rows that are a prompt's: a padded row chooses no expert (what it
+    # leaves is read by nothing: attention is causal, the head reads each
+    # row's last real position, the pool's padded positions are masked)
+    real = (jnp.arange(P)[None, :] < lengths[:, None]).reshape(-1)
 
     def mixer(layer, li, a, pool):
         out, rows = M.sequence_mixer(layer, a, cos, sin, cfg)
@@ -95,7 +99,9 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
                                            adm_tables, starts, valid)
         return out, pool
 
-    x, pool = M.run_layers(params, M.embed_tokens(params, prompts, cfg), cache["latent"], cfg, mixer)
+    x, pool = M.run_layers(
+        params, M.embed_tokens(params, prompts, cfg), cache["latent"], cfg, mixer,
+        lambda p, m, carry: (afmoe.moe_ffn(m, p, cfg, live=real)[0], carry))
     # the head at each row's last real position only
     x_last = jnp.take_along_axis(
         x, (jnp.maximum(lengths, 1) - 1)[:, None, None], axis=1)[:, 0, :]

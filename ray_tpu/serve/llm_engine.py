@@ -90,7 +90,7 @@ import queue
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -308,7 +308,8 @@ def _suffix_len(req: "_Request") -> int:
 
 
 def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
-                     ctx_chunk: int = 0, window: int = 0) -> Dict[str, int]:
+                     ctx_chunk: int = 0, window: int = 0,
+                     variant: Tuple[int, int] = (0, 0)) -> Dict[str, int]:
     """What one macro dispatch carries, from the plan alone: the keyword
     arguments of its `engine.dispatch` span (host integers; nothing is
     read from the device). `_plan` leaves every request at its
@@ -337,7 +338,10 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
     `prompt_pairs` the (query, key) pairs of its admissions' causal
     attention, n (n + 1) / 2 for a prompt of n tokens (and n times its
     reused prefix): what the attention of each half has to do whatever
-    does it, for every model."""
+    does it, for every model. `admit_rows` is the token rows the admissions
+    run, padding included: A x P (`variant`, the compiled program's) for
+    each phase that admits; `prompt_tokens / admit_rows` is the share of
+    them that is a prompt's."""
     total = sum(ph["steps"] for ph in phases)
     done = 0  # decode steps of this dispatch run so far
     last: Dict[int, int] = {}  # finishing request -> `done` at its last token
@@ -359,7 +363,9 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
               "lane_steps": lane_steps,
               "finishing": len(last),
               "finish_wait_steps": sum(total - d for d in last.values()),
-              "ctx_tokens": _ctx_tokens(phases), "prompt_pairs": _prompt_pairs(phases)}
+              "ctx_tokens": _ctx_tokens(phases), "prompt_pairs": _prompt_pairs(phases),
+              "admit_rows": variant[0] * variant[1] * sum(
+                  1 for ph in phases if ph["admissions"])}
     if recurrent:
         counts["state_lanes"] = lane_steps
     if ctx_chunk:
@@ -710,6 +716,9 @@ class ContinuousBatchingEngine:
                    # positions the planned decode steps attend, and (query,
                    # key) pairs of the planned admissions' attention
                    "ctx_tokens": 0, "prompt_pairs": 0,
+                   # token rows the planned admissions run, padding
+                   # included (A x P a phase that admits)
+                   "admit_rows": 0,
                    # planned live lane-steps whose context passes the
                    # model's sliding window (0 for a model without one)
                    "past_window_lane_steps": 0,
@@ -1738,6 +1747,7 @@ class ContinuousBatchingEngine:
                 phases, self._window)
         self._m["ctx_tokens"] += counts["ctx_tokens"]
         self._m["prompt_pairs"] += counts["prompt_pairs"]
+        self._m["admit_rows"] += counts["admit_rows"]
         self._planned[seq] = counts
         if self._ctx_chunk:
             self._m["ctx_chunks"] += _ctx_chunks(phases, self._ctx_chunk)
@@ -1845,7 +1855,7 @@ class ContinuousBatchingEngine:
                 if phases:
                     A, P = self._variant(phases)
                     counts = _dispatch_counts(phases, bool(self.state_bytes),
-                                              self._ctx_chunk, self._window)
+                                              self._ctx_chunk, self._window, (A, P))
             if phases:
                 with span(_SPAN_DISPATCH, seq=self._m["dispatches"], A=A, P=P,
                           **counts):

@@ -187,46 +187,81 @@ def test_router(case):
 
 
 # ----------------------------------------------------- the expert products
-@pytest.mark.parametrize("live", [None, (True, False, True, True, False, True, True)],
-                         ids=["all-rows", "some-rows-not-live"])
-def test_expert_products_are_a_loop_over_each_rows_chosen_experts(live):
-    """`expert_ffn` against the definition, a NumPy float64 loop over rows
-    and over each row's chosen experts; rows that are not live get nothing,
-    hit no expert and count in no group."""
+def _loop_over_chosen(u, chosen, w, layer_experts, live, first=0):
+    """The definition: a NumPy float64 loop over rows and over each row's
+    chosen experts (those of `layer_experts`, which start at the router's
+    expert `first`); a row that is not live gets nothing and counts in no
+    group. Returns (out (N, d), rows an expert (E,))."""
+    e64 = jax.tree.map(lambda a: np.asarray(a, np.float64), layer_experts)
+    E = e64["w_gate"].shape[0]
+    want, count = np.zeros(u.shape, np.float64), np.zeros(E, int)
+    for n in range(u.shape[0]):
+        for e, w_e in zip(chosen[n] - first, w[n]):
+            if 0 <= e < E and (live is None or live[n]):
+                g, up = u[n] @ e64["w_gate"][e], u[n] @ e64["w_up"][e]
+                want[n] += w_e * ((g / (1.0 + np.exp(-g)) * up) @ e64["w_down"][e])
+                count[e] += 1
+    return want, count
+
+
+def _pairs(case, cfg, rng):
+    """(chosen (N, top_k), live (N,) or None, chunk) of a case of
+    `test_expert_products_...`: what the sorted pairs look like beside the
+    chunks' edges. None for the chunk is `expert_ffn`'s own (all of these
+    are fewer pairs than that: the straight-line path)."""
+    E, k = cfg.n_experts, cfg.top_k
+    any_k = lambda n: np.stack([rng.permutation(E)[:k] for _ in range(n)])  # noqa: E731
+    if case in ("all-rows", "some-rows-not-live"):  # 7 rows, 28 pairs, at once
+        return any_k(7), (None if case == "all-rows" else np.array([1, 0, 1, 1, 0, 1, 1], bool)), None
+    if case == "groups-cut-by-chunk-edges":  # 4 groups of 23 pairs, edges at 16, 32, ...
+        return np.tile(np.array([2, 5, 6, 11]), (23, 1)), None, 16
+    if case == "most-groups-empty":  # and the chunk no divisor of anything
+        return np.stack([rng.permutation([3, 4, 9, 15, 0])[:k] for _ in range(19)]), None, 7
+    if case == "no-pair-in-a-group":  # no row live: the loop runs no pass
+        return any_k(9), np.zeros(9, bool), 8
+    if case == "pairs-fill-whole-chunks":  # 8 live rows of 13: 32 pairs, two chunks of 16
+        return any_k(13), np.arange(13) % 13 < 8, 16
+    if case == "live-among-padded":  # an admission: each row's tail is padding
+        return any_k(24), (np.arange(24) % 8) < np.repeat([5, 0, 8], 8), 16
+    if case == "one-chunk-past-all-pairs":  # the last chunk reaches past N * top_k
+        return any_k(11), None, 32
+    raise KeyError(case)
+
+
+@pytest.mark.parametrize("case", [
+    "all-rows", "some-rows-not-live", "groups-cut-by-chunk-edges", "most-groups-empty",
+    "no-pair-in-a-group", "pairs-fill-whole-chunks", "live-among-padded",
+    "one-chunk-past-all-pairs"])
+def test_expert_products_are_a_loop_over_each_rows_chosen_experts(case):
+    """`expert_ffn` against the definition (`_loop_over_chosen`); rows that
+    are not live get nothing, hit no expert and count in no group. With more
+    pairs than a chunk the sorted pairs go through a chunk at a time, as far
+    as the pairs in a group reach: the same sums as all pairs at once, with
+    groups cut by a chunk's edge, empty groups, no pair at all, pairs that
+    fill whole chunks, padding among the rows."""
     cfg, _, params = _model()
     experts = params[M.MOE]["experts"]  # the stack of all three layers'; the second is meant
     rng = np.random.default_rng(5)
-    u = rng.normal(size=(7, cfg.d_model)).astype(np.float32)
-    chosen = np.stack([rng.permutation(cfg.n_experts)[:cfg.top_k] for _ in range(7)]).astype(np.int32)
-    w = rng.uniform(0.1, 1.0, size=(7, cfg.top_k)).astype(np.float32)
-    mask = None if live is None else jnp.asarray(live)
-    got, sizes = M.expert_ffn(jnp.asarray(u), jnp.asarray(chosen), jnp.asarray(w), experts, 1,
-                              cfg, mask)
-    e64 = jax.tree.map(lambda a: np.asarray(a[1], np.float64), experts)
-    want = np.zeros((7, cfg.d_model))
-    count = np.zeros(cfg.n_experts, int)
-    for n in range(7):
-        if live is not None and not live[n]:
-            continue
-        for e, w_e in zip(chosen[n], w[n]):
-            g, up = u[n] @ e64["w_gate"][e], u[n] @ e64["w_up"][e]
-            want[n] += w_e * ((g / (1.0 + np.exp(-g)) * up) @ e64["w_down"][e])
-            count[e] += 1
-    assert np.abs(np.asarray(got) - want).max() <= 1e-5 * np.abs(want).max()
+    chosen, live, chunk = _pairs(case, cfg, rng)
+    N = chosen.shape[0]
+    u = rng.normal(size=(N, cfg.d_model)).astype(np.float32)
+    w = rng.uniform(0.1, 1.0, size=(N, cfg.top_k)).astype(np.float32)
+    args = (jnp.asarray(u), jnp.asarray(chosen.astype(np.int32)), jnp.asarray(w), experts, 1, cfg,
+            None if live is None else jnp.asarray(live))
+    at_once, sizes = M.expert_ffn(*args)
+    want, count = _loop_over_chosen(u, chosen, w, jax.tree.map(lambda a: a[1], experts), live)
+    scale = max(np.abs(want).max(), 1e-30)
+    assert np.abs(np.asarray(at_once) - want).max() <= 1e-5 * scale
     np.testing.assert_array_equal(np.asarray(sizes), count)
-
-
-def test_the_expert_layer_in_pieces_is_the_expert_layer(monkeypatch):
-    """Rows past MOE_ROWS go through in pieces (padding rows included and
-    dropped): the same rows' outputs, whatever the split."""
-    cfg, _, params = _model()
-    own = {k: v for k, v in params[M.MOE].items() if k != "experts"}
-    p = {**jax.tree.map(lambda a: a[1], own), "experts": params[M.MOE]["experts"], "at": 1}
-    m = jnp.asarray(np.random.default_rng(6).normal(size=(21, cfg.d_model)), jnp.float32)
-    whole = M.moe_ffn_in_pieces(m, p, cfg)
-    monkeypatch.setattr(M, "MOE_ROWS", 8)
-    pieces = M.moe_ffn_in_pieces(m, p, cfg)
-    assert np.abs(np.asarray(pieces) - np.asarray(whole)).max() <= 1e-5 * np.abs(whole).max()
+    if chunk is not None:
+        assert N * cfg.top_k > chunk
+        got, sizes = jax.jit(lambda *a: M.expert_ffn(*a, args[-1], chunk=chunk),
+                             static_argnums=(5,))(*args[:-1])
+        assert np.abs(np.asarray(got) - want).max() <= 1e-5 * scale
+        assert np.abs(np.asarray(got) - np.asarray(at_once)).max() <= 2e-6 * scale
+        np.testing.assert_array_equal(np.asarray(sizes), count)
+        if not count.sum():
+            assert not np.asarray(got).any()
 
 
 # --------------------------------------------------------- the window mask
@@ -340,6 +375,39 @@ def test_admission_then_decode_matches_the_reference_at_every_position(dtype):
     worst, firsts_agree = _through_the_cache(cfg, key, params, dtype=dtype)
     assert worst <= 1.0
     assert firsts_agree or dtype != jnp.float32
+
+
+@pytest.mark.parametrize("chunk", [None, 16], ids=["pairs-at-once", "pairs-in-chunks-of-16"])
+def test_a_padded_admission_is_each_prompt_admitted_alone(chunk, monkeypatch):
+    """Right-padded prompts of unequal length and a row of length 0 in one
+    (4, 32) admission, whose padded rows choose no expert: each lane's first
+    token, its pool rows at every real position, the slots its rings hold
+    and the next step's logits are what the prompt gives admitted alone in
+    a bucket of its own length (in whole blocks); with the pairs at once
+    (the tiny admission has fewer than `expert_ffn`'s chunk) and in chunks."""
+    cfg, _, params = _model()
+    if chunk is not None:
+        monkeypatch.setattr(M, "expert_ffn", functools.partial(M.expert_ffn, chunk=chunk))
+    halves = (jax.jit(functools.partial(D.admit_slots_paged, cfg=cfg, sampled=False)),
+              jax.jit(functools.partial(D.decode_step_slots_paged, cfg=cfg, sampled=False)))
+    prompts = [_tokens(1, 19, seed=3)[0], _tokens(1, 5, seed=4)[0], _tokens(1, 32, seed=5)[0]]
+    together = Lanes(cfg, params, n=3, halves=halves)
+    first = together.admit([(0, prompts[0]), (2, prompts[2]), (1, prompts[1])], bucket=32, width=4)
+
+    def rows_of(lanes, b, n):
+        pool = [np.asarray(lanes.cache[name][:, 1 + b * lanes.mb:1 + (b + 1) * lanes.mb]).reshape(
+            lanes.cache[name].shape[0], -1, lanes.cache[name].shape[-1])[:, :n] for name in ("k", "v")]
+        held = np.asarray(D.ring_slots_held(jnp.asarray([n - 1]), cfg.sliding_window))[0]
+        return pool + [np.asarray(lanes.cache[name][:, b])[:, held] for name in ("wk", "wv")]
+
+    admitted = [rows_of(together, b, len(p)) for b, p in enumerate(prompts)]
+    logits, _ = together.step()
+    for b, (i, p) in enumerate(zip((0, 2, 1), prompts)):
+        alone = Lanes(cfg, params, n=3, halves=halves)
+        assert alone.admit([(b, p)], bucket=-(-len(p) // BLOCK) * BLOCK)[0] == first[i]
+        for got, want in zip(admitted[b], rows_of(alone, b, len(p))):
+            assert np.abs(got - want).max() <= F32_RTOL * np.abs(want).max()
+        _close(logits[b], alone.step()[0][b], jnp.float32)
 
 
 @pytest.mark.parametrize("name", ["ring-read-one-slot-off", "decode-mask-shows-every-slot",

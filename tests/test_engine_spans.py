@@ -266,7 +266,11 @@ def test_finish_wait_steps_on_a_hand_built_plan():
         # a attends 10, 11; b 13..24; c 21..26: what the decode steps read
         "ctx_tokens": 21 + 222 + 141,
         # n (n + 1) / 2 a prompt, and c's 12 new tokens over its 8 reused
-        "prompt_pairs": 45 + 78 + (96 + 78) + 15}
+        "prompt_pairs": 45 + 78 + (96 + 78) + 15,
+        # no compiled variant given: no rows
+        "admit_rows": 0}
+    # three phases admit, each A x P rows of the dispatch's one variant
+    assert llm_engine._dispatch_counts(phases, variant=(2, 16)) == {**counts, "admit_rows": 96}
     # a plan that finishes nobody waits for nothing
     assert llm_engine._dispatch_counts([{"steps": 4, "admissions": [], "takes": [(1, b, 4)]}])[
         "finish_wait_steps"] == 0
@@ -285,9 +289,13 @@ def _drive(eng, prompts_and_answers):
     seen = []
     while eng._waiting or any(r is not None for r in eng._slots):
         phases = eng._plan()
-        seen.append(([(ph["steps"], [(r, t) for _, r, t in ph["takes"]]) for ph in phases],
-                     llm_engine._dispatch_counts(phases, False, eng._ctx_chunk)))
-        eng._dispatch_macro(phases, seen[-1][1])
+        A, P = eng._variant(phases)
+        counts = llm_engine._dispatch_counts(phases, False, eng._ctx_chunk, variant=(A, P))
+        # the admissions' rows with their padding: A x P a phase that admits
+        assert counts["admit_rows"] == A * P * sum(1 for ph in phases if ph["admissions"])
+        assert counts["prompt_tokens"] <= counts["admit_rows"]
+        seen.append(([(ph["steps"], [(r, t) for _, r, t in ph["takes"]]) for ph in phases], counts))
+        eng._dispatch_macro(phases, counts)
     while eng._pending:
         eng._resolve(eng._pending.popleft())
     assert all(r.done.is_set() and r.error is None for r in reqs)
@@ -339,6 +347,9 @@ def test_ctx_chunks_follow_the_planned_contexts():
     m2 = eng.metrics()
     assert m2["ctx_chunks"] - m["ctx_chunks"] == want
     assert m2["span_chunks"] - m["span_chunks"] == 8 * steps
+    # four prompts in buckets of 256, 32, 512 and 32 positions at the least
+    assert (m2["admit_rows"] - m["admit_rows"] == sum(c["admit_rows"] for _, c in seen)
+            >= 256 + 32 + 512 + 32 > 0)
 
 
 # ---------------------------------------------- spans in a real trace (c)
@@ -400,12 +411,12 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
     dispatches = [stats for name, _, _, stats in top if name == "engine.dispatch"]
     keys = {"seq", "phases", "steps", "admissions", "A", "P", "prompt_tokens", "prefix_tokens",
             "lane_steps", "finishing", "finish_wait_steps", "ctx_chunks", "ctx_tokens",
-            "prompt_pairs"}
+            "prompt_pairs", "admit_rows"}
     assert all(set(d) == keys for d in dispatches)
     diff = {k: m1[k] - m0[k] for k in ("dispatches", "slot_steps", "useful_slot_steps",
                                        "prefill_tokens", "reused_prefix_tokens",
                                        "requests_completed", "ctx_chunks", "span_chunks",
-                                       "ctx_tokens", "prompt_pairs")}
+                                       "ctx_tokens", "prompt_pairs", "admit_rows")}
     assert len(dispatches) == diff["dispatches"] >= 2
     assert [d["seq"] for d in dispatches] == list(
         range(m0["dispatches"], m0["dispatches"] + len(dispatches)))
@@ -422,6 +433,13 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
     assert sum(d["ctx_tokens"] for d in dispatches) == diff["ctx_tokens"] > diff["useful_slot_steps"]
     assert sum(d["prompt_pairs"] for d in dispatches) == diff["prompt_pairs"] > diff["prefill_tokens"]
     assert all(d["P"] in (16, 32) and d["A"] in (1, 2) for d in dispatches)
+    # the admissions' rows, padding included: whole (A, P) admissions, one a
+    # phase at the most, none where the plan admits nobody (PR 40)
+    assert sum(d["admit_rows"] for d in dispatches) == diff["admit_rows"]
+    for d in dispatches:
+        n, rest = divmod(d["admit_rows"], d["A"] * d["P"])
+        assert rest == 0 and n <= d["phases"] and (n > 0) == (d["admissions"] > 0)
+        assert d["prompt_tokens"] <= d["admit_rows"]
     # each resolve repeats its dispatch's plan counts: an execution whose
     # dispatch lies before a trace's start is still counted (PR 39)
     resolved = [stats for name, _, _, stats in top if name == "engine.resolve"]

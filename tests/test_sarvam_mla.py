@@ -172,12 +172,15 @@ def _expert_layer_params(cfg, key, at=0):
     return {**jax.tree.map(lambda a: a[at], own), "experts": moe["experts"], "at": at}
 
 
+@pytest.mark.parametrize("chunk", [None, 8, 5], ids=["at-once", "chunks-of-8", "chunks-of-5"])
 @pytest.mark.parametrize("live", [None, (True, False, True, True, False, True, True)],
                          ids=["all-rows", "some-rows-not-live"])
-def test_held_expert_products_are_a_loop_over_the_held_chosen_experts(live):
+def test_held_expert_products_are_a_loop_over_the_held_chosen_experts(live, chunk):
     """`expert_ffn` with a held range against the definition, a NumPy float64
     loop over rows and each row's chosen experts that are HELD: a pair whose
-    expert is elsewhere adds nothing and counts in no group."""
+    expert is elsewhere adds nothing and counts in no group. In chunks of
+    the sorted pairs (28 pairs, about a quarter of them held: the loop ends
+    where the held pairs do) the sums are the same."""
     cfg, _, params = _model()
     first, count = cfg.held_experts
     experts = params[M.MOE]["experts"]
@@ -187,7 +190,7 @@ def test_held_expert_products_are_a_loop_over_the_held_chosen_experts(live):
     w = rng.uniform(0.1, 1.0, size=(7, cfg.top_k)).astype(np.float32)
     mask = None if live is None else jnp.asarray(live)
     got, sizes = afmoe.expert_ffn(jnp.asarray(u), jnp.asarray(chosen), jnp.asarray(w), experts, 1,
-                                  cfg, mask)
+                                  cfg, mask, **({} if chunk is None else {"chunk": chunk}))
     e64 = jax.tree.map(lambda a: np.asarray(a[1], np.float64), experts)
     want, rows = np.zeros((7, cfg.d_model)), np.zeros(count, int)
     for n in range(7):
@@ -201,12 +204,16 @@ def test_held_expert_products_are_a_loop_over_the_held_chosen_experts(live):
     np.testing.assert_array_equal(np.asarray(sizes), rows)
 
 
-def test_the_four_shares_add_up_to_the_whole_layer():
+@pytest.mark.parametrize("chunk", [None, 16], ids=["at-once", "chunks-of-16"])
+def test_the_four_shares_add_up_to_the_whole_layer(chunk, monkeypatch):
     """Four programs that each hold a quarter of a layer's experts: their
     routed parts plus the shared expert counted once are the uncut
     reference's whole expert layer (router weights normalised over all the
     chosen, held or not; an expert's matrices keyed by its index among the
-    router's experts)."""
+    router's experts). 23 rows are 92 pairs: in chunks of 16 each share's
+    loop passes over its own quarter of them."""
+    if chunk is not None:
+        monkeypatch.setattr(afmoe, "expert_ffn", functools.partial(afmoe.expert_ffn, chunk=chunk))
     key = W.seed_key(SEED)
     whole = M.SarvamMlaConfig.tiny(dtype=jnp.float32, held_first=0, held_count=16)
     m = jnp.asarray(np.random.default_rng(7).normal(size=(23, whole.d_model)), jnp.float32)
@@ -249,8 +256,10 @@ class Lanes:
                          stop_ids=jnp.full((n, 1), -1, jnp.int32))
         self._admit, self._step = halves or _jitted_halves(cfg)
 
-    def admit(self, rows, bucket, new=8):
-        A = len(rows)
+    def admit(self, rows, bucket, new=8, width=None):
+        """rows: [(lane, prompt)]; the admission is `width` rows wide (the
+        rest padding rows of length 0) and `bucket` positions long."""
+        A = width or len(rows)
         prompts = np.zeros((A, bucket), np.int32)
         lengths, slots = np.zeros(A, np.int32), np.zeros(A, np.int32)
         for i, (lane, p) in enumerate(rows):
@@ -304,6 +313,37 @@ def test_admission_then_decode_matches_the_reference_at_every_position(dtype):
     assert "k" not in cache and "v" not in cache
     assert cache["latent"].shape == (cfg.n_layers, 9, BLOCK, D.pool_row(cfg))
     assert D.state_bytes_per_lane(cfg) == 0 and D.LATENT_POOL
+
+
+@pytest.mark.parametrize("chunk", [None, 16], ids=["pairs-at-once", "pairs-in-chunks-of-16"])
+def test_a_padded_admission_is_each_prompt_admitted_alone(chunk, monkeypatch):
+    """Right-padded prompts of unequal length and a row of length 0 in one
+    (4, 32) admission, whose padded rows choose no expert: each lane's first
+    token, its latent rows at every real position and the next step's logits
+    are what the prompt gives admitted alone in a bucket of its own length
+    (in whole blocks); with the pairs at once (the tiny admission has
+    fewer than `expert_ffn`'s chunk) and in chunks."""
+    cfg, _, params = _model()
+    if chunk is not None:
+        monkeypatch.setattr(afmoe, "expert_ffn", functools.partial(afmoe.expert_ffn, chunk=chunk))
+    halves = (jax.jit(functools.partial(D.admit_slots_paged, cfg=cfg, sampled=False)),
+              jax.jit(functools.partial(D.decode_step_slots_paged, cfg=cfg, sampled=False)))
+    prompts = [_tokens(1, 19, seed=3)[0], _tokens(1, 5, seed=4)[0], _tokens(1, 32, seed=5)[0]]
+    together = Lanes(cfg, params, n=3, halves=halves)
+    first = together.admit([(0, prompts[0]), (2, prompts[2]), (1, prompts[1])], bucket=32, width=4)
+
+    def rows_of(lanes, b, n):
+        pool = lanes.cache["latent"][:, 1 + b * lanes.mb:1 + (b + 1) * lanes.mb]
+        return np.asarray(pool).reshape(pool.shape[0], -1, pool.shape[-1])[:, :n]
+
+    admitted = [rows_of(together, b, len(p)) for b, p in enumerate(prompts)]
+    logits, _ = together.step()
+    for b, (i, p) in enumerate(zip((0, 2, 1), prompts)):
+        alone = Lanes(cfg, params, n=3, halves=halves)
+        assert alone.admit([(b, p)], bucket=-(-len(p) // BLOCK) * BLOCK)[0] == first[i]
+        got, want = admitted[b], rows_of(alone, b, len(p))
+        assert np.abs(got - want).max() <= 1e-4 * np.abs(want).max()
+        assert _worst(logits[b], alone.step()[0][b], jnp.float32) <= 1.0
 
 
 def _no_rope_on_the_shared_key(orig):
@@ -480,7 +520,8 @@ def test_other_models_dispatches_and_refusals_are_what_they_were():
     # every model's dispatch: what it carried, and the two plan-only counts
     assert set(_dispatch_counts([], False, 16)) == {
         "phases", "steps", "admissions", "prompt_tokens", "prefix_tokens", "lane_steps",
-        "finishing", "finish_wait_steps", "ctx_chunks", "ctx_tokens", "prompt_pairs"}
+        "finishing", "finish_wait_steps", "ctx_chunks", "ctx_tokens", "prompt_pairs",
+        "admit_rows"}
     assert set(_dispatch_counts([], True, 16, window=8)) - set(_dispatch_counts([], False, 16)) == {
         "state_lanes", "past_window_lane_steps"}
     with pytest.raises(ValueError, match="recurrent state"):

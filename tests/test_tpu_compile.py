@@ -428,11 +428,33 @@ def test_flash_forward_kernel_with_a_window_compiles(one_chip):
     lowered.compile()
 
 
-def test_afmoe_macro_step_reads_the_expert_stacks_in_place(one_chip, monkeypatch):
+@functools.lru_cache(maxsize=2)
+def _afmoe_macro_step(one_chip, A, P):
     """The AFMoE decoder's paged macro-step at `trinity-mini.serve`'s widths
     (one dense and four expert layers of 128 experts, 8 lanes, a table span
-    of 8192), the dispatch that admits nothing: 8.75 GB of weights, pool and
-    rings go in, and no operation outputs one layer's experts (a
+    of 8192), compiled for the described chip at the (A, P) variant."""
+    from ray_tpu.models import afmoe as M
+    from ray_tpu.models import afmoe_decode as D
+    from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
+
+    cfg = M.AfmoeConfig(layer_types=(M.SLIDING,) * 4 + (M.FULL,), n_dense_layers=1,
+                        max_seq_len=8192)
+    B, bs, K = 8, 16, 8
+    MB = cfg.max_seq_len // bs
+
+    arr, shaped = _shapes_on(one_chip)
+    params = shaped(jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
+    return D.jitted_macro_step_slots_paged(cfg, 8, sampled=False).lower(
+        params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
+        arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
+        arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
+        arr((K, B, MAX_STOP_TOKENS))).compile()
+
+
+def test_afmoe_macro_step_reads_the_expert_stacks_in_place(one_chip, monkeypatch):
+    """The dispatch that admits nothing, (1, 16): 8.75 GB of weights, pool
+    and rings go in, and no operation outputs one layer's experts (a
     bf16[128, 2048, 1024] or its transpose, 537 MB). It did, three times a
     layer and decode step, 0.83 GB of temporaries, while `expert_ffn` was
     handed a layer's experts sliced out of the stack: a ragged product is a
@@ -440,24 +462,8 @@ def test_afmoe_macro_step_reads_the_expert_stacks_in_place(one_chip, monkeypatch
     the layer folded into the group axis the temporaries are 0.18 GB."""
     import re
 
-    from ray_tpu.models import afmoe as M
-    from ray_tpu.models import afmoe_decode as D
-    from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
-
     monkeypatch.setattr(FA, "_on_tpu", lambda: True)
-    cfg = M.AfmoeConfig(layer_types=(M.SLIDING,) * 4 + (M.FULL,), n_dense_layers=1,
-                        max_seq_len=8192)
-    B, bs, K, A, P = 8, 16, 8, 1, 16
-    MB = cfg.max_seq_len // bs
-
-    arr, shaped = _shapes_on(one_chip)
-    params = shaped(jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
-    cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
-    compiled = D.jitted_macro_step_slots_paged(cfg, 8, sampled=False).lower(
-        params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
-        arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
-        arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
-        arr((K, B, MAX_STOP_TOKENS))).compile()
+    compiled = _afmoe_macro_step(one_chip, 1, 16)
     m = compiled.memory_analysis()
     assert 8.7e9 < m.argument_size_in_bytes < 8.8e9 and m.alias_size_in_bytes > 0.26e9
     assert m.temp_size_in_bytes < 0.3e9, m.temp_size_in_bytes
@@ -552,3 +558,42 @@ def test_mla_widest_admission_fits_the_chip_and_attends_through_the_kernel(one_c
     # 2 rows x 64 heads a call, values 128 wide: one call in each layer loop
     kernels = re.findall(r"%flash_fwd[.\d]* = \((bf16\[[\d,]+\])[^=]*custom-call\(", compiled.as_text())
     assert kernels == ["bf16[128,4096,128]"] * 2, kernels
+
+
+def _loops_under(text, *scopes):
+    """The `op_name`s of an optimized module's loops that lie under every one
+    of `scopes`."""
+    import re
+
+    names = [re.search(r'op_name="([^"]*)"', ln) for ln in text.splitlines()
+             if " while(" in ln and "condition=" in ln]
+    return [m.group(1) for m in names if m and all(f"/{s}/" in m.group(1) + "/" for s in scopes)]
+
+
+@pytest.mark.parametrize("model", ["afmoe", "mla"])
+def test_an_admissions_expert_layer_moves_the_pairs_in_a_group_and_no_others(
+        one_chip, monkeypatch, model):
+    """(8, 4096), 32,768 rows of which each chooses 8 experts: the sorted
+    pairs go through a chunk at a time as far as the pairs in a group reach
+    (one loop under `admit_prefill/../moe_experts`), so the module holds NO
+    array of numbers 262,144 long and two or more dimensions (the parent
+    held the gathered rows, the products' results and their un-sorted copy,
+    32,768 x d each, for every piece of 4,096 rows; 32,768 x d is now the
+    rows themselves and their float32 sum), and the loop reads the expert
+    stacks where they lie: no operation outputs a layer's experts. The
+    dispatch that admits nothing, (1, 16), 64 pairs a decode step and 128
+    an admission: no loop under `moe_experts` in either half, the
+    straight-line path (PR 40; compiled only)."""
+    import re
+
+    monkeypatch.setattr(FA, "_on_tpu", lambda: True)
+    step = {"afmoe": _afmoe_macro_step, "mla": _mla_macro_step}[model]
+    wide = step(one_chip, 8, 4096).as_text()
+    long_arrays = set(re.findall(r"(?:bf16|f32)\[262144,[\d,]+\]", wide))
+    assert not long_arrays, long_arrays
+    assert len(_loops_under(wide, "admit_prefill", "moe_experts")) >= 1
+    assert not _loops_under(wide, "decode_chunk", "moe_experts")
+    layer = re.compile(r"bf16\[(1,)?(128,(2048,1024|1024,2048)|32,(4096,2048|2048,4096))\]")
+    assert not [(n, s) for n, _, shapes in _outputs_of_own_operations(wide) for s in shapes
+                if layer.fullmatch(s)]
+    assert not _loops_under(step(one_chip, 1, 16).as_text(), "moe_experts")
