@@ -40,10 +40,14 @@ ENGINE_SPANS = (
     "engine.dispatch",  # `_dispatch_macro()`: plan arrays built and the macro-step enqueued; stats: seq, phases,
                         # steps, admissions, A, P, prompt_tokens, lane_steps, finishing, finish_wait_steps, ctx_chunks,
                         # ctx_tokens, prompt_pairs (what the decode steps' and the admissions' attention has to do),
-                        # past_window_lane_steps (a model with sliding-window layers only)
+                        # past_window_lane_steps (a model with sliding-window layers only), admit_rows, admit_phases;
+                        # the lane account (PR 41): vacant_lane_steps, blocked_lane_steps, spent_lane_steps, which with
+                        # lane_steps are n_slots x steps; the wait account over its admissions: plan_wait_us, lane_wait_us,
+                        # admitted_first_plan; the lead and the stall: admit_lead_steps, admit_lead_phases, stall_lane_phases
     "engine.resolve",   # `_resolve()` of dispatch `seq`: the fetch, then delivery of its tokens to the requests; stats:
-                        # seq, the plan counts of its dispatch over again (a trace that starts after a dispatch
-                        # still holds them), and the dispatch's device counters where the decode module names any
+                        # seq, the plan counts of its dispatch over again, the three accounts among them (a trace that
+                        # starts after a dispatch still holds them), and the dispatch's device counters where the decode
+                        # module names any
     "engine.fetch",     # inside resolve: the blocking device-to-host reads of the dispatch's tokens
 )
 

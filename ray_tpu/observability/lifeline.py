@@ -39,9 +39,12 @@ __all__ = ["record", "events", "finish", "store", "set_process_label",
 # how many events each rid keeps
 _MAX_RIDS = 512
 _MAX_EVENTS_PER_RID = 128
-# finished rids linger briefly so late cross-process queries still see
-# them, then age out — the leak audit pins this
-_MAX_FINISHED = 256
+# finished rids linger so late cross-process queries still see them,
+# then age out — the leak audit pins this. Room for what one replica
+# completes in a minute at the rates served (a benchmark window of 40 s
+# asks afterwards for every request it completed, ~280 at the most:
+# at 256 the oldest had aged out); ~1 KB a request of four events
+_MAX_FINISHED = 1024
 
 _proc_label: Optional[str] = None
 

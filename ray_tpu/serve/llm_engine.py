@@ -71,7 +71,12 @@ engine to wrap, so this is the green-field TPU-native equivalent
   `engine.plan`, `engine.dispatch`, `engine.resolve`, and
   `engine.fetch` inside the last), written on the profiler's clock
   beside the device's events; `engine.dispatch` carries the plan's
-  counts (`_dispatch_counts`). What the host costs the device is read
+  counts (`_dispatch_counts`), among them three accounts the plan
+  keeps as it decides: of every lane-step (live, vacant, blocked on the
+  pool, spent on an admission that decodes nothing), of every admitted
+  request's wait (for a plan, then for a lane) and of what its dispatch
+  runs before its own phase and admits while it is live;
+  `engine.resolve` repeats them. What the host costs the device is read
   from those, by the benchmark: `engine.starved_idle_pct` (device idle
   time under any span but `engine.idle`) and `engine.deliver_lag_ms`
   (end of a dispatch's execution to the end of its resolve), per cell
@@ -238,7 +243,7 @@ class _Request:
     __slots__ = ("prompt", "max_new_tokens", "tokens", "done", "error",
                  "exc", "on_done", "sampling", "finish_reason",
                  "_remaining", "_rounds_est", "_rounds_inflight",
-                 "_t_submit", "_t_first", "_t_done",
+                 "_t_submit", "_t_seen", "_t_admit", "_t_first", "_t_done",
                  "_trace_ctx", "_start", "_blocks", "_blocks_freed",
                  "_done_lock", "rid", "_rid_b", "_migrate", "export",
                  "_resume", "_qtok")
@@ -292,6 +297,11 @@ class _Request:
         self._rounds_est = 0
         self._rounds_inflight = 0
         self._t_submit = time.perf_counter()
+        # the wait account: the start of the first plan that found the
+        # request waiting, and of the plan that admitted it (the same
+        # stamp where that was one plan); a resumed migration has neither
+        self._t_seen: Optional[float] = None
+        self._t_admit: Optional[float] = None
         self._t_first: Optional[float] = None
         self._t_done: Optional[float] = None
         # trace context captured on the SUBMITTING thread (the engine
@@ -307,9 +317,27 @@ def _suffix_len(req: "_Request") -> int:
     return len(req.prompt) - req._start
 
 
+# the counts of `_dispatch_counts` that `engine.metrics()` sums as they are
+_PLAN_SUMS = ("ctx_tokens", "prompt_pairs", "admit_rows", "admit_phases",
+              "vacant_lane_steps", "blocked_lane_steps", "spent_lane_steps",
+              "plan_wait_us", "lane_wait_us", "admitted_first_plan",
+              "admit_lead_steps", "admit_lead_phases", "stall_lane_phases")
+
+
+def _wait_us(req: "_Request") -> Tuple[int, int]:
+    """(plan wait, lane wait) of an admitted request, whole microseconds:
+    submit to the start of the first plan that found it waiting, and from
+    there to the start of the plan that admitted it. The second is the
+    whole wait less the first, so the two add up to (admitting plan's
+    start - submit) exactly, and is 0 where one plan did both."""
+    seen = round((req._t_seen - req._t_submit) * 1e6)
+    return seen, round((req._t_admit - req._t_submit) * 1e6) - seen
+
+
 def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
                      ctx_chunk: int = 0, window: int = 0,
-                     variant: Tuple[int, int] = (0, 0)) -> Dict[str, int]:
+                     variant: Tuple[int, int] = (0, 0),
+                     n_slots: int = 0) -> Dict[str, int]:
     """What one macro dispatch carries, from the plan alone: the keyword
     arguments of its `engine.dispatch` span (host integers; nothing is
     read from the device). `_plan` leaves every request at its
@@ -340,12 +368,37 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
     reused prefix): what the attention of each half has to do whatever
     does it, for every model. `admit_rows` is the token rows the admissions
     run, padding included: A x P (`variant`, the compiled program's) for
-    each phase that admits; `prompt_tokens / admit_rows` is the share of
-    them that is a prompt's."""
+    each phase that admits (`admit_phases`); `prompt_tokens / admit_rows`
+    is the share of them that is a prompt's.
+
+    The wait account, summed over the dispatch's admissions (`_wait_us`):
+    `plan_wait_us`, submit to the first plan that found the request
+    waiting (plan granularity), `lane_wait_us`, from there to the plan
+    that admits it (no lane or no block was free), and
+    `admitted_first_plan`, how many one plan both found and admitted.
+    What then runs on the device before an admission's own phase:
+    `admit_lead_steps`, the decode steps of the phases before it, and
+    `admit_lead_phases`, the admitting phases before it, both summed over
+    the admissions. `stall_lane_phases` sums, over the admitting phases,
+    the lanes that are live through one and were admitted before it (in
+    this dispatch or an earlier one): what a request sits through of
+    others' admissions.
+
+    The lane account, for an engine of `n_slots` lanes (no other call
+    carries the three keys): a phase's lanes are live (`lane_steps`),
+    taken by an admission that owes no decode step in it (a one-token
+    answer, a migration's prefill: `spent_lane_steps`), or empty, and the
+    plan says why when it closes the phase: `vacant` lanes found nobody
+    waiting, `blocked` ones a request the pool refused (a phase that says
+    neither counts its empty lanes as vacant). Each times the phase's
+    steps: `lane_steps + vacant_lane_steps + blocked_lane_steps +
+    spent_lane_steps == n_slots * steps` for every dispatch."""
     total = sum(ph["steps"] for ph in phases)
     done = 0  # decode steps of this dispatch run so far
     last: Dict[int, int] = {}  # finishing request -> `done` at its last token
     admissions = prompt_tokens = prefix_tokens = lane_steps = 0
+    plan_wait = lane_wait = first_plan = lead_steps = lead_phases = 0
+    admit_phases = stall = vacant = blocked = spent = 0
     for ph in phases:
         admissions += len(ph["admissions"])
         for _, req in ph["admissions"]:
@@ -353,19 +406,43 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
             prefix_tokens += req._start  # > 0: the admission's prefix loop runs
             if req._remaining == 0:
                 last[id(req)] = done  # the prefill's token, unless it decodes
+            seen_us, lane_us = _wait_us(req)
+            plan_wait += seen_us
+            lane_wait += lane_us
+            first_plan += req._t_seen == req._t_admit
+            lead_steps += done
+            lead_phases += admit_phases
         done += ph["steps"]
+        new = {id(req) for _, req in ph["admissions"]}
+        riding = len(ph["takes"])
+        decoding = 0  # admitted in this phase and decoding in it
         for _, req, take in ph["takes"]:
             lane_steps += take
+            decoding += id(req) in new
             if take and req._remaining == 0:
                 last[id(req)] = done
+        if new:
+            admit_phases += 1
+            stall += riding - decoding
+        idle = len(new) - decoding  # admitted here, no decode step owed here
+        empty = n_slots - riding - idle
+        spent += idle * ph["steps"]
+        blocked += ph.get("blocked", 0) * ph["steps"]
+        vacant += ph.get("vacant", empty - ph.get("blocked", 0)) * ph["steps"]
     counts = {"phases": len(phases), "steps": total, "admissions": admissions,
               "prompt_tokens": prompt_tokens, "prefix_tokens": prefix_tokens,
               "lane_steps": lane_steps,
               "finishing": len(last),
               "finish_wait_steps": sum(total - d for d in last.values()),
               "ctx_tokens": _ctx_tokens(phases), "prompt_pairs": _prompt_pairs(phases),
-              "admit_rows": variant[0] * variant[1] * sum(
-                  1 for ph in phases if ph["admissions"])}
+              "admit_rows": variant[0] * variant[1] * admit_phases,
+              "admit_phases": admit_phases, "plan_wait_us": plan_wait,
+              "lane_wait_us": lane_wait, "admitted_first_plan": first_plan,
+              "admit_lead_steps": lead_steps, "admit_lead_phases": lead_phases,
+              "stall_lane_phases": stall}
+    if n_slots:
+        counts.update(vacant_lane_steps=vacant, blocked_lane_steps=blocked,
+                      spent_lane_steps=spent)
     if recurrent:
         counts["state_lanes"] = lane_steps
     if ctx_chunk:
@@ -648,6 +725,7 @@ class ContinuousBatchingEngine:
         self._next_dev = jnp.zeros(n_slots, jnp.int32)  # device-side feed tokens
         self._queue: "queue.Queue[_Request]" = queue.Queue()
         self._waiting: deque = deque()       # planner-side FIFO (loop thread only)
+        self._t_plan = 0.0                   # start of the plan at hand (perf_counter)
         self._pending: deque = deque()       # fetch frontier: tagged entries
         self._planned: Dict[int, Dict[str, int]] = {}  # seq -> plan counts, until resolved
         # KV-plane plumbing: inbound migrations (fetched payloads
@@ -718,7 +796,22 @@ class ContinuousBatchingEngine:
                    "ctx_tokens": 0, "prompt_pairs": 0,
                    # token rows the planned admissions run, padding
                    # included (A x P a phase that admits)
-                   "admit_rows": 0,
+                   "admit_rows": 0, "admit_phases": 0,
+                   # the lane account: lane-steps left empty with nobody
+                   # waiting, with a request the pool refused, and taken
+                   # by an admission that owes no decode step; with
+                   # useful_slot_steps they are slot_steps
+                   "vacant_lane_steps": 0, "blocked_lane_steps": 0,
+                   "spent_lane_steps": 0,
+                   # the wait account, summed over admissions: submit to
+                   # the first plan that saw the request, from there to
+                   # the plan that admitted it, how many one plan did
+                   # both; then decode steps and admitting phases of its
+                   # dispatch before its own phase, and the admitting
+                   # phases live lanes sat through
+                   "plan_wait_us": 0, "lane_wait_us": 0,
+                   "admitted_first_plan": 0, "admit_lead_steps": 0,
+                   "admit_lead_phases": 0, "stall_lane_phases": 0,
                    # planned live lane-steps whose context passes the
                    # model's sliding window (0 for a model without one)
                    "past_window_lane_steps": 0,
@@ -1508,6 +1601,47 @@ class ContinuousBatchingEngine:
         self._free_request_blocks(req)
         self._wake.set()
 
+    def _plan_start(self) -> None:
+        """One clock read a plan: its start is the admission stamp of the
+        requests it admits and the first-seen stamp of those the queue has
+        brought since the last plan, the unstamped tail of `_waiting` (the
+        wait account of `_dispatch_counts`)."""
+        self._t_plan = now = time.perf_counter()
+        for req in reversed(self._waiting):  # arrivals are at the tail
+            if req._t_seen is not None:
+                break
+            req._t_seen = now
+
+    def _admit_waiting(self) -> Tuple[List[Tuple[int, _Request]], Dict[str, int]]:
+        """Open a phase: admit from the head of `_waiting` into the free
+        lanes, FIFO, until lanes or blocks run out. Returns the admissions
+        and the lane account of the lanes left empty: `vacant` where nobody
+        was waiting, `blocked` where the head of the queue was refused by
+        the pool (it stays queued, FIFO order kept)."""
+        admissions = []
+        free = [i for i, r in enumerate(self._slots) if r is None]
+        while free and self._waiting:
+            req = self._waiting[0]
+            if not self._try_admit_paged(req):
+                break
+            self._waiting.popleft()
+            slot = free.pop(0)
+            req._t_admit = self._t_plan
+            # migrating requests are prefill-only: zero decode steps
+            # owed here, so the slot frees this very phase and the
+            # device lane goes inactive right after its admission
+            # prefill (rems row 0 in _dispatch_macro)
+            req._remaining = 0 if req._migrate else req.max_new_tokens - 1
+            if self.draft_params is not None:
+                req._rounds_est = self._rounds_for(req._remaining) \
+                    if req._remaining > 0 else 0
+                req._rounds_inflight = 0
+            self._slots[slot] = req
+            admissions.append((slot, req))
+        idle, refused = len(free), bool(self._waiting)
+        return admissions, {"vacant": 0 if refused else idle,
+                            "blocked": idle if refused else 0}
+
     def _plan(self) -> Optional[List[Dict[str, Any]]]:
         """Plan up to macro_phases phases of admissions + adaptive decode
         chunks purely from host counters. Greedy requests make this
@@ -1516,26 +1650,13 @@ class ContinuousBatchingEngine:
         bookkeeping to the post-macro-step state: slot assignments,
         per-request remaining counters, evictions, block
         allocations/frees."""
+        self._plan_start()
         if self.draft_params is not None:
             return self._plan_spec()
         self._admit_resumes()
         phases = []
         while len(phases) < self.macro_phases:
-            admissions = []
-            free = [i for i, r in enumerate(self._slots) if r is None]
-            while free and self._waiting:
-                req = self._waiting[0]
-                if not self._try_admit_paged(req):
-                    break  # pool exhausted: stays queued, FIFO order kept
-                self._waiting.popleft()
-                slot = free.pop(0)
-                # migrating requests are prefill-only: zero decode steps
-                # owed here, so the slot frees this very phase and the
-                # device lane goes inactive right after its admission
-                # prefill (rems row 0 in _dispatch_macro)
-                req._remaining = 0 if req._migrate else req.max_new_tokens - 1
-                self._slots[slot] = req
-                admissions.append((slot, req))
+            admissions, empty = self._admit_waiting()
             live = [(s, r) for s, r in enumerate(self._slots)
                     if r is not None and r._remaining > 0]
             if not live and not admissions:
@@ -1560,7 +1681,7 @@ class ContinuousBatchingEngine:
                         # the _deliver stop/cancel paths free them
                         self._free_request_blocks(r)
             phases.append({"steps": steps, "admissions": admissions,
-                           "takes": takes, **snapshot})
+                           "takes": takes, **empty, **snapshot})
         return phases or None
 
     def _rounds_for(self, tokens_owed: int) -> int:
@@ -1585,20 +1706,7 @@ class ContinuousBatchingEngine:
         self._admit_resumes()
         phases = []
         while len(phases) < self.macro_phases:
-            admissions = []
-            free = [i for i, r in enumerate(self._slots) if r is None]
-            while free and self._waiting:
-                req = self._waiting[0]
-                if not self._try_admit_paged(req):
-                    break  # pool exhausted: stays queued, FIFO order kept
-                self._waiting.popleft()
-                slot = free.pop(0)
-                req._remaining = 0 if req._migrate else req.max_new_tokens - 1
-                req._rounds_est = self._rounds_for(req._remaining) \
-                    if req._remaining > 0 else 0
-                req._rounds_inflight = 0
-                self._slots[slot] = req
-                admissions.append((slot, req))
+            admissions, empty = self._admit_waiting()
             live = [(s, r) for s, r in enumerate(self._slots)
                     if r is not None]
             owing = [r._rounds_est for _, r in live if r._rounds_est > 0]
@@ -1620,7 +1728,7 @@ class ContinuousBatchingEngine:
                     r._rounds_inflight += steps
                     takes.append((s, r, steps))
             phases.append({"steps": steps, "admissions": admissions,
-                           "takes": takes, **snapshot})
+                           "takes": takes, **empty, **snapshot})
         return phases or None
 
     def _bucket_paged(self, n: int) -> int:
@@ -1745,9 +1853,8 @@ class ContinuousBatchingEngine:
         if self._window:
             self._m["past_window_lane_steps"] += _past_window_lane_steps(
                 phases, self._window)
-        self._m["ctx_tokens"] += counts["ctx_tokens"]
-        self._m["prompt_pairs"] += counts["prompt_pairs"]
-        self._m["admit_rows"] += counts["admit_rows"]
+        for key in _PLAN_SUMS:
+            self._m[key] += counts.get(key, 0)
         self._planned[seq] = counts
         if self._ctx_chunk:
             self._m["ctx_chunks"] += _ctx_chunks(phases, self._ctx_chunk)
@@ -1855,7 +1962,8 @@ class ContinuousBatchingEngine:
                 if phases:
                     A, P = self._variant(phases)
                     counts = _dispatch_counts(phases, bool(self.state_bytes),
-                                              self._ctx_chunk, self._window, (A, P))
+                                              self._ctx_chunk, self._window, (A, P),
+                                              self.n_slots)
             if phases:
                 with span(_SPAN_DISPATCH, seq=self._m["dispatches"], A=A, P=P,
                           **counts):

@@ -240,22 +240,33 @@ def test_names_and_scopes_are_metadata_only(name, monkeypatch):
 
 
 # ------------------------------------------------- the plan's counts (d)
-def _req(prompt_len, remaining, start=0, max_new=1):
+def _req(prompt_len, remaining, start=0, max_new=1, submit=0.0, seen=0.0, admit=0.0):
     return types.SimpleNamespace(prompt=[0] * prompt_len, _start=start, _remaining=remaining,
-                                 max_new_tokens=max_new)
+                                 max_new_tokens=max_new, _t_submit=submit, _t_seen=seen,
+                                 _t_admit=admit)
 
 
 def test_finish_wait_steps_on_a_hand_built_plan():
-    """Two lanes, chunk 4, four phases; `_remaining` is the state `_plan`
-    leaves behind (0 = the request's last token is in this dispatch)."""
-    a, b, c, d = (_req(9, 0, max_new=3), _req(12, 7, max_new=20),
-                  _req(20, 0, start=8, max_new=7), _req(5, 0))
+    """Three lanes (the third never taken), chunk 4, four phases;
+    `_remaining` is the state `_plan` leaves behind (0 = the request's last
+    token is in this dispatch). The plan began at 10.25 s: a, b and d were
+    first seen by it, c by an earlier one (the stamps are binary fractions,
+    so every difference is exact)."""
+    a, b, c, d = (_req(9, 0, max_new=3, submit=10.0, seen=10.25, admit=10.25),
+                  _req(12, 7, max_new=20, submit=10.0, seen=10.25, admit=10.25),
+                  _req(20, 0, start=8, max_new=7, submit=9.0, seen=9.5, admit=10.25),
+                  _req(5, 0, submit=10.125, seen=10.25, admit=10.25))
     phases = [
-        {"steps": 2, "admissions": [(0, a), (1, b)], "takes": [(0, a, 2), (1, b, 2)]},
-        {"steps": 4, "admissions": [(0, c)], "takes": [(0, c, 4), (1, b, 4)]},
+        # lane 2 empty and nobody waiting
+        {"steps": 2, "admissions": [(0, a), (1, b)], "takes": [(0, a, 2), (1, b, 2)],
+         "vacant": 1, "blocked": 0},
+        # lane 2 empty with a request waiting that the pool refused
+        {"steps": 4, "admissions": [(0, c)], "takes": [(0, c, 4), (1, b, 4)],
+         "vacant": 0, "blocked": 1},
+        # a phase that says neither: its empty lane counts as vacant
         {"steps": 2, "admissions": [], "takes": [(0, c, 2), (1, b, 2)]},
         # d owes one token only: the prefill's own, before this phase's steps
-        {"steps": 4, "admissions": [(0, d)], "takes": [(1, b, 4)]},
+        {"steps": 4, "admissions": [(0, d)], "takes": [(1, b, 4)], "vacant": 0, "blocked": 1},
     ]
     counts = llm_engine._dispatch_counts(phases)
     assert counts == {
@@ -268,9 +279,26 @@ def test_finish_wait_steps_on_a_hand_built_plan():
         # n (n + 1) / 2 a prompt, and c's 12 new tokens over its 8 reused
         "prompt_pairs": 45 + 78 + (96 + 78) + 15,
         # no compiled variant given: no rows
-        "admit_rows": 0}
+        "admit_rows": 0,
+        # phases 0, 1 and 3 admit
+        "admit_phases": 3,
+        # submit to first seen: a and b 0.25 s, c 0.5 s, d 0.125 s; c alone
+        # waited on for a lane, 9.5 to 10.25; the three others in their first plan
+        "plan_wait_us": 250_000 + 250_000 + 500_000 + 125_000, "lane_wait_us": 750_000,
+        "admitted_first_plan": 3,
+        # c behind phase 0's 2 steps and its admission, d behind 8 steps and
+        # two admitting phases (0 and 1; phase 2 admits nobody)
+        "admit_lead_steps": 2 + 8, "admit_lead_phases": 1 + 2,
+        # b is live through c's admission and through d's
+        "stall_lane_phases": 2}
     # three phases admit, each A x P rows of the dispatch's one variant
     assert llm_engine._dispatch_counts(phases, variant=(2, 16)) == {**counts, "admit_rows": 96}
+    # the lane account of three lanes: lane 2 vacant through phases 0 and 2
+    # (2 + 2 steps), blocked through 1 and 3 (4 + 4), lane 0 spent on d (4)
+    lanes = llm_engine._dispatch_counts(phases, n_slots=3)
+    assert lanes == {**counts, "vacant_lane_steps": 4, "blocked_lane_steps": 8,
+                     "spent_lane_steps": 4}
+    assert 20 + 4 + 8 + 4 == 3 * 12
     # a plan that finishes nobody waits for nothing
     assert llm_engine._dispatch_counts([{"steps": 4, "admissions": [], "takes": [(1, b, 4)]}])[
         "finish_wait_steps"] == 0
@@ -290,7 +318,11 @@ def _drive(eng, prompts_and_answers):
     while eng._waiting or any(r is not None for r in eng._slots):
         phases = eng._plan()
         A, P = eng._variant(phases)
-        counts = llm_engine._dispatch_counts(phases, False, eng._ctx_chunk, variant=(A, P))
+        counts = llm_engine._dispatch_counts(phases, False, eng._ctx_chunk, variant=(A, P),
+                                             n_slots=eng.n_slots)
+        # the lane account: every lane-step of the dispatch is live, vacant, blocked or spent
+        assert (counts["lane_steps"] + counts["vacant_lane_steps"] + counts["blocked_lane_steps"]
+                + counts["spent_lane_steps"] == eng.n_slots * counts["steps"])
         # the admissions' rows with their padding: A x P a phase that admits
         assert counts["admit_rows"] == A * P * sum(1 for ph in phases if ph["admissions"])
         assert counts["prompt_tokens"] <= counts["admit_rows"]
@@ -352,6 +384,145 @@ def test_ctx_chunks_follow_the_planned_contexts():
             >= 256 + 32 + 512 + 32 > 0)
 
 
+# ------------------------------------ the lane and the wait accounts (ISSUE 41)
+LANE_SCENARIOS = {
+    # (engine options, [(prompt length, answer)]): what the account has to read
+    # one request and a one-token one on four lanes: lanes empty, nobody waiting
+    "fewer_requests_than_lanes": (dict(n_slots=4), [(9, 12), (11, 1)]),
+    # six equal requests on two lanes: a pair admitted as the pair before it ends
+    "more_requests_than_lanes": (dict(n_slots=2), [(9, 5)] * 6),
+    # four blocks a request and five in the pool: one lane runs, the queue waits on blocks
+    "pool_too_small_for_the_queue": (dict(n_slots=2, n_blocks=6), [(20, 12)] * 3),
+}
+
+
+@pytest.mark.parametrize("scenario", sorted(LANE_SCENARIOS))
+def test_lane_account_on_a_real_engine(scenario):
+    """`lane_steps + vacant + blocked + spent == n_slots x steps` for every
+    dispatch (`_drive` holds each to it), and each scenario reads the cause
+    it was built for; `engine.metrics()` sums what the dispatches carried."""
+    cfg, params = _cfg_params()
+    options, load = LANE_SCENARIOS[scenario]
+    eng = ContinuousBatchingEngine(params, cfg, chunk=4, macro_phases=4, max_len=64,
+                                   block_size=8, prefix_cache=False, **options)
+    eng.shutdown()  # the plans below are made on this thread
+    rng = np.random.default_rng(2)
+    _, seen = _drive(eng, [(rng.integers(0, cfg.vocab_size, n).tolist(), new) for n, new in load])
+    total = {k: sum(c[k] for _, c in seen) for k in (
+        "steps", "lane_steps", "vacant_lane_steps", "blocked_lane_steps", "spent_lane_steps")}
+    m = eng.metrics()
+    assert m["slot_steps"] == eng.n_slots * total["steps"]
+    assert m["useful_slot_steps"] == total["lane_steps"] == sum(new - 1 for _, new in load)
+    for key in ("vacant_lane_steps", "blocked_lane_steps", "spent_lane_steps"):
+        assert m[key] == total[key]
+    vacant, blocked, spent = (total[k] for k in (
+        "vacant_lane_steps", "blocked_lane_steps", "spent_lane_steps"))
+    if scenario == "fewer_requests_than_lanes":
+        # lanes 2 and 3 all along, lane 1 after its phase: and lane 1 in the
+        # phase that admits the one-token request is neither live nor empty
+        assert vacant > 0 == blocked and spent == 4
+        assert vacant == 2 * 11 + (11 - 4)
+    elif scenario == "more_requests_than_lanes":
+        # every phase full: no lane-step carries nothing
+        assert vacant == 0 == blocked and spent == 0
+        assert total["lane_steps"] == eng.n_slots * total["steps"]
+    else:
+        # the second lane is empty while a request waits for blocks, through
+        # the first two requests; through the third nobody waits any more
+        assert blocked == 2 * 11 and vacant == 11 and spent == 0
+
+
+def test_lane_account_holds_in_a_speculative_plan(monkeypatch):
+    """Verify rounds for steps, estimates for counts, lanes never evicted at
+    plan time: every dispatch of the live loop still accounts for each of its
+    lane-steps, and the counters add up to `slot_steps`."""
+    cfg, params = _cfg_params()
+    seen = []
+    counted = llm_engine._dispatch_counts
+    monkeypatch.setattr(llm_engine, "_dispatch_counts",
+                        lambda *a, **kw: seen.append(counted(*a, **kw)) or seen[-1])
+    eng = ContinuousBatchingEngine(params, cfg, n_slots=2, chunk=4, macro_phases=4, max_len=64,
+                                   block_size=8, draft_model="self", num_speculative_tokens=N_SPEC)
+    rng = np.random.default_rng(4)
+    try:
+        reqs = [eng.submit(rng.integers(0, cfg.vocab_size, n).tolist(), new)
+                for n, new in ((9, 6), (12, 1), (7, 9), (10, 4), (8, 3))]
+        assert all(r.done.wait(120) for r in reqs) and all(r.error is None for r in reqs)
+    finally:
+        eng.shutdown()
+    assert seen and sum(c["admissions"] for c in seen) == len(reqs)
+    for c in seen:
+        assert (c["lane_steps"] + c["vacant_lane_steps"] + c["blocked_lane_steps"]
+                + c["spent_lane_steps"] == eng.n_slots * c["steps"])
+    m = eng.metrics()
+    assert m["slot_steps"] == m["useful_slot_steps"] + sum(
+        m[k] for k in ("vacant_lane_steps", "blocked_lane_steps", "spent_lane_steps"))
+    assert m["lane_wait_us"] > 0 == m["blocked_lane_steps"]  # five requests on two lanes
+
+
+def test_wait_account_per_request():
+    """One lane, plans of two phases of four steps. `plan_wait + lane_wait`
+    is the admitting plan's start minus the submit, to the microsecond, and
+    no more than the lifeline's `admit` - `submit` (stamped inside the plan,
+    on the wall clock: a millisecond of room for the two clocks)."""
+    from ray_tpu.observability import lifeline
+
+    cfg, params = _cfg_params()
+    eng = ContinuousBatchingEngine(params, cfg, n_slots=1, chunk=4, macro_phases=2,
+                                   max_len=64, block_size=8, prefix_cache=False)
+    eng.shutdown()  # planned on this thread, at this test's pace
+    rng = np.random.default_rng(3)
+    prompt = lambda n: rng.integers(0, cfg.vocab_size, n).tolist()  # noqa: E731
+    plans = []  # (start, end on the engine's clock, counts) of each plan made
+
+    def plan_and_dispatch():
+        phases = eng._plan()
+        end = time.perf_counter()
+        counts = llm_engine._dispatch_counts(phases, n_slots=eng.n_slots)
+        plans.append((eng._t_plan, end, counts))
+        eng._dispatch_macro(phases, counts)
+
+    first = eng.submit(prompt(9), 10, rid="wait-first")    # the queue empty, the lane free
+    queued = eng.submit(prompt(9), 3, rid="wait-queued")   # behind it: no lane in the first plan
+    time.sleep(0.003)
+    eng._drain_queue()
+    plan_and_dispatch()                                    # admits `first`; sees `queued`
+    late = eng.submit(prompt(9), 2, rid="wait-late")       # arrives inside the dispatch
+    time.sleep(0.003)
+    eng._drain_queue()
+    while eng._waiting or any(r is not None for r in eng._slots):
+        plan_and_dispatch()
+    while eng._pending:
+        eng._resolve(eng._pending.popleft())
+    reqs = (first, queued, late)
+    assert all(r.done.is_set() and r.error is None for r in reqs)
+
+    starts = [start for start, _, _ in plans]
+    # `first` decodes 9 steps, 8 a plan: the lane frees inside the second plan
+    assert first._t_seen == first._t_admit == starts[0]
+    assert queued._t_seen == starts[0] and queued._t_admit == starts[1]
+    assert late._t_seen == starts[1] and late._t_admit in starts[1:]
+    for r in reqs:
+        plan_wait, lane_wait = llm_engine._wait_us(r)
+        assert plan_wait >= 0 and lane_wait >= 0
+        assert plan_wait + lane_wait == round((r._t_admit - r._t_submit) * 1e6)
+        ev = {e["kind"]: e["t"] for e in lifeline.events(r.rid)}
+        plan_s = next(end - start for start, end, _ in plans if start == r._t_admit)
+        waited = (plan_wait + lane_wait) * 1e-6
+        assert waited - 1e-3 <= ev["admit"] - ev["submit"] <= waited + plan_s + 1e-3
+    assert llm_engine._wait_us(first)[0] >= 3000 and llm_engine._wait_us(first)[1] == 0
+    assert llm_engine._wait_us(queued)[1] == round((starts[1] - queued._t_submit) * 1e6) \
+        - llm_engine._wait_us(queued)[0] > 0
+    # the dispatches carry the sums, `engine.metrics()` their total
+    m = eng.metrics()
+    for i, key in enumerate(("plan_wait_us", "lane_wait_us")):
+        assert sum(c[key] for _, _, c in plans) == m[key] == sum(
+            llm_engine._wait_us(r)[i] for r in reqs)
+    assert plans[0][2]["admitted_first_plan"] == 1 == plans[0][2]["admissions"]
+    assert m["admitted_first_plan"] == sum(r._t_seen == r._t_admit for r in reqs) >= 1
+    assert sum(c["admissions"] for _, _, c in plans) == 3
+
+
 # ---------------------------------------------- spans in a real trace (c)
 def _engine_events(trace_dir):
     """{line: [(name, start_ns, end_ns, stats)]} of the host lines that hold
@@ -409,14 +580,17 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
             assert any(n == "engine.resolve" and s <= start and end <= e for n, s, e, _ in top)
 
     dispatches = [stats for name, _, _, stats in top if name == "engine.dispatch"]
+    account = ("admit_phases", "vacant_lane_steps", "blocked_lane_steps", "spent_lane_steps",
+               "plan_wait_us", "lane_wait_us", "admitted_first_plan", "admit_lead_steps",
+               "admit_lead_phases", "stall_lane_phases")  # ISSUE 41
     keys = {"seq", "phases", "steps", "admissions", "A", "P", "prompt_tokens", "prefix_tokens",
             "lane_steps", "finishing", "finish_wait_steps", "ctx_chunks", "ctx_tokens",
-            "prompt_pairs", "admit_rows"}
+            "prompt_pairs", "admit_rows", *account}
     assert all(set(d) == keys for d in dispatches)
     diff = {k: m1[k] - m0[k] for k in ("dispatches", "slot_steps", "useful_slot_steps",
                                        "prefill_tokens", "reused_prefix_tokens",
                                        "requests_completed", "ctx_chunks", "span_chunks",
-                                       "ctx_tokens", "prompt_pairs", "admit_rows")}
+                                       "ctx_tokens", "prompt_pairs", "admit_rows", *account)}
     assert len(dispatches) == diff["dispatches"] >= 2
     assert [d["seq"] for d in dispatches] == list(
         range(m0["dispatches"], m0["dispatches"] + len(dispatches)))
@@ -440,6 +614,19 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
         n, rest = divmod(d["admit_rows"], d["A"] * d["P"])
         assert rest == 0 and n <= d["phases"] and (n > 0) == (d["admissions"] > 0)
         assert d["prompt_tokens"] <= d["admit_rows"]
+    # the lane, wait and lead accounts (ISSUE 41): the spans' sums are the
+    # counters', every dispatch's lane-steps are all accounted for, and the
+    # six requests were each seen by a plan, then admitted by one
+    for key in account:
+        assert sum(d[key] for d in dispatches) == diff[key]
+    for d in dispatches:
+        assert (d["lane_steps"] + d["vacant_lane_steps"] + d["blocked_lane_steps"]
+                + d["spent_lane_steps"] == eng.n_slots * d["steps"])
+        assert d["admit_phases"] <= d["phases"] and d["admitted_first_plan"] <= d["admissions"]
+        assert d["admit_lead_phases"] <= d["admissions"] * d["admit_phases"]
+    assert diff["spent_lane_steps"] > 0 == diff["blocked_lane_steps"]  # the one-token request
+    assert diff["plan_wait_us"] > 0 and diff["lane_wait_us"] > 0  # six requests on two lanes
+    assert diff["stall_lane_phases"] > 0 and diff["admit_lead_steps"] > 0
     # each resolve repeats its dispatch's plan counts: an execution whose
     # dispatch lies before a trace's start is still counted (PR 39)
     resolved = [stats for name, _, _, stats in top if name == "engine.resolve"]

@@ -521,7 +521,13 @@ def test_other_models_dispatches_and_refusals_are_what_they_were():
     assert set(_dispatch_counts([], False, 16)) == {
         "phases", "steps", "admissions", "prompt_tokens", "prefix_tokens", "lane_steps",
         "finishing", "finish_wait_steps", "ctx_chunks", "ctx_tokens", "prompt_pairs",
-        "admit_rows"}
+        "admit_rows",
+        # the wait and lead accounts of every model's dispatch (PR 41); the lane
+        # account's three keys come with an engine's `n_slots`
+        "admit_phases", "plan_wait_us", "lane_wait_us", "admitted_first_plan",
+        "admit_lead_steps", "admit_lead_phases", "stall_lane_phases"}
+    assert set(_dispatch_counts([], False, 16, n_slots=4)) - set(_dispatch_counts([], False, 16)) == {
+        "vacant_lane_steps", "blocked_lane_steps", "spent_lane_steps"}
     assert set(_dispatch_counts([], True, 16, window=8)) - set(_dispatch_counts([], False, 16)) == {
         "state_lanes", "past_window_lane_steps"}
     with pytest.raises(ValueError, match="recurrent state"):
