@@ -1102,6 +1102,52 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     return first, cache, feed
 
 
+def admit_width(n: int, lanes: int) -> int:
+    """Rows the admission of a phase runs for `n` prompts in a program
+    `lanes` admission rows wide: the smallest power of two that holds
+    them, `lanes` at the most, one for a phase that admits nobody. The
+    device picks its branch by this function (`admit_phase`) and the
+    engine counts `admit_rows` by it (`_dispatch_counts`): plain Python
+    on host integers."""
+    return min(1 << max(n - 1, 0).bit_length(), lanes)
+
+
+def admit_phase(admit_rows, has_admit, rows, carry):
+    """A phase's admission at the width of its own prompts. `rows` are the
+    phase's per-row plan arrays, each with a leading A and the prompts'
+    true lengths second (0 = a padding row); `admit_rows(rows, carry) ->
+    (first (w,), carry)` is the admission proper, row-independent, for
+    any leading w. One `lax.cond` a width w = 1, 2, 4, .., A
+    (`admit_width`), of which an admitting phase takes exactly one: the
+    narrowest that reaches its last non-empty row, which for a plan that
+    fills rows 0 .. n - 1 (`_dispatch_macro`) is `admit_width(n, A)`. It
+    runs `admit_rows` on the first w rows under ADMIT_SCOPE; the rows
+    behind them are padding and are not computed. A chain of two-way
+    conds and not one `lax.switch`: under a switch of three or more
+    branches the TPU compiler copies the K/V pool (the hybrid's state)
+    twice a layer in every branch but the widest (compiled only, PR 42);
+    through a cond that either admits or hands its operands on, as the
+    skeleton always had, they stay in place, and the branches share one
+    set of temporaries, the widest's. -> (first (A,), carry)."""
+    lengths = rows[1]
+    A = lengths.shape[0]
+    reach = jnp.max(jnp.where(lengths > 0, jnp.arange(1, A + 1), 0))
+    first = jnp.zeros((A,), jnp.int32)
+    below = -1  # a phase flagged as admitting with no row set runs one row
+    for w in sorted({admit_width(n, A) for n in range(1, A + 1)}):
+
+        def run(carry, w=w):
+            with jax.named_scope(ADMIT_SCOPE):
+                first, carry = admit_rows(tuple(r[:w] for r in rows), carry)
+            return jnp.pad(first, (0, A - w)), carry
+
+        got, carry = jax.lax.cond(
+            has_admit & (below < reach) & (reach <= w), run,
+            lambda carry: (jnp.zeros((A,), jnp.int32), carry), carry)
+        first, below = first + got, w
+    return first, carry
+
+
 def macro_step_slots_paged(params, cache, feed, steps, has_admit, prompts,
                            lengths, starts, slots, rems, seeds, tables, temps,
                            top_ks, top_ps, stop_ids, chunk: int,
@@ -1112,7 +1158,14 @@ def macro_step_slots_paged(params, cache, feed, steps, has_admit, prompts,
     and step skeleton is every model's: `admit` and `decode_step` are
     the model's own admission and one-token step over its own cache
     pytree, with the signatures of admit_slots_paged and
-    decode_step_slots_paged (the defaults, Llama's). Extra
+    decode_step_slots_paged (the defaults, Llama's). A is the widest a
+    phase can admit (the engine passes its lanes' bucket, `_variant`),
+    not the width an admission runs at: each admitting phase runs the
+    model's `admit` on the rows up to its last non-empty one, rounded
+    up to a power of two (`admit_phase`: a plan fills a phase's rows
+    from 0 up, and what lies behind them is not computed), so the
+    program holds one admission body a width 1, 2, 4, .., A and one
+    decode body. Extra
     per-phase arrays (K phases, B slots, A admission lanes, MB table
     width, NS stop width):
       starts   (K, A)        cached-prefix length per admission row
@@ -1136,29 +1189,23 @@ def macro_step_slots_paged(params, cache, feed, steps, has_admit, prompts,
     and an all-greedy plan must not pay the per-step sort/softmax/rng
     pipeline. Returns (toks (K, chunk, B), firsts (K, A), feed,
     cache)."""
-    A = prompts.shape[1]
     admit = admit or admit_slots_paged
     decode_step = decode_step or decode_step_slots_paged
 
     def phase(carry, xs):
-        cache, feed = carry
         (steps_k, admit_k, prompts_k, lengths_k, starts_k, slots_k, rems_k,
          seeds_k, tables_k, temps_k, topk_k, topp_k, stop_k) = xs
 
-        def do_admit(op):
-            c, fd = op
-            with jax.named_scope(ADMIT_SCOPE):
-                return admit(
-                    params, prompts_k, lengths_k, starts_k, slots_k, rems_k,
-                    seeds_k, c, fd, tables_k, temps_k, topk_k, topp_k, stop_k,
-                    cfg, sampled=sampled,
-                )
+        def admit_rows(rows, op):
+            first, c, fd = admit(
+                params, *rows, *op, tables_k, temps_k, topk_k, topp_k, stop_k,
+                cfg, sampled=sampled,
+            )
+            return first, (c, fd)
 
-        def no_admit(op):
-            c, fd = op
-            return jnp.zeros((A,), jnp.int32), c, fd
-
-        first, cache, feed = jax.lax.cond(admit_k, do_admit, no_admit, (cache, feed))
+        first, (cache, feed) = admit_phase(
+            admit_rows, admit_k,
+            (prompts_k, lengths_k, starts_k, slots_k, rems_k, seeds_k), carry)
 
         def step(c, t):
             def run(op):
@@ -1518,57 +1565,54 @@ def macro_step_slots_spec(params, draft_params, cache, draft_cache, feed,
     FLOPs). Admissions prefill BOTH pools: the target admission is the
     stock admit_slots_paged; the draft pool mirrors the same suffix
     through the same block tables, and the slot's tracked previous
-    token is reset. Returns (toks (K, chunk, B, n_spec+1),
+    token is reset; both at the width of the phase's own prompts
+    (`admit_phase`, as in macro_step_slots_paged). Returns
+    (toks (K, chunk, B, n_spec+1),
     counts (K, chunk, B), firsts (K, A), feed, cache, draft_cache) —
     counts[k, t, b] is the number of real tokens in toks[k, t, b] (0
     for skipped phases and inactive lanes); the host's plan-and-repair
     loop reconciles its round ESTIMATES against these observed
     accepted lengths."""
-    A = prompts.shape[1]
     B = feed.shape[0]
     S1 = n_spec + 1
 
     def phase(carry, xs):
-        cache, draft_cache, feed = carry
         (steps_k, admit_k, prompts_k, lengths_k, starts_k, slots_k, rems_k,
          seeds_k, tables_k, temps_k, topk_k, topp_k, stop_k) = xs
 
-        def do_admit(op):
+        def admit_rows(rows, op):
             c, dc, fd = op
-            with jax.named_scope(ADMIT_SCOPE):
-                first, c, fd = admit_slots_paged(
-                    params, prompts_k, lengths_k, starts_k, slots_k, rems_k,
-                    seeds_k, c, fd, tables_k, temps_k, topk_k, topp_k, stop_k,
-                    cfg, sampled=sampled,
-                )
-                if dc is None:
-                    # shared-pool self-drafting: the target admission IS the
-                    # draft admission — no mirror prefill, no bookkeeping
-                    return first, c, None, fd
-                _, dk2, dv2 = _forward_tokens_paged(
-                    draft_params, dc["k"], dc["v"], prompts_k,
-                    tables_k[slots_k], starts_k, lengths_k > 0, draft_cfg,
-                    with_logits=False,
-                )
-                # seed the slot's previous token with the last prompt token
-                # (position pos - 1, whose draft KV the mirror prefill just
-                # wrote — the first round's 2-wide pass rewrites it
-                # idempotently). Plan-padding rows route to index B and the
-                # scatter drops them, so a real admission is never clobbered.
-                last = jnp.take_along_axis(
-                    prompts_k, jnp.maximum(lengths_k - 1, 0)[:, None],
-                    axis=1)[:, 0]
-                prev = dc["prev"].at[
-                    jnp.where(lengths_k > 0, slots_k, B)
-                ].set(last, mode="drop")
-                return first, c, {"k": dk2, "v": dv2, "prev": prev}, fd
+            prompts_w, lengths_w, starts_w, slots_w = rows[:4]
+            first, c, fd = admit_slots_paged(
+                params, *rows, c, fd, tables_k, temps_k, topk_k, topp_k,
+                stop_k, cfg, sampled=sampled,
+            )
+            if dc is None:
+                # shared-pool self-drafting: the target admission IS the
+                # draft admission — no mirror prefill, no bookkeeping
+                return first, (c, None, fd)
+            _, dk2, dv2 = _forward_tokens_paged(
+                draft_params, dc["k"], dc["v"], prompts_w,
+                tables_k[slots_w], starts_w, lengths_w > 0, draft_cfg,
+                with_logits=False,
+            )
+            # seed the slot's previous token with the last prompt token
+            # (position pos - 1, whose draft KV the mirror prefill just
+            # wrote — the first round's 2-wide pass rewrites it
+            # idempotently). Plan-padding rows route to index B and the
+            # scatter drops them, so a real admission is never clobbered.
+            last = jnp.take_along_axis(
+                prompts_w, jnp.maximum(lengths_w - 1, 0)[:, None],
+                axis=1)[:, 0]
+            prev = dc["prev"].at[
+                jnp.where(lengths_w > 0, slots_w, B)
+            ].set(last, mode="drop")
+            return first, (c, {"k": dk2, "v": dv2, "prev": prev}, fd)
 
-        def no_admit(op):
-            c, dc, fd = op
-            return jnp.zeros((A,), jnp.int32), c, dc, fd
-
-        first, cache, draft_cache, feed = jax.lax.cond(
-            admit_k, do_admit, no_admit, (cache, draft_cache, feed))
+        # both pools' admissions at the width of the phase's own prompts
+        first, (cache, draft_cache, feed) = admit_phase(
+            admit_rows, admit_k,
+            (prompts_k, lengths_k, starts_k, slots_k, rems_k, seeds_k), carry)
 
         def step(c, t):
             def run(op):

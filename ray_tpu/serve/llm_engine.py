@@ -54,8 +54,10 @@ engine to wrap, so this is the green-field TPU-native equivalent
   lax.scan over the plan whose phases run a fused admission prefill
   and a decode chunk). Prompts ride along as program arguments, so an
   admission is no dispatch of its own. One program is compiled per
-  (admission lanes, padded prompt width) pair, both powers of two
-  (`_variant`), times greedy / sampled.
+  padded prompt width, a power of two (`_variant`), times greedy /
+  sampled: its admission lanes are the engine's lanes rounded up to a
+  power of two, and each admitting phase runs at the width of its own
+  admissions inside it (`llama_decode.admit_phase`).
 - ADAPTIVE CHUNKS. Each phase decodes exactly to the next scheduling
   event, min(chunk, least steps owed over the live lanes), so a freed
   lane is re-admitted at the very next phase and does not idle to a
@@ -366,10 +368,14 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
     `prompt_pairs` the (query, key) pairs of its admissions' causal
     attention, n (n + 1) / 2 for a prompt of n tokens (and n times its
     reused prefix): what the attention of each half has to do whatever
-    does it, for every model. `admit_rows` is the token rows the admissions
-    run, padding included: A x P (`variant`, the compiled program's) for
-    each phase that admits (`admit_phases`); `prompt_tokens / admit_rows`
-    is the share of them that is a prompt's.
+    does it, for every model. `admit_rows` is the token rows the device
+    runs for the admissions, padding included: for each phase that admits
+    (`admit_phases`) its admissions rounded up to a power of two, A at
+    the most (`llama_decode.admit_width`, the function the device picks
+    its branch by), times P, with (A, P) the compiled program's
+    (`variant`); `prompt_tokens / admit_rows` is the share of them that
+    is a prompt's, and A x P x `admit_phases` what the program would run
+    with every phase at its full width.
 
     The wait account, summed over the dispatch's admissions (`_wait_us`):
     `plan_wait_us`, submit to the first plan that found the request
@@ -393,12 +399,14 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
     neither counts its empty lanes as vacant). Each times the phase's
     steps: `lane_steps + vacant_lane_steps + blocked_lane_steps +
     spent_lane_steps == n_slots * steps` for every dispatch."""
+    from ray_tpu.models.llama_decode import admit_width
+
     total = sum(ph["steps"] for ph in phases)
     done = 0  # decode steps of this dispatch run so far
     last: Dict[int, int] = {}  # finishing request -> `done` at its last token
     admissions = prompt_tokens = prefix_tokens = lane_steps = 0
     plan_wait = lane_wait = first_plan = lead_steps = lead_phases = 0
-    admit_phases = stall = vacant = blocked = spent = 0
+    admit_phases = admit_rows = stall = vacant = blocked = spent = 0
     for ph in phases:
         admissions += len(ph["admissions"])
         for _, req in ph["admissions"]:
@@ -423,6 +431,7 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
                 last[id(req)] = done
         if new:
             admit_phases += 1
+            admit_rows += admit_width(len(ph["admissions"]), variant[0]) * variant[1]
             stall += riding - decoding
         idle = len(new) - decoding  # admitted here, no decode step owed here
         empty = n_slots - riding - idle
@@ -435,7 +444,7 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
               "finishing": len(last),
               "finish_wait_steps": sum(total - d for d in last.values()),
               "ctx_tokens": _ctx_tokens(phases), "prompt_pairs": _prompt_pairs(phases),
-              "admit_rows": variant[0] * variant[1] * admit_phases,
+              "admit_rows": admit_rows,
               "admit_phases": admit_phases, "plan_wait_us": plan_wait,
               "lane_wait_us": lane_wait, "admitted_first_plan": first_plan,
               "admit_lead_steps": lead_steps, "admit_lead_phases": lead_phases,
@@ -628,6 +637,8 @@ class ContinuousBatchingEngine:
         self.params = params
         self.cfg = cfg
         self.n_slots = n_slots
+        # the macro-step's admission lanes: the lanes' power-of-two bucket
+        self._admit_lanes = 1 << (n_slots - 1).bit_length()
         self.max_len = max_len or cfg.max_seq_len
         self.chunk = chunk
         self.macro_phases = macro_phases
@@ -637,6 +648,8 @@ class ContinuousBatchingEngine:
         self.block_size = block_size
         # table width: blocks to cover max_len (per-slot ceiling)
         self._mb = -(-self.max_len // block_size)
+        # P of the last dispatch, for a plan that admits nobody (`_variant`)
+        self._last_P = self._bucket_paged(1)
         # default pool: the K/V budget of slots x max_len stripes (+1 for
         # the reserved null block), shared by however many lanes fit
         self.n_blocks = n_blocks or n_slots * self._mb + 1
@@ -794,8 +807,9 @@ class ContinuousBatchingEngine:
                    # positions the planned decode steps attend, and (query,
                    # key) pairs of the planned admissions' attention
                    "ctx_tokens": 0, "prompt_pairs": 0,
-                   # token rows the planned admissions run, padding
-                   # included (A x P a phase that admits)
+                   # token rows the device runs for the planned admissions,
+                   # padding included (P x a phase's admissions rounded up
+                   # to a power of two), and the phases that admit
                    "admit_rows": 0, "admit_phases": 0,
                    # the lane account: lane-steps left empty with nobody
                    # waiting, with a request the pool refused, and taken
@@ -1741,18 +1755,18 @@ class ContinuousBatchingEngine:
         return min(max(b, self.block_size), self._mb * self.block_size)
 
     def _variant(self, phases: List[Dict[str, Any]]):
-        """(A, P) of the compiled macro-step a plan needs: admission
-        lanes and padded prompt width, both bucketed to powers of two so
-        the jit cache stays small."""
-        max_admit = max((len(p["admissions"]) for p in phases), default=0)
-        A = 1
-        while A < max(1, max_admit):
-            A *= 2
-        P = self._bucket_paged(max(
-            (_suffix_len(r) for p in phases for _, r in p["admissions"]),
-            default=1,
-        ))
-        return A, P
+        """(A, P) of the compiled macro-step a plan runs. P, the padded
+        prompt width, is the plan's longest suffix bucketed to a power of
+        two, and alone names the program: A, its admission lanes, is
+        always the engine's lanes rounded up to a power of two (no phase
+        admits more), and each admitting phase runs at the width of its
+        own admissions inside the program (`llama_decode.admit_phase`).
+        A plan that admits nobody runs the program of the last dispatch:
+        it takes no admission branch, so any P serves it and none is
+        compiled for it."""
+        suffixes = [_suffix_len(r) for p in phases for _, r in p["admissions"]]
+        P = self._bucket_paged(max(suffixes)) if suffixes else self._last_P
+        return self._admit_lanes, P
 
     def _dispatch_macro(self, phases: List[Dict[str, Any]],
                         counts: Dict[str, int]) -> None:
@@ -1768,6 +1782,7 @@ class ContinuousBatchingEngine:
 
         K = self.macro_phases
         A, P = self._variant(phases)
+        self._last_P = P
         B, MB = self.n_slots, self._mb
         seq = self._m["dispatches"]
         steps = np.zeros(K, np.int32)

@@ -1,0 +1,215 @@
+"""An admitting phase runs at the width of its own admissions (ISSUE 42).
+
+The paged macro-step's skeleton (`llama_decode.admit_phase`) hands the model's
+own admission the first w rows of a phase, w the smallest power of two that
+reaches the phase's last non-empty row, where it used to hand it all A. The
+four models' admissions are row-independent, so nothing a real row leaves
+behind may differ: CPU, float32, each model's tiny config.
+
+Tolerance: 1e-4 of the largest entry, the one
+`test_a_padded_admission_is_each_prompt_admitted_alone` uses (a product over w
+rows and one over A rows may sum in another order); integers are equal.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import afmoe, granite_hybrid, llama, llama_decode, sarvam_mla
+from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
+
+RTOL = 1e-4
+A, P, BLOCK, MB, CHUNK = 4, 16, 4, 8, 4
+MODELS = {
+    "llama": (llama, lambda: llama.LlamaConfig.tiny(dtype=jnp.float32, attn_impl="blockwise",
+                                                     remat=False)),
+    "granite_hybrid": (granite_hybrid, lambda: granite_hybrid.GraniteHybridConfig.tiny(
+        dtype=jnp.float32)),
+    "afmoe": (afmoe, lambda: afmoe.AfmoeConfig.tiny(dtype=jnp.float32)),
+    "sarvam_mla": (sarvam_mla, lambda: sarvam_mla.SarvamMlaConfig.tiny(dtype=jnp.float32)),
+}
+# row i of the phase: its prompt's length and the lane it lands in (not its own index)
+LENGTHS, LANES = (13, 5, 16, 9), (2, 0, 3, 1)
+
+
+@functools.lru_cache(maxsize=4)
+def _model(name):
+    module, make = MODELS[name]
+    cfg = make()
+    return cfg, module.init_params(jax.random.PRNGKey(7), cfg), cfg.decode_module
+
+
+def _phase(n, vocab):
+    """The plan arrays of one phase whose first `n` rows are real, as
+    `_dispatch_macro` lays them out: (prompts, lengths, starts, slots, rems, seeds)."""
+    rng = np.random.default_rng(42)
+    prompts = np.zeros((A, P), np.int32)
+    lengths, slots, rems = (np.zeros(A, np.int32) for _ in range(3))
+    for i in range(n):
+        prompts[i, :LENGTHS[i]] = rng.integers(0, vocab, LENGTHS[i])
+        lengths[i], slots[i], rems[i] = LENGTHS[i], LANES[i], 5
+    return tuple(jnp.asarray(x) for x in (
+        prompts, lengths, np.zeros(A, np.int32), slots, rems, np.zeros(A, np.uint32)))
+
+
+def _close(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    if np.issubdtype(want.dtype, np.floating):
+        assert np.abs(got - want).max() <= RTOL * max(np.abs(want).max(), 1e-30)
+    else:
+        assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, A])
+@pytest.mark.parametrize("name", sorted(MODELS))
+def test_a_phase_admitted_at_its_own_width_is_the_full_width_admission(name, n):
+    """n real rows of a phase A = 4 wide, through the macro-step (which runs
+    1, 2, 4 and 4 rows) and through the model's own admission at all four
+    rows: the real rows' first tokens, EVERY leaf of the cache (the pool or
+    latent pool, rings, recurrent state, positions, remaining: a padding row
+    writes nothing in either, so no entry is left out) and the next decode
+    step's logits at the admitted lanes are the same."""
+    cfg, params, D = _model(name)
+    tables = 1 + jnp.arange(A * MB, dtype=jnp.int32).reshape(A, MB)
+    z = jnp.zeros((A,), jnp.int32)
+    plan = (tables, jnp.zeros((A,), jnp.float32), z, jnp.ones((A,), jnp.float32),
+            jnp.full((A, 1), -1, jnp.int32))
+    rows = _phase(n, cfg.vocab_size)
+    fresh = lambda: D.init_paged_cache(cfg, A, A * MB + 1, BLOCK)  # noqa: E731
+
+    admit = jax.jit(functools.partial(D.admit_slots_paged, cfg=cfg, sampled=False))
+    first_full, cache_full, feed_full = admit(params, *rows, fresh(), z, *plan)
+
+    # one phase that admits and decodes nothing, then one that does neither
+    K = 2
+    per_phase = lambda x: jnp.stack([x, jnp.zeros_like(x)])  # noqa: E731
+    _, firsts, feed, cache, *_ = D.jitted_macro_step_slots_paged(cfg, CHUNK, sampled=False)(
+        params, fresh(), z, jnp.zeros((K,), jnp.int32), jnp.asarray([True, False]),
+        *(per_phase(r) for r in rows), *(jnp.stack([p, p]) for p in plan))
+
+    # the rows that ran give what they give at full width (a padding row among
+    # them its garbage, which no host reads); the rows that did not run give 0
+    w = llama_decode.admit_width(n, A)
+    assert np.array_equal(np.asarray(firsts[0, :w]), np.asarray(first_full[:w]))
+    assert not np.asarray(firsts[0, w:]).any() and not np.asarray(firsts[1]).any()
+    assert np.array_equal(np.asarray(feed), np.asarray(feed_full))
+    assert jax.tree.structure(cache) == jax.tree.structure(cache_full)
+    for got, want in zip(jax.tree.leaves(cache), jax.tree.leaves(cache_full)):
+        _close(got, want)
+
+    step = jax.jit(functools.partial(D.decode_step_slots_paged, cfg=cfg, sampled=False))
+    logits, nxt, _ = step(params, cache, feed, *plan)
+    logits_full, nxt_full, _ = step(params, cache_full, feed_full, *plan)
+    lanes = list(LANES[:n])
+    _close(np.asarray(logits)[lanes], np.asarray(logits_full)[lanes])
+    assert np.array_equal(np.asarray(nxt)[lanes], np.asarray(nxt_full)[lanes])
+
+
+def test_the_width_follows_the_last_real_row_not_the_count():
+    """`admit_width` rounds a phase's admissions up to a power of two, the
+    program's A at the most; the device takes the branch that reaches the
+    LAST non-empty row, so a real row behind an empty one (no plan of the
+    engine's makes one) is still admitted."""
+    assert [llama_decode.admit_width(n, 32) for n in (0, 1, 2, 3, 4, 5, 8, 9, 17, 32)] == [
+        1, 1, 2, 4, 4, 8, 8, 16, 32, 32]
+    assert [llama_decode.admit_width(n, 6) for n in (1, 2, 3, 4, 5, 6)] == [1, 2, 4, 4, 6, 6]
+
+    seen = []
+
+    def admit_rows(rows, carry):
+        seen.append(rows[1].shape[0])
+        return rows[1] * 10, carry + rows[1].sum()
+
+    for lengths, want_first, want_carry in (
+            ([7, 0, 0, 0, 0], [70, 0, 0, 0, 0], 7),       # one row
+            ([7, 3, 0, 0, 0], [70, 30, 0, 0, 0], 10),     # two
+            ([0, 0, 5, 0, 0], [0, 0, 50, 0, 0], 5),       # a gap: four rows reach row 2
+            ([1, 1, 1, 1, 1], [10] * 5, 5),               # A itself, not a power of two
+            ([0, 0, 0, 0, 0], [0] * 5, 0)):               # flagged, nothing set: one row
+        lengths = jnp.asarray(lengths, jnp.int32)
+        first, carry = jax.jit(lambda l: llama_decode.admit_phase(
+            admit_rows, jnp.asarray(True), (l * 2, l), jnp.asarray(0)))(lengths)
+        assert np.asarray(first).tolist() == want_first and int(carry) == want_carry
+    assert set(seen) == {1, 2, 4, 5}  # one body a width, each traced in every program
+    first, carry = llama_decode.admit_phase(
+        admit_rows, jnp.asarray(False), (lengths * 2, lengths + 3), jnp.asarray(11))
+    assert not np.asarray(first).any() and int(carry) == 11  # a phase that admits nothing
+
+
+# what the parent commit's `_plan` made of the arrivals below (PR 41, f45e62d): per
+# plan its phases' (steps, [(lane, prompt length)] admitted, [(lane, take)])
+PARENT_PLANS = [
+    [(4, [(0, 9), (1, 17), (2, 12)], [(0, 4), (1, 4), (2, 4)]),
+     (1, [], [(0, 1), (1, 1), (2, 1)]),
+     (1, [(0, 30)], [(0, 1), (1, 1), (2, 1)]),
+     (2, [(0, 5)], [(0, 2), (1, 2)])],
+    [(1, [(0, 21), (2, 8)], [(0, 1), (1, 1), (2, 1)]),
+     (2, [(0, 14), (2, 6)], [(0, 2), (1, 2), (2, 2)]),
+     (2, [], [(0, 2), (2, 2)]),
+     (3, [], [(0, 3)])],
+    [(4, [(0, 11)], [(0, 4)]), (4, [], [(0, 4)]), (4, [], [(0, 4)]), (4, [], [(0, 4)])],
+    [(4, [], [(0, 4)]), (3, [], [(0, 3)])],
+]
+# the programs the parent named for them: (A, P) by each plan's widest phase
+PARENT_VARIANTS = [(4, 32), (2, 32), (1, 16), (1, 16)]
+
+
+def test_the_plan_is_the_parents_for_a_scripted_arrival_sequence():
+    """`_plan` is not this PR's: three lanes, chunk 4, four phases a plan,
+    ten requests arriving in three batches, then a plan with no arrival. The
+    phases, their admissions and their takes are the parent's to the entry;
+    what changed is the program each plan names: A is the lanes' bucket, 4,
+    whatever a phase admits, P alone follows the plan (its longest prompt's
+    bucket), and a plan that admits nobody keeps the P of the dispatch before
+    it, so no program is compiled for it."""
+    cfg = llama.LlamaConfig.tiny(dtype=jnp.float32, attn_impl="blockwise", remat=False)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    eng = ContinuousBatchingEngine(params, cfg, n_slots=3, chunk=4, macro_phases=4, max_len=64,
+                                   block_size=8, prefix_cache=False)
+    eng.shutdown()  # planned on this thread; nothing is dispatched
+    assert eng._variant([]) == (4, 16)  # before any dispatch: the smallest bucket
+    rng = np.random.default_rng(5)
+    batches = [[(9, 6), (17, 12), (12, 7), (30, 2), (5, 3)],
+               [(21, 2), (8, 2), (14, 8), (6, 5)],
+               [(11, 24)],
+               []]
+    plans, variants = [], []
+    for batch in batches:
+        for n, new in batch:
+            eng.submit(rng.integers(0, cfg.vocab_size, n).tolist(), new)
+        eng._drain_queue()
+        phases = eng._plan()
+        variants.append(eng._variant(phases))
+        eng._last_P = variants[-1][1]  # as `_dispatch_macro` leaves it
+        plans.append([(ph["steps"], [(s, len(r.prompt)) for s, r in ph["admissions"]],
+                       [(s, t) for s, _, t in ph["takes"]]) for ph in phases])
+    assert eng._plan() is None
+    assert plans == PARENT_PLANS
+    assert variants == [(4, P) for _, P in PARENT_VARIANTS]
+    # one program a P bucket: the parent named three for these four plans
+    assert len(set(variants)) == 2 < len(set(PARENT_VARIANTS))
+
+
+def test_one_program_a_prompt_bucket():
+    """A live engine of four lanes: bursts of 4, 1, 3 and 2 prompts in the
+    bucket of 32, one of 2 in the bucket of 16, then a request alone that
+    decodes through dispatches that admit nobody. The parent compiled a
+    program for every (A, P) a plan named, five for these and a sixth,
+    (1, 16), for the dispatch that admits nobody; now there is one a bucket."""
+    cfg, params, _ = _model("llama")
+    eng = ContinuousBatchingEngine(params, cfg, n_slots=4, chunk=2, macro_phases=2, max_len=64,
+                                   block_size=8, prefix_cache=False)
+    rng = np.random.default_rng(6)
+    try:
+        for burst, n, new in ((4, 30, 2), (1, 20, 2), (3, 25, 2), (2, 17, 2), (2, 9, 2), (1, 27, 12)):
+            prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for _ in range(burst)]
+            reqs = eng.call_on_loop(lambda: [eng.submit(p, new) for p in prompts], timeout=60.0)
+            assert all(r.done.wait(120) and r.error is None for r in reqs)
+        assert eng._macro_paged_fn._cache_size() == 2
+        m = eng.metrics()
+        # 13 admissions; the burst of three ran four rows, every other its own count
+        assert m["admit_rows"] == (4 + 1 + 4 + 2 + 1) * 32 + 2 * 16
+    finally:
+        eng.shutdown()

@@ -291,8 +291,17 @@ def test_finish_wait_steps_on_a_hand_built_plan():
         "admit_lead_steps": 2 + 8, "admit_lead_phases": 1 + 2,
         # b is live through c's admission and through d's
         "stall_lane_phases": 2}
-    # three phases admit, each A x P rows of the dispatch's one variant
-    assert llm_engine._dispatch_counts(phases, variant=(2, 16)) == {**counts, "admit_rows": 96}
+    # three phases admit, 2, 1 and 1 prompts: each runs at the width of its
+    # own admissions (a power of two), not of the program, so in a program of
+    # (2, 16) they are 2 x 16 + 1 x 16 + 1 x 16 = 64 rows of the 3 x 2 x 16 =
+    # 96 that every phase at full width would be, and 64 as well in one of
+    # (4, 16); a program one lane wide cannot run wider than that
+    assert llm_engine._dispatch_counts(phases, variant=(2, 16)) == {**counts, "admit_rows": 64}
+    assert llm_engine._dispatch_counts(phases, variant=(4, 16))["admit_rows"] == 64
+    assert llm_engine._dispatch_counts(phases, variant=(1, 16))["admit_rows"] == 48
+    # three prompts a phase take four rows, five take eight: 4 x 32 + 8 x 32
+    wide = [{"steps": 0, "admissions": [(i, d) for i in range(n)], "takes": []} for n in (3, 5)]
+    assert llm_engine._dispatch_counts(wide, variant=(8, 32))["admit_rows"] == 384
     # the lane account of three lanes: lane 2 vacant through phases 0 and 2
     # (2 + 2 steps), blocked through 1 and 3 (4 + 4), lane 0 spent on d (4)
     lanes = llm_engine._dispatch_counts(phases, n_slots=3)
@@ -323,9 +332,13 @@ def _drive(eng, prompts_and_answers):
         # the lane account: every lane-step of the dispatch is live, vacant, blocked or spent
         assert (counts["lane_steps"] + counts["vacant_lane_steps"] + counts["blocked_lane_steps"]
                 + counts["spent_lane_steps"] == eng.n_slots * counts["steps"])
-        # the admissions' rows with their padding: A x P a phase that admits
-        assert counts["admit_rows"] == A * P * sum(1 for ph in phases if ph["admissions"])
-        assert counts["prompt_tokens"] <= counts["admit_rows"]
+        # the admissions' rows with their padding, as the device runs them:
+        # a phase's admissions rounded up to a power of two, times P; A is the
+        # lanes' bucket and bounds every phase
+        assert A == 1 << (eng.n_slots - 1).bit_length()
+        admitting = [len(ph["admissions"]) for ph in phases if ph["admissions"]]
+        assert counts["admit_rows"] == P * sum(D.admit_width(n, A) for n in admitting)
+        assert counts["prompt_tokens"] <= counts["admit_rows"] <= A * P * len(admitting)
         seen.append(([(ph["steps"], [(r, t) for _, r, t in ph["takes"]]) for ph in phases], counts))
         eng._dispatch_macro(phases, counts)
     while eng._pending:
@@ -606,14 +619,19 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
     # what the attention of each half has to do, for every model (PR 39)
     assert sum(d["ctx_tokens"] for d in dispatches) == diff["ctx_tokens"] > diff["useful_slot_steps"]
     assert sum(d["prompt_pairs"] for d in dispatches) == diff["prompt_pairs"] > diff["prefill_tokens"]
-    assert all(d["P"] in (16, 32) and d["A"] in (1, 2) for d in dispatches)
-    # the admissions' rows, padding included: whole (A, P) admissions, one a
-    # phase at the most, none where the plan admits nobody (PR 40)
+    # A is the lanes' bucket in every dispatch; P names the program
+    assert all(d["P"] in (16, 32) and d["A"] == 2 for d in dispatches)
+    # the admissions' rows, padding included, as the device runs them: whole
+    # rows of P, one or two a phase that admits (its admissions rounded up to
+    # a power of two), none where the plan admits nobody (PR 40, ISSUE 42)
     assert sum(d["admit_rows"] for d in dispatches) == diff["admit_rows"]
     for d in dispatches:
-        n, rest = divmod(d["admit_rows"], d["A"] * d["P"])
-        assert rest == 0 and n <= d["phases"] and (n > 0) == (d["admissions"] > 0)
-        assert d["prompt_tokens"] <= d["admit_rows"]
+        n, rest = divmod(d["admit_rows"], d["P"])
+        assert rest == 0 and d["admissions"] <= n <= d["A"] * d["admit_phases"]
+        assert d["admit_phases"] <= n and (n > 0) == (d["admissions"] > 0)
+        assert d["prompt_tokens"] <= d["admit_rows"] <= d["A"] * d["P"] * d["admit_phases"]
+    # six requests on two lanes: some phase admitted one prompt alone, and ran one row
+    assert diff["admit_rows"] < sum(d["A"] * d["P"] * d["admit_phases"] for d in dispatches)
     # the lane, wait and lead accounts (ISSUE 41): the spans' sums are the
     # counters', every dispatch's lane-steps are all accounted for, and the
     # six requests were each seen by a plan, then admitted by one

@@ -205,7 +205,9 @@ def _mistral_macro_step(one_chip, A, P):
     """Llama's paged macro-step as the Mistral serve cells run it (16 layers
     at the published widths, 4 lanes, blocks of 16, a table span of 4096, the
     default pool of 1,025 blocks, 8 phases of 8 steps, greedy, cache
-    donated), compiled for the described chip at the (A, P) variant."""
+    donated), compiled for the described chip at the (A, P) program: since
+    PR 42 the engine's A is its lanes' bucket, 4 here, and the program holds
+    an admission body a width 1, 2, 4."""
     from ray_tpu.models import llama
     from ray_tpu.models import llama_decode as D
     from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
@@ -243,6 +245,18 @@ def _outputs_of_own_operations(text):
     return out
 
 
+def _admission_bodies(text):
+    """(admission bodies, decode bodies) of an optimized macro-step: the
+    conditionals of a phase (each either admits at one width or hands its
+    operands on, `llama_decode.admit_phase`) and those of a step of the decode
+    scan inside it, by the name stack of the `lax.cond` that made them."""
+    import re
+
+    conds = re.findall(r' conditional\(.*op_name="jit\(macro_step_slots_paged\)/([\w/]+)"', text)
+    phase, step = "while/body/closed_call/cond", "while/body/closed_call/while/body/closed_call/cond"
+    return conds.count(phase), conds.count(step)
+
+
 def _weight_and_pool_copies(text):
     """What the folded q / k / v products cost, among the operations of an
     optimized module (`_outputs_of_own_operations`): (outputs that are one
@@ -261,12 +275,16 @@ def _weight_and_pool_copies(text):
 
 
 def test_paged_macro_step_reads_projection_weights_in_place(one_chip, monkeypatch):
-    """Mistral's macro-step at the serve cells' size, the dispatch that
-    admits nothing, (1, 16), and the chat cells' widest, (4, 512): the
+    """Mistral's macro-step at the serve cells' size, the chat cells' two
+    programs, (4, 256) and (4, 512), each with its three admission widths
+    (a dispatch that admits nothing runs one of them: PR 42): the
     q / k / v products read the stacked parameters where they lie. No
     operation outputs a layer's whole projection matrix, none copies a
-    stack of weights, none copies the K or V pool, and the temporaries
-    stay under 0.9 GB.
+    stack of weights, none copies the K or V pool in ANY branch (under one
+    `lax.switch` over the widths every branch but the widest copied both
+    pools twice a layer, 1.07 GB of temporaries more: compiled only, PR
+    42), and the temporaries stay under 0.9 GB (0.275 GB at (4, 512), the
+    parent's (4, 512) to 1 %).
 
     Until PR 32 the head reshape sat on the product, the compiler folded it
     into the matmul and fed that from copies: three fusions in every decode
@@ -280,8 +298,9 @@ def test_paged_macro_step_reads_projection_weights_in_place(one_chip, monkeypatc
     from tests.test_paged_kv import _qkv_folded
 
     monkeypatch.setattr(FA, "_on_tpu", lambda: True)
-    for A, P in ((1, 16), (4, 512)):
+    for A, P in ((4, 256), (4, 512)):
         compiled = _mistral_macro_step(one_chip, A, P)
+        assert _admission_bodies(compiled.as_text()) == (3, 1)
         slices, stacks, pools = _weight_and_pool_copies(compiled.as_text())
         assert not slices, f"({A}, {P}): a layer's projection weights are copied: {slices[:4]}"
         assert not stacks, f"({A}, {P}): a stack of weights is copied: {stacks}"
@@ -297,12 +316,15 @@ def test_paged_macro_step_reads_projection_weights_in_place(one_chip, monkeypatc
 
 
 @functools.lru_cache(maxsize=2)
-def _hybrid_macro_step(one_chip, kernel: bool = True):
+def _hybrid_macro_step(one_chip, kernel: bool = True, A: int = 32):
     """The optimized text and the memory analysis of the hybrid decoder's
     paged macro-step at granite-4.0-h-micro's widths and
-    `batch-generate-wide`'s 32 lanes, the dispatch that admits nothing,
-    (1, 16), compiled for the chip; the state update through its Pallas
-    kernel, as the chip runs it, or through plain XLA."""
+    `batch-generate-wide`'s 32 lanes, compiled for the chip with A = 32
+    admission lanes (the engine's, since PR 42: six admission bodies, of 1
+    to 32 rows) of the shortest prompt bucket, 16 (the cell's 256 and 512
+    compile in 70 s each and differ in the rows' length alone); the state
+    update through its Pallas kernel, as the chip runs it, or through plain
+    XLA."""
     from unittest import mock
 
     from ray_tpu.models import granite_hybrid as G
@@ -311,7 +333,7 @@ def _hybrid_macro_step(one_chip, kernel: bool = True):
     from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
 
     cfg = G.GraniteHybridConfig(max_seq_len=4096)
-    B, bs, K, A, P = 32, 16, 8, 1, 16
+    B, bs, K, P = 32, 16, 8, 16
     MB = cfg.max_seq_len // bs
 
     arr, shaped = _shapes_on(one_chip)
@@ -356,9 +378,9 @@ def _state_passes(text):
 
 def test_hybrid_macro_step_keeps_state_and_pool_in_place(one_chip):
     """The hybrid decoder's paged macro-step at granite-4.0-h-micro's widths,
-    32 lanes, the dispatch that admits nothing: 9.9 GB of weights, recurrent
-    state and K/V pool go in, and the program's temporaries stay a few
-    hundred MB. They were 4.1 GB (compiled only, PR 29) while a stack's minor
+    32 lanes, six admission widths of the shortest bucket: 9.9 GB of weights,
+    recurrent state and K/V pool go in, and the program's temporaries stay a
+    few hundred MB. They were 4.1 GB (compiled only, PR 29) while a stack's minor
     axis was no multiple of 128: the one 8,512-column input projection, the
     pool's head size of 64 and the conv tail's 3 taps each took a relayout
     copy of the whole stack in every dispatch, and the tied head a float32
@@ -374,15 +396,19 @@ def test_hybrid_macro_step_keeps_state_and_pool_in_place(one_chip):
     assert m.argument_size_in_bytes > 9.8e9 and m.alias_size_in_bytes > 3.5e9  # cache donated
     # 0.354 GB while the decode step gathered every lane's whole span, 0.150
     # with the chunked decode attention (compiled only, PR 30), 0.133 with
-    # the state update's kernel (PR 36)
-    assert m.temp_size_in_bytes < 0.25e9, m.temp_size_in_bytes
+    # the state update's kernel (PR 36), all at (1, 16); 0.243 at (32, 16),
+    # the widest branch's 512 rows (PR 42: a switch over the widths copied
+    # the 2.45 GB state in every branch but the widest, 3.9 GB at (32, 512))
+    assert m.temp_size_in_bytes < 0.3e9, m.temp_size_in_bytes
+    assert _admission_bodies(text) == (6, 1)
     in_decode, copies = _state_passes(text)
     assert not in_decode, f"a decode step passes over the state outside the kernel: {in_decode}"
     assert not copies, f"the state is copied: {copies}"
     kernels = _state_update_kernels(text)
     assert kernels and all("output_to_operand_aliasing={{1}: (7, {})}" in ln for ln in kernels)
 
-    in_decode, _ = _state_passes(_hybrid_macro_step(one_chip, kernel=False)[0])
+    # the decode step is the same whatever A: one admission lane compiles sooner
+    in_decode, _ = _state_passes(_hybrid_macro_step(one_chip, kernel=False, A=1)[0])
     assert len(in_decode) >= 5, "the detector failed to flag the select over all lanes"
 
 
@@ -392,8 +418,9 @@ def test_hybrid_state_update_kernel_compiles_and_the_layers_stay_rolled(one_chip
     block) compiles for the chip with the stack aliased and nothing beside
     it; and in the macro-step the layer scans stayed rolled: as many kernel
     calls as there are runs of Mamba layers, five (9, 9, 9, 5, 4), not
-    thirty-six. Every (A, P) variant of the macro-step is built in warm-up,
-    so a kernel a layer would be paid thirteen times over in `setup_s`."""
+    thirty-six. Every program of the macro-step is built in warm-up (one a
+    prompt bucket since PR 42, thirteen (A, P) variants before), so a kernel
+    a layer would be paid in every one of them in `setup_s`."""
     from ray_tpu.models import granite_hybrid as G
     from ray_tpu.ops import ssm_update as SU
 
@@ -453,17 +480,20 @@ def _afmoe_macro_step(one_chip, A, P):
 
 
 def test_afmoe_macro_step_reads_the_expert_stacks_in_place(one_chip, monkeypatch):
-    """The dispatch that admits nothing, (1, 16): 8.75 GB of weights, pool
+    """The engine's eight admission lanes at the shortest bucket, (8, 16),
+    four admission bodies: 8.75 GB of weights, pool
     and rings go in, and no operation outputs one layer's experts (a
     bf16[128, 2048, 1024] or its transpose, 537 MB). It did, three times a
     layer and decode step, 0.83 GB of temporaries, while `expert_ffn` was
     handed a layer's experts sliced out of the stack: a ragged product is a
     kernel and no slice fuses into its operand (compiled only, PR 33). With
-    the layer folded into the group axis the temporaries are 0.18 GB."""
+    the layer folded into the group axis the temporaries are 0.18 GB at
+    (1, 16), 0.22 at (8, 16)."""
     import re
 
     monkeypatch.setattr(FA, "_on_tpu", lambda: True)
-    compiled = _afmoe_macro_step(one_chip, 1, 16)
+    compiled = _afmoe_macro_step(one_chip, 8, 16)
+    assert _admission_bodies(compiled.as_text()) == (4, 1)
     m = compiled.memory_analysis()
     assert 8.7e9 < m.argument_size_in_bytes < 8.8e9 and m.alias_size_in_bytes > 0.26e9
     assert m.temp_size_in_bytes < 0.3e9, m.temp_size_in_bytes
@@ -515,17 +545,20 @@ def _mla_macro_step(one_chip, A, P):
 
 
 def test_mla_macro_step_reads_pool_latent_weights_and_experts_in_place(one_chip, monkeypatch):
-    """The dispatch that admits nothing, (1, 16): 9.07 GB of weights and a
+    """The engine's eight admission lanes at the shortest bucket, (8, 16),
+    four admission bodies: 9.07 GB of weights and a
     0.42 GB latent pool go in (the pool donated). No operation outputs a
     layer of the pool or copies the pool (a 576-column row did: two relayout
     copies of the whole pool a dispatch, 0.42 GB of temporaries; the row is
     padded to 640 for that, compiled only, PR 39), none outputs a layer's
     W_uk / W_uv in another layout or the stack of them, none a layer's held
-    experts (bf16[32, 4096, 2048], 537 MB): the temporaries are 0.03 GB."""
+    experts (bf16[32, 4096, 2048], 537 MB): the temporaries are 0.03 GB at
+    (1, 16), 0.07 at (8, 16)."""
     import re
 
     monkeypatch.setattr(FA, "_on_tpu", lambda: True)
-    compiled = _mla_macro_step(one_chip, 1, 16)
+    compiled = _mla_macro_step(one_chip, 8, 16)
+    assert _admission_bodies(compiled.as_text()) == (4, 1)
     m = compiled.memory_analysis()
     assert 9.45e9 < m.argument_size_in_bytes < 9.55e9 and m.alias_size_in_bytes > 0.41e9
     assert m.temp_size_in_bytes < 0.1e9, m.temp_size_in_bytes
@@ -534,18 +567,22 @@ def test_mla_macro_step_reads_pool_latent_weights_and_experts_in_place(one_chip,
     copies = [(n, s) for n, op, shapes in ops for s in shapes
               if pool.fullmatch(s) and (op == "copy" or s.startswith("bf16[1,"))]
     assert not copies, copies
-    assert sum(1 for _, _, shapes in ops if "bf16[5,4097,16,640]" in shapes) <= 2  # the in-place writes
+    # the in-place writes: one in each of the two layer loops of an admission body
+    assert sum(1 for _, _, shapes in ops if "bf16[5,4097,16,640]" in shapes) <= 2 * 4
     moved = re.compile(r"bf16\[(5,|1,)?(64,128,512|64,512,128|512,64,128|128,64,512|32,4096,2048|"
                        r"32,2048,4096)\]")
     assert not [(n, s) for n, _, shapes in ops for s in shapes if moved.fullmatch(s)]
 
 
 def test_mla_widest_admission_fits_the_chip_and_attends_through_the_kernel(one_chip, monkeypatch):
-    """(A, P) = (8, 4096), 32,768 admitted tokens: the admission's attention
-    is the flash kernel (one call in each of the two layer loops), two rows
-    at a time (`sarvam_mla.ATTN_TOKENS`), and arguments + temporaries stay
-    under 13.5 GB of the chip's 16 (12.77 compiled only, PR 39: 9.49 GB of
-    arguments, 3.28 GB of temporaries)."""
+    """(A, P) = (8, 4096), up to 32,768 admitted tokens, the program of the
+    cell's longest bucket with its four admission bodies: each body's
+    attention is the flash kernel (one call in each of its two layer
+    loops), two rows at a time (`sarvam_mla.ATTN_TOKENS`; the one-row body
+    its one), and arguments + temporaries stay under 13.5 GB of the chip's
+    16 (12.77 compiled only, PR 39: 9.49 GB of arguments, 3.28 GB of
+    temporaries; 12.78 with all four bodies, PR 42: they share the widest's
+    temporaries)."""
     monkeypatch.setattr(FA, "_on_tpu", lambda: True)
     compiled = _mla_macro_step(one_chip, 8, 4096)
     m = compiled.memory_analysis()
@@ -556,8 +593,10 @@ def test_mla_widest_admission_fits_the_chip_and_attends_through_the_kernel(one_c
     import re
 
     # 2 rows x 64 heads a call, values 128 wide: one call in each layer loop
+    # of the bodies of 2, 4 and 8 rows, and 1 x 64 heads in the one-row body's
     kernels = re.findall(r"%flash_fwd[.\d]* = \((bf16\[[\d,]+\])[^=]*custom-call\(", compiled.as_text())
-    assert kernels == ["bf16[128,4096,128]"] * 2, kernels
+    assert sorted(kernels) == ["bf16[128,4096,128]"] * 6 + ["bf16[64,4096,128]"] * 2, kernels
+    assert _admission_bodies(compiled.as_text()) == (4, 1)
 
 
 def _loops_under(text, *scopes):
@@ -580,10 +619,12 @@ def test_an_admissions_expert_layer_moves_the_pairs_in_a_group_and_no_others(
     held the gathered rows, the products' results and their un-sorted copy,
     32,768 x d each, for every piece of 4,096 rows; 32,768 x d is now the
     rows themselves and their float32 sum), and the loop reads the expert
-    stacks where they lie: no operation outputs a layer's experts. The
-    dispatch that admits nothing, (1, 16), 64 pairs a decode step and 128
-    an admission: no loop under `moe_experts` in either half, the
-    straight-line path (PR 40; compiled only)."""
+    stacks where they lie: no operation outputs a layer's experts; that in
+    every one of the program's four admission bodies (1, 2, 4 and 8 rows of
+    4096: the narrowest has 32,768 pairs). The shortest bucket, (8, 16), 64
+    pairs a decode step and 1,024 at the most an admission: no loop under
+    `moe_experts` in either half, the straight-line path (PR 40; compiled
+    only)."""
     import re
 
     monkeypatch.setattr(FA, "_on_tpu", lambda: True)
@@ -591,9 +632,33 @@ def test_an_admissions_expert_layer_moves_the_pairs_in_a_group_and_no_others(
     wide = step(one_chip, 8, 4096).as_text()
     long_arrays = set(re.findall(r"(?:bf16|f32)\[262144,[\d,]+\]", wide))
     assert not long_arrays, long_arrays
-    assert len(_loops_under(wide, "admit_prefill", "moe_experts")) >= 1
+    assert len(_loops_under(wide, "admit_prefill", "moe_experts")) >= 4  # one a body at the least
     assert not _loops_under(wide, "decode_chunk", "moe_experts")
     layer = re.compile(r"bf16\[(1,)?(128,(2048,1024|1024,2048)|32,(4096,2048|2048,4096))\]")
     assert not [(n, s) for n, _, shapes in _outputs_of_own_operations(wide) for s in shapes
                 if layer.fullmatch(s)]
-    assert not _loops_under(step(one_chip, 1, 16).as_text(), "moe_experts")
+    assert not _loops_under(step(one_chip, 8, 16).as_text(), "moe_experts")
+
+
+@pytest.mark.parametrize("model,lanes", [("mistral", 4), ("hybrid", 32), ("afmoe", 8), ("mla", 8)])
+def test_macro_step_holds_one_admission_body_a_width_and_one_decode_body(
+        one_chip, monkeypatch, model, lanes):
+    """The program of a prompt bucket, for each model at its cell's lanes (A is
+    the lanes' bucket, `llm_engine._variant`): log2(A) + 1 conditionals a phase,
+    one a width 1, 2, 4, .., A, each of which admits at that width or hands its
+    operands on, and ONE conditional a step of the decode scan; the parent
+    compiled one admission body AND one decode body for every (A, P) a plan
+    could name, thirteen programs for the hybrid's cell where there are two
+    now. That a real engine compiles one program a bucket whatever its phases
+    admit is `tests/test_admit_width.py::test_one_program_a_prompt_bucket`."""
+    monkeypatch.setattr(FA, "_on_tpu", lambda: True)
+    text = {"mistral": lambda: _mistral_macro_step(one_chip, 4, 16).as_text(),
+            "hybrid": lambda: _hybrid_macro_step(one_chip)[0],
+            "afmoe": lambda: _afmoe_macro_step(one_chip, 8, 16).as_text(),
+            "mla": lambda: _mla_macro_step(one_chip, 8, 16).as_text()}[model]()
+    assert _admission_bodies(text) == (lanes.bit_length(), 1)
+    # every body lies under the admission's scope, so a device trace counts all of them
+    import re
+
+    branches = set(re.findall(r"while/body/closed_call/(cond/branch_\d_fun)/admit_prefill/", text))
+    assert branches == {"cond/branch_1_fun"}
