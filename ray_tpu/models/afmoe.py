@@ -15,7 +15,8 @@ after each half):
   out = Wo (concat(heads) * sigmoid(g)).
 - `FFN` of the first `n_dense_layers` layers: SwiGLU of width `d_ff`.
 - `FFN` of every other layer: s = sigmoid(Wr u) in float32, one score an
-  expert; the `top_k` largest of s + b are chosen, b a per-expert bias that
+  expert (`route_scoring` "softmax", another family's: a softmax over all
+  the experts, and no bias); the `top_k` largest of s + b are chosen, b a per-expert bias that
   enters the CHOICE only; w = s[chosen] / (sum s[chosen] + 1e-20) *
   `route_scale`; out = sum_e w_e SwiGLU_e(u) + SwiGLU_shared(u), experts of
   width `moe_d_ff`, the shared one `moe_d_ff * n_shared_experts`. No
@@ -91,6 +92,7 @@ class AfmoeConfig:
     rms_eps: float = 1e-5
     max_seq_len: int = 131072
     dtype: Any = jnp.bfloat16
+    route_scoring = "sigmoid"             # a constant of the family, no field: `route`
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -236,11 +238,17 @@ def swiglu(m, p, cfg: AfmoeConfig):
 
 def route(u, router, bias, cfg: AfmoeConfig):
     """The router, for rows u (N, d): (chosen experts (N, top_k) int32,
-    their weights (N, top_k) float32). Sigmoid scores in float32; the bias
-    moves the choice and never the weight; the chosen scores normalised to
-    sum 1 (`route_norm`) and scaled by `route_scale`."""
-    scores = jax.nn.sigmoid(jnp.einsum("nd,de->ne", u, router, preferred_element_type=F32))
-    _, chosen = jax.lax.top_k(scores + bias, cfg.top_k)
+    their weights (N, top_k) float32). The scores, float32, are by
+    `cfg.route_scoring` a sigmoid of each expert's logit or a softmax over
+    all of them; the bias (None: the router has none) moves the choice and
+    never the weight; the chosen scores normalised to sum 1 (`route_norm`)
+    and scaled by `route_scale`."""
+    logits = jnp.einsum("nd,de->ne", u, router, preferred_element_type=F32)
+    if cfg.route_scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        scores = jax.nn.sigmoid(logits)
+    _, chosen = jax.lax.top_k(scores if bias is None else scores + bias, cfg.top_k)
     w = jnp.take_along_axis(scores, chosen, axis=-1)
     if cfg.route_norm:
         w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
@@ -348,14 +356,20 @@ def _expert_ffn_in_chunks(w, order, sizes, products, d: int, chunk: int):
 def moe_ffn(m, p, cfg: AfmoeConfig, live=None):
     """The expert layer's FFN for rows m (N, d): routed experts plus the
     shared expert; `p` as `run_layers` hands it on (this layer's router,
-    bias and shared expert, every layer's experts and this layer's index
-    among them). Returns (out (N, d), rows an expert (E,) int32)."""
+    its bias where it has one and shared expert, every layer's experts and
+    this layer's index among them). Where the layer has a `shared_gate`
+    (d,), the shared expert's output is multiplied by sigmoid(w_g . m), a
+    number a row. Returns (out (N, d), rows an expert (E,) int32)."""
     with jax.named_scope(SCOPE_ROUTE):
-        chosen, w = route(m, p["router"], p["bias"], cfg)
+        chosen, w = route(m, p["router"], p.get("bias"), cfg)
     with jax.named_scope(SCOPE_EXPERTS):
         out, sizes = expert_ffn(m, chosen, w, p["experts"], p["at"], cfg, live)
     with jax.named_scope(SCOPE_SHARED):
-        out = out + swiglu(m, p["shared"], cfg)
+        shared = swiglu(m, p["shared"], cfg)
+        if "shared_gate" in p:
+            gate = jnp.einsum("nd,d->n", m, p["shared_gate"], preferred_element_type=F32)
+            shared = (shared * jax.nn.sigmoid(gate)[:, None]).astype(cfg.dtype)
+        out = out + shared
     return out, sizes
 
 

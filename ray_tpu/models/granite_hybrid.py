@@ -56,6 +56,31 @@ _MICRO_LAYERS = tuple(
     ATTENTION if i % 10 == 5 else MAMBA for i in range(40))
 
 
+def runs_of(layer_types) -> Tuple[Tuple[str, int, int, int], ...]:
+    """Maximal runs of one kind of layer: (kind, index of the run's first
+    layer among its kind, among all layers, layers in the run)."""
+    out, seen = [], {}
+    for g, kind in enumerate(layer_types):
+        if out and out[-1][0] == kind:
+            out[-1][3] += 1
+        else:
+            out.append([kind, seen.get(kind, 0), g, 1])
+        seen[kind] = seen.get(kind, 0) + 1
+    return tuple(tuple(r) for r in out)
+
+
+def scan_runs(runs, c, body):
+    """`c = body(c, kind, index among its kind, index among all layers)` for
+    every layer in order, each of `runs` (`runs_of`) one rolled `lax.scan`:
+    a program's size follows the number of runs, not of layers."""
+    for kind, k0, g0, n in runs:
+        def step(c, i, kind=kind, k0=k0, g0=g0):
+            return body(c, kind, k0 + i, g0 + i), None
+
+        c, _ = jax.lax.scan(step, c, jnp.arange(n))
+    return c
+
+
 @dataclasses.dataclass(frozen=True)
 class GraniteHybridConfig:
     """The source's fields under this repo's names; the defaults are
@@ -115,16 +140,7 @@ class GraniteHybridConfig:
 
     @property
     def runs(self) -> Tuple[Tuple[str, int, int, int], ...]:
-        """Maximal runs of one kind of layer: (kind, index of the run's
-        first layer among its kind, among all layers, layers in the run)."""
-        out, seen = [], {MAMBA: 0, ATTENTION: 0}
-        for g, kind in enumerate(self.layer_types):
-            if out and out[-1][0] == kind:
-                out[-1][3] += 1
-            else:
-                out.append([kind, seen[kind], g, 1])
-            seen[kind] += 1
-        return tuple(tuple(r) for r in out)
+        return runs_of(self.layer_types)
 
     @property
     def model_module(self):
@@ -250,18 +266,16 @@ def run_layers(params, x, carry, cfg: GraniteHybridConfig, mixers: Dict[str, Cal
     caller: the full forward, admission and the decode step."""
     r = cfg.residual_multiplier
 
-    for kind, k0, g0, n in cfg.runs:
-        def body(c, i, kind=kind, k0=k0, g0=g0):
-            x, carry = c
-            layer = _layer_at(params[kind], k0 + i)
-            ff = _layer_at(params["mlp"], g0 + i)
-            o, carry = mixers[kind](layer, k0 + i, rms_norm(x, layer["norm"], cfg.rms_eps), carry)
-            x = x + (r * o).astype(x.dtype)
-            x = x + (r * mlp(rms_norm(x, ff["norm"], cfg.rms_eps), ff, cfg)).astype(x.dtype)
-            return (x, carry), None
+    def body(c, kind, ki, gi):
+        x, carry = c
+        layer = _layer_at(params[kind], ki)
+        ff = _layer_at(params["mlp"], gi)
+        o, carry = mixers[kind](layer, ki, rms_norm(x, layer["norm"], cfg.rms_eps), carry)
+        x = x + (r * o).astype(x.dtype)
+        x = x + (r * mlp(rms_norm(x, ff["norm"], cfg.rms_eps), ff, cfg)).astype(x.dtype)
+        return x, carry
 
-        (x, carry), _ = jax.lax.scan(body, (x, carry), jnp.arange(n))
-    return x, carry
+    return scan_runs(cfg.runs, (x, carry), body)
 
 
 def embed_tokens(params, tokens, cfg: GraniteHybridConfig):
@@ -297,14 +311,19 @@ def step_sizes(dt, layer):
     return jax.nn.softplus(dt.astype(F32) + layer["dt_bias"])
 
 
+def _conv_bias(layer):
+    return layer["conv_b"].astype(F32) if "conv_b" in layer else 0.0
+
+
 def causal_conv(xBC, layer, lengths):
-    """Depthwise causal conv + SiLU over xBC (B, T, C), zeros before the
-    start, and each row's conv tail: its last K-1 REAL inputs, positions
-    lengths-K+1 .. lengths-1 (zeros where the row is shorter)."""
+    """Depthwise causal conv (`conv_w` (K, C), `conv_b` where the layer has
+    one) + SiLU over xBC (B, T, C), zeros before the start, and each row's
+    conv tail: its last K-1 REAL inputs, positions lengths-K+1 .. lengths-1
+    (zeros where the row is shorter)."""
     K, T = layer["conv_w"].shape[0], xBC.shape[1]
     xp = jnp.pad(xBC, ((0, 0), (K - 1, 0), (0, 0)))
     w = layer["conv_w"].astype(F32)
-    acc = layer["conv_b"].astype(F32)
+    acc = _conv_bias(layer)
     for j in range(K):
         acc = acc + w[j] * xp[:, j:j + T].astype(F32)
     idx = lengths[:, None] + jnp.arange(K - 1)[None, :]  # into the padded rows
@@ -317,7 +336,7 @@ def conv_step(tail, xBC, layer):
     first; rows on the second axis, as the cache keeps them), xBC (R, C)
     -> (conv + SiLU (R, C), the new tail)."""
     window = jnp.concatenate([tail, xBC[None]], axis=0)
-    acc = layer["conv_b"].astype(F32) + jnp.sum(
+    acc = _conv_bias(layer) + jnp.sum(
         layer["conv_w"].astype(F32)[:, None, :] * window.astype(F32), axis=0)
     return jax.nn.silu(acc).astype(xBC.dtype), window[1:]
 

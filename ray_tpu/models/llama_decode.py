@@ -1663,6 +1663,17 @@ def write_lane_rows(full, li, rows, slots, valid, lane_axis: int = 1):
     return jax.lax.fori_loop(0, rows.shape[0], write, full)
 
 
+def rows_a_piece(R: int, T: int, tokens: int) -> int:
+    """How many of an admission's R rows of T positions one pass of a mixer
+    takes so that it holds `tokens` tokens at the most (one row at the
+    least): a divisor of R, so that the pieces are of one shape (rows are
+    independent sequences, so a mixer may walk them in pieces)."""
+    n = max(1, min(R, tokens // T))
+    while R % n:
+        n -= 1
+    return n
+
+
 def generate_through_paged_cache(init_cache, admit, decode_step, params, prompt,
                                  cfg, n_new: int, block: int = 16):
     """Greedy tokens (R, n_new) for prompts (R, T) of one length, for a

@@ -55,6 +55,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.afmoe import (  # the FFN half is that model's, called not copied
     DENSE, MOE, _dense, _layer_at, logits_of, make_moe, make_swiglu, moe_ffn, swiglu)
+from ray_tpu.models.llama_decode import rows_a_piece
 from ray_tpu.ops.normalization import rms_norm
 from ray_tpu.ops.rope import apply_rope, yarn_frequencies, yarn_mscale
 
@@ -101,6 +102,7 @@ class SarvamMlaConfig:
     rms_eps: float = 1e-6
     max_seq_len: int = 131072
     dtype: Any = jnp.bfloat16
+    route_scoring = "sigmoid"             # a constant of the family, no field: afmoe.route
 
     def __post_init__(self):
         if self.held_count is None:
@@ -256,9 +258,7 @@ def sequence_mixer(layer, a, cos, sin, cfg: SarvamMlaConfig):
     (output (R, T, d), the cache rows (R, T, latent_row): the admission
     writes them to the pool)."""
     R, T, _ = a.shape
-    n = max(1, min(R, ATTN_TOKENS // T))  # rows a piece
-    while R % n:
-        n -= 1
+    n = rows_a_piece(R, T, ATTN_TOKENS)
 
     def piece(a_piece):
         with jax.named_scope(SCOPE_PROJ):

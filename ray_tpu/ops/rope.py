@@ -62,3 +62,13 @@ def apply_rope(x, cos, sin, positions=None):
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     return out.astype(x.dtype)
+
+
+def apply_partial_rope(x, cos, sin, positions=None):
+    """`apply_rope` on the FIRST entries of each head only: x [B, T, H, D];
+    cos/sin [maxT, R/2] with R <= D the rotary part (`rope_frequencies(R,
+    ...)`); x[..., R:] passes untouched."""
+    rot = 2 * cos.shape[-1]
+    if rot == x.shape[-1]:
+        return apply_rope(x, cos, sin, positions)
+    return jnp.concatenate([apply_rope(x[..., :rot], cos, sin, positions), x[..., rot:]], axis=-1)

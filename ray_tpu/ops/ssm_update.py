@@ -1,5 +1,6 @@
-"""One decode step of a Mamba-2 layer's state, in place in the stacked state:
-a Pallas TPU kernel.
+"""One decode step of a recurrent layer's state, in place in the stacked
+state: a Pallas TPU kernel with two bodies, Mamba-2's update and the gated
+delta rule's (at the end of this text).
 
 The state of all Mamba layers is one array `(layers, lanes, H, P, N)` float32
 (models/granite_hybrid_decode.py). A decode step of layer `mi` is, for every
@@ -29,6 +30,18 @@ log-sum-exp), `y` leaves as such a row.
 `update_stacked_state` is the entry; `engages` says whether a step takes it
 (a TPU, and shapes the tiles take), and the caller (models/granite_hybrid.py)
 keeps `ssm_step` as the definition and the path everywhere else.
+
+The gated delta rule (models/qwen3_next.py) keeps a MATRIX a head, `(K, V)`
+float32 in a stack `(layers, lanes, H, K, V)`, and its step reads the state
+before it writes it: `S' = a S; d = b (v - S'^T k); S'' = S' + k (x) d;
+o = S''^T q`. Same grid, index maps and aliasing (`_stacked_call`), another
+body: a live lane's block is read once, decayed, `S'^T k` taken as a multiply
+and a sum over the key rows (float32 on the vector unit), the outer product
+added, `S''^T q` taken the same way and `S''` written over the block. The
+tile is `(heads * K, V)`: V on the lanes, one row a (head, key) pair; `a`, `k`
+and `q` come in as rows `(1, heads * K)` and are turned into columns, `v`, `b`
+(a number a head, laid over the V lanes) and `o` are `(heads, V)`.
+`delta_update_stacked_state` is that entry.
 """
 from __future__ import annotations
 
@@ -94,37 +107,53 @@ def _kernel(mi_ref, n_live_ref, order_ref, decay_ref, dtx_ref, b_ref, c_ref, h_r
         o_ref[...] = h_ref[...]
 
 
+def _stacked_call(kernel, name, ssm, mi, order, n_live, rows, vecs, heads, y_is_row: bool):
+    """The one `pallas_call` of both bodies over ssm (M, L, H, P, N) float32,
+    aliased onto the second result. Grid (lanes, blocks of heads); the layer
+    `mi` and the compacted live lanes (`order`, `n_live`) are scalar
+    prefetch. Operands by how a grid step sees them: `rows` (L, 1, H * P),
+    a block of heads' columns; `vecs` (L, 1, N), the lane's whole; `heads`
+    (L, H, N), a block of heads' rows. The first result is such a row
+    (`y_is_row`) or such a block of heads."""
+    M, L, H, P, N = ssm.shape
+    hb = heads_per_block(H, P, N)
+    nj, R = H // hb, hb * P
+
+    # a step past the live lanes repeats the last live step's block indices
+    def at(i, j, n_live):
+        return jnp.where(i < n_live[0], j, nj - 1)
+
+    row = pl.BlockSpec((1, 1, R), lambda i, j, mi, n, order: (order[i], 0, at(i, j, n)))
+    vec = pl.BlockSpec((1, 1, N), lambda i, j, mi, n, order: (order[i], 0, 0))
+    head = pl.BlockSpec((1, hb, N), lambda i, j, mi, n, order: (order[i], at(i, j, n), 0))
+    state = pl.BlockSpec((1, 1, hb, P, N),
+                         lambda i, j, mi, n, order: (mi[0], order[i], at(i, j, n), 0, 0))
+    n_in = len(rows) + len(vecs) + len(heads)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(L, nj),
+            in_specs=[row] * len(rows) + [vec] * len(vecs) + [head] * len(heads) + [state],
+            out_specs=[row if y_is_row else head, state]),
+        out_shape=[jax.ShapeDtypeStruct((L, 1, H * P) if y_is_row else (L, H, N), F32),
+                   jax.ShapeDtypeStruct(ssm.shape, F32)],
+        input_output_aliases={3 + n_in: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT),
+        name=name,
+    )(jnp.reshape(mi, (1,)).astype(jnp.int32), n_live, order, *rows, *vecs, *heads, ssm)
+
+
 @jax.jit  # the five layer scans of a macro-step share one lowering of the kernel (`setup_s`)
 def _ssm_update_pallas(ssm, mi, order, n_live, decay, dtx, B, C):
     """ssm (M, L, H, P, N) float32, aliased onto the second result; decay
     (L, H), dtx (L, H, P), B and C (L, N), all float32. Returns (sum_N(h' C)
     (L, H, P), meaningful on live lanes only, and the stack)."""
     M, L, H, P, N = ssm.shape
-    hb = heads_per_block(H, P, N)
-    nj, R = H // hb, hb * P
-
-    # a step past the live lanes repeats the last live step's block indices
-    def heads(i, j, n_live):
-        return jnp.where(i < n_live[0], j, nj - 1)
-
-    row = pl.BlockSpec((1, 1, R), lambda i, j, mi, n, order: (order[i], 0, heads(i, j, n)))
-    vec = pl.BlockSpec((1, 1, N), lambda i, j, mi, n, order: (order[i], 0, 0))
-    state = pl.BlockSpec((1, 1, hb, P, N),
-                         lambda i, j, mi, n, order: (mi[0], order[i], heads(i, j, n), 0, 0))
-    y, ssm = pl.pallas_call(
-        _kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(L, nj),
-            in_specs=[row, row, vec, vec, state], out_specs=[row, state]),
-        out_shape=[jax.ShapeDtypeStruct((L, 1, H * P), F32),
-                   jax.ShapeDtypeStruct(ssm.shape, F32)],
-        input_output_aliases={7: 1},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT),
-        name="ssm_update",
-    )(jnp.reshape(mi, (1,)).astype(jnp.int32), n_live, order,
-      jnp.repeat(decay, P, axis=-1).reshape(L, 1, H * P), dtx.reshape(L, 1, H * P),
-      B.reshape(L, 1, N), C.reshape(L, 1, N), ssm)
+    y, ssm = _stacked_call(
+        _kernel, "ssm_update", ssm, mi, order, n_live,
+        rows=(jnp.repeat(decay, P, axis=-1).reshape(L, 1, H * P), dtx.reshape(L, 1, H * P)),
+        vecs=(B.reshape(L, 1, N), C.reshape(L, 1, N)), heads=(), y_is_row=True)
     return y.reshape(L, H, P), ssm
 
 
@@ -143,3 +172,51 @@ def update_stacked_state(ssm, mi, live, x, dt, A, B, C, D):
         C.astype(F32))
     y = y + D[None, :, None] * xf
     return jnp.where(active[:, None, None], y, 0.0), ssm
+
+
+# ------------------------------------------------------ the gated delta rule
+def _delta_kernel(mi_ref, n_live_ref, order_ref, a_ref, k_ref, q_ref, v_ref, b_ref, h_ref,
+                  o_ref, s_ref):
+    i = pl.program_id(0)
+    n_live = n_live_ref[0]
+    hb, K, V = h_ref.shape[2:]
+    column = lambda ref: jnp.transpose(ref[0], (1, 0)).reshape(hb, K, 1)  # noqa: E731
+
+    @pl.when(i < n_live)
+    def _():
+        k = column(k_ref)
+        s = column(a_ref) * h_ref[0, 0]                                  # (hb, K, V)
+        d = b_ref[0] * (v_ref[0] - jnp.sum(s * k, axis=1))               # (hb, V)
+        s = s + k * d[:, None, :]
+        s_ref[0, 0] = s
+        o_ref[0] = jnp.sum(s * column(q_ref), axis=1)
+
+    @pl.when(jnp.logical_and(i == 0, n_live == 0))  # `_kernel` says why
+    def _():
+        s_ref[...] = h_ref[...]
+
+
+@jax.jit  # every layer scan of a macro-step shares one lowering of the kernel
+def _delta_update_pallas(state, mi, order, n_live, a, k, q, v, b):
+    """state (M, L, H, K, V) float32, aliased onto the second result; a, b
+    (L, H); k, q (L, H, K); v (L, H, V); all float32. Returns (S''^T q (L, H,
+    V), meaningful on live lanes only, and the stack)."""
+    M, L, H, K, V = state.shape
+    row = lambda x: x.reshape(L, 1, H * K)  # noqa: E731
+    return _stacked_call(
+        _delta_kernel, "gdn_update", state, mi, order, n_live,
+        rows=(row(jnp.repeat(a, K, axis=-1)), row(k), row(q)), vecs=(),
+        heads=(v, jnp.broadcast_to(b[:, :, None], v.shape)), y_is_row=False)
+
+
+def delta_update_stacked_state(state, mi, live, q, k, v, a, b):
+    """Layer `mi`'s gated delta rule for one position on the live lanes of
+    the stacked state (M, L, H, K, V) float32: `live` as
+    `update_stacked_state` takes it; q, k (L, H, K) (a key head repeated for
+    each of its value heads); v (L, H, V); a = exp(g), b = beta (L, H)
+    float32. Returns (o (L, H, V) float32, zero on a lane that is not live;
+    the stack, that lane's row and every other layer untouched)."""
+    active, order, n_live = live
+    f = lambda x: x.astype(F32)  # noqa: E731
+    o, state = _delta_update_pallas(state, mi, order, n_live, a, f(k), f(q), f(v), b)
+    return jnp.where(active[:, None, None], o, 0.0), state

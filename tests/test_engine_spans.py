@@ -650,3 +650,57 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
     resolved = [stats for name, _, _, stats in top if name == "engine.resolve"]
     plan = keys - {"A", "P"}
     assert [{k: r[k] for k in plan} for r in resolved] == [{k: d[k] for k in plan} for d in dispatches]
+
+
+def test_a_recurrent_expert_models_spans_sum_to_its_counters(tmp_path):
+    """The Qwen3-Next decoder as a case (ISSUE 43): a model whose lanes hold
+    recurrent state AND whose macro-step counts experts on the device. The
+    plan's `state_lanes` on each `engine.dispatch` and the device's counts of
+    HELD experts on each `engine.resolve` sum to `engine.metrics()`' own, and
+    to what the requests' lengths say they must be."""
+    from benchmark import weights_qwen3_next as W
+    from ray_tpu.models import qwen3_next as M
+    from ray_tpu.models import qwen3_next_decode as QD
+
+    cfg = M.Qwen3NextConfig.tiny(dtype=jnp.float32)
+    params = W.init_params(W.seed_key(43), cfg)
+    eng = ContinuousBatchingEngine(params, cfg, n_slots=3, chunk=4, macro_phases=4, max_len=128,
+                                   block_size=4, prefix_cache=False)
+    rng = np.random.default_rng(1)
+    try:
+        lengths, answers = (9, 30, 21, 9, 30, 5), (6, 20, 11, 11, 6, 1)
+        prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
+        eng.generate(prompts[0], 2)  # the loop is up, a program compiled
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            m0 = eng.metrics()
+            reqs = [eng.submit(p, n) for p, n in zip(prompts, answers)]
+            assert all(r.done.wait(240) for r in reqs)
+            assert all(r.error is None for r in reqs)
+            m1 = eng.metrics()
+            time.sleep(0.15)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        eng.shutdown()
+    (events,) = _engine_events(tmp_path).values()
+    dispatches = [st for name, _, _, st in events if name == "engine.dispatch"]
+    resolves = [st for name, _, _, st in events if name == "engine.resolve"]
+    moved = {k: m1[k] - m0[k] for k in QD.DEVICE_COUNTERS + (
+        "state_lane_steps", "useful_slot_steps", "prefill_tokens", "dispatches")}
+    lane_steps = moved["useful_slot_steps"]
+    assert lane_steps == sum(n - 1 for n in answers) == moved["state_lane_steps"]
+    assert len(dispatches) == moved["dispatches"]
+    assert sum(int(d["state_lanes"]) for d in dispatches) == lane_steps
+    assert sum(int(d["prompt_tokens"]) for d in dispatches) == moved["prefill_tokens"] == sum(lengths)
+    assert m1["state_bytes"] == QD.state_bytes_per_lane(cfg) > 0
+    # held experts only (4 of 16, top-4): fewer than top_k pairs a live row and layer
+    assert 0 < moved["expert_rows"] < lane_steps * cfg.top_k * cfg.n_layers
+    assert moved["expert_rows"] >= moved["experts_hit"] >= moved["expert_rows_max"] > 0
+    for key in QD.DEVICE_COUNTERS:
+        assert sum(int(st[key]) for st in resolves) == moved[key]
+    # each resolve repeats its dispatch's plan counts, `state_lanes` among them
+    assert [int(r["state_lanes"]) for r in resolves] == [int(d["state_lanes"]) for d in dispatches]
+    assert sorted(int(st["seq"]) for st in resolves) == sorted(int(st["seq"]) for st in dispatches)

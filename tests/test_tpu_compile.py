@@ -662,3 +662,89 @@ def test_macro_step_holds_one_admission_body_a_width_and_one_decode_body(
 
     branches = set(re.findall(r"while/body/closed_call/(cond/branch_\d_fun)/admit_prefill/", text))
     assert branches == {"cond/branch_1_fun"}
+
+
+# ---------------------------------------------------------------- ISSUE 43
+def _qwen3_next_macro_step(one_chip, A, P):
+    """The Qwen3-Next decoder's paged macro-step at
+    `qwen3-next-80b-a3b.serve`'s widths (two periods L L L A holding 128 of
+    the router's 512 experts, a 37,984-row vocabulary, 8 lanes, a table span
+    of 8192), compiled for the described chip at the (A, P) variant, the
+    state update through its kernel as the chip runs it."""
+    from unittest import mock
+
+    from ray_tpu.models import qwen3_next as M
+    from ray_tpu.models import qwen3_next_decode as D
+    from ray_tpu.ops import ssm_update as SU
+    from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
+
+    cfg = M.Qwen3NextConfig(vocab_size=37984, n_layers=8, held_count=128, max_seq_len=8192)
+    B, bs, K = 8, 16, 8
+    MB = cfg.max_seq_len // bs
+    arr, shaped = _shapes_on(one_chip)
+    params = shaped(jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
+    with mock.patch.object(SU, "_on_tpu", lambda: True):
+        return D.jitted_macro_step_slots_paged.__wrapped__(cfg, 8, sampled=False).lower(
+            params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
+            arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
+            arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
+            arr((K, B, MAX_STOP_TOKENS))).compile()
+
+
+def test_delta_rule_update_kernel_compiles_with_the_stack_aliased(one_chip):
+    """The second body of ops/ssm_update.py at the cell's shapes (6 linear
+    layers x 8 lanes x 32 heads x 128 x 128 float32; a whole lane's 32 heads,
+    2 MB, a block) compiles for the chip with the stack aliased and nothing
+    beside it."""
+    from ray_tpu.ops import ssm_update as SU
+
+    M_, L, H, K, V = 6, 8, 32, 128, 128
+    assert SU.supported(H, K, V) and SU.heads_per_block(H, K, V) == H
+    arr, _ = _shapes_on(one_chip)
+    f32 = functools.partial(arr, dtype=jnp.float32)
+    compiled = jax.jit(SU._delta_update_pallas, donate_argnums=(0,)).lower(
+        f32((M_, L, H, K, V)), arr(()), arr((L,)), arr((1,)), f32((L, H)), f32((L, H, K)),
+        f32((L, H, K)), f32((L, H, V)), f32((L, H))).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 4 * M_ * L * H * K * V
+    assert m.temp_size_in_bytes < 1e6, m.temp_size_in_bytes
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_qwen3_next_widest_admission_fits_the_chip_with_state_pool_and_experts_in_place(
+        one_chip, monkeypatch):
+    """(A, P) = (8, 4096), up to 32,768 admitted tokens, the program of the
+    cell's longest bucket with its four admission bodies: 7.33 GB of weights,
+    a 0.27 GB K/V pool and 0.10 GB of states and conv tails go in (the cache
+    donated), 2.10 GB of temporaries (the linear mixer two rows and the
+    expert layer 4,096 pairs at a time), 9.81 GB of the chip's 16 (compiled
+    only, PR 43). Each body's attention is the flash kernel at head size 256
+    (one call in each of its two layer loops that hold an attention layer).
+    The decode step's state update is the kernel `gdn_update`, one call a run
+    of linear layers with the stack aliased; no operation copies the stacked
+    state, the pool or a layer's held experts (bf16[128, 2048, 512], 268 MB)."""
+    import re
+
+    monkeypatch.setattr(FA, "_on_tpu", lambda: True)
+    compiled = _qwen3_next_macro_step(one_chip, 8, 4096)
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+    print(f"memory_analysis (8, 4096): arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, total {total / 1e9:.2f} GB")
+    assert 7.6e9 < m.argument_size_in_bytes < 7.8e9 and m.alias_size_in_bytes > 0.36e9
+    assert total < 10.5e9, total
+    text = compiled.as_text()
+    assert _admission_bodies(text) == (4, 1)
+    kernels = re.findall(r"%flash_fwd[.\d]* = \((bf16\[[\d,]+\])[^=]*custom-call\(", text)
+    assert sorted(kernels) == sorted(
+        [f"bf16[{16 * rows},4096,256]" for rows in (1, 2, 4, 8) for _ in range(2)]), kernels
+    updates = [ln for ln in text.splitlines() if "custom-call(" in ln and " %gdn_update" in ln]
+    assert len(updates) == 2 and all("decode_chunk" in ln and "/gdn_update/" in ln for ln in updates)
+    assert all("output_to_operand_aliasing={{1}: (8, {})}" in ln for ln in updates)
+    ops = _outputs_of_own_operations(text)
+    big = re.compile(r"(f32\[(6|1),8,32,128,128\]|bf16\[(2|1),4097,16,512\]|"
+                     r"bf16\[(8,|1,)?128,(2048,512|512,2048)\])")
+    moved = [(n, op, s) for n, op, shapes in ops for s in shapes
+             if big.fullmatch(s) and (op == "copy" or s.startswith(("f32[1,", "bf16[1,")))]
+    assert not moved, moved
