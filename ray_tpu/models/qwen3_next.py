@@ -38,7 +38,21 @@ layer is linear attention (`layer_types`).
   U = T (beta v); a chunk entering with state S gives V' = U - W S,
   O = (q exp G) S + lower(q k^T exp(G_i - G_j)) V', and leaves
   S exp(G_C) + (k exp(G_C - G))^T V'. Past a row's length g = 0 and beta =
-  0: the state stands.
+  0: the state stands. Of all this only V', O and the state's update read
+  the state a chunk enters with, so `gdn_chunked` works in two stages, a
+  group of `GROUP_TOKENS` tokens' chunks after another. First, for ALL
+  chunks of the group at once, the chunk a batch axis of every operation: G,
+  the decays exp(G_i - G_j), k k^T, q k^T, A, T, W, U and the three
+  decay-weighted operands q exp G, lower(q k^T exp(G_i - G_j)) and
+  k exp(G_C - G). Then a loop over the group's chunks in order, which
+  carries S and makes those three and nothing else: four products a chunk.
+  A group is as many chunks as keep the first stage's float32 C x C
+  matrices in the chip's fast memory from one product to the next (all
+  chunks of a pass at once go through main memory and are slower than one
+  chunk at a time). In the chain of T each factor's T P and the next
+  factor's P P are ONE product, [T; P] P: the same rows at the same
+  precision, and the matrix unit streams 2 C rows past a right-hand side
+  it would otherwise load twice.
 - FULL attention (models/afmoe.py's `qkvg` / `gated_out`, at this model's
   sizes): q, k, v and an output gate as wide as q; q and k through the
   zero-centred norm over a head; RoPE on the FIRST `rotary_dim` entries of
@@ -92,6 +106,14 @@ SCOPE_PROJ, SCOPE_SCAN, SCOPE_UPDATE, SCOPE_ATTN = (
 # projections' output (6 x d_model numbers a token) and the conv's float32
 # sums stay under a GB
 LIN_TOKENS = 8192
+# tokens whose chunks `gdn_chunked` prepares at once: their float32 C x C
+# matrices (a chunk's decays, A, the inverse's powers: a MB a row's chunk at
+# the published sizes) then stay in a v5e's fast memory from product to
+# product. On the chip, one pass of 8,192 tokens (my chip runs, PR 44): 5.1 ms
+# at 512 and at 1,024 for two rows of 4,096 (6.7 and 5.1 for four of 2,048),
+# 6.2 at 2,048, 10.8 with all 8,192 at once, 6.0 a chunk at a time (8.0 the
+# parent, everything in one loop over chunks)
+GROUP_TOKENS = 1024
 
 
 @dataclasses.dataclass(frozen=True)
@@ -370,68 +392,89 @@ def gdn_step_stacked(state, li, live, q, k, v, a, b):
 
 def gdn_chunked(q, k, v, g, beta, chunk: int):
     """The gated delta rule over whole sequences in its chunked form (this
-    module's text has the algebra). q, k (R, T, Hk, K) float32, normalised;
-    v (R, T, H, V), H a multiple of Hk; g <= 0 and beta (R, T, H) float32,
-    both 0 where a position is padding (decay 1, nothing written: the state
-    stands still). From a zero state. Returns (o (R, T, H, V) in v's type,
-    final state (R, H, K, V) float32)."""
+    module's text has the algebra, and what is made a group of chunks at a
+    time and what in the loop over chunks). q, k (R, T, Hk, K) float32,
+    normalised; v (R, T, H, V), H a multiple of Hk; g <= 0 and beta (R, T, H)
+    float32, both 0 where a position is padding (decay 1, nothing written:
+    the state stands still). From a zero state. Returns (o (R, T, H, V) in
+    v's type, final state (R, H, K, V) float32)."""
     R, T, H, V = v.shape
     Hk, K = q.shape[2:]
     E = H // Hk  # value heads a key head
     C = min(chunk, T)
-    pad = -T % C
+    nc = -(-T // C)
+    ng = min(nc, -(-R * nc * C // GROUP_TOKENS))  # groups, of
+    per = -(-nc // ng)                            # chunks each, the last filled with padding
+    pad = ng * per * C - T
     if pad:
         q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
                             for x in (q, k, v, g, beta))
-    nc = (T + pad) // C
     mm = v.dtype
     hi = jax.lax.Precision.HIGHEST
-    chunks = lambda x: jnp.moveaxis(x.reshape(R, nc, C, *x.shape[2:]), 1, 0)  # noqa: E731
-    heads = lambda x: x.reshape(*x.shape[:2], Hk, E, *x.shape[3:])  # noqa: E731  H -> (Hk, E)
     lower = jnp.tril(jnp.ones((C, C), bool))
     strict = jnp.tril(jnp.ones((C, C), bool), -1)
     eye = jnp.eye(C, dtype=F32)
     doublings = max(C - 1, 1).bit_length() - 1  # factors after (I + A)
 
-    def step(S, inp):
-        qc, kc, vc, gc, bc = inp          # (R,C,Hk,K) x 2, (R,C,Hk,E,V), (R,C,Hk,E) x 2
-        G = jnp.cumsum(gc, axis=1)                                        # <= 0 and falling
-        Gh = jnp.moveaxis(G, 1, 3)                                        # (R,Hk,E,C)
-        decay = jnp.exp(jnp.where(lower, Gh[..., :, None] - Gh[..., None, :], -jnp.inf))
+    def chunks(x, *heads):
+        """(R, T, H or Hk, ..) -> (ng, per, R, *heads, C, ..): group- and
+        chunk-major for the two loops, head-major for the products, and so
+        to the end."""
+        x = x.reshape(R, ng, per, C, *heads, *x.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 3 + len(heads)), (1, 2), (0, 1))
+
+    def product(a, b):
+        return jnp.matmul(a, b, preferred_element_type=F32)
+
+    def prepare(qc, kc, vc, gc, bc):
+        """A group's chunks at once, (per, R, Hk, C, K) x 2 and (per, R, Hk,
+        E, C[, V]) x 3: nothing here reads the state."""
+        G = jnp.cumsum(gc, axis=-1)                                      # <= 0 and falling
+        decay = jnp.exp(jnp.where(lower, G[..., :, None] - G[..., None, :], -jnp.inf))
         kb, qb = kc.astype(mm), qc.astype(mm)
-        kk = jnp.einsum("righ,rjgh->rgij", kb, kb, preferred_element_type=F32)
-        qk = jnp.einsum("righ,rjgh->rgij", qb, kb, preferred_element_type=F32)
-        bh = jnp.moveaxis(bc, 1, 3)                                       # (R,Hk,E,C)
-        A = jnp.where(strict, -(bh[..., None] * kk[:, :, None] * decay), 0.0)
-        # T = (I - A)^-1 = (I + A)(I + A^2)(I + A^4)..: float32 products
-        Tm, P = eye + A, A
-        for _ in range(doublings):
-            P = jnp.matmul(P, P, precision=hi)
-            Tm = Tm + jnp.matmul(Tm, P, precision=hi)
+        kk = product(kb, jnp.swapaxes(kb, -1, -2))                        # (per,R,Hk,C,C)
+        qk = product(qb, jnp.swapaxes(kb, -1, -2))
+        A = jnp.where(strict, -(bc[..., None] * kk[:, :, :, None] * decay), 0.0)
+        # both operands of the chain's first product: left to itself the TPU
+        # compiler builds A inside that product, once for each (on the chip
+        # 108 us a group of 16 chunks where the bare product takes 43)
+        A = jax.lax.optimization_barrier(A)
+        # T = (I - A)^-1 = (I + A)(I + A^2)(I + A^4)..: float32 products. A
+        # factor's T P and the next factor's P P have their right-hand side
+        # in common and are one product, of X = [T; P]: X <- [T; 0] + X P
+        Tm = eye + A
+        if doublings:
+            X = jnp.concatenate([Tm, jnp.matmul(A, A, precision=hi)], axis=-2)
+            for _ in range(doublings - 1):
+                X = (jnp.concatenate([X[..., :C, :], jnp.zeros_like(X[..., C:, :])], axis=-2)
+                     + jnp.matmul(X, X[..., C:, :], precision=hi))
+            Tm = X[..., :C, :] + jnp.matmul(X[..., :C, :], X[..., C:, :], precision=hi)
         Tb = Tm.astype(mm)
         eG = jnp.exp(G)
-        k_in = (kc[:, :, :, None, :] * (bc * eG)[..., None]).astype(mm)   # beta k exp G
+        k_in = (kc[:, :, :, None] * (bc * eG)[..., None]).astype(mm)      # beta k exp G
         v_in = (vc.astype(F32) * bc[..., None]).astype(mm)                # beta v
-        # what a product gives stays head-major, (R,Hk,E,C,.), to the end
-        W = jnp.einsum("rgeij,rjgek->rgeik", Tb, k_in, preferred_element_type=F32)
-        U = jnp.einsum("rgeij,rjgev->rgeiv", Tb, v_in, preferred_element_type=F32)
+        W = product(Tb, k_in).astype(mm)                                  # (per,R,Hk,E,C,K)
+        U = product(Tb, v_in)                                             # (per,R,Hk,E,C,V) float32
+        q_in = (qc[:, :, :, None] * eG[..., None]).astype(mm)             # q exp G
+        qk_in = (qk[:, :, :, None] * decay).astype(mm)                    # lower(q k^T decay)
+        k_out = (kc[:, :, :, None] * jnp.exp(G[..., -1:] - G)[..., None]).astype(mm)
+        return W, U, q_in, qk_in, k_out, eG[..., -1]
+
+    def step(S, inp):
+        """One chunk: what reads the state S it enters with."""
+        W, U, q_in, qk_in, k_out, eG_end = inp
         Sb = S.astype(mm)
-        Vn = U - jnp.einsum("rgeik,rgekv->rgeiv", W.astype(mm), Sb, preferred_element_type=F32)
-        Vb = Vn.astype(mm)
-        q_in = (qc[:, :, :, None, :] * eG[..., None]).astype(mm)          # q exp G
-        o = jnp.einsum("rigek,rgekv->rgeiv", q_in, Sb, preferred_element_type=F32)
-        o = o + jnp.einsum("rgeij,rgejv->rgeiv", (qk[:, :, None] * decay).astype(mm), Vb,
-                           preferred_element_type=F32)
-        to_end = jnp.exp(G[:, -1:] - G)                                   # (R,C,Hk,E)
-        k_out = (kc[:, :, :, None, :] * to_end[..., None]).astype(mm)
-        S = eG[:, -1][..., None, None] * S + jnp.einsum(
-            "rjgek,rgejv->rgekv", k_out, Vb, preferred_element_type=F32)
+        Vb = (U - product(W, Sb)).astype(mm)
+        o = product(q_in, Sb) + product(qk_in, Vb)
+        S = eG_end[..., None, None] * S + product(jnp.swapaxes(k_out, -1, -2), Vb)
         return S, o.astype(mm)
 
-    S, os_ = jax.lax.scan(step, jnp.zeros((R, Hk, E, K, V), F32),
-                          (chunks(q), chunks(k), chunks(heads(v)), chunks(heads(g)),
-                           chunks(heads(beta))))
-    o = jnp.transpose(os_, (1, 0, 4, 2, 3, 5)).reshape(R, nc * C, H, V)  # (nc,R,Hk,E,C,V)
+    S, os_ = jax.lax.scan(lambda S, group: jax.lax.scan(step, S, prepare(*group)),
+                          jnp.zeros((R, Hk, E, K, V), F32),
+                          (chunks(q, Hk), chunks(k, Hk), chunks(v, Hk, E), chunks(g, Hk, E),
+                           chunks(beta, Hk, E)))
+    # (ng,per,R,Hk,E,C,V) -> (R,T,H,V)
+    o = jnp.transpose(os_, (2, 0, 1, 5, 3, 4, 6)).reshape(R, ng * per * C, H, V)
     return o[:, :T], S.reshape(R, H, K, V)
 
 

@@ -128,14 +128,38 @@ def _rule_inputs(R_, T, Hk, K, H, V, lengths, seed=2):
     return q, k, v, jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
 
 
-@pytest.mark.parametrize("T,chunk", [(5, 8), (8, 8), (21, 8), (32, 8), (21, 64), (19, 5)])
-def test_gdn_chunked_is_gdn_step_iterated(T, chunk):
-    """Float32, ragged lengths and a padded row (length 0): the chunked
-    form's outputs at every real position and its final states are the
-    one-position form's, iterated; a padded row's state stays zero."""
-    lengths = (T, max(T - 7, 1), 0)
+# (T, chunk, lengths): the first six with `T, T - 7, 0`; then what splitting
+# the chunk's work from the loop's could break: a sequence shorter than one
+# chunk, a length that is no multiple of the chunk, one chunk exactly, many
+# chunks, a first row that is all padding, every row ending inside another chunk
+RULE_CASES = [(T, chunk, (T, max(T - 7, 1), 0))
+              for T, chunk in [(5, 8), (8, 8), (21, 8), (32, 8), (21, 64), (19, 5)]] + [
+    (3, 8, (3, 1, 0)), (1, 8, (1, 0, 1)), (13, 4, (13, 6, 0)), (8, 8, (8, 8, 0)),
+    (64, 64, (64, 33, 0)), (40, 4, (0, 40, 17)), (27, 8, (9, 27, 0)), (16, 16, (5, 0, 16))]
+
+
+@pytest.fixture(params=["one group", "groups of two chunks"])
+def chunk_groups(request, monkeypatch):
+    """`gdn_chunked` prepares its chunks a group at a time (`GROUP_TOKENS`):
+    at these sizes all of them in one, or, with the constant set for the
+    case, two chunks of the three rows a group (an odd count of chunks then
+    ends in a chunk of padding)."""
+    def set_for(chunk):
+        if request.param != "one group":
+            monkeypatch.setattr(M, "GROUP_TOKENS", 3 * 2 * chunk)
+    return set_for
+
+
+@pytest.mark.parametrize("T,chunk,lengths", RULE_CASES)
+def test_gdn_chunked_is_gdn_step_iterated(T, chunk, lengths, chunk_groups):
+    """Float32, three rows of ragged lengths, one of them all padding (length
+    0): the chunked form's outputs at every real position and its final
+    states are the one-position form's, iterated; the padded row's state
+    stays zero, bit for bit."""
+    chunk_groups(min(chunk, T))
     q, k, v, g, beta = _rule_inputs(3, T, 2, 8, 4, 8, lengths)
     o, S = M.gdn_chunked(q, k, v, g, beta, chunk)
+    assert o.shape == v.shape and o.dtype == v.dtype and S.shape == (3, 4, 8, 8)
     S2, os_ = jnp.zeros((3, 4, 8, 8)), []
     for t in range(T):
         ot, S2 = M.gdn_step(S2, jnp.repeat(q[:, t], 2, 1), jnp.repeat(k[:, t], 2, 1), v[:, t],
@@ -145,7 +169,32 @@ def test_gdn_chunked_is_gdn_step_iterated(T, chunk):
     np.testing.assert_allclose(np.asarray(o)[real], np.asarray(jnp.stack(os_, 1))[real],
                                rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(S, S2, rtol=1e-4, atol=1e-5)
-    assert not np.asarray(S[2]).any()
+    empty = lengths.index(0)
+    assert np.array_equal(np.asarray(S[empty]), np.zeros((4, 8, 8), np.float32))
+    assert np.delete(np.asarray(S), empty, 0).any(axis=(1, 2, 3)).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_gdn_chunked_rows_and_chunks_do_not_mix(dtype, chunk_groups):
+    """What is made for a group's chunks of all rows at once is still each
+    chunk's own: a row's outputs and state are the same whether it goes
+    through alone or beside other rows, and the positions before a chunk
+    boundary the same whether or not chunks follow; to a rounding of the type
+    (a product's sum may be ordered otherwise in another batch), where a
+    mixed-up chunk or row would differ in the first digit. The bfloat16 case
+    rounds the products' operands as the served model does."""
+    chunk_groups(8)
+    q, k, v, g, beta = _rule_inputs(3, 24, 2, 8, 4, 8, (24, 11, 0))
+    v = v.astype(dtype)
+    same = functools.partial(np.testing.assert_allclose, atol=1e-6,
+                             rtol=1e-5 if dtype == jnp.float32 else 2.0 ** -7)
+    o, S = M.gdn_chunked(q, k, v, g, beta, 8)
+    for r in range(3):
+        o1, S1 = M.gdn_chunked(*(x[r:r + 1] for x in (q, k, v, g, beta)), 8)
+        same(np.asarray(o1[0], np.float32), np.asarray(o[r], np.float32))
+        same(np.asarray(S1[0]), np.asarray(S[r]), rtol=1e-5)
+    o2, _ = M.gdn_chunked(*(x[:, :16] for x in (q, k, v, g, beta)), 8)
+    same(np.asarray(o2, np.float32), np.asarray(o[:, :16], np.float32))
 
 
 def test_the_delta_rule_is_no_added_outer_product():

@@ -712,22 +712,32 @@ def test_delta_rule_update_kernel_compiles_with_the_stack_aliased(one_chip):
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
+@pytest.fixture(scope="module")
+def qwen3_next_widest(one_chip):
+    """The (8, 4096) program of `qwen3-next-80b-a3b.serve`, compiled once for
+    the tests that read it."""
+    from unittest import mock
+
+    with mock.patch.object(FA, "_on_tpu", lambda: True):
+        return _qwen3_next_macro_step(one_chip, 8, 4096)
+
+
 def test_qwen3_next_widest_admission_fits_the_chip_with_state_pool_and_experts_in_place(
-        one_chip, monkeypatch):
+        qwen3_next_widest):
     """(A, P) = (8, 4096), up to 32,768 admitted tokens, the program of the
     cell's longest bucket with its four admission bodies: 7.33 GB of weights,
     a 0.27 GB K/V pool and 0.10 GB of states and conv tails go in (the cache
     donated), 2.10 GB of temporaries (the linear mixer two rows and the
     expert layer 4,096 pairs at a time), 9.81 GB of the chip's 16 (compiled
-    only, PR 43). Each body's attention is the flash kernel at head size 256
+    only, PR 43; PR 44, whose chunked rule prepares 1,024 tokens' chunks at
+    once: 9.812). Each body's attention is the flash kernel at head size 256
     (one call in each of its two layer loops that hold an attention layer).
     The decode step's state update is the kernel `gdn_update`, one call a run
     of linear layers with the stack aliased; no operation copies the stacked
     state, the pool or a layer's held experts (bf16[128, 2048, 512], 268 MB)."""
     import re
 
-    monkeypatch.setattr(FA, "_on_tpu", lambda: True)
-    compiled = _qwen3_next_macro_step(one_chip, 8, 4096)
+    compiled = qwen3_next_widest
     m = compiled.memory_analysis()
     total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
     print(f"memory_analysis (8, 4096): arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
@@ -748,3 +758,39 @@ def test_qwen3_next_widest_admission_fits_the_chip_with_state_pool_and_experts_i
     moved = [(n, op, s) for n, op, shapes in ops for s in shapes
              if big.fullmatch(s) and (op == "copy" or s.startswith(("f32[1,", "bf16[1,")))]
     assert not moved, moved
+
+
+# ---------------------------------------------------------------- ISSUE 44
+def test_the_delta_rules_loop_over_chunks_holds_only_what_reads_the_carried_state(
+        qwen3_next_widest):
+    """The same (8, 4096) program: under `admit_prefill/../gdn_scan` each of
+    its eight walks over a pass's chunks (four admission bodies x two runs of
+    three linear layers) is a loop over groups of chunks around a loop over a
+    group's chunks. The inner one, which carries the float32 state from chunk
+    to chunk, holds FOUR products, those that read the state (W S,
+    (q exp G) S, lower(..) V' and the state's update), none of them at
+    `highest` precision. The chunk's inverse left it: its float32 products
+    at `highest`, six where two that share a right-hand side are one, are
+    made with k k^T, q k^T, W and U in the outer loop for all of a group's
+    chunks at once, ten products (compiled only, PR 44; the parent's one
+    loop held all eighteen)."""
+    import re
+
+    text = qwen3_next_widest.as_text()
+    products = [ln for ln in text.splitlines()
+                if " convolution(" in ln and "/admit_prefill/" in ln and "/gdn_scan/" in ln]
+    inner, outer = "/gdn_scan/while/body/closed_call/while/body/", "/gdn_scan/while/body/"
+    in_chunk_loop = [ln for ln in products if inner in ln]
+    in_group_loop = [ln for ln in products if outer in ln and inner not in ln]
+    at_highest = [ln for ln in products if "operand_precision={highest,highest}" in ln]
+    loops = _loops_under(text, "admit_prefill", "gdn_scan")
+    walks = [n for n in loops if n.endswith("gdn_scan/while")]
+    assert len(walks) == 8 and len(loops) == 16, loops
+    assert len(in_chunk_loop) == 4 * len(walks), len(in_chunk_loop)
+    assert len(in_group_loop) == 10 * len(walks) and len(products) == 14 * len(walks)
+    assert len(at_highest) == 6 * len(walks), len(at_highest)  # the precision is still stated
+    assert set(at_highest) <= set(in_group_loop)
+    # the inner loop carries the float32 state of one or two rows
+    carried = [ln for ln in text.splitlines() if " while(" in ln and "condition=" in ln
+               and "/gdn_scan/while/body/closed_call/while\"" in ln]
+    assert len(carried) == 8 and all(re.search(r"f32\[[12],16,2,128,128\]", ln) for ln in carried)
