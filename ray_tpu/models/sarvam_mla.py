@@ -103,6 +103,7 @@ class SarvamMlaConfig:
     max_seq_len: int = 131072
     dtype: Any = jnp.bfloat16
     route_scoring = "sigmoid"             # a constant of the family, no field: afmoe.route
+    mla_q_scale = mla_kv_scale = 1.0      # no `mla_scale_*` in this family: `project`
 
     def __post_init__(self):
         if self.held_count is None:
@@ -213,12 +214,31 @@ def project(layer, a, cos, sin, positions, cfg: SarvamMlaConfig):
     """a (R, T, d) at `positions` (R, T) or None (0..T-1) -> q_nope (R, T, h,
     nope), q_rope (R, T, h, rope) with its RoPE on, and the cache row (R, T,
     latent_row) = [N_kv(c) | RoPE(N(k_r))]. The head split stays out of the
-    products (llama_decode._qkv says why)."""
-    q, ckr = jax.lax.optimization_barrier((a @ layer["wq"], a @ layer["w_kv_a"]))
-    q = rms_norm(q.reshape(*a.shape[:2], cfg.n_heads, cfg.q_head_dim), layer["q_norm"], cfg.rms_eps)
+    products (llama_decode._qkv says why).
+
+    What the layer holds says which of the family's forms it is: with `w_qa`
+    the query is compressed, q = W_qb N_q(W_qa a) (`q_lora_rank`); without
+    `q_norm` / `k_rope_norm` the query heads and k_r go un-normed. `cfg.
+    mla_q_scale` / `mla_kv_scale`, where they are not 1, multiply q (every
+    entry, after W_qb) and the normed latent c (never k_r)."""
+    if "w_qa" in layer:
+        q, ckr = jax.lax.optimization_barrier((a @ layer["w_qa"], a @ layer["w_kv_a"]))
+        # the barrier keeps the head split out of this product too
+        q = jax.lax.optimization_barrier(rms_norm(q, layer["q_a_norm"], cfg.rms_eps) @ layer["w_qb"])
+    else:
+        q, ckr = jax.lax.optimization_barrier((a @ layer["wq"], a @ layer["w_kv_a"]))
+    q = q.reshape(*a.shape[:2], cfg.n_heads, cfg.q_head_dim)
+    if "q_norm" in layer:
+        q = rms_norm(q, layer["q_norm"], cfg.rms_eps)
+    if cfg.mla_q_scale != 1.0:
+        q = q * cfg.mla_q_scale
     q_nope, q_rope = q[..., :cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]
     c = rms_norm(ckr[..., :cfg.kv_lora_rank], layer["kv_norm"], cfg.rms_eps)
-    k_r = rms_norm(ckr[..., cfg.kv_lora_rank:], layer["k_rope_norm"], cfg.rms_eps)
+    if cfg.mla_kv_scale != 1.0:
+        c = c * cfg.mla_kv_scale
+    k_r = ckr[..., cfg.kv_lora_rank:]
+    if "k_rope_norm" in layer:
+        k_r = rms_norm(k_r, layer["k_rope_norm"], cfg.rms_eps)
     k_r = apply_rope(k_r[:, :, None, :], cos, sin, positions)[:, :, 0, :]
     return q_nope, apply_rope(q_rope, cos, sin, positions), jnp.concatenate([c, k_r], axis=-1)
 

@@ -77,6 +77,32 @@ def _padded(row, cfg: SarvamMlaConfig):
     return jnp.pad(row, [(0, 0)] * (row.ndim - 1) + [(0, pool_row(cfg) - cfg.latent_row)])
 
 
+def admit_mixer(layer, plane, a, pool, cos, sin, adm_tables, starts, valid, cfg):
+    """The attention half of an admission: whole rows a (A, P, d) the expanded
+    way, their cache rows written to plane `plane` of the pool."""
+    out, rows = M.sequence_mixer(layer, a, cos, sin, cfg)
+    with jax.named_scope(M.SCOPE_CTX):
+        pool, _ = L.write_admission_kv(pool, None, plane, _padded(rows, cfg), None,
+                                       adm_tables, starts, valid)
+    return out, pool
+
+
+def decode_mixer(layer, plane, a, pool, cos, sin, tables, pos, active, cfg):
+    """The attention half of a decode step: one position a (B, d) a lane the
+    absorbed way, over plane `plane` of the pool."""
+    B, r = a.shape[0], cfg.kv_lora_rank
+    with jax.named_scope(M.SCOPE_PROJ):
+        q_nope, q_rope, row = M.project(layer, a[:, None, :], cos, sin, pos[:, None], cfg)
+        q = _padded(jnp.concatenate([M.absorb_q(layer, q_nope[:, 0]), q_rope[:, 0]], axis=-1), cfg)
+    with jax.named_scope(M.SCOPE_CTX):
+        pool, _ = L.write_decode_kv(pool, None, plane, _padded(row, cfg), None, tables, pos, active)
+        o_lat = L.attend_decode_paged(q, pool, None, plane, tables, pos, active, cfg.sm_scale,
+                                      v_cols=r)
+    with jax.named_scope(M.SCOPE_PROJ):
+        out = M.absorbed_out(layer, o_lat.reshape(B, cfg.n_heads, r), cfg) @ layer["wo"]
+    return out, pool
+
+
 def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
                       cache, feed, tables, temps, top_ks, top_ps, stop_ids,
                       cfg: SarvamMlaConfig, sampled: bool = True):
@@ -93,11 +119,7 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     real = (jnp.arange(P)[None, :] < lengths[:, None]).reshape(-1)
 
     def mixer(layer, li, a, pool):
-        out, rows = M.sequence_mixer(layer, a, cos, sin, cfg)
-        with jax.named_scope(M.SCOPE_CTX):
-            pool, _ = L.write_admission_kv(pool, None, li, _padded(rows, cfg), None,
-                                           adm_tables, starts, valid)
-        return out, pool
+        return admit_mixer(layer, li, a, pool, cos, sin, adm_tables, starts, valid, cfg)
 
     x, pool = M.run_layers(
         params, M.embed_tokens(params, prompts, cfg), cache["latent"], cfg, mixer,
@@ -118,24 +140,13 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
     """One token on every lane, with llama_decode.decode_step_slots_paged's
     arguments and returns; attention the absorbed way, the expert layers over
     the live lanes' rows only."""
-    B = tokens.shape[0]
     pos = cache["pos"]
     active = cache["remaining"] > 0
-    r = cfg.kv_lora_rank
     cos, sin = M.rope_tables(cfg, tables.shape[1] * cache["latent"].shape[2])
 
     def mixer(layer, li, a, carry):
-        pool, counts = carry
-        with jax.named_scope(M.SCOPE_PROJ):
-            q_nope, q_rope, row = M.project(layer, a[:, None, :], cos, sin, pos[:, None], cfg)
-            q = _padded(jnp.concatenate([M.absorb_q(layer, q_nope[:, 0]), q_rope[:, 0]], axis=-1), cfg)
-        with jax.named_scope(M.SCOPE_CTX):
-            pool, _ = L.write_decode_kv(pool, None, li, _padded(row, cfg), None, tables, pos, active)
-            o_lat = L.attend_decode_paged(q, pool, None, li, tables, pos, active, cfg.sm_scale,
-                                          v_cols=r)
-        with jax.named_scope(M.SCOPE_PROJ):
-            out = M.absorbed_out(layer, o_lat.reshape(B, cfg.n_heads, r), cfg) @ layer["wo"]
-        return out, (pool, counts)
+        out, pool = decode_mixer(layer, li, a, carry[0], cos, sin, tables, pos, active, cfg)
+        return out, (pool, carry[1])
 
     def experts(p, m, carry):
         out, sizes = afmoe.moe_ffn(m, p, cfg, live=active)

@@ -704,3 +704,75 @@ def test_a_recurrent_expert_models_spans_sum_to_its_counters(tmp_path):
     # each resolve repeats its dispatch's plan counts, `state_lanes` among them
     assert [int(r["state_lanes"]) for r in resolves] == [int(d["state_lanes"]) for d in dispatches]
     assert sorted(int(st["seq"]) for st in resolves) == sorted(int(st["seq"]) for st in dispatches)
+
+
+def test_a_shortcut_expert_models_scopes_and_choice_counters(tmp_path):
+    """The LongCat-Flash decoder as a case (ISSUE 45): a model with a latent
+    pool of two planes a layer whose macro-step counts, beside the held
+    experts' three, a live row's chosen indices under and past the real
+    experts. Its scopes (`mla_proj` / `mla_absorb` / `mla_ctx` over both
+    attentions, `ffn_dense`, `moe_route`, `moe_experts`, the new `moe_zero`)
+    name operations of BOTH halves of the compiled macro-step; the five device
+    counts on each `engine.resolve` sum to `engine.metrics()`' own, and the
+    two of the choices to top_k a live row and layer."""
+    from benchmark import weights_longcat_flash as W
+    from ray_tpu.models import longcat_flash as M
+    from ray_tpu.models import longcat_flash_decode as LD
+
+    cfg = M.LongcatFlashConfig.tiny(dtype=jnp.float32)
+    params = W.init_params(W.seed_key(45), cfg)
+    eng = ContinuousBatchingEngine(params, cfg, n_slots=3, chunk=4, macro_phases=4, max_len=128,
+                                   block_size=4, prefix_cache=False)
+    rng = np.random.default_rng(1)
+    try:
+        lengths, answers = (9, 30, 21, 9, 30, 5), (6, 20, 11, 11, 6, 1)
+        prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
+        eng.generate(prompts[0], 2)  # the loop is up, a program compiled
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            m0 = eng.metrics()
+            reqs = [eng.submit(p, n) for p, n in zip(prompts, answers)]
+            assert all(r.done.wait(240) for r in reqs)
+            assert all(r.error is None for r in reqs)
+            m1 = eng.metrics()
+            time.sleep(0.15)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        eng.shutdown()
+    # the scopes, in the name stacks of the macro-step program lowered at the engine's shapes
+    K, A, P, B, MB = 4, 4, 16, 3, 32
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
+    f32 = lambda *s: jnp.zeros(s, jnp.float32)  # noqa: E731
+    from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
+
+    text = LD.jitted_macro_step_slots_paged(cfg, 4, sampled=False).lower(
+        params, LD.init_paged_cache(cfg, B, B * MB + 1, 4), i32(B), i32(K), jnp.zeros((K,), bool),
+        i32(K, A, P), i32(K, A), i32(K, A), i32(K, A), i32(K, A), jnp.zeros((K, A), jnp.uint32),
+        i32(K, B, MB), f32(K, B), i32(K, B), f32(K, B), i32(K, B, MAX_STOP_TOKENS)
+    ).compile().as_text()
+    stacks = set(re.findall(r'op_name="([^"]*)"', text))
+    for half in ("admit_prefill", "decode_chunk"):
+        for scope in ("mla_proj", "mla_ctx", "ffn_dense", "moe_route", "moe_experts", "moe_zero"):
+            assert any(f"/{half}/" in st and f"/{scope}/" in st for st in stacks), (half, scope)
+    assert any("/decode_chunk/" in st and "/mla_proj/mla_absorb/" in st for st in stacks)
+    assert not any("/admit_prefill/" in st and "/mla_absorb/" in st for st in stacks)  # it expands
+    assert "moe_shared" not in text  # no shared expert
+    (events,) = _engine_events(tmp_path).values()
+    dispatches = [st for name, _, _, st in events if name == "engine.dispatch"]
+    resolves = [st for name, _, _, st in events if name == "engine.resolve"]
+    assert LD.DEVICE_COUNTERS == ("expert_rows", "experts_hit", "expert_rows_max",
+                                  "real_choices", "zero_choices")
+    moved = {k: m1[k] - m0[k] for k in LD.DEVICE_COUNTERS + (
+        "useful_slot_steps", "prefill_tokens", "dispatches", "ctx_tokens")}
+    lane_steps = moved["useful_slot_steps"]
+    assert lane_steps == sum(n - 1 for n in answers) and len(dispatches) == moved["dispatches"]
+    assert moved["real_choices"] + moved["zero_choices"] == lane_steps * cfg.top_k * cfg.n_layers
+    assert moved["zero_choices"] > 0 and moved["real_choices"] > moved["expert_rows"] > 0
+    for key in LD.DEVICE_COUNTERS:
+        assert sum(int(st[key]) for st in resolves) == moved[key]
+    assert sum(int(d["ctx_tokens"]) for d in dispatches) == moved["ctx_tokens"]
+    assert m1["state_bytes"] == 0 and "state_lanes" not in dispatches[0]
+    assert sorted(int(st["seq"]) for st in resolves) == sorted(int(st["seq"]) for st in dispatches)

@@ -794,3 +794,112 @@ def test_the_delta_rules_loop_over_chunks_holds_only_what_reads_the_carried_stat
     carried = [ln for ln in text.splitlines() if " while(" in ln and "condition=" in ln
                and "/gdn_scan/while/body/closed_call/while\"" in ln]
     assert len(carried) == 8 and all(re.search(r"f32\[[12],16,2,128,128\]", ln) for ln in carried)
+
+
+# ---------------------------------------------------------------- ISSUE 45
+@functools.lru_cache(maxsize=2)
+def _longcat_flash_macro_step(one_chip, A, P):
+    """The LongCat-Flash decoder's paged macro-step at
+    `longcat-flash-chat.serve`'s widths (four double layers holding 16 of the
+    512 real experts under a router of 768 outputs, a 16,384-row vocabulary,
+    32 lanes, a table span of 1024), compiled for the described chip at the
+    (A, P) variant."""
+    from unittest import mock
+
+    from ray_tpu.models import longcat_flash as M
+    from ray_tpu.models import longcat_flash_decode as D
+    from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
+
+    cfg = M.LongcatFlashConfig(vocab_size=16384, n_layers=4, held_count=16, max_seq_len=1024)
+    B, bs, K = 32, 16, 8
+    MB = cfg.max_seq_len // bs
+    arr, shaped = _shapes_on(one_chip)
+    params = shaped(jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
+    with mock.patch.object(FA, "_on_tpu", lambda: True):
+        return D.jitted_macro_step_slots_paged.__wrapped__(cfg, 8, sampled=False).lower(
+            params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
+            arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
+            arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
+            arr((K, B, MAX_STOP_TOKENS))).compile()
+
+
+def _longcat_flash_moved(text):
+    """Operations of an optimized module's DECODE half that output one
+    sublayer's or layer's weights whole, in whatever layout (W_uk / W_uv,
+    W_qb, Wo, a dense FFN's matrices, the held experts' stacks), and copies
+    of the pool's eight planes anywhere. (In an admission's widest bodies
+    activations have some of these shapes, and a body that walks its rows in
+    pieces slices a sublayer's attention out once for all pieces.)"""
+    import re
+
+    weights = re.compile(r"bf16\[(8,|1,)?(64,128,512|64,512,128|1536,12288|8192,6144|"
+                         r"6144,12288|12288,6144|(4,|1,)?16,(6144,2048|2048,6144))\]")
+    line = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(")
+    out = []
+    for ln in text.splitlines():
+        m = line.match(ln)
+        if not m or '"estimated_cycles"' not in ln:
+            continue
+        for s in set(re.findall(r"bf16\[[\d,]+\]", m.group(2))):
+            if (weights.fullmatch(s) and "/decode_chunk/" in ln) or (
+                    s == "bf16[8,2049,16,640]" and m.group(3) == "copy"):
+                out.append((m.group(1), m.group(3), s))
+    return out
+
+
+def test_longcat_flash_widest_admission_fits_the_chip_with_both_planes_and_weights_in_place(
+        one_chip):
+    """(A, P) = (32, 512), up to 16,384 admitted tokens, the program of the
+    cell's longest bucket with its six admission bodies: 10.35 GB of weights
+    and a 0.34 GB latent pool of EIGHT planes go in (the pool donated), 2.08
+    GB of temporaries (a dense FFN's 16,384 x 12,288 products, the attention
+    16 rows at a time), 12.76 GB of the chip's 16 (compiled only, PR 45;
+    13.45 with 2.77 GB of temporaries before `sarvam_mla.project` kept the
+    head split out of the W_qb product: the compiler then copied the W_qb
+    stack, 302 MB, every dispatch and a sublayer's W_qb, 38 MB, out of it in
+    every layer of every decode step). Each
+    body's two attentions are the flash kernel (one call a sublayer in the
+    layer loop's body), the widest body's in pieces of 16 rows
+    (`sarvam_mla.ATTN_TOKENS`). The pool is written in place, a plane a
+    sublayer (no copy of it, every output of its shape a dynamic-update-slice
+    under `mla_ctx`), and no operation outputs a sublayer's or a layer's
+    weights whole."""
+    import re
+
+    compiled = _longcat_flash_macro_step(one_chip, 32, 512)
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+    print(f"memory_analysis (32, 512): arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, total {total / 1e9:.2f} GB")
+    assert 10.6e9 < m.argument_size_in_bytes < 10.75e9 and m.alias_size_in_bytes > 0.33e9
+    assert total < 13.3e9, total
+    text = compiled.as_text()
+    assert _admission_bodies(text) == (6, 1)
+    kernels = re.findall(r"%flash_fwd[.\d]* = \((bf16\[[\d,]+\])[^=]*custom-call\(", text)
+    assert sorted(kernels) == sorted(
+        [f"bf16[{64 * min(rows, 16)},512,128]" for rows in (1, 2, 4, 8, 16, 32) for _ in range(2)]), kernels
+    assert not _longcat_flash_moved(text), _longcat_flash_moved(text)
+    writes = [ln for ln in text.splitlines() if " = bf16[8,2049,16,640]" in ln and '"estimated_cycles"' in ln]
+    assert writes and all("dynamic_update_slice" in ln and "/mla_ctx/" in ln for ln in writes)
+
+
+def test_longcat_flash_shortest_bucket_decodes_with_pool_and_weights_in_place(one_chip):
+    """(A, P) = (32, 16), the program of the dispatch that admits nothing (and
+    of the shortest bucket): 10.68 GB of arguments, 0.28 GB of temporaries,
+    10.96 GB (compiled only, PR 45; 0.62 and 11.30 before the barrier). Its decode body reads every sublayer's
+    attention and dense FFN, the router and the held experts where they lie
+    and updates the eight planes in place; 32 rows x top-12 = 384 pairs a
+    step take the straight-line expert path (no loop under `moe_experts`)."""
+    compiled = _longcat_flash_macro_step(one_chip, 32, 16)
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+    print(f"memory_analysis (32, 16): arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, total {total / 1e9:.2f} GB")
+    assert m.temp_size_in_bytes < 0.4e9 and total < 11.2e9, (m.temp_size_in_bytes, total)
+    text = compiled.as_text()
+    assert _admission_bodies(text) == (6, 1)
+    assert not _longcat_flash_moved(text), _longcat_flash_moved(text)
+    assert not _loops_under(text, "decode_chunk", "moe_experts")
+    for scope in ("mla_proj", "mla_absorb", "mla_ctx", "ffn_dense", "moe_route", "moe_experts", "moe_zero"):
+        assert "/decode_chunk/" in text and f"/{scope}/" in text, scope
