@@ -19,7 +19,7 @@ IS the jitted jax program). TPU-first decode design:
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1102,49 +1102,92 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     return first, cache, feed
 
 
-def admit_width(n: int, lanes: int) -> int:
-    """Rows the admission of a phase runs for `n` prompts in a program
-    `lanes` admission rows wide: the smallest power of two that holds
-    them, `lanes` at the most, one for a phase that admits nobody. The
-    device picks its branch by this function (`admit_phase`) and the
-    engine counts `admit_rows` by it (`_dispatch_counts`): plain Python
-    on host integers."""
-    return min(1 << max(n - 1, 0).bit_length(), lanes)
+# Tokens a pass over bfloat16 weights is worth on a v5e: 197 TFLOP/s over
+# 819 GB/s (`benchmark/peaks.json`) is 240 operations a byte, at two
+# operations a token and two bytes a weight ~240 tokens, taken at the next
+# power of two. An admission of fewer tokens waits for the weights and
+# costs a pass whatever its rows; one of more is bound by its rows, and
+# the pass hides behind them (on the chip, PR 46: Mistral admits 1, 2 and
+# 4 rows of 256 in 15.2, 30.8 and 53.4 ms where a pass is ~9; PERF.md
+# section 6).
+RIDGE_TOKENS = 256
+
+
+def admit_pieces(n: int, lanes: int, P: int) -> Tuple[int, ...]:
+    """The widths, widest first, of the admissions that a phase of `n`
+    prompts runs in a program `lanes` admission rows wide and `P` tokens
+    long: the binary pieces of its count (3 = 2 + 1, 7 = 4 + 2 + 1) where
+    the rows left out are worth the further admissions, one piece of a
+    phase that admits nobody. The candidates keep the top j bits of `n`
+    and round what is left up to a power of two (n = 11: (16,), (8, 4),
+    (8, 2, 1); `lanes` caps the single piece), so the widths of a choice
+    are distinct. A piece costs its tokens, or a pass over the weights
+    where it has fewer than `RIDGE_TOKENS`; the candidate of the least
+    cost is taken, the fewest pieces among equals: from rows of 256 up
+    every piece is worth its rows and the pieces are the count's own
+    bits, at 16 a phase runs one piece as it did before PR 46. The device
+    runs its bodies by this function (`admit_phase`) and the engine
+    counts `admit_rows` and `admit_pieces` by it (`_dispatch_counts`):
+    plain Python on host integers."""
+    def cost(pieces):
+        return sum(max(w * P, RIDGE_TOKENS) for w in pieces)
+
+    n = max(n, 1)
+    bits = [1 << b for b in reversed(range(n.bit_length())) if n >> b & 1]
+    best = (min(1 << (n - 1).bit_length(), lanes),)
+    for j in range(1, len(bits)):
+        rest = n - sum(bits[:j])
+        pieces = (*bits[:j], 1 << (rest - 1).bit_length())
+        # (4, 4) is (8,), a candidate already; a sum past `lanes` has no rows
+        if pieces[-1] < bits[j - 1] and sum(pieces) <= lanes and cost(pieces) < cost(best):
+            best = pieces
+    return best
 
 
 def admit_phase(admit_rows, has_admit, rows, carry):
-    """A phase's admission at the width of its own prompts. `rows` are the
-    phase's per-row plan arrays, each with a leading A and the prompts'
-    true lengths second (0 = a padding row); `admit_rows(rows, carry) ->
-    (first (w,), carry)` is the admission proper, row-independent, for
-    any leading w. One `lax.cond` a width w = 1, 2, 4, .., A
-    (`admit_width`), of which an admitting phase takes exactly one: the
-    narrowest that reaches its last non-empty row, which for a plan that
-    fills rows 0 .. n - 1 (`_dispatch_macro`) is `admit_width(n, A)`. It
-    runs `admit_rows` on the first w rows under ADMIT_SCOPE; the rows
-    behind them are padding and are not computed. A chain of two-way
-    conds and not one `lax.switch`: under a switch of three or more
-    branches the TPU compiler copies the K/V pool (the hybrid's state)
-    twice a layer in every branch but the widest (compiled only, PR 42);
-    through a cond that either admits or hands its operands on, as the
-    skeleton always had, they stay in place, and the branches share one
-    set of temporaries, the widest's. -> (first (A,), carry)."""
-    lengths = rows[1]
-    A = lengths.shape[0]
-    reach = jnp.max(jnp.where(lengths > 0, jnp.arange(1, A + 1), 0))
+    """A phase's admission as the pieces of its count. `rows` are the
+    phase's per-row plan arrays, each with a leading A, the prompts (A, P)
+    first and their true lengths second (0 = a padding row);
+    `admit_rows(rows, carry) -> (first (w,), carry)` is the admission
+    proper, row-independent, for any leading w. One `lax.cond` a width w
+    = A, .., 4, 2, 1, of which an admitting phase takes those that
+    `admit_pieces(reach, A, P)` names, widest first, `reach` the phase's
+    last non-empty row (for a plan that fills rows 0 .. n - 1,
+    `_dispatch_macro`, its count n): each runs `admit_rows` under
+    ADMIT_SCOPE on the w rows behind those of the pieces before it, so
+    rows are admitted in plan order (a row whose table names blocks that
+    an earlier row of its phase fills reads them written), and the rows
+    behind the last piece are padding and are not computed. Which widths
+    run and where they start is a static table the device indexes by
+    `reach`. A chain of two-way conds and not one `lax.switch`: under a
+    switch of three or more branches the TPU compiler copies the K/V pool
+    (the hybrid's state) twice a layer in every branch but the widest
+    (compiled only, PR 42); through a cond that either admits or hands
+    its operands on, as the skeleton always had, they stay in place, and
+    the branches share one set of temporaries, the widest's.
+    -> (first (A,), carry)."""
+    A, P = rows[0].shape
+    reach = jnp.max(jnp.where(rows[1] > 0, jnp.arange(1, A + 1), 0))
+    begins: Dict[int, List[int]] = {}  # width -> its first row by reach, -1 where it does not run
+    for n in range(A + 1):
+        at = 0
+        for w in admit_pieces(n, A, P):
+            begins.setdefault(w, [-1] * (A + 1))[n] = at
+            at += w
     first = jnp.zeros((A,), jnp.int32)
-    below = -1  # a phase flagged as admitting with no row set runs one row
-    for w in sorted({admit_width(n, A) for n in range(1, A + 1)}):
+    for w in sorted(begins, reverse=True):
+        at = jnp.asarray(begins[w], jnp.int32)[reach]
 
-        def run(carry, w=w):
+        def run(carry, w=w, at=at):
             with jax.named_scope(ADMIT_SCOPE):
-                first, carry = admit_rows(tuple(r[:w] for r in rows), carry)
-            return jnp.pad(first, (0, A - w)), carry
+                got, carry = admit_rows(
+                    tuple(jax.lax.dynamic_slice_in_dim(r, at, w) for r in rows), carry)
+            return jax.lax.dynamic_update_slice(jnp.zeros((A,), jnp.int32), got, (at,)), carry
 
         got, carry = jax.lax.cond(
-            has_admit & (below < reach) & (reach <= w), run,
+            has_admit & (at >= 0), run,
             lambda carry: (jnp.zeros((A,), jnp.int32), carry), carry)
-        first, below = first + got, w
+        first = first + got
     return first, carry
 
 
@@ -1161,11 +1204,11 @@ def macro_step_slots_paged(params, cache, feed, steps, has_admit, prompts,
     decode_step_slots_paged (the defaults, Llama's). A is the widest a
     phase can admit (the engine passes its lanes' bucket, `_variant`),
     not the width an admission runs at: each admitting phase runs the
-    model's `admit` on the rows up to its last non-empty one, rounded
-    up to a power of two (`admit_phase`: a plan fills a phase's rows
-    from 0 up, and what lies behind them is not computed), so the
-    program holds one admission body a width 1, 2, 4, .., A and one
-    decode body. Extra
+    model's `admit` on the rows up to its last non-empty one as the
+    pieces of its count, 3 rows as 2 + 1 (`admit_phase`: a plan fills a
+    phase's rows from 0 up, and what lies behind them is not computed),
+    each piece through the body of its width, so the program holds one
+    admission body a width 1, 2, 4, .., A and one decode body. Extra
     per-phase arrays (K phases, B slots, A admission lanes, MB table
     width, NS stop width):
       starts   (K, A)        cached-prefix length per admission row
@@ -1565,7 +1608,7 @@ def macro_step_slots_spec(params, draft_params, cache, draft_cache, feed,
     FLOPs). Admissions prefill BOTH pools: the target admission is the
     stock admit_slots_paged; the draft pool mirrors the same suffix
     through the same block tables, and the slot's tracked previous
-    token is reset; both at the width of the phase's own prompts
+    token is reset; both as the pieces of the phase's own count
     (`admit_phase`, as in macro_step_slots_paged). Returns
     (toks (K, chunk, B, n_spec+1),
     counts (K, chunk, B), firsts (K, A), feed, cache, draft_cache) —
@@ -1609,7 +1652,7 @@ def macro_step_slots_spec(params, draft_params, cache, draft_cache, feed,
             ].set(last, mode="drop")
             return first, (c, {"k": dk2, "v": dv2, "prev": prev}, fd)
 
-        # both pools' admissions at the width of the phase's own prompts
+        # both pools' admissions as the pieces of the phase's own count
         first, (cache, draft_cache, feed) = admit_phase(
             admit_rows, admit_k,
             (prompts_k, lengths_k, starts_k, slots_k, rems_k, seeds_k), carry)

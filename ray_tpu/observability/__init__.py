@@ -40,9 +40,10 @@ ENGINE_SPANS = (
     "engine.dispatch",  # `_dispatch_macro()`: plan arrays built and the macro-step enqueued; stats: seq, phases,
                         # steps, admissions, A, P, prompt_tokens, lane_steps, finishing, finish_wait_steps, ctx_chunks,
                         # ctx_tokens, prompt_pairs (what the decode steps' and the admissions' attention has to do),
-                        # past_window_lane_steps (a model with sliding-window layers only), admit_rows, admit_phases (A is
-                        # the lanes' power-of-two bucket in every dispatch and P names the program; admit_rows is what the
-                        # device runs: P x each admitting phase's admissions rounded up to a power of two, not A x P a phase);
+                        # past_window_lane_steps (a model with sliding-window layers only), admit_rows, admit_pieces, admit_phases
+                        # (A is the lanes' power-of-two bucket in every dispatch and P names the program; admit_rows is what the
+                        # device runs: P x the pieces of each admitting phase's count, `llama_decode.admit_pieces`, 3 admissions
+                        # as 2 + 1 rows, not A x P a phase; admit_pieces is the admission bodies run);
                         # the lane account (PR 41): vacant_lane_steps, blocked_lane_steps, spent_lane_steps, which with
                         # lane_steps are n_slots x steps; the wait account over its admissions: plan_wait_us, lane_wait_us,
                         # admitted_first_plan; the lead and the stall: admit_lead_steps, admit_lead_phases, stall_lane_phases

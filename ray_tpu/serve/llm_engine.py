@@ -56,8 +56,8 @@ engine to wrap, so this is the green-field TPU-native equivalent
   admission is no dispatch of its own. One program is compiled per
   padded prompt width, a power of two (`_variant`), times greedy /
   sampled: its admission lanes are the engine's lanes rounded up to a
-  power of two, and each admitting phase runs at the width of its own
-  admissions inside it (`llama_decode.admit_phase`).
+  power of two, and each admitting phase runs its admissions as the
+  pieces of their count inside it (`llama_decode.admit_phase`).
 - ADAPTIVE CHUNKS. Each phase decodes exactly to the next scheduling
   event, min(chunk, least steps owed over the live lanes), so a freed
   lane is re-admitted at the very next phase and does not idle to a
@@ -320,7 +320,7 @@ def _suffix_len(req: "_Request") -> int:
 
 
 # the counts of `_dispatch_counts` that `engine.metrics()` sums as they are
-_PLAN_SUMS = ("ctx_tokens", "prompt_pairs", "admit_rows", "admit_phases",
+_PLAN_SUMS = ("ctx_tokens", "prompt_pairs", "admit_rows", "admit_pieces", "admit_phases",
               "vacant_lane_steps", "blocked_lane_steps", "spent_lane_steps",
               "plan_wait_us", "lane_wait_us", "admitted_first_plan",
               "admit_lead_steps", "admit_lead_phases", "stall_lane_phases")
@@ -370,10 +370,13 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
     reused prefix): what the attention of each half has to do whatever
     does it, for every model. `admit_rows` is the token rows the device
     runs for the admissions, padding included: for each phase that admits
-    (`admit_phases`) its admissions rounded up to a power of two, A at
-    the most (`llama_decode.admit_width`, the function the device picks
-    its branch by), times P, with (A, P) the compiled program's
-    (`variant`); `prompt_tokens / admit_rows` is the share of them that
+    (`admit_phases`) the pieces of its count (`llama_decode.admit_pieces`,
+    the function the device runs its admission bodies by: 3 admissions
+    run 2 + 1 rows where each piece has tokens enough to be worth its
+    pass over the weights, 4 where not), summed and times P, with (A, P) the compiled
+    program's (`variant`), and `admit_pieces` the admission bodies run,
+    so `admit_pieces / admit_phases` says how often a phase runs more
+    than one; `prompt_tokens / admit_rows` is the share of the rows that
     is a prompt's, and A x P x `admit_phases` what the program would run
     with every phase at its full width.
 
@@ -399,14 +402,14 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
     neither counts its empty lanes as vacant). Each times the phase's
     steps: `lane_steps + vacant_lane_steps + blocked_lane_steps +
     spent_lane_steps == n_slots * steps` for every dispatch."""
-    from ray_tpu.models.llama_decode import admit_width
+    from ray_tpu.models import llama_decode
 
     total = sum(ph["steps"] for ph in phases)
     done = 0  # decode steps of this dispatch run so far
     last: Dict[int, int] = {}  # finishing request -> `done` at its last token
     admissions = prompt_tokens = prefix_tokens = lane_steps = 0
     plan_wait = lane_wait = first_plan = lead_steps = lead_phases = 0
-    admit_phases = admit_rows = stall = vacant = blocked = spent = 0
+    admit_phases = admit_pieces = admit_rows = stall = vacant = blocked = spent = 0
     for ph in phases:
         admissions += len(ph["admissions"])
         for _, req in ph["admissions"]:
@@ -431,7 +434,9 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
                 last[id(req)] = done
         if new:
             admit_phases += 1
-            admit_rows += admit_width(len(ph["admissions"]), variant[0]) * variant[1]
+            pieces = llama_decode.admit_pieces(len(ph["admissions"]), *variant)
+            admit_pieces += len(pieces)
+            admit_rows += sum(pieces) * variant[1]
             stall += riding - decoding
         idle = len(new) - decoding  # admitted here, no decode step owed here
         empty = n_slots - riding - idle
@@ -444,7 +449,7 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
               "finishing": len(last),
               "finish_wait_steps": sum(total - d for d in last.values()),
               "ctx_tokens": _ctx_tokens(phases), "prompt_pairs": _prompt_pairs(phases),
-              "admit_rows": admit_rows,
+              "admit_rows": admit_rows, "admit_pieces": admit_pieces,
               "admit_phases": admit_phases, "plan_wait_us": plan_wait,
               "lane_wait_us": lane_wait, "admitted_first_plan": first_plan,
               "admit_lead_steps": lead_steps, "admit_lead_phases": lead_phases,
@@ -808,9 +813,9 @@ class ContinuousBatchingEngine:
                    # key) pairs of the planned admissions' attention
                    "ctx_tokens": 0, "prompt_pairs": 0,
                    # token rows the device runs for the planned admissions,
-                   # padding included (P x a phase's admissions rounded up
-                   # to a power of two), and the phases that admit
-                   "admit_rows": 0, "admit_phases": 0,
+                   # padding included (P x the pieces of a phase's count),
+                   # the admission bodies run and the phases that admit
+                   "admit_rows": 0, "admit_pieces": 0, "admit_phases": 0,
                    # the lane account: lane-steps left empty with nobody
                    # waiting, with a request the pool refused, and taken
                    # by an admission that owes no decode step; with

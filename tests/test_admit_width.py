@@ -1,10 +1,12 @@
-"""An admitting phase runs at the width of its own admissions (ISSUE 42).
+"""An admitting phase runs at the width of its own admissions (ISSUE 42), as
+the binary pieces of their count (ISSUE 46).
 
 The paged macro-step's skeleton (`llama_decode.admit_phase`) hands the model's
-own admission the first w rows of a phase, w the smallest power of two that
-reaches the phase's last non-empty row, where it used to hand it all A. The
-four models' admissions are row-independent, so nothing a real row leaves
-behind may differ: CPU, float32, each model's tiny config.
+own admission the rows of a phase a piece at a time: `admit_pieces(n, A, P)`,
+3 rows as 2 + 1 where a piece has tokens enough to be worth its pass over the
+weights, one piece rounded up to a power of two where it has not (PR 42's), and
+never all A. The four models' admissions are row-independent, so nothing a
+real row leaves behind may differ: CPU, float32, each model's tiny config.
 
 Tolerance: 1e-4 of the largest entry, the one
 `test_a_padded_admission_is_each_prompt_admitted_alone` uses (a product over w
@@ -21,7 +23,7 @@ from ray_tpu.models import afmoe, granite_hybrid, llama, llama_decode, sarvam_ml
 from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
 
 RTOL = 1e-4
-A, P, BLOCK, MB, CHUNK = 4, 16, 4, 8, 4
+CHUNK = 4
 MODELS = {
     "llama": (llama, lambda: llama.LlamaConfig.tiny(dtype=jnp.float32, attn_impl="blockwise",
                                                      remat=False)),
@@ -30,8 +32,13 @@ MODELS = {
     "afmoe": (afmoe, lambda: afmoe.AfmoeConfig.tiny(dtype=jnp.float32)),
     "sarvam_mla": (sarvam_mla, lambda: sarvam_mla.SarvamMlaConfig.tiny(dtype=jnp.float32)),
 }
-# row i of the phase: its prompt's length and the lane it lands in (not its own index)
-LENGTHS, LANES = (13, 5, 16, 9), (2, 0, 3, 1)
+# (A, P, block, blocks a lane): rows too short to be worth a second pass over
+# the weights, PR 42's program, and the shortest of which one is worth a pass
+LONG_P = llama_decode.RIDGE_TOKENS
+SHORT, LONG = (4, 16, 4, 8), (8, LONG_P, 16, LONG_P // 16 + 1)
+# row i of the phase: its prompt's length in sixteenths of P and the lane it
+# lands in (not its own index)
+LENGTHS, LANES = (13, 5, 16, 9, 2, 11, 16, 7), (2, 0, 3, 1, 6, 4, 7, 5)
 
 
 @functools.lru_cache(maxsize=4)
@@ -41,15 +48,24 @@ def _model(name):
     return cfg, module.init_params(jax.random.PRNGKey(7), cfg), cfg.decode_module
 
 
-def _phase(n, vocab):
+@functools.lru_cache(maxsize=4)
+def _halves(name):
+    """The model's own admission and decode step, jitted once a model: every
+    case of a shape shares their compiles."""
+    cfg, _, D = _model(name)
+    return (jax.jit(functools.partial(D.admit_slots_paged, cfg=cfg, sampled=False)),
+            jax.jit(functools.partial(D.decode_step_slots_paged, cfg=cfg, sampled=False)))
+
+
+def _phase(n, vocab, A, P):
     """The plan arrays of one phase whose first `n` rows are real, as
     `_dispatch_macro` lays them out: (prompts, lengths, starts, slots, rems, seeds)."""
     rng = np.random.default_rng(42)
     prompts = np.zeros((A, P), np.int32)
     lengths, slots, rems = (np.zeros(A, np.int32) for _ in range(3))
     for i in range(n):
-        prompts[i, :LENGTHS[i]] = rng.integers(0, vocab, LENGTHS[i])
-        lengths[i], slots[i], rems[i] = LENGTHS[i], LANES[i], 5
+        lengths[i], slots[i], rems[i] = LENGTHS[i] * P // 16, LANES[i], 5
+        prompts[i, :lengths[i]] = rng.integers(0, vocab, lengths[i])
     return tuple(jnp.asarray(x) for x in (
         prompts, lengths, np.zeros(A, np.int32), slots, rems, np.zeros(A, np.uint32)))
 
@@ -62,24 +78,30 @@ def _close(got, want):
         assert np.array_equal(got, want)
 
 
-@pytest.mark.parametrize("n", [1, 2, 3, A])
+@pytest.mark.parametrize("shape,n", [(SHORT, 1), (SHORT, 2), (SHORT, 3), (SHORT, 4),
+                                     (LONG, 3), (LONG, 5), (LONG, 6), (LONG, 7)],
+                         ids=lambda v: "long" if v == LONG else "short" if v == SHORT else str(v))
 @pytest.mark.parametrize("name", sorted(MODELS))
-def test_a_phase_admitted_at_its_own_width_is_the_full_width_admission(name, n):
-    """n real rows of a phase A = 4 wide, through the macro-step (which runs
-    1, 2, 4 and 4 rows) and through the model's own admission at all four
-    rows: the real rows' first tokens, EVERY leaf of the cache (the pool or
-    latent pool, rings, recurrent state, positions, remaining: a padding row
-    writes nothing in either, so no entry is left out) and the next decode
-    step's logits at the admitted lanes are the same."""
+def test_a_phase_admitted_at_its_own_width_is_the_full_width_admission(name, shape, n):
+    """n real rows of a phase A wide, through the macro-step and through the
+    model's own admission at all A rows: the real rows' first tokens, EVERY
+    leaf of the cache (the pool or latent pool, rings, recurrent state,
+    positions, remaining: a padding row writes nothing in either, so no entry
+    is left out) and the next decode step's logits at the admitted lanes are
+    the same. Of A = 4 at P = 16 the macro-step runs ONE piece, of 1, 2, 4 and
+    4 rows, as the parent of PR 46 did; of A = 8 at rows of the ridge's length it
+    runs 3, 5, 6 and 7 rows as 2 + 1, 4 + 1, 4 + 2 and 4 + 2 + 1, each piece
+    behind the one before it, and no row that holds no prompt."""
+    A, P, block, MB = shape
     cfg, params, D = _model(name)
     tables = 1 + jnp.arange(A * MB, dtype=jnp.int32).reshape(A, MB)
     z = jnp.zeros((A,), jnp.int32)
     plan = (tables, jnp.zeros((A,), jnp.float32), z, jnp.ones((A,), jnp.float32),
             jnp.full((A, 1), -1, jnp.int32))
-    rows = _phase(n, cfg.vocab_size)
-    fresh = lambda: D.init_paged_cache(cfg, A, A * MB + 1, BLOCK)  # noqa: E731
+    rows = _phase(n, cfg.vocab_size, A, P)
+    fresh = lambda: D.init_paged_cache(cfg, A, A * MB + 1, block)  # noqa: E731
 
-    admit = jax.jit(functools.partial(D.admit_slots_paged, cfg=cfg, sampled=False))
+    admit, step = _halves(name)
     first_full, cache_full, feed_full = admit(params, *rows, fresh(), z, *plan)
 
     # one phase that admits and decodes nothing, then one that does neither
@@ -89,9 +111,14 @@ def test_a_phase_admitted_at_its_own_width_is_the_full_width_admission(name, n):
         params, fresh(), z, jnp.zeros((K,), jnp.int32), jnp.asarray([True, False]),
         *(per_phase(r) for r in rows), *(jnp.stack([p, p]) for p in plan))
 
+    pieces = llama_decode.admit_pieces(n, A, P)
+    if shape == SHORT:
+        assert pieces == (1 << (n - 1).bit_length(),)
+    else:
+        assert sum(pieces) == n and len(pieces) == bin(n).count("1")
     # the rows that ran give what they give at full width (a padding row among
     # them its garbage, which no host reads); the rows that did not run give 0
-    w = llama_decode.admit_width(n, A)
+    w = sum(pieces)
     assert np.array_equal(np.asarray(firsts[0, :w]), np.asarray(first_full[:w]))
     assert not np.asarray(firsts[0, w:]).any() and not np.asarray(firsts[1]).any()
     assert np.array_equal(np.asarray(feed), np.asarray(feed_full))
@@ -99,7 +126,6 @@ def test_a_phase_admitted_at_its_own_width_is_the_full_width_admission(name, n):
     for got, want in zip(jax.tree.leaves(cache), jax.tree.leaves(cache_full)):
         _close(got, want)
 
-    step = jax.jit(functools.partial(D.decode_step_slots_paged, cfg=cfg, sampled=False))
     logits, nxt, _ = step(params, cache, feed, *plan)
     logits_full, nxt_full, _ = step(params, cache_full, feed_full, *plan)
     lanes = list(LANES[:n])
@@ -107,35 +133,82 @@ def test_a_phase_admitted_at_its_own_width_is_the_full_width_admission(name, n):
     assert np.array_equal(np.asarray(nxt)[lanes], np.asarray(nxt_full)[lanes])
 
 
-def test_the_width_follows_the_last_real_row_not_the_count():
-    """`admit_width` rounds a phase's admissions up to a power of two, the
-    program's A at the most; the device takes the branch that reaches the
-    LAST non-empty row, so a real row behind an empty one (no plan of the
-    engine's makes one) is still admitted."""
-    assert [llama_decode.admit_width(n, 32) for n in (0, 1, 2, 3, 4, 5, 8, 9, 17, 32)] == [
-        1, 1, 2, 4, 4, 8, 8, 16, 32, 32]
-    assert [llama_decode.admit_width(n, 6) for n in (1, 2, 3, 4, 5, 6)] == [1, 2, 4, 4, 6, 6]
+@pytest.mark.parametrize("lanes", [4, 6, 8, 11, 32])
+def test_the_pieces_of_a_count(lanes):
+    """`admit_pieces` alone, plain Python: distinct powers of two, widest
+    first (`lanes` itself where it is none and one piece holds them all),
+    that hold the n prompts in no more rows than the one piece PR 42 ran; one
+    piece for a power of two, for a full program, for a phase that admits
+    nobody and wherever that piece costs no more than two passes over the
+    weights; with a longer row never more rows nor fewer pieces; from rows of
+    the ridge's length up the count itself, bit by bit."""
+    R = llama_decode.RIDGE_TOKENS
+    one = lambda n: min(1 << max(n - 1, 0).bit_length(), lanes)  # noqa: E731
+    buckets = [16 << i for i in range(9)]
+    assert buckets[0] < R < buckets[-1]
+    for n in range(lanes + 1):
+        before = None
+        for P in buckets:
+            pieces = llama_decode.admit_pieces(n, lanes, P)
+            assert all(w == lanes or w & (w - 1) == 0 for w in pieces)
+            assert list(pieces) == sorted(set(pieces), reverse=True)
+            assert max(n, 1) <= sum(pieces) <= one(n)
+            if n in (0, lanes) or n & (n - 1) == 0 or one(n) * P <= 2 * R:
+                assert pieces == (one(n),)
+            elif P >= R:  # every piece is worth its pass (5 of 6 lanes as 4 + 1)
+                assert sum(pieces) == n and len(pieces) == bin(n).count("1")
+            if before:
+                assert sum(pieces) <= sum(before) and len(pieces) >= len(before)
+            before = pieces
+    assert llama_decode.admit_pieces(3, 8, R // 2) == (4,)  # a one-row piece costs a pass: no gain
+    assert llama_decode.admit_pieces(5, 8, R // 2) == (4, 1)  # three rows dropped for it: a gain
+    assert llama_decode.admit_pieces(7, 8, R // 2) == (8,)
+    assert llama_decode.admit_pieces(3, 8, R) == (2, 1)
+    assert llama_decode.admit_pieces(7, 8, R) == (4, 2, 1)
+    assert llama_decode.admit_pieces(11, 16, R) == (8, 2, 1)
+    assert llama_decode.admit_pieces(11, 16, R // 4) == (8, 4)  # the remainder rounded up
+    assert llama_decode.admit_pieces(23, 32, 16) == (32,)
 
+
+def test_the_pieces_follow_the_last_real_row_not_the_count():
+    """The device runs the pieces that reach the LAST non-empty row, each on
+    the rows behind the piece before it, widest first, so a real row behind an
+    empty one (no plan of the engine's makes one) is still admitted; below the
+    ridge one piece, rounded up to a power of two, the program's A at the most."""
     seen = []
 
     def admit_rows(rows, carry):
         seen.append(rows[1].shape[0])
-        return rows[1] * 10, carry + rows[1].sum()
+        assert rows[0].shape[0] == rows[1].shape[0]
+        return rows[1] * 10, carry * 10 + rows[1].shape[0]  # the widths run, in their order
 
-    for lengths, want_first, want_carry in (
-            ([7, 0, 0, 0, 0], [70, 0, 0, 0, 0], 7),       # one row
-            ([7, 3, 0, 0, 0], [70, 30, 0, 0, 0], 10),     # two
-            ([0, 0, 5, 0, 0], [0, 0, 50, 0, 0], 5),       # a gap: four rows reach row 2
-            ([1, 1, 1, 1, 1], [10] * 5, 5),               # A itself, not a power of two
-            ([0, 0, 0, 0, 0], [0] * 5, 0)):               # flagged, nothing set: one row
+    def run(lengths, P, admits=True):
         lengths = jnp.asarray(lengths, jnp.int32)
         first, carry = jax.jit(lambda l: llama_decode.admit_phase(
-            admit_rows, jnp.asarray(True), (l * 2, l), jnp.asarray(0)))(lengths)
-        assert np.asarray(first).tolist() == want_first and int(carry) == want_carry
+            admit_rows, jnp.asarray(admits), (jnp.zeros((l.shape[0], P), jnp.int32), l),
+            jnp.asarray(0)))(lengths)
+        return np.asarray(first).tolist(), int(carry)
+
+    for lengths, want_carry in (
+            ([7, 0, 0, 0, 0], 1),       # one row
+            ([7, 3, 0, 0, 0], 2),       # two
+            ([0, 0, 5, 0, 0], 4),       # a gap: four rows reach row 2
+            ([1, 1, 1, 1, 1], 5),       # A itself, not a power of two
+            ([0, 0, 0, 0, 0], 1)):      # flagged, nothing set: one row
+        assert run(lengths, 16) == ([10 * n for n in lengths], want_carry)
     assert set(seen) == {1, 2, 4, 5}  # one body a width, each traced in every program
-    first, carry = llama_decode.admit_phase(
-        admit_rows, jnp.asarray(False), (lengths * 2, lengths + 3), jnp.asarray(11))
-    assert not np.asarray(first).any() and int(carry) == 11  # a phase that admits nothing
+    del seen[:]
+    for lengths, want_carry in (
+            ([7, 3, 2, 0, 0, 0, 0, 0], 21),          # 3 = 2 + 1
+            ([0, 0, 5, 0, 0, 0, 0, 0], 21),          # a gap: the second piece holds row 2
+            ([1, 2, 3, 4, 5, 0, 0, 0], 41),
+            ([1, 2, 3, 4, 5, 6, 0, 0], 42),
+            ([1, 2, 3, 4, 5, 6, 7, 0], 421),
+            ([1, 2, 3, 4, 5, 6, 7, 8], 8),
+            ([0, 0, 0, 0, 0, 0, 0, 0], 1)):
+        assert run(lengths, 1024) == ([10 * n for n in lengths], want_carry)
+    assert seen == [8, 4, 2, 1] * 7  # log2(A) + 1 bodies a program, widest first
+    assert run([1, 2, 3], 1024, admits=False) == ([0, 0, 0], 0)  # a phase that admits nothing
 
 
 # what the parent commit's `_plan` made of the arrivals below (PR 41, f45e62d): per
@@ -209,7 +282,9 @@ def test_one_program_a_prompt_bucket():
             assert all(r.done.wait(120) and r.error is None for r in reqs)
         assert eng._macro_paged_fn._cache_size() == 2
         m = eng.metrics()
-        # 13 admissions; the burst of three ran four rows, every other its own count
+        # 13 admissions; the burst of three ran four rows (a row of 32 is not
+        # worth a pass over the weights), every other its own count
         assert m["admit_rows"] == (4 + 1 + 4 + 2 + 1) * 32 + 2 * 16
+        assert m["admit_pieces"] == m["admit_phases"] == 6
     finally:
         eng.shutdown()

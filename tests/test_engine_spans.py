@@ -280,8 +280,8 @@ def test_finish_wait_steps_on_a_hand_built_plan():
         "prompt_pairs": 45 + 78 + (96 + 78) + 15,
         # no compiled variant given: no rows
         "admit_rows": 0,
-        # phases 0, 1 and 3 admit
-        "admit_phases": 3,
+        # phases 0, 1 and 3 admit, each through one admission body
+        "admit_phases": 3, "admit_pieces": 3,
         # submit to first seen: a and b 0.25 s, c 0.5 s, d 0.125 s; c alone
         # waited on for a lane, 9.5 to 10.25; the three others in their first plan
         "plan_wait_us": 250_000 + 250_000 + 500_000 + 125_000, "lane_wait_us": 750_000,
@@ -292,16 +292,20 @@ def test_finish_wait_steps_on_a_hand_built_plan():
         # b is live through c's admission and through d's
         "stall_lane_phases": 2}
     # three phases admit, 2, 1 and 1 prompts: each runs at the width of its
-    # own admissions (a power of two), not of the program, so in a program of
+    # own admissions (`admit_pieces`), not of the program, so in a program of
     # (2, 16) they are 2 x 16 + 1 x 16 + 1 x 16 = 64 rows of the 3 x 2 x 16 =
     # 96 that every phase at full width would be, and 64 as well in one of
     # (4, 16); a program one lane wide cannot run wider than that
     assert llm_engine._dispatch_counts(phases, variant=(2, 16)) == {**counts, "admit_rows": 64}
     assert llm_engine._dispatch_counts(phases, variant=(4, 16))["admit_rows"] == 64
     assert llm_engine._dispatch_counts(phases, variant=(1, 16))["admit_rows"] == 48
-    # three prompts a phase take four rows, five take eight: 4 x 32 + 8 x 32
+    # three prompts a phase take four rows, five take eight, where a row is
+    # too short to be worth a pass over the weights: 4 x 32 + 8 x 32 in two
+    # bodies; at P = 1024 they run as 2 + 1 and 4 + 1 rows, in four
     wide = [{"steps": 0, "admissions": [(i, d) for i in range(n)], "takes": []} for n in (3, 5)]
-    assert llm_engine._dispatch_counts(wide, variant=(8, 32))["admit_rows"] == 384
+    short, long = (llm_engine._dispatch_counts(wide, variant=(8, P)) for P in (32, 1024))
+    assert (short["admit_rows"], short["admit_pieces"], short["admit_phases"]) == (384, 2, 2)
+    assert (long["admit_rows"], long["admit_pieces"], long["admit_phases"]) == (8 * 1024, 4, 2)
     # the lane account of three lanes: lane 2 vacant through phases 0 and 2
     # (2 + 2 steps), blocked through 1 and 3 (4 + 4), lane 0 spent on d (4)
     lanes = llm_engine._dispatch_counts(phases, n_slots=3)
@@ -333,11 +337,13 @@ def _drive(eng, prompts_and_answers):
         assert (counts["lane_steps"] + counts["vacant_lane_steps"] + counts["blocked_lane_steps"]
                 + counts["spent_lane_steps"] == eng.n_slots * counts["steps"])
         # the admissions' rows with their padding, as the device runs them:
-        # a phase's admissions rounded up to a power of two, times P; A is the
-        # lanes' bucket and bounds every phase
+        # the pieces of a phase's count, times P; A is the lanes' bucket and
+        # bounds every phase
         assert A == 1 << (eng.n_slots - 1).bit_length()
         admitting = [len(ph["admissions"]) for ph in phases if ph["admissions"]]
-        assert counts["admit_rows"] == P * sum(D.admit_width(n, A) for n in admitting)
+        pieces = [D.admit_pieces(n, A, P) for n in admitting]
+        assert counts["admit_rows"] == P * sum(map(sum, pieces))
+        assert counts["admit_pieces"] == sum(map(len, pieces)) >= counts["admit_phases"]
         assert counts["prompt_tokens"] <= counts["admit_rows"] <= A * P * len(admitting)
         seen.append(([(ph["steps"], [(r, t) for _, r, t in ph["takes"]]) for ph in phases], counts))
         eng._dispatch_macro(phases, counts)
@@ -395,6 +401,7 @@ def test_ctx_chunks_follow_the_planned_contexts():
     # four prompts in buckets of 256, 32, 512 and 32 positions at the least
     assert (m2["admit_rows"] - m["admit_rows"] == sum(c["admit_rows"] for _, c in seen)
             >= 256 + 32 + 512 + 32 > 0)
+    assert m2["admit_pieces"] - m["admit_pieces"] == sum(c["admit_pieces"] for _, c in seen) > 0
 
 
 # ------------------------------------ the lane and the wait accounts (ISSUE 41)
@@ -593,9 +600,9 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
             assert any(n == "engine.resolve" and s <= start and end <= e for n, s, e, _ in top)
 
     dispatches = [stats for name, _, _, stats in top if name == "engine.dispatch"]
-    account = ("admit_phases", "vacant_lane_steps", "blocked_lane_steps", "spent_lane_steps",
-               "plan_wait_us", "lane_wait_us", "admitted_first_plan", "admit_lead_steps",
-               "admit_lead_phases", "stall_lane_phases")  # ISSUE 41
+    account = ("admit_phases", "admit_pieces", "vacant_lane_steps", "blocked_lane_steps",
+               "spent_lane_steps", "plan_wait_us", "lane_wait_us", "admitted_first_plan",
+               "admit_lead_steps", "admit_lead_phases", "stall_lane_phases")  # ISSUE 41, 46
     keys = {"seq", "phases", "steps", "admissions", "A", "P", "prompt_tokens", "prefix_tokens",
             "lane_steps", "finishing", "finish_wait_steps", "ctx_chunks", "ctx_tokens",
             "prompt_pairs", "admit_rows", *account}
@@ -622,13 +629,13 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
     # A is the lanes' bucket in every dispatch; P names the program
     assert all(d["P"] in (16, 32) and d["A"] == 2 for d in dispatches)
     # the admissions' rows, padding included, as the device runs them: whole
-    # rows of P, one or two a phase that admits (its admissions rounded up to
-    # a power of two), none where the plan admits nobody (PR 40, ISSUE 42)
+    # rows of P, one or two a phase that admits (the pieces of its count),
+    # none where the plan admits nobody (PR 40, ISSUE 42, ISSUE 46)
     assert sum(d["admit_rows"] for d in dispatches) == diff["admit_rows"]
     for d in dispatches:
         n, rest = divmod(d["admit_rows"], d["P"])
         assert rest == 0 and d["admissions"] <= n <= d["A"] * d["admit_phases"]
-        assert d["admit_phases"] <= n and (n > 0) == (d["admissions"] > 0)
+        assert d["admit_phases"] <= d["admit_pieces"] <= n and (n > 0) == (d["admissions"] > 0)
         assert d["prompt_tokens"] <= d["admit_rows"] <= d["A"] * d["P"] * d["admit_phases"]
     # six requests on two lanes: some phase admitted one prompt alone, and ran one row
     assert diff["admit_rows"] < sum(d["A"] * d["P"] * d["admit_phases"] for d in dispatches)

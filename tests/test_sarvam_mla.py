@@ -521,7 +521,7 @@ def test_other_models_dispatches_and_refusals_are_what_they_were():
     assert set(_dispatch_counts([], False, 16)) == {
         "phases", "steps", "admissions", "prompt_tokens", "prefix_tokens", "lane_steps",
         "finishing", "finish_wait_steps", "ctx_chunks", "ctx_tokens", "prompt_pairs",
-        "admit_rows",
+        "admit_rows", "admit_pieces",
         # the wait and lead accounts of every model's dispatch (PR 41); the lane
         # account's three keys come with an engine's `n_slots`
         "admit_phases", "plan_wait_us", "lane_wait_us", "admitted_first_plan",
