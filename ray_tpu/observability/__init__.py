@@ -46,7 +46,8 @@ ENGINE_SPANS = (
                         # as 2 + 1 rows, not A x P a phase; admit_pieces is the admission bodies run);
                         # the lane account (PR 41): vacant_lane_steps, blocked_lane_steps, spent_lane_steps, which with
                         # lane_steps are n_slots x steps; the wait account over its admissions: plan_wait_us, lane_wait_us,
-                        # admitted_first_plan; the lead and the stall: admit_lead_steps, admit_lead_phases, stall_lane_phases
+                        # admitted_first_plan; the lead and the stall: admit_lead_steps, admit_lead_phases, stall_lane_phases;
+                        # short, q (PR 47): 1 and the vacancy quantum in steps where a lane left vacant closed the plan
     "engine.resolve",   # `_resolve()` of dispatch `seq`: the fetch, then delivery of its tokens to the requests; stats:
                         # seq, the plan counts of its dispatch over again, the three accounts among them (a trace that
                         # starts after a dispatch still holds them), and the dispatch's device counters where the decode

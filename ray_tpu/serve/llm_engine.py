@@ -63,11 +63,39 @@ engine to wrap, so this is the green-field TPU-native equivalent
   lane is re-admitted at the very next phase and does not idle to a
   fixed chunk boundary; a phase's unused steps are skipped with
   lax.cond, so a shrunk phase costs only its real steps.
+- A PLAN ENDS WHERE A LANE IS LEFT VACANT. The loop sees an arrival only
+  between two dispatches, so a plan is as long as what the lanes hold
+  allows. While every lane is live or somebody waits, nobody new could
+  be let in anyway, and the plan runs to `macro_phases` phases. The
+  first phase that opens with a lane free and nobody waiting for it
+  (`vacant` of the lane account) is the plan's LAST, and it decodes at
+  most the vacancy quantum (`_quantum`): the steps that take about
+  `VACANT_PLAN_S` at the pace the engine measures for its own decode
+  steps at resolve. The same compiled program runs it (the other
+  phases' `steps` are 0 and they admit nobody), `short_plans` of
+  `metrics()` counts such plans and the spans carry `short` and `q`.
+  Whoever is let in stalls every resident for the length of an
+  admission, at every quantum where arrivals keep coming; so the rule
+  holds only once the engine has timed three admissions and while the
+  last few are shorter than `VACANT_PLAN_S` themselves (prompts of a
+  few hundred tokens). Only an engine that has not yet timed a single
+  decode step closes its plans at `chunk` steps: such plans are what
+  brings the first reading, and a deployment's warm-up is over them.
+  Where one takes longer (prompts of a thousand tokens and more) the
+  plan keeps its length, lets arrivals in together at its next
+  dispatch, and a resident's decode steps run undisturbed.
 - ONE BEHIND. Tokens are fetched one macro-step behind the dispatch
   frontier: while macro-step N executes, the host plans and dispatches
   N+1 from counters, then resolves N's tokens (the only blocking reads,
   `_resolve_inner`) overlapped with N+1's compute. A request is handed
   back when its last token has been resolved, not before (ROADMAP W2).
+  The depth stays two under short plans too: with one dispatch queued
+  behind the running one the device never waits for the host's plan,
+  dispatch and resolve (tens of milliseconds an iteration), and an
+  arrival is seen after the rest of the running dispatch and runs after
+  the one queued behind it, about a quantum and a half while lanes
+  stand vacant. A depth of one would see it half a quantum sooner and
+  leave the device idle for a host iteration between any two dispatches.
 - SPANS. Every stretch of a loop iteration runs under one of
   `observability.ENGINE_SPANS` (`engine.idle`, `engine.intake`,
   `engine.plan`, `engine.dispatch`, `engine.resolve`, and
@@ -97,6 +125,7 @@ import queue
 import threading
 import time
 from collections import deque
+from statistics import median_high
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -319,6 +348,17 @@ def _suffix_len(req: "_Request") -> int:
     return len(req.prompt) - req._start
 
 
+# How long a plan decodes once a lane stands vacant and nobody waits (the
+# vacancy quantum of `_plan`, in seconds of the engine's own measured
+# decode steps). Long enough that the host is back with the next dispatch
+# before the device runs dry (an iteration of `_loop_macro` costs the host
+# 7-18 ms of plan and dispatch and 3-8 ms of resolve: ledger, PR 46,
+# `engine.dispatch_lead_ms`, `engine.deliver_lag_ms`), and no longer, since
+# an arrival waits a quantum and a half to run. Also what an admission may
+# take for the rule to hold at all: one that stalls the residents for
+# longer than the quantum costs them more than it saves whoever arrives.
+VACANT_PLAN_S = 0.040
+
 # the counts of `_dispatch_counts` that `engine.metrics()` sums as they are
 _PLAN_SUMS = ("ctx_tokens", "prompt_pairs", "admit_rows", "admit_pieces", "admit_phases",
               "vacant_lane_steps", "blocked_lane_steps", "spent_lane_steps",
@@ -401,7 +441,11 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
     waiting, `blocked` ones a request the pool refused (a phase that says
     neither counts its empty lanes as vacant). Each times the phase's
     steps: `lane_steps + vacant_lane_steps + blocked_lane_steps +
-    spent_lane_steps == n_slots * steps` for every dispatch."""
+    spent_lane_steps == n_slots * steps` for every dispatch.
+
+    `short` is 1 where a vacant lane closed the plan (`_plan` marks that
+    phase, its last, with the vacancy quantum it decoded by) and `q` that
+    quantum in steps; both 0 in every other plan."""
     from ray_tpu.models import llama_decode
 
     total = sum(ph["steps"] for ph in phases)
@@ -443,6 +487,7 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
         spent += idle * ph["steps"]
         blocked += ph.get("blocked", 0) * ph["steps"]
         vacant += ph.get("vacant", empty - ph.get("blocked", 0)) * ph["steps"]
+    q = phases[-1].get("short", 0) if phases else 0
     counts = {"phases": len(phases), "steps": total, "admissions": admissions,
               "prompt_tokens": prompt_tokens, "prefix_tokens": prefix_tokens,
               "lane_steps": lane_steps,
@@ -453,7 +498,8 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
               "admit_phases": admit_phases, "plan_wait_us": plan_wait,
               "lane_wait_us": lane_wait, "admitted_first_plan": first_plan,
               "admit_lead_steps": lead_steps, "admit_lead_phases": lead_phases,
-              "stall_lane_phases": stall}
+              "stall_lane_phases": stall,
+              "short": int(q > 0), "q": q}
     if n_slots:
         counts.update(vacant_lane_steps=vacant, blocked_lane_steps=blocked,
                       spent_lane_steps=spent)
@@ -744,6 +790,14 @@ class ContinuousBatchingEngine:
         self._queue: "queue.Queue[_Request]" = queue.Queue()
         self._waiting: deque = deque()       # planner-side FIFO (loop thread only)
         self._t_plan = 0.0                   # start of the plan at hand (perf_counter)
+        # the vacancy quantum's measurements (`_quantum`, `_time_dispatch`):
+        # when the oldest dispatch in flight started on the device, where the
+        # host knows it (it was shipped to an idle device, or the host was on
+        # time for the resolve before it), and the last few readings of a
+        # decode step's and of an admitting phase's time
+        self._t_started: Optional[float] = None
+        self._step_s: deque = deque(maxlen=5)
+        self._admit_s: deque = deque(maxlen=5)
         self._pending: deque = deque()       # fetch frontier: tagged entries
         self._planned: Dict[int, Dict[str, int]] = {}  # seq -> plan counts, until resolved
         # KV-plane plumbing: inbound migrations (fetched payloads
@@ -792,7 +846,7 @@ class ContinuousBatchingEngine:
         # per-process crash ring: per-dispatch events land here with ONE
         # ring write (no allocation, no pickle, no RPC — lint-pinned)
         self._fr = _flightrec.get_recorder()
-        self._m = {"dispatches": 0, "tokens_out": 0, "slot_steps": 0,
+        self._m = {"dispatches": 0, "short_plans": 0, "tokens_out": 0, "slot_steps": 0,
                    "useful_slot_steps": 0, "wasted_steps": 0,
                    "prefill_tokens": 0, "reused_prefix_tokens": 0,
                    "kv_blocks_peak_in_use": 0, "shed_queue_full": 0,
@@ -1668,7 +1722,19 @@ class ContinuousBatchingEngine:
         end them early — _deliver/_repair reconcile). Mutates engine
         bookkeeping to the post-macro-step state: slot assignments,
         per-request remaining counters, evictions, block
-        allocations/frees."""
+        allocations/frees.
+
+        The plan's length follows what the lanes hold. While every lane
+        is live or somebody waits (`vacant` 0: full, or `blocked` on the
+        pool) an arrival could not be let in, so the phases run on to
+        macro_phases. The first phase `_admit_waiting` closes with a lane
+        vacant is the plan's last and decodes at most the vacancy quantum
+        (`_quantum`, from the engine's own measured step time; the phase
+        carries it as `short`): the loop is back at its intake that soon,
+        and whoever arrived meanwhile is admitted by the plan after the
+        one already queued behind this dispatch. Where the quantum is 0
+        (admissions not yet timed, or that take longer than a quantum)
+        no phase closes the plan: it is the parent's."""
         self._plan_start()
         if self.draft_params is not None:
             return self._plan_spec()
@@ -1681,9 +1747,12 @@ class ContinuousBatchingEngine:
             if not live and not admissions:
                 break
             snapshot = self._snapshot_phase()
+            # a lane nobody waits for closes the plan, a quantum on (none:
+            # letting somebody in would stall the residents for longer)
+            q = self._quantum() if empty["vacant"] else 0
             # adaptive chunk: decode exactly to the next scheduling event
             # (a slot finishing) so the freed lane re-admits immediately
-            steps = min([self.chunk] + [r._remaining for _, r in live]) if live else 0
+            steps = min([q or self.chunk] + [r._remaining for _, r in live]) if live else 0
             # invariant: steps <= every live remaining, so each live slot
             # takes exactly `steps` real tokens this phase
             takes = []
@@ -1700,8 +1769,44 @@ class ContinuousBatchingEngine:
                         # the _deliver stop/cancel paths free them
                         self._free_request_blocks(r)
             phases.append({"steps": steps, "admissions": admissions,
-                           "takes": takes, **empty, **snapshot})
+                           "takes": takes, **empty, **({"short": q} if q else {}), **snapshot})
+            if q:
+                break
         return phases or None
+
+    def _quantum(self) -> int:
+        """The vacancy quantum in decode steps: what takes about
+        `VACANT_PLAN_S` at the median of the last few readings of a decode
+        step's time (`_time_dispatch`), between 1 and `chunk`; `chunk`
+        until there is a reading (short plans are what brings the first:
+        a dispatch of decode steps alone behind another). From there 0, no
+        short plan at all, until three admitting phases have been timed (a
+        median of fewer is one reading's word: an engine that has not
+        seen what its admissions cost plans as the parent did, so a
+        closed loop's ramp, one lone client and then a burst, is the
+        parent's) and while the median of the last few took longer than
+        `VACANT_PLAN_S`: each arrival let in costs every resident that
+        long, quantum after quantum."""
+        if not self._step_s:
+            return self.chunk
+        if len(self._admit_s) < 3 or median_high(self._admit_s) > VACANT_PLAN_S:
+            return 0
+        return min(self.chunk, max(1, int(np.ceil(VACANT_PLAN_S / median_high(self._step_s)))))
+
+    def _time_dispatch(self, planned: Dict[str, int], ran_s: float) -> None:
+        """Read a decode step's or an admitting phase's time off a dispatch
+        that ran for `ran_s`: one that admitted nobody spent it on its
+        decode steps; one that admitted spent on each admitting phase what
+        its decode steps, at the measured pace, leave over. `_resolve_next`
+        reads only where the host knows both ends (so whatever the plans'
+        length, and whether the gate of `_quantum` is open or shut, the
+        next admissions are read and can move it); `_quantum` takes
+        medians over what is left of the host's jitter."""
+        steps, admitting = planned.get("steps", 0), planned.get("admit_phases", 0)
+        if admitting and self._step_s:
+            self._admit_s.append((ran_s - steps * median_high(self._step_s)) / admitting)
+        elif steps and not admitting:
+            self._step_s.append(ran_s / steps)
 
     def _rounds_for(self, tokens_owed: int) -> int:
         """Verify rounds expected to cover `tokens_owed` tokens, from
@@ -1864,6 +1969,7 @@ class ContinuousBatchingEngine:
         self._record_dispatch(t0, time.perf_counter(), self._macro_paged_fn,
                               riders)
         self._m["dispatches"] += 1
+        self._m["short_plans"] += counts.get("short", 0)
         for ph in phases:
             live = sum(t for _, _, t in ph["takes"])
             self._m["slot_steps"] += ph["steps"] * self.n_slots
@@ -1880,6 +1986,8 @@ class ContinuousBatchingEngine:
             self._m["ctx_chunks"] += _ctx_chunks(phases, self._ctx_chunk)
             self._m["span_chunks"] += sum(ph["steps"] for ph in phases) * -(
                 -self._mb * self.block_size // self._ctx_chunk)
+        if not self._pending:  # an idle device starts on it now
+            self._t_started = time.perf_counter()
         self._pending.append(entry)
 
     def _shed_expired(self) -> None:
@@ -1944,15 +2052,26 @@ class ContinuousBatchingEngine:
             gc.collect()
 
     def _resolve_next(self) -> None:
-        """Resolve the oldest dispatch in flight, under its span."""
+        """Resolve the oldest dispatch in flight, under its span, and time
+        it for the vacancy quantum: it ran from `_t_started` (the end of
+        the resolve before it or, shipped to an idle device, its own
+        dispatch) to this resolve's end, if the host was on time for both
+        (each still ran when the host came to fetch it: an interval that
+        holds a compile, a collection or a device left idle by a late host
+        is no reading)."""
         entry = self._pending.popleft()
+        planned = self._planned.pop(entry[4], {})
+        on_time = entry[2] is not None and not entry[2].is_ready()
         # the span repeats its dispatch's plan counts: a trace that starts
         # after a dispatch still knows what its execution was planned to do
-        with self._span(_SPAN_RESOLVE, seq=entry[4],
-                        **self._planned.pop(entry[4], {})) as span:
+        with self._span(_SPAN_RESOLVE, seq=entry[4], **planned) as span:
             counted = self._resolve(entry)
             if counted:  # the dispatch's device counters, as the span's stats
                 span.set_metadata(**counted)
+        now = time.perf_counter()
+        if on_time and self._t_started is not None:
+            self._time_dispatch(planned, now - self._t_started)
+        self._t_started = now if on_time and self._pending else None
 
     def _loop_macro(self) -> None:
         # every stretch of an iteration runs under one of ENGINE_SPANS, so

@@ -230,13 +230,17 @@ PARENT_VARIANTS = [(4, 32), (2, 32), (1, 16), (1, 16)]
 
 
 def test_the_plan_is_the_parents_for_a_scripted_arrival_sequence():
-    """`_plan` is not this PR's: three lanes, chunk 4, four phases a plan,
-    ten requests arriving in three batches, then a plan with no arrival. The
-    phases, their admissions and their takes are the parent's to the entry;
-    what changed is the program each plan names: A is the lanes' bucket, 4,
-    whatever a phase admits, P alone follows the plan (its longest prompt's
-    bucket), and a plan that admits nobody keeps the P of the dispatch before
-    it, so no program is compiled for it."""
+    """Three lanes, chunk 4, four phases a plan, ten requests arriving in
+    three batches, then plans with no arrival. While every lane is live or
+    somebody waits the phases, their admissions and their takes are the
+    parent's to the entry; since ISSUE 47 the first phase that opens with a
+    lane vacant is the plan's last, so the second plan is the parent's first
+    three phases and what the parent ran behind them goes out in the plans
+    after it, a quantum (`chunk` here: no step has been timed) at a time.
+    What ISSUE 42 changed is the program each plan names: A is the lanes'
+    bucket, 4, whatever a phase admits, P alone follows the plan (its longest
+    prompt's bucket), and a plan that admits nobody keeps the P of the
+    dispatch before it, so no program is compiled for it."""
     cfg = llama.LlamaConfig.tiny(dtype=jnp.float32, attn_impl="blockwise", remat=False)
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
     eng = ContinuousBatchingEngine(params, cfg, n_slots=3, chunk=4, macro_phases=4, max_len=64,
@@ -246,9 +250,8 @@ def test_the_plan_is_the_parents_for_a_scripted_arrival_sequence():
     rng = np.random.default_rng(5)
     batches = [[(9, 6), (17, 12), (12, 7), (30, 2), (5, 3)],
                [(21, 2), (8, 2), (14, 8), (6, 5)],
-               [(11, 24)],
-               []]
-    plans, variants = [], []
+               [(11, 24)]] + [[]] * 5
+    plans, variants, vacant = [], [], []
     for batch in batches:
         for n, new in batch:
             eng.submit(rng.integers(0, cfg.vocab_size, n).tolist(), new)
@@ -258,10 +261,21 @@ def test_the_plan_is_the_parents_for_a_scripted_arrival_sequence():
         eng._last_P = variants[-1][1]  # as `_dispatch_macro` leaves it
         plans.append([(ph["steps"], [(s, len(r.prompt)) for s, r in ph["admissions"]],
                        [(s, t) for s, _, t in ph["takes"]]) for ph in phases])
+        vacant.append([ph["vacant"] for ph in phases])
     assert eng._plan() is None
-    assert plans == PARENT_PLANS
-    assert variants == [(4, P) for _, P in PARENT_VARIANTS]
-    # one program a P bucket: the parent named three for these four plans
+    # the first plan's lanes are full or waited for until its fourth phase,
+    # the second's until its third: the parent's, phase for phase, to there
+    assert plans[0] == PARENT_PLANS[0] and vacant[0] == [0, 0, 0, 1]
+    assert plans[1] == PARENT_PLANS[1][:3] and vacant[1] == [0, 0, 1]
+    # the third arrival finds two lanes free and a resident with 3 steps owed
+    # (the parent's fourth phase of its second plan, now run beside it), then
+    # decodes alone, 4 steps a plan where the parent planned 16 and 7
+    assert plans[2] == [(3, [(1, 11)], [(0, 3), (1, 3)])]
+    assert plans[3:] == [[(4, [], [(1, 4)])]] * 5
+    assert sum(n for plan in plans[2:] for n, _, _ in plan) == 23
+    assert all(v[-1] > 0 and not any(v[:-1]) for v in vacant)
+    assert variants == [(4, 32), (4, 32)] + [(4, 16)] * 6
+    # one program a P bucket: the parent named three for its four plans
     assert len(set(variants)) == 2 < len(set(PARENT_VARIANTS))
 
 

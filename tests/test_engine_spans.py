@@ -290,7 +290,12 @@ def test_finish_wait_steps_on_a_hand_built_plan():
         # two admitting phases (0 and 1; phase 2 admits nobody)
         "admit_lead_steps": 2 + 8, "admit_lead_phases": 1 + 2,
         # b is live through c's admission and through d's
-        "stall_lane_phases": 2}
+        "stall_lane_phases": 2,
+        # the last phase is not one a vacant lane closed
+        "short": 0, "q": 0}
+    # a plan whose last phase a vacant lane closed says so, with its quantum
+    closed = llm_engine._dispatch_counts(phases[:1] + [{**phases[2], "vacant": 1, "short": 3}])
+    assert (closed["short"], closed["q"]) == (1, 3)
     # three phases admit, 2, 1 and 1 prompts: each runs at the width of its
     # own admissions (`admit_pieces`), not of the program, so in a program of
     # (2, 16) they are 2 x 16 + 1 x 16 + 1 x 16 = 64 rows of the 3 x 2 x 16 =
@@ -564,6 +569,7 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
     cfg, params = _cfg_params()
     eng = ContinuousBatchingEngine(params, cfg, n_slots=2, chunk=4,
                                    macro_phases=4, max_len=64, block_size=8)
+    eng._quantum = lambda: 2  # held: what this CPU's timings would make of it is not the test's
     rng = np.random.default_rng(0)
     prompt = lambda n: rng.integers(0, cfg.vocab_size, n).tolist()  # noqa: E731
     try:
@@ -579,6 +585,9 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
             # one radix-cache hit: the warm-up prompt's first block, reused
             reqs.append(eng.submit(warm[:8] + prompt(6), 4))
             assert all(r.done.wait(120) for r in reqs)
+            # a seventh alone on the two lanes: one stands vacant, so its plans are short
+            reqs.append(eng.submit(prompt(9), 6))
+            assert reqs[-1].done.wait(120)
             assert all(r.error is None for r in reqs)
             m1 = eng.metrics()
             time.sleep(0.15)  # the last dispatch resolves, then a few idle iterations
@@ -605,8 +614,14 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
                "admit_lead_steps", "admit_lead_phases", "stall_lane_phases")  # ISSUE 41, 46
     keys = {"seq", "phases", "steps", "admissions", "A", "P", "prompt_tokens", "prefix_tokens",
             "lane_steps", "finishing", "finish_wait_steps", "ctx_chunks", "ctx_tokens",
-            "prompt_pairs", "admit_rows", *account}
+            "prompt_pairs", "admit_rows", "short", "q", *account}
     assert all(set(d) == keys for d in dispatches)
+    # a plan a vacant lane closed (ISSUE 47): it says so, with the quantum its
+    # one last phase decoded by, and `short_plans` counts them (the seventh
+    # request's at the least)
+    assert sum(d["short"] for d in dispatches) == m1["short_plans"] - m0["short_plans"] > 0
+    assert all((d["short"], d["q"] > 0) in ((0, False), (1, True)) for d in dispatches)
+    assert all(d["steps"] <= d["q"] or d["phases"] > 1 for d in dispatches if d["short"])
     diff = {k: m1[k] - m0[k] for k in ("dispatches", "slot_steps", "useful_slot_steps",
                                        "prefill_tokens", "reused_prefix_tokens",
                                        "requests_completed", "ctx_chunks", "span_chunks",

@@ -525,7 +525,9 @@ def test_other_models_dispatches_and_refusals_are_what_they_were():
         # the wait and lead accounts of every model's dispatch (PR 41); the lane
         # account's three keys come with an engine's `n_slots`
         "admit_phases", "plan_wait_us", "lane_wait_us", "admitted_first_plan",
-        "admit_lead_steps", "admit_lead_phases", "stall_lane_phases"}
+        "admit_lead_steps", "admit_lead_phases", "stall_lane_phases",
+        # whether a vacant lane closed the plan, and the quantum it decoded by (PR 47)
+        "short", "q"}
     assert set(_dispatch_counts([], False, 16, n_slots=4)) - set(_dispatch_counts([], False, 16)) == {
         "vacant_lane_steps", "blocked_lane_steps", "spent_lane_steps"}
     assert set(_dispatch_counts([], True, 16, window=8)) - set(_dispatch_counts([], False, 16)) == {
