@@ -48,7 +48,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama_decode import _qkv
+from ray_tpu.models.llama import _qkv
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.normalization import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -377,7 +377,7 @@ def moe_ffn(m, p, cfg: AfmoeConfig, live=None):
 def qkvg(layer, a, cfg: AfmoeConfig):
     """a (..., d) -> q (..., h, hd) and k (..., kvh, hd), each RMS-normed
     over the head size; v (..., kvh, hd); the output gate (..., h * hd).
-    The three products are llama_decode._qkv's, for its reason: the head
+    The three products are llama._qkv's, for its reason: the head
     split stays out of the product, so the stacked weights are read where
     they lie."""
     q, k, v = _qkv(a, layer, cfg)

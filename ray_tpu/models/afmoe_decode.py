@@ -1,7 +1,7 @@
 """The AFMoE decoder on the paged serving path: window layers whose cache
 stops growing, an expert layer in the decode step.
 
-The macro-step is models/llama_decode.macro_step_slots_paged, handed this
+The macro-step is models/paged.macro_step_slots_paged, handed this
 module's admission and decode step and this module's cache pytree:
 
   k, v      (full layers, n_blocks, bs, kvh * hd)  the block pool, for the
@@ -44,8 +44,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import afmoe as M
-from ray_tpu.models import llama_decode as L
+from ray_tpu.models import paged
 from ray_tpu.models.afmoe import FULL, SLIDING, AfmoeConfig
+from ray_tpu.ops.blockwise_attention import NEG_INF
 from ray_tpu.ops.rope import apply_rope
 
 F32 = jnp.float32
@@ -126,7 +127,7 @@ def attend_decode_ring(q, wk, wv, wi, pos, scale):
     vc = jax.lax.dynamic_index_in_dim(wv, wi, 0, keepdims=False)
     s = jnp.einsum("bhc,bsc->bhs", qx, kc, preferred_element_type=F32) * scale
     held = ring_slots_held(pos, window)
-    p = jax.nn.softmax(jnp.where(held[:, None, :], s, L.NEG_INF), axis=-1)
+    p = jax.nn.softmax(jnp.where(held[:, None, :], s, NEG_INF), axis=-1)
     o = jnp.einsum("bhs,bsc->bhc", p.astype(vc.dtype), vc, preferred_element_type=F32)
     o = (o.reshape(B, kvh, h // kvh, kvh, hd) * own.astype(F32)).sum(axis=3)
     return o.reshape(B, h * hd).astype(q.dtype)
@@ -153,10 +154,10 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
         with jax.named_scope(M.SCOPE_WINDOW):
             q, k, v, gate = M.qkvg(layer, a, cfg)
             q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-            wk = L.write_lane_rows(wk, wi, ring_rows(k.reshape(A, P, -1), lengths, window),
-                                  slots, valid)
-            wv = L.write_lane_rows(wv, wi, ring_rows(v.reshape(A, P, -1), lengths, window),
-                                  slots, valid)
+            wk = paged.write_lane_rows(wk, wi, ring_rows(k.reshape(A, P, -1), lengths, window),
+                                       slots, valid)
+            wv = paged.write_lane_rows(wv, wi, ring_rows(v.reshape(A, P, -1), lengths, window),
+                                       slots, valid)
             out = M.gated_out(M.sequence_attention(q, k, v, cfg, window), gate, layer, cfg)
         return out, (k_full, v_full, wk, wv)
 
@@ -164,7 +165,7 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
         k_full, v_full, wk, wv = carry
         with jax.named_scope(M.SCOPE_FULL):
             q, k, v, gate = M.qkvg(layer, a, cfg)
-            k_full, v_full = L.write_admission_kv(
+            k_full, v_full = paged.write_admission_kv(
                 k_full, v_full, fi, k.reshape(A, P, -1), v.reshape(A, P, -1),
                 adm_tables, starts, valid)
             out = M.gated_out(M.sequence_attention(q, k, v, cfg, None), gate, layer, cfg)
@@ -179,7 +180,7 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     # float32 over this vocabulary would be gigabytes
     x_last = jnp.take_along_axis(
         x, (jnp.maximum(lengths, 1) - 1)[:, None, None], axis=1)[:, 0, :]
-    first, pos, rem, feed, rng = L.finish_admission(
+    first, pos, rem, feed, rng = paged.finish_admission(
         M.logits_of(params, x_last, cfg), cache, feed, valid, lengths, starts,
         slots, rems, seeds, temps, top_ks, top_ps, stop_ids, sampled)
     cache = {"k": k_full, "v": v_full, "wk": wk, "wv": wv, "counts": cache["counts"],
@@ -216,11 +217,11 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
         k_full, v_full, wk, wv, counts = carry
         with jax.named_scope(M.SCOPE_FULL):
             q, k, v, gate = M.qkvg(layer, a[:, None, :], cfg)
-            k_full, v_full = L.write_decode_kv(
+            k_full, v_full = paged.write_decode_kv(
                 k_full, v_full, fi, k.reshape(B, 1, -1), v.reshape(B, 1, -1),
                 tables, pos, active)
             out = M.gated_out(
-                L.attend_decode_paged(q[:, 0], k_full, v_full, fi, tables, pos, active, scale),
+                paged.attend_decode_paged(q[:, 0], k_full, v_full, fi, tables, pos, active, scale),
                 gate[:, 0], layer, cfg)
         return out, (k_full, v_full, wk, wv, counts)
 
@@ -234,7 +235,7 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
         (cache["k"], cache["v"], cache["wk"], cache["wv"], cache["counts"]), cfg,
         {SLIDING: window_mixer, FULL: full_mixer}, experts)
     logits = M.logits_of(params, x, cfg)
-    nxt, new_pos, remaining, rng = L.finish_decode_step(
+    nxt, new_pos, remaining, rng = paged.finish_decode_step(
         logits, cache, active, temps, top_ks, top_ps, stop_ids, sampled)
     cache = {"k": k_full, "v": v_full, "wk": wk, "wv": wv, "counts": counts,
              "pos": new_pos, "remaining": remaining, "rng": rng}
@@ -243,12 +244,12 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
 
 def macro_step_slots_paged(params, cache, feed, *plan, chunk: int, cfg: AfmoeConfig,
                            sampled: bool = True):
-    """llama_decode's macro-step skeleton with this model's two halves, under
+    """models/paged.py's macro-step skeleton with this model's two halves, under
     the skeleton's name (a device trace finds the program by it), and one
     return more: DEVICE_COUNTERS of this dispatch alone, (3,) int32, for the
     engine to fetch beside the tokens."""
     cache = {**cache, "counts": jnp.zeros_like(cache["counts"])}
-    toks, firsts, feed, cache = L.macro_step_slots_paged(
+    toks, firsts, feed, cache = paged.macro_step_slots_paged(
         params, cache, feed, *plan, chunk=chunk, cfg=cfg, sampled=sampled,
         admit=admit_slots_paged, decode_step=decode_step_slots_paged)
     return toks, firsts, feed, cache, cache["counts"] + 0
@@ -257,20 +258,20 @@ def macro_step_slots_paged(params, cache, feed, *plan, chunk: int, cfg: AfmoeCon
 @functools.lru_cache(maxsize=16)
 def jitted_macro_step_slots_paged(cfg: AfmoeConfig, chunk: int, sampled: bool = True):
     return jax.jit(
-        L._bind(macro_step_slots_paged, chunk=chunk, cfg=cfg, sampled=sampled),
+        paged._bind(macro_step_slots_paged, chunk=chunk, cfg=cfg, sampled=sampled),
         donate_argnums=(1,),
     )
 
 
 # ------------------------------------------------------- static generation
 def _generate(params, prompt, cfg: AfmoeConfig, n_new: int):
-    return L.generate_through_paged_cache(
+    return paged.generate_through_paged_cache(
         init_paged_cache, admit_slots_paged, decode_step_slots_paged, params, prompt, cfg, n_new)
 
 
 @functools.lru_cache(maxsize=64)
 def _jitted_generate(cfg: AfmoeConfig, n_new: int):
-    return jax.jit(L._bind(_generate, cfg=cfg, n_new=n_new))
+    return jax.jit(paged._bind(_generate, cfg=cfg, n_new=n_new))
 
 
 def generate(params, prompt, cfg: AfmoeConfig, max_new_tokens: int):

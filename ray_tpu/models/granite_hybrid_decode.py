@@ -1,7 +1,7 @@
 """The hybrid decoder on the paged serving path: a lane holds recurrent
 state beside its K/V blocks.
 
-The macro-step is models/llama_decode.macro_step_slots_paged, handed this
+The macro-step is models/paged.macro_step_slots_paged, handed this
 module's admission and decode step and this module's cache pytree:
 
   k, v      (attention layers, n_blocks, bs, kvh * hd)  the block pool, for
@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import granite_hybrid as G
-from ray_tpu.models import llama_decode as L
+from ray_tpu.models import paged
 from ray_tpu.models.granite_hybrid import ATTENTION, MAMBA, GraniteHybridConfig
 
 
@@ -84,15 +84,15 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     def mamba_mixer(layer, mi, a, carry):
         k_full, v_full, conv, ssm = carry
         out, tail, h = G.mamba_sequence(layer, a, lengths, cfg)
-        conv = L.write_lane_rows(conv, mi, tail, slots, valid, lane_axis=2)
-        ssm = L.write_lane_rows(ssm, mi, h, slots, valid)
+        conv = paged.write_lane_rows(conv, mi, tail, slots, valid, lane_axis=2)
+        ssm = paged.write_lane_rows(ssm, mi, h, slots, valid)
         return out, (k_full, v_full, conv, ssm)
 
     def attn_mixer(layer, ai, a, carry):
         k_full, v_full, conv, ssm = carry
         with jax.named_scope(G.SCOPE_ATTN):
             q, k, v = G.qkv(layer, a, cfg)
-            k_full, v_full = L.write_admission_kv(
+            k_full, v_full = paged.write_admission_kv(
                 k_full, v_full, ai, k.reshape(A, P, -1), v.reshape(A, P, -1),
                 adm_tables, starts, valid)
             out = G.causal_attention(q, k, v, cfg) @ layer["wo"]
@@ -106,7 +106,7 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     # float32 over this vocabulary would be gigabytes
     x_last = jnp.take_along_axis(
         x, (jnp.maximum(lengths, 1) - 1)[:, None, None], axis=1)[:, 0, :]
-    first, pos, rem, feed, rng = L.finish_admission(
+    first, pos, rem, feed, rng = paged.finish_admission(
         G.logits_of(params, x_last, cfg), cache, feed, valid, lengths, starts,
         slots, rems, seeds, temps, top_ks, top_ps, stop_ids, sampled)
     cache = {"k": k_full, "v": v_full, "conv": conv, "ssm": ssm,
@@ -122,7 +122,7 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
     tail and state as they are, aims its K/V write at the null block, and
     its logits mean nothing (its mixer output is not computed on a TPU). The
     attention layers read the lanes' contexts out of the flat pool in place,
-    through llama_decode.attend_decode_paged: work follows the longest live
+    through paged.attend_decode_paged: work follows the longest live
     lane, not the table span."""
     B = tokens.shape[0]
     pos = cache["pos"]
@@ -142,10 +142,10 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
         k_full, v_full, conv, ssm = carry
         with jax.named_scope(G.SCOPE_ATTN):
             q, k, v = G.qkv(layer, a[:, None, :], cfg)
-            k_full, v_full = L.write_decode_kv(
+            k_full, v_full = paged.write_decode_kv(
                 k_full, v_full, ai, k.reshape(B, 1, -1), v.reshape(B, 1, -1),
                 tables, pos, active)
-            out = L.attend_decode_paged(
+            out = paged.attend_decode_paged(
                 q[:, 0], k_full, v_full, ai, tables, pos, active,
                 cfg.attention_multiplier) @ layer["wo"]
         return out, (k_full, v_full, conv, ssm)
@@ -155,7 +155,7 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
         (cache["k"], cache["v"], cache["conv"], cache["ssm"]), cfg,
         {MAMBA: mamba_mixer, ATTENTION: attn_mixer})
     logits = G.logits_of(params, x, cfg)
-    nxt, new_pos, remaining, rng = L.finish_decode_step(
+    nxt, new_pos, remaining, rng = paged.finish_decode_step(
         logits, cache, active, temps, top_ks, top_ps, stop_ids, sampled)
     cache = {"k": k_full, "v": v_full, "conv": conv, "ssm": ssm,
              "pos": new_pos, "remaining": remaining, "rng": rng}
@@ -165,24 +165,24 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
 @functools.lru_cache(maxsize=16)
 def jitted_macro_step_slots_paged(cfg: GraniteHybridConfig, chunk: int,
                                   sampled: bool = True):
-    """llama_decode's macro-step skeleton with this model's two halves;
+    """models/paged.py's macro-step skeleton with this model's two halves;
     the program keeps the skeleton's name."""
     return jax.jit(
-        L._bind(L.macro_step_slots_paged, chunk=chunk, cfg=cfg, sampled=sampled,
-                admit=admit_slots_paged, decode_step=decode_step_slots_paged),
+        paged._bind(paged.macro_step_slots_paged, chunk=chunk, cfg=cfg, sampled=sampled,
+                    admit=admit_slots_paged, decode_step=decode_step_slots_paged),
         donate_argnums=(1,),
     )
 
 
 # ------------------------------------------------------- static generation
 def _generate(params, prompt, cfg: GraniteHybridConfig, n_new: int):
-    return L.generate_through_paged_cache(
+    return paged.generate_through_paged_cache(
         init_paged_cache, admit_slots_paged, decode_step_slots_paged, params, prompt, cfg, n_new)
 
 
 @functools.lru_cache(maxsize=64)
 def _jitted_generate(cfg: GraniteHybridConfig, n_new: int):
-    return jax.jit(L._bind(_generate, cfg=cfg, n_new=n_new))
+    return jax.jit(paged._bind(_generate, cfg=cfg, n_new=n_new))
 
 
 def generate(params, prompt, cfg: GraniteHybridConfig, max_new_tokens: int):

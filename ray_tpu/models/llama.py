@@ -190,6 +190,38 @@ def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     }
 
 
+def _qkv(a, layer, cfg: LlamaConfig):
+    """The paged serving programs' q / k / v projections (llama_decode's two
+    halves and its speculative mirror, afmoe's attention): a (..., d_model) against
+    one layer's `wq`, `wk`, `wv`, split into heads AFTER the products.
+    Returns q (..., h, hd), k and v (..., kvh, hd), each `a @ w` bit for bit.
+
+    The barrier keeps the head split out of the product. Without it the TPU
+    compiler folds `.reshape(..., h, hd)` into the matmul and reads the
+    weight as (head, head_dim, d_model); to feed that it copies every
+    layer's slice out of the stacked parameter in EVERY decode step and
+    transposes the three whole stacks in every dispatch. Compiled for a
+    v5e at Mistral-7B's widths, 16 layers, 4 lanes (compiled only, PR 32;
+    tests/test_tpu_compile.py holds it): three multi-output fusions a step
+    that write 16 x bf16[1,4096,4096] + 2 x 16 x bf16[1,4096,1024], 805 MB
+    (2.22 ms of an 11.69 ms step on the chip, PR 30's trace, segment
+    `slice`), three to six copies of a bf16[16,4096,*] stack a dispatch,
+    and 1.56 / 1.87 GB of temporaries at (A, P) = (1, 16) / (4, 512); with
+    it the product reads `params["layers"]["wq"]` where it lies, as `wo`
+    and the MLP's do, and the temporaries are 0.002 / 0.27 GB. The barrier
+    alone is NOT enough: with the sixteen layers unrolled every form that
+    drops the stack copies makes the compiler copy the whole K pool
+    (bf16[16,1025,16,8,128], 537 MB) twice a decode step, so the decode
+    step and the admission walk their layers in a rolled scan. Do not
+    simplify either away without running that compile test."""
+    h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    lead = a.shape[:-1]
+    q, k, v = jax.lax.optimization_barrier(
+        (a @ layer["wq"], a @ layer["wk"], a @ layer["wv"]))
+    return (q.reshape(*lead, h, hd), k.reshape(*lead, kvh, hd),
+            v.reshape(*lead, kvh, hd))
+
+
 def _attention(q, k, v, cfg: LlamaConfig, mesh=None, rules=None):
     impl = cfg.attn_impl
     if impl == "auto":

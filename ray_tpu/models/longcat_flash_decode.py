@@ -3,7 +3,7 @@ planes a layer, an expanded admission and an absorbed decode step over each
 sublayer's own plane, and the shortcut branch's output carried across the
 second half of every layer.
 
-The macro-step is models/llama_decode.macro_step_slots_paged, handed this
+The macro-step is models/paged.macro_step_slots_paged, handed this
 module's admission and decode step and this module's cache pytree:
 
   latent    (2 x layers, n_blocks, bs, ROW)  models/sarvam_mla_decode.py's
@@ -33,8 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import afmoe, afmoe_decode
-from ray_tpu.models import llama_decode as L
 from ray_tpu.models import longcat_flash as M
+from ray_tpu.models import paged
 from ray_tpu.models import sarvam_mla_decode as S
 from ray_tpu.models.longcat_flash import LongcatFlashConfig
 
@@ -80,7 +80,7 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     # the head at each row's last real position only
     x_last = jnp.take_along_axis(
         x, (jnp.maximum(lengths, 1) - 1)[:, None, None], axis=1)[:, 0, :]
-    first, pos, rem, feed, rng = L.finish_admission(
+    first, pos, rem, feed, rng = paged.finish_admission(
         afmoe.logits_of(params, x_last, cfg), cache, feed, valid, lengths, starts,
         slots, rems, seeds, temps, top_ks, top_ps, stop_ids, sampled)
     cache = {"latent": pool, "counts": cache["counts"], "pos": pos, "remaining": rem, "rng": rng}
@@ -111,7 +111,7 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
         params, M.embed_tokens(params, tokens, cfg), (cache["latent"], cache["counts"]), cfg,
         mixer, experts)
     logits = afmoe.logits_of(params, x, cfg)
-    nxt, new_pos, remaining, rng = L.finish_decode_step(
+    nxt, new_pos, remaining, rng = paged.finish_decode_step(
         logits, cache, active, temps, top_ks, top_ps, stop_ids, sampled)
     cache = {"latent": pool, "counts": counts, "pos": new_pos, "remaining": remaining, "rng": rng}
     return logits, nxt, cache
@@ -119,11 +119,11 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
 
 def macro_step_slots_paged(params, cache, feed, *plan, chunk: int, cfg: LongcatFlashConfig,
                            sampled: bool = True):
-    """llama_decode's macro-step skeleton with this model's two halves, under
+    """models/paged.py's macro-step skeleton with this model's two halves, under
     the skeleton's name (a device trace finds the program by it), and
     DEVICE_COUNTERS of this dispatch alone as a fifth return."""
     cache = {**cache, "counts": jnp.zeros_like(cache["counts"])}
-    toks, firsts, feed, cache = L.macro_step_slots_paged(
+    toks, firsts, feed, cache = paged.macro_step_slots_paged(
         params, cache, feed, *plan, chunk=chunk, cfg=cfg, sampled=sampled,
         admit=admit_slots_paged, decode_step=decode_step_slots_paged)
     return toks, firsts, feed, cache, cache["counts"] + 0
@@ -132,20 +132,20 @@ def macro_step_slots_paged(params, cache, feed, *plan, chunk: int, cfg: LongcatF
 @functools.lru_cache(maxsize=16)
 def jitted_macro_step_slots_paged(cfg: LongcatFlashConfig, chunk: int, sampled: bool = True):
     return jax.jit(
-        L._bind(macro_step_slots_paged, chunk=chunk, cfg=cfg, sampled=sampled),
+        paged._bind(macro_step_slots_paged, chunk=chunk, cfg=cfg, sampled=sampled),
         donate_argnums=(1,),
     )
 
 
 # ------------------------------------------------------- static generation
 def _generate(params, prompt, cfg: LongcatFlashConfig, n_new: int):
-    return L.generate_through_paged_cache(
+    return paged.generate_through_paged_cache(
         init_paged_cache, admit_slots_paged, decode_step_slots_paged, params, prompt, cfg, n_new)
 
 
 @functools.lru_cache(maxsize=64)
 def _jitted_generate(cfg: LongcatFlashConfig, n_new: int):
-    return jax.jit(L._bind(_generate, cfg=cfg, n_new=n_new))
+    return jax.jit(paged._bind(_generate, cfg=cfg, n_new=n_new))
 
 
 def generate(params, prompt, cfg: LongcatFlashConfig, max_new_tokens: int):

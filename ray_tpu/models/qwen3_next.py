@@ -90,7 +90,7 @@ import jax.numpy as jnp
 from ray_tpu.models import afmoe
 from ray_tpu.models.afmoe import MOE, _dense, _layer_at, make_swiglu, moe_ffn
 from ray_tpu.models.granite_hybrid import causal_conv, conv_step, runs_of, scan_runs
-from ray_tpu.models.llama_decode import rows_a_piece
+from ray_tpu.models.paged import rows_a_piece
 from ray_tpu.ops.normalization import rms_norm
 from ray_tpu.ops.rope import apply_partial_rope, rope_frequencies
 

@@ -2,7 +2,7 @@
 state a head beside its K/V blocks, and every layer is an expert layer that
 holds a part of its experts.
 
-The macro-step is models/llama_decode.macro_step_slots_paged, handed this
+The macro-step is models/paged.macro_step_slots_paged, handed this
 module's admission and decode step and this module's cache pytree, the
 hybrid decoder's kind (models/granite_hybrid_decode.py) with other rows:
 
@@ -45,9 +45,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import afmoe
-from ray_tpu.models import llama_decode as L
+from ray_tpu.models import paged
 from ray_tpu.models import qwen3_next as M
-from ray_tpu.models.afmoe_decode import DEVICE_COUNTERS  # noqa: F401  (the engine reads it here)
+from ray_tpu.models.afmoe_decode import DEVICE_COUNTERS  # noqa: F401  (the engine reads it here: paged.py)
 from ray_tpu.models.granite_hybrid import live_rows
 from ray_tpu.models.qwen3_next import FULL, LINEAR, Qwen3NextConfig
 
@@ -93,15 +93,15 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     def linear_mixer(layer, li, a, carry):
         k_full, v_full, conv, state = carry
         out, tail, S = M.linear_sequence(layer, a, lengths, cfg)
-        conv = L.write_lane_rows(conv, li, tail, slots, valid, lane_axis=2)
-        state = L.write_lane_rows(state, li, S, slots, valid)
+        conv = paged.write_lane_rows(conv, li, tail, slots, valid, lane_axis=2)
+        state = paged.write_lane_rows(state, li, S, slots, valid)
         return out, (k_full, v_full, conv, state)
 
     def full_mixer(layer, fi, a, carry):
         k_full, v_full, conv, state = carry
         with jax.named_scope(M.SCOPE_ATTN):
             q, k, v, gate = M.qkvg(layer, a, cos, sin, None, cfg)
-            k_full, v_full = L.write_admission_kv(
+            k_full, v_full = paged.write_admission_kv(
                 k_full, v_full, fi, k.reshape(A, P, -1), v.reshape(A, P, -1),
                 adm_tables, starts, valid)
             out = afmoe.gated_out(afmoe.sequence_attention(q, k, v, cfg, None), gate, layer, cfg)
@@ -115,7 +115,7 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     # the head at each row's last real position only
     x_last = jnp.take_along_axis(
         x, (jnp.maximum(lengths, 1) - 1)[:, None, None], axis=1)[:, 0, :]
-    first, pos, rem, feed, rng = L.finish_admission(
+    first, pos, rem, feed, rng = paged.finish_admission(
         M.logits_of(params, x_last, cfg), cache, feed, valid, lengths, starts,
         slots, rems, seeds, temps, top_ks, top_ps, stop_ids, sampled)
     cache = {"k": k_full, "v": v_full, "conv": conv, "state": state, "counts": cache["counts"],
@@ -149,12 +149,12 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
         k_full, v_full, conv, state, counts = carry
         with jax.named_scope(M.SCOPE_ATTN):
             q, k, v, gate = M.qkvg(layer, a[:, None, :], cos, sin, pos[:, None], cfg)
-            k_full, v_full = L.write_decode_kv(
+            k_full, v_full = paged.write_decode_kv(
                 k_full, v_full, fi, k.reshape(B, 1, -1), v.reshape(B, 1, -1),
                 tables, pos, active)
             out = afmoe.gated_out(
-                L.attend_decode_paged(q[:, 0], k_full, v_full, fi, tables, pos, active,
-                                      cfg.head_dim ** -0.5),
+                paged.attend_decode_paged(q[:, 0], k_full, v_full, fi, tables, pos, active,
+                                          cfg.head_dim ** -0.5),
                 gate[:, 0], layer, cfg)
         return out, (k_full, v_full, conv, state, counts)
 
@@ -168,7 +168,7 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
         (cache["k"], cache["v"], cache["conv"], cache["state"], cache["counts"]), cfg,
         {LINEAR: linear_mixer, FULL: full_mixer}, experts)
     logits = M.logits_of(params, x, cfg)
-    nxt, new_pos, remaining, rng = L.finish_decode_step(
+    nxt, new_pos, remaining, rng = paged.finish_decode_step(
         logits, cache, active, temps, top_ks, top_ps, stop_ids, sampled)
     cache = {"k": k_full, "v": v_full, "conv": conv, "state": state, "counts": counts,
              "pos": new_pos, "remaining": remaining, "rng": rng}
@@ -177,11 +177,11 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
 
 def macro_step_slots_paged(params, cache, feed, *plan, chunk: int, cfg: Qwen3NextConfig,
                            sampled: bool = True):
-    """llama_decode's macro-step skeleton with this model's two halves, under
+    """models/paged.py's macro-step skeleton with this model's two halves, under
     the skeleton's name (a device trace finds the program by it), and
     DEVICE_COUNTERS of this dispatch alone as a fifth return."""
     cache = {**cache, "counts": jnp.zeros_like(cache["counts"])}
-    toks, firsts, feed, cache = L.macro_step_slots_paged(
+    toks, firsts, feed, cache = paged.macro_step_slots_paged(
         params, cache, feed, *plan, chunk=chunk, cfg=cfg, sampled=sampled,
         admit=admit_slots_paged, decode_step=decode_step_slots_paged)
     return toks, firsts, feed, cache, cache["counts"] + 0
@@ -190,20 +190,20 @@ def macro_step_slots_paged(params, cache, feed, *plan, chunk: int, cfg: Qwen3Nex
 @functools.lru_cache(maxsize=16)
 def jitted_macro_step_slots_paged(cfg: Qwen3NextConfig, chunk: int, sampled: bool = True):
     return jax.jit(
-        L._bind(macro_step_slots_paged, chunk=chunk, cfg=cfg, sampled=sampled),
+        paged._bind(macro_step_slots_paged, chunk=chunk, cfg=cfg, sampled=sampled),
         donate_argnums=(1,),
     )
 
 
 # ------------------------------------------------------- static generation
 def _generate(params, prompt, cfg: Qwen3NextConfig, n_new: int):
-    return L.generate_through_paged_cache(
+    return paged.generate_through_paged_cache(
         init_paged_cache, admit_slots_paged, decode_step_slots_paged, params, prompt, cfg, n_new)
 
 
 @functools.lru_cache(maxsize=64)
 def _jitted_generate(cfg: Qwen3NextConfig, n_new: int):
-    return jax.jit(L._bind(_generate, cfg=cfg, n_new=n_new))
+    return jax.jit(paged._bind(_generate, cfg=cfg, n_new=n_new))
 
 
 def generate(params, prompt, cfg: Qwen3NextConfig, max_new_tokens: int):

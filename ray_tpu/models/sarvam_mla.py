@@ -55,7 +55,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.afmoe import (  # the FFN half is that model's, called not copied
     DENSE, MOE, _dense, _layer_at, logits_of, make_moe, make_swiglu, moe_ffn, swiglu)
-from ray_tpu.models.llama_decode import rows_a_piece
+from ray_tpu.models.paged import rows_a_piece
 from ray_tpu.ops.normalization import rms_norm
 from ray_tpu.ops.rope import apply_rope, yarn_frequencies, yarn_mscale
 
@@ -214,7 +214,7 @@ def project(layer, a, cos, sin, positions, cfg: SarvamMlaConfig):
     """a (R, T, d) at `positions` (R, T) or None (0..T-1) -> q_nope (R, T, h,
     nope), q_rope (R, T, h, rope) with its RoPE on, and the cache row (R, T,
     latent_row) = [N_kv(c) | RoPE(N(k_r))]. The head split stays out of the
-    products (llama_decode._qkv says why).
+    products (llama._qkv says why).
 
     What the layer holds says which of the family's forms it is: with `w_qa`
     the query is compressed, q = W_qb N_q(W_qa a) (`q_lora_rank`); without

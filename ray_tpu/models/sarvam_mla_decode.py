@@ -2,7 +2,7 @@
 rows, an expanded admission and an absorbed decode step, an expert layer that
 holds a part of its experts.
 
-The macro-step is models/llama_decode.macro_step_slots_paged, handed this
+The macro-step is models/paged.macro_step_slots_paged, handed this
 module's admission and decode step and this module's cache pytree:
 
   latent    (layers, n_blocks, bs, ROW)  the block pool: ONE row a position
@@ -42,9 +42,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import afmoe
-from ray_tpu.models import llama_decode as L
+from ray_tpu.models import paged
 from ray_tpu.models import sarvam_mla as M
-from ray_tpu.models.afmoe_decode import DEVICE_COUNTERS  # noqa: F401  (the engine reads it here)
+from ray_tpu.models.afmoe_decode import DEVICE_COUNTERS  # noqa: F401  (the engine reads it here: paged.py)
 from ray_tpu.models.sarvam_mla import SarvamMlaConfig
 
 # the pool holds latent rows, not keys and values: serve/llm_engine.py refuses
@@ -82,8 +82,8 @@ def admit_mixer(layer, plane, a, pool, cos, sin, adm_tables, starts, valid, cfg)
     way, their cache rows written to plane `plane` of the pool."""
     out, rows = M.sequence_mixer(layer, a, cos, sin, cfg)
     with jax.named_scope(M.SCOPE_CTX):
-        pool, _ = L.write_admission_kv(pool, None, plane, _padded(rows, cfg), None,
-                                       adm_tables, starts, valid)
+        pool, _ = paged.write_admission_kv(pool, None, plane, _padded(rows, cfg), None,
+                                           adm_tables, starts, valid)
     return out, pool
 
 
@@ -95,9 +95,9 @@ def decode_mixer(layer, plane, a, pool, cos, sin, tables, pos, active, cfg):
         q_nope, q_rope, row = M.project(layer, a[:, None, :], cos, sin, pos[:, None], cfg)
         q = _padded(jnp.concatenate([M.absorb_q(layer, q_nope[:, 0]), q_rope[:, 0]], axis=-1), cfg)
     with jax.named_scope(M.SCOPE_CTX):
-        pool, _ = L.write_decode_kv(pool, None, plane, _padded(row, cfg), None, tables, pos, active)
-        o_lat = L.attend_decode_paged(q, pool, None, plane, tables, pos, active, cfg.sm_scale,
-                                      v_cols=r)
+        pool, _ = paged.write_decode_kv(pool, None, plane, _padded(row, cfg), None, tables, pos, active)
+        o_lat = paged.attend_decode_paged(q, pool, None, plane, tables, pos, active, cfg.sm_scale,
+                                          v_cols=r)
     with jax.named_scope(M.SCOPE_PROJ):
         out = M.absorbed_out(layer, o_lat.reshape(B, cfg.n_heads, r), cfg) @ layer["wo"]
     return out, pool
@@ -127,7 +127,7 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     # the head at each row's last real position only
     x_last = jnp.take_along_axis(
         x, (jnp.maximum(lengths, 1) - 1)[:, None, None], axis=1)[:, 0, :]
-    first, pos, rem, feed, rng = L.finish_admission(
+    first, pos, rem, feed, rng = paged.finish_admission(
         afmoe.logits_of(params, x_last, cfg), cache, feed, valid, lengths, starts,
         slots, rems, seeds, temps, top_ks, top_ps, stop_ids, sampled)
     cache = {"latent": pool, "counts": cache["counts"], "pos": pos, "remaining": rem, "rng": rng}
@@ -157,7 +157,7 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
         params, M.embed_tokens(params, tokens, cfg), (cache["latent"], cache["counts"]), cfg,
         mixer, experts)
     logits = afmoe.logits_of(params, x, cfg)
-    nxt, new_pos, remaining, rng = L.finish_decode_step(
+    nxt, new_pos, remaining, rng = paged.finish_decode_step(
         logits, cache, active, temps, top_ks, top_ps, stop_ids, sampled)
     cache = {"latent": pool, "counts": counts, "pos": new_pos, "remaining": remaining, "rng": rng}
     return logits, nxt, cache
@@ -165,11 +165,11 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
 
 def macro_step_slots_paged(params, cache, feed, *plan, chunk: int, cfg: SarvamMlaConfig,
                            sampled: bool = True):
-    """llama_decode's macro-step skeleton with this model's two halves, under
+    """models/paged.py's macro-step skeleton with this model's two halves, under
     the skeleton's name (a device trace finds the program by it), and
     DEVICE_COUNTERS of this dispatch alone as a fifth return."""
     cache = {**cache, "counts": jnp.zeros_like(cache["counts"])}
-    toks, firsts, feed, cache = L.macro_step_slots_paged(
+    toks, firsts, feed, cache = paged.macro_step_slots_paged(
         params, cache, feed, *plan, chunk=chunk, cfg=cfg, sampled=sampled,
         admit=admit_slots_paged, decode_step=decode_step_slots_paged)
     return toks, firsts, feed, cache, cache["counts"] + 0
@@ -178,20 +178,20 @@ def macro_step_slots_paged(params, cache, feed, *plan, chunk: int, cfg: SarvamMl
 @functools.lru_cache(maxsize=16)
 def jitted_macro_step_slots_paged(cfg: SarvamMlaConfig, chunk: int, sampled: bool = True):
     return jax.jit(
-        L._bind(macro_step_slots_paged, chunk=chunk, cfg=cfg, sampled=sampled),
+        paged._bind(macro_step_slots_paged, chunk=chunk, cfg=cfg, sampled=sampled),
         donate_argnums=(1,),
     )
 
 
 # ------------------------------------------------------- static generation
 def _generate(params, prompt, cfg: SarvamMlaConfig, n_new: int):
-    return L.generate_through_paged_cache(
+    return paged.generate_through_paged_cache(
         init_paged_cache, admit_slots_paged, decode_step_slots_paged, params, prompt, cfg, n_new)
 
 
 @functools.lru_cache(maxsize=64)
 def _jitted_generate(cfg: SarvamMlaConfig, n_new: int):
-    return jax.jit(L._bind(_generate, cfg=cfg, n_new=n_new))
+    return jax.jit(paged._bind(_generate, cfg=cfg, n_new=n_new))
 
 
 def generate(params, prompt, cfg: SarvamMlaConfig, max_new_tokens: int):

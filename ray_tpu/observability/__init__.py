@@ -42,7 +42,7 @@ ENGINE_SPANS = (
                         # ctx_tokens, prompt_pairs (what the decode steps' and the admissions' attention has to do),
                         # past_window_lane_steps (a model with sliding-window layers only), admit_rows, admit_pieces, admit_phases
                         # (A is the lanes' power-of-two bucket in every dispatch and P names the program; admit_rows is what the
-                        # device runs: P x the pieces of each admitting phase's count, `llama_decode.admit_pieces`, 3 admissions
+                        # device runs: P x the pieces of each admitting phase's count, `models/paged.admit_pieces`, 3 admissions
                         # as 2 + 1 rows, not A x P a phase; admit_pieces is the admission bodies run);
                         # the lane account (PR 41): vacant_lane_steps, blocked_lane_steps, spent_lane_steps, which with
                         # lane_steps are n_slots x steps; the wait account over its admissions: plan_wait_us, lane_wait_us,

@@ -3,7 +3,7 @@
 Host-side machinery behind the continuous-batching engine's paged mode:
 the block allocator (kv_blocks), the radix prefix cache (prefix_cache)
 and the sampling-parameter plumbing (sampling). Device-side paged
-attention lives in models/llama_decode.py; these modules never import
+attention lives in models/paged.py; these modules never import
 jax — they are pure host bookkeeping that compiles block tables and
 sampling plans into the i32/f32 program arguments the device programs
 consume.

@@ -10,7 +10,7 @@ and this module is that seam:
 - MIGRATION: a prefill replica finishes a request's prompt pass (one
   macro-step admission that samples the first token), lifts the
   request's KV blocks out of the paged pool as ONE pair of device
-  arrays (models/llama_decode.gather_kv_blocks), and ships them through
+  arrays (models/paged.gather_kv_blocks), and ships them through
   the PR-12 zero-copy object plane with ONE put per handoff —
   never per-block serialization. The decode replica fetches with ONE
   get (dlpack, zero-copy on colocated hosts), scatters the slices into
@@ -150,10 +150,10 @@ def export_kv_blocks(cache: Dict[str, Any], blocks: Sequence[int],
     import jax.numpy as jnp
 
     import ray_tpu
-    from ray_tpu.models import llama_decode as D
+    from ray_tpu.models import paged
 
     ids = pad_block_ids(blocks)
-    k, v = D.jitted_gather_kv_blocks()(cache, jnp.asarray(ids))
+    k, v = paged.jitted_gather_kv_blocks()(cache, jnp.asarray(ids))
     ref = ray_tpu.put({"k": k, "v": v, "n": len(blocks)})
     if rid:
         try:

@@ -4,7 +4,7 @@ The host half of real sampling: a validated, immutable parameter set
 that rides a request from serve/llm.py through the engine into the
 macro plan, where it is compiled into the per-phase f32/i32 plan
 arrays (temperature/top_k/top_p per slot, stop-token id rows padded
-with -1) that models/llama_decode.sample_tokens consumes device-side.
+with -1) that models/paged.sample_tokens consumes device-side.
 
 Greedy is temperature == 0.0 (the default), which keeps every
 pre-sampling caller's behavior bit-identical: sample_tokens lowers to

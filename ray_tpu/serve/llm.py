@@ -2,8 +2,9 @@
 
 The packaged form of the TPU LLM-serving shape (the reference serves
 LLMs through external engines inside replicas — vLLM in its examples;
-here the engine is the jitted prefill + device-side decode loop from
-models/llama_decode). Concurrent requests coalesce through
+here the engine is the jitted prefill + device-side decode loop of the
+model's decode module, `cfg.decode_module.generate`: models/llama_decode's
+for a LlamaConfig). Concurrent requests coalesce through
 @serve.batch; within a batch, prompts are grouped by length so each
 group runs one prefill + one lax.scan decode with static shapes and no
 padding/masking complications. Shape churn is bounded by rounding
@@ -23,7 +24,7 @@ repeat traffic lands on the replica whose radix cache is hot.
 
 temperature/top-k/top-p sampling and stop tokens need the engine
 (`continuous=True`): they run device-side inside the decode scan
-(models/llama_decode.sample_tokens).
+(models/paged.sample_tokens).
 """
 from __future__ import annotations
 

@@ -7,10 +7,10 @@ engine to wrap, so this is the green-field TPU-native equivalent
 
 - LANES. A fixed number of slots, each an independent sequence at its
   own position. What a lane holds is the model's: the decode module the
-  config object names (`cfg.decode_module`: models/llama_decode.py,
-  models/granite_hybrid_decode.py, models/afmoe_decode.py,
-  models/sarvam_mla_decode.py) owns the cache pytree and the two
-  halves of the macro-step. For an attention-only model a lane is a block
+  config object names (`cfg.decode_module`, one of the six
+  models/*_decode.py; models/paged.py's docstring says what it offers)
+  owns the cache pytree and the two halves of the macro-step, which that
+  module's skeleton runs. For an attention-only model a lane is a block
   table into the K/V pool and a few scalars; where the pool holds ONE
   latent row a position in place of keys and values (a decode module
   with `LATENT_POOL`), the tables, the allocator and the planner are the
@@ -57,7 +57,7 @@ engine to wrap, so this is the green-field TPU-native equivalent
   padded prompt width, a power of two (`_variant`), times greedy /
   sampled: its admission lanes are the engine's lanes rounded up to a
   power of two, and each admitting phase runs its admissions as the
-  pieces of their count inside it (`llama_decode.admit_phase`).
+  pieces of their count inside it (`models/paged.admit_phase`).
 - ADAPTIVE CHUNKS. Each phase decodes exactly to the next scheduling
   event, min(chunk, least steps owed over the live lanes), so a freed
   lane is re-admitted at the very next phase and does not idle to a
@@ -410,7 +410,7 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
     reused prefix): what the attention of each half has to do whatever
     does it, for every model. `admit_rows` is the token rows the device
     runs for the admissions, padding included: for each phase that admits
-    (`admit_phases`) the pieces of its count (`llama_decode.admit_pieces`,
+    (`admit_phases`) the pieces of its count (`models/paged.admit_pieces`,
     the function the device runs its admission bodies by: 3 admissions
     run 2 + 1 rows where each piece has tokens enough to be worth its
     pass over the weights, 4 where not), summed and times P, with (A, P) the compiled
@@ -446,7 +446,7 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
     `short` is 1 where a vacant lane closed the plan (`_plan` marks that
     phase, its last, with the vacancy quantum it decoded by) and `q` that
     quantum in steps; both 0 in every other plan."""
-    from ray_tpu.models import llama_decode
+    from ray_tpu.models.paged import admit_pieces as pieces_of
 
     total = sum(ph["steps"] for ph in phases)
     done = 0  # decode steps of this dispatch run so far
@@ -478,7 +478,7 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
                 last[id(req)] = done
         if new:
             admit_phases += 1
-            pieces = llama_decode.admit_pieces(len(ph["admissions"]), *variant)
+            pieces = pieces_of(len(ph["admissions"]), *variant)
             admit_pieces += len(pieces)
             admit_rows += sum(pieces) * variant[1]
             stall += riding - decoding
@@ -730,7 +730,7 @@ class ContinuousBatchingEngine:
         # planner reads counters of its own only
         self._device_counters = tuple(getattr(D, "DEVICE_COUNTERS", ()))
         if draft_model is None:
-            from ray_tpu.models.llama_decode import decode_chunk_positions
+            from ray_tpu.models.paged import decode_chunk_positions
 
             self._ctx_chunk = decode_chunk_positions(block_size, self._mb)
         self.draft_params = None
@@ -772,17 +772,6 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 "disaggregated pools require a shared-pool draft model "
                 "(separate draft KV cannot migrate across replicas)")
-        # The one wart this engine keeps: the wrappers of the dense slot
-        # programs, bound and NEVER called. The Mistral benchmark driver
-        # counts their compilations by these names
-        # (benchmark/drivers/serve.py:55, `bench_compiles`); they go with
-        # ROADMAP B7 (the driver drops the three keys) and D2b (the
-        # programs themselves). Only Llama's decode module has them.
-        self._prefill_slots = self._chunk_fn = self._macro_fn = None
-        if hasattr(D, "jitted_macro_step_slots"):
-            self._prefill_slots = D.jitted_prefill_into_slots(cfg)
-            self._chunk_fn = D.jitted_decode_chunk_slots(cfg, chunk)
-            self._macro_fn = D.jitted_macro_step_slots(cfg, chunk)
         self._slots: List[Optional[_Request]] = [None] * n_slots
         import jax.numpy as jnp
 
@@ -1242,8 +1231,10 @@ class ContinuousBatchingEngine:
                 return None
             import jax.numpy as jnp
 
+            from ray_tpu.models import paged
+
             ids = kv_plane.pad_block_ids(blocks)
-            k, v = self._D.jitted_gather_kv_blocks()(
+            k, v = paged.jitted_gather_kv_blocks()(
                 self.cache, jnp.asarray(ids))
             return list(tokens[: len(blocks) * self.block_size]), k, v, \
                 len(blocks)
@@ -1287,8 +1278,10 @@ class ContinuousBatchingEngine:
                 return 0
             import jax.numpy as jnp
 
+            from ray_tpu.models import paged
+
             dst = kv_plane.pad_block_ids(blocks)
-            self.cache = self._D.jitted_scatter_kv_blocks()(
+            self.cache = paged.jitted_scatter_kv_blocks()(
                 self.cache, jnp.asarray(dst), k, v)
             committed = tokens[: n_data_blocks * self.block_size]
             added = self._prefix.insert(committed, blocks)
@@ -1539,6 +1532,7 @@ class ContinuousBatchingEngine:
             return
         import jax.numpy as jnp
 
+        from ray_tpu.models import paged
         from ray_tpu.serve._internal import kv_plane
         from ray_tpu.serve._internal.kv_blocks import BlockPoolExhausted
 
@@ -1576,7 +1570,7 @@ class ContinuousBatchingEngine:
                 # side's admission would have stored in its slot — rng
                 # state never rides the wire
                 rng = kv_plane.carried_rng_for_seed(req.sampling.seed or 0)
-            self.cache = self._D.jitted_import_kv_blocks()(
+            self.cache = paged.jitted_import_kv_blocks()(
                 self.cache, jnp.asarray(dst), payload["k"], payload["v"],
                 jnp.int32(slot), jnp.int32(len(req.prompt)),
                 jnp.int32(req.max_new_tokens - 1), jnp.asarray(rng))
@@ -1870,7 +1864,7 @@ class ContinuousBatchingEngine:
         two, and alone names the program: A, its admission lanes, is
         always the engine's lanes rounded up to a power of two (no phase
         admits more), and each admitting phase runs at the width of its
-        own admissions inside the program (`llama_decode.admit_phase`).
+        own admissions inside the program (`models/paged.admit_phase`).
         A plan that admits nobody runs the program of the last dispatch:
         it takes no admission branch, so any P serves it and none is
         compiled for it."""

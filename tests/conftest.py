@@ -11,9 +11,16 @@ import os
 # Must be set before any jax backend is initialized: the suite runs on a
 # virtual 8-device CPU mesh, and the explicit worker pin keeps a `TPU`
 # grant on a fake-chip node from re-pointing a worker at the TPU backend.
+# The CPU backend's code generation runs at level 2 of 3: tier-1 is bound by
+# the CPU time of compiling hundreds of small programs once each (six xdist
+# workers want thirteen of the machine's eight cores), nothing here measures
+# the speed of CPU code, and the last level's passes cost a third of a
+# model test's CPU seconds (PR 48, CHANGES.md). The programs compiled for a
+# described TPU (tests/test_tpu_compile.py) do not pass through this backend.
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
-    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+    os.environ.get("XLA_FLAGS", "")
+    + " --xla_force_host_platform_device_count=8 --xla_backend_optimization_level=2"
 ).strip()
 os.environ["RAY_TPU_WORKER_JAX_PLATFORMS"] = "cpu"
 
@@ -30,6 +37,22 @@ def pytest_configure(config):
         "explicitly with -m chaos (chaos tests are also marked slow so they "
         "stay out of tier-1 timing)",
     )
+
+
+def static_answers(generate, params, cfg, prompts, answers):
+    """What a decode module's static `generate` gives each request of an
+    engine test, greedy: a prompt alone, as many tokens as the longest answer
+    asked of any prompt of its length, cut to the request's own (a greedy
+    answer is a prefix of a longer one). `generate` compiles a program for
+    every (prompt length, answer length): one a length this way, not one a
+    request."""
+    import numpy as np
+
+    longest = {}
+    for p, n in zip(prompts, answers):
+        longest[len(p)] = max(longest.get(len(p), 0), n)
+    return [generate(params, np.asarray([p]), cfg, longest[len(p)])[0, :n].tolist()
+            for p, n in zip(prompts, answers)]
 
 
 @pytest.fixture(scope="module")

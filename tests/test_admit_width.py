@@ -1,16 +1,15 @@
 """An admitting phase runs at the width of its own admissions (ISSUE 42), as
 the binary pieces of their count (ISSUE 46).
 
-The paged macro-step's skeleton (`llama_decode.admit_phase`) hands the model's
+The paged macro-step's skeleton (`paged.admit_phase`) hands the model's
 own admission the rows of a phase a piece at a time: `admit_pieces(n, A, P)`,
 3 rows as 2 + 1 where a piece has tokens enough to be worth its pass over the
 weights, one piece rounded up to a power of two where it has not (PR 42's), and
 never all A. The four models' admissions are row-independent, so nothing a
 real row leaves behind may differ: CPU, float32, each model's tiny config.
 
-Tolerance: 1e-4 of the largest entry, the one
-`test_a_padded_admission_is_each_prompt_admitted_alone` uses (a product over w
-rows and one over A rows may sum in another order); integers are equal.
+Tolerance: 1e-4 of the largest entry (a product over w rows and one over A
+rows may sum in another order); integers are equal.
 """
 import functools
 
@@ -19,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import afmoe, granite_hybrid, llama, llama_decode, sarvam_mla
+from ray_tpu.models import afmoe, granite_hybrid, llama, longcat_flash, paged, sarvam_mla
 from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
 
 RTOL = 1e-4
@@ -32,23 +31,26 @@ MODELS = {
     "afmoe": (afmoe, lambda: afmoe.AfmoeConfig.tiny(dtype=jnp.float32)),
     "sarvam_mla": (sarvam_mla, lambda: sarvam_mla.SarvamMlaConfig.tiny(dtype=jnp.float32)),
 }
+# walked through the one-by-one comparison below and not through the widths
+ONE_BY_ONE = {**MODELS, "longcat_flash": (
+    longcat_flash, lambda: longcat_flash.LongcatFlashConfig.tiny(dtype=jnp.float32))}
 # (A, P, block, blocks a lane): rows too short to be worth a second pass over
 # the weights, PR 42's program, and the shortest of which one is worth a pass
-LONG_P = llama_decode.RIDGE_TOKENS
+LONG_P = paged.RIDGE_TOKENS
 SHORT, LONG = (4, 16, 4, 8), (8, LONG_P, 16, LONG_P // 16 + 1)
 # row i of the phase: its prompt's length in sixteenths of P and the lane it
 # lands in (not its own index)
 LENGTHS, LANES = (13, 5, 16, 9, 2, 11, 16, 7), (2, 0, 3, 1, 6, 4, 7, 5)
 
 
-@functools.lru_cache(maxsize=4)
+@functools.lru_cache(maxsize=8)
 def _model(name):
-    module, make = MODELS[name]
+    module, make = ONE_BY_ONE[name]
     cfg = make()
     return cfg, module.init_params(jax.random.PRNGKey(7), cfg), cfg.decode_module
 
 
-@functools.lru_cache(maxsize=4)
+@functools.lru_cache(maxsize=8)
 def _halves(name):
     """The model's own admission and decode step, jitted once a model: every
     case of a shape shares their compiles."""
@@ -111,7 +113,7 @@ def test_a_phase_admitted_at_its_own_width_is_the_full_width_admission(name, sha
         params, fresh(), z, jnp.zeros((K,), jnp.int32), jnp.asarray([True, False]),
         *(per_phase(r) for r in rows), *(jnp.stack([p, p]) for p in plan))
 
-    pieces = llama_decode.admit_pieces(n, A, P)
+    pieces = paged.admit_pieces(n, A, P)
     if shape == SHORT:
         assert pieces == (1 << (n - 1).bit_length(),)
     else:
@@ -133,6 +135,43 @@ def test_a_phase_admitted_at_its_own_width_is_the_full_width_admission(name, sha
     assert np.array_equal(np.asarray(nxt)[lanes], np.asarray(nxt_full)[lanes])
 
 
+@pytest.mark.parametrize("name", sorted(ONE_BY_ONE))
+def test_rows_admitted_together_are_the_rows_admitted_one_by_one(name):
+    """Three right-padded prompts of unequal length and a padding row in one
+    (4, 16) admission, landing in lanes 2, 0 and 3, against the same three
+    admitted one after another, each alone beside three padding rows: the
+    first tokens, the feed, EVERY leaf of the cache and the next decode
+    step's logits at the admitted lanes are the same. What
+    `test_a_padded_admission_is_each_prompt_admitted_alone` held in three
+    model files until PR 48, each for its own model's pool rows; here every
+    leaf (a padding row writes nothing, a row nothing of another's) through
+    the program the cases above compiled, so a case costs no compile of its own."""
+    A, P, block, MB = SHORT
+    cfg, params, D = _model(name)
+    tables = 1 + jnp.arange(A * MB, dtype=jnp.int32).reshape(A, MB)
+    z = jnp.zeros((A,), jnp.int32)
+    plan = (tables, jnp.zeros((A,), jnp.float32), z, jnp.ones((A,), jnp.float32),
+            jnp.full((A, 1), -1, jnp.int32))
+    rows = _phase(3, cfg.vocab_size, A, P)
+    admit, step = _halves(name)
+    first_all, cache_all, feed_all = admit(
+        params, *rows, D.init_paged_cache(cfg, A, A * MB + 1, block), z, *plan)
+
+    cache, feed = D.init_paged_cache(cfg, A, A * MB + 1, block), z
+    for i in range(3):
+        alone = tuple(jnp.zeros_like(r).at[0].set(r[i]) for r in rows)
+        first, cache, feed = admit(params, *alone, cache, feed, *plan)
+        assert int(first[0]) == int(first_all[i])
+    assert np.array_equal(np.asarray(feed), np.asarray(feed_all))
+    for got, want in zip(jax.tree.leaves(cache), jax.tree.leaves(cache_all)):
+        _close(got, want)
+    lanes = list(LANES[:3])
+    logits, nxt, _ = step(params, cache, feed, *plan)
+    logits_all, nxt_all, _ = step(params, cache_all, feed_all, *plan)
+    _close(np.asarray(logits)[lanes], np.asarray(logits_all)[lanes])
+    assert np.array_equal(np.asarray(nxt)[lanes], np.asarray(nxt_all)[lanes])
+
+
 @pytest.mark.parametrize("lanes", [4, 6, 8, 11, 32])
 def test_the_pieces_of_a_count(lanes):
     """`admit_pieces` alone, plain Python: distinct powers of two, widest
@@ -142,14 +181,14 @@ def test_the_pieces_of_a_count(lanes):
     nobody and wherever that piece costs no more than two passes over the
     weights; with a longer row never more rows nor fewer pieces; from rows of
     the ridge's length up the count itself, bit by bit."""
-    R = llama_decode.RIDGE_TOKENS
+    R = paged.RIDGE_TOKENS
     one = lambda n: min(1 << max(n - 1, 0).bit_length(), lanes)  # noqa: E731
     buckets = [16 << i for i in range(9)]
     assert buckets[0] < R < buckets[-1]
     for n in range(lanes + 1):
         before = None
         for P in buckets:
-            pieces = llama_decode.admit_pieces(n, lanes, P)
+            pieces = paged.admit_pieces(n, lanes, P)
             assert all(w == lanes or w & (w - 1) == 0 for w in pieces)
             assert list(pieces) == sorted(set(pieces), reverse=True)
             assert max(n, 1) <= sum(pieces) <= one(n)
@@ -160,14 +199,14 @@ def test_the_pieces_of_a_count(lanes):
             if before:
                 assert sum(pieces) <= sum(before) and len(pieces) >= len(before)
             before = pieces
-    assert llama_decode.admit_pieces(3, 8, R // 2) == (4,)  # a one-row piece costs a pass: no gain
-    assert llama_decode.admit_pieces(5, 8, R // 2) == (4, 1)  # three rows dropped for it: a gain
-    assert llama_decode.admit_pieces(7, 8, R // 2) == (8,)
-    assert llama_decode.admit_pieces(3, 8, R) == (2, 1)
-    assert llama_decode.admit_pieces(7, 8, R) == (4, 2, 1)
-    assert llama_decode.admit_pieces(11, 16, R) == (8, 2, 1)
-    assert llama_decode.admit_pieces(11, 16, R // 4) == (8, 4)  # the remainder rounded up
-    assert llama_decode.admit_pieces(23, 32, 16) == (32,)
+    assert paged.admit_pieces(3, 8, R // 2) == (4,)  # a one-row piece costs a pass: no gain
+    assert paged.admit_pieces(5, 8, R // 2) == (4, 1)  # three rows dropped for it: a gain
+    assert paged.admit_pieces(7, 8, R // 2) == (8,)
+    assert paged.admit_pieces(3, 8, R) == (2, 1)
+    assert paged.admit_pieces(7, 8, R) == (4, 2, 1)
+    assert paged.admit_pieces(11, 16, R) == (8, 2, 1)
+    assert paged.admit_pieces(11, 16, R // 4) == (8, 4)  # the remainder rounded up
+    assert paged.admit_pieces(23, 32, 16) == (32,)
 
 
 def test_the_pieces_follow_the_last_real_row_not_the_count():
@@ -184,7 +223,7 @@ def test_the_pieces_follow_the_last_real_row_not_the_count():
 
     def run(lengths, P, admits=True):
         lengths = jnp.asarray(lengths, jnp.int32)
-        first, carry = jax.jit(lambda l: llama_decode.admit_phase(
+        first, carry = jax.jit(lambda l: paged.admit_phase(
             admit_rows, jnp.asarray(admits), (jnp.zeros((l.shape[0], P), jnp.int32), l),
             jnp.asarray(0)))(lengths)
         return np.asarray(first).tolist(), int(carry)

@@ -35,6 +35,7 @@ from benchmark import weights_afmoe as W
 from ray_tpu.models import afmoe as M
 from ray_tpu.models import afmoe_decode as D
 from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
+from tests.conftest import static_answers
 
 F32_RTOL = 1e-4
 BF16_ATOL = 0.15
@@ -322,10 +323,9 @@ class Lanes:
                          stop_ids=jnp.full((n, 1), -1, jnp.int32))
         self._admit, self._step = halves or _jitted_halves(cfg)
 
-    def admit(self, rows, bucket, new=8, width=None):
-        """rows: [(lane, prompt)]; the admission is `width` rows wide (the
-        rest padding rows of length 0) and `bucket` positions long."""
-        A = width or len(rows)
+    def admit(self, rows, bucket, new=8):
+        """rows: [(lane, prompt)], one admission row each, `bucket` positions long."""
+        A = len(rows)
         prompts = np.zeros((A, bucket), np.int32)
         lengths, slots = np.zeros(A, np.int32), np.zeros(A, np.int32)
         for i, (lane, p) in enumerate(rows):
@@ -375,39 +375,6 @@ def test_admission_then_decode_matches_the_reference_at_every_position(dtype):
     worst, firsts_agree = _through_the_cache(cfg, key, params, dtype=dtype)
     assert worst <= 1.0
     assert firsts_agree or dtype != jnp.float32
-
-
-@pytest.mark.parametrize("chunk", [None, 16], ids=["pairs-at-once", "pairs-in-chunks-of-16"])
-def test_a_padded_admission_is_each_prompt_admitted_alone(chunk, monkeypatch):
-    """Right-padded prompts of unequal length and a row of length 0 in one
-    (4, 32) admission, whose padded rows choose no expert: each lane's first
-    token, its pool rows at every real position, the slots its rings hold
-    and the next step's logits are what the prompt gives admitted alone in
-    a bucket of its own length (in whole blocks); with the pairs at once
-    (the tiny admission has fewer than `expert_ffn`'s chunk) and in chunks."""
-    cfg, _, params = _model()
-    if chunk is not None:
-        monkeypatch.setattr(M, "expert_ffn", functools.partial(M.expert_ffn, chunk=chunk))
-    halves = (jax.jit(functools.partial(D.admit_slots_paged, cfg=cfg, sampled=False)),
-              jax.jit(functools.partial(D.decode_step_slots_paged, cfg=cfg, sampled=False)))
-    prompts = [_tokens(1, 19, seed=3)[0], _tokens(1, 5, seed=4)[0], _tokens(1, 32, seed=5)[0]]
-    together = Lanes(cfg, params, n=3, halves=halves)
-    first = together.admit([(0, prompts[0]), (2, prompts[2]), (1, prompts[1])], bucket=32, width=4)
-
-    def rows_of(lanes, b, n):
-        pool = [np.asarray(lanes.cache[name][:, 1 + b * lanes.mb:1 + (b + 1) * lanes.mb]).reshape(
-            lanes.cache[name].shape[0], -1, lanes.cache[name].shape[-1])[:, :n] for name in ("k", "v")]
-        held = np.asarray(D.ring_slots_held(jnp.asarray([n - 1]), cfg.sliding_window))[0]
-        return pool + [np.asarray(lanes.cache[name][:, b])[:, held] for name in ("wk", "wv")]
-
-    admitted = [rows_of(together, b, len(p)) for b, p in enumerate(prompts)]
-    logits, _ = together.step()
-    for b, (i, p) in enumerate(zip((0, 2, 1), prompts)):
-        alone = Lanes(cfg, params, n=3, halves=halves)
-        assert alone.admit([(b, p)], bucket=-(-len(p) // BLOCK) * BLOCK)[0] == first[i]
-        for got, want in zip(admitted[b], rows_of(alone, b, len(p))):
-            assert np.abs(got - want).max() <= F32_RTOL * np.abs(want).max()
-        _close(logits[b], alone.step()[0][b], jnp.float32)
 
 
 @pytest.mark.parametrize("name", ["ring-read-one-slot-off", "decode-mask-shows-every-slot",
@@ -496,9 +463,9 @@ def test_engine_serves_more_requests_than_lanes_and_counts_what_it_routed(tmp_pa
             m1 = eng.metrics()
         finally:
             jax.profiler.stop_trace()
-        for p, n, r in zip(prompts, answers, reqs):
+        for want, r in zip(static_answers(D.generate, params, cfg, prompts, answers), reqs):
             assert r.error is None
-            assert r.tokens == D.generate(params, np.asarray([p]), cfg, n)[0].tolist()
+            assert r.tokens == want
     finally:
         eng.shutdown()
     from jax.profiler import ProfileData

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import llama
+from ray_tpu.models import llama, paged
 from ray_tpu.models import llama_decode as D
 from ray_tpu.observability import ENGINE_SPANS
 from ray_tpu.ops import flash_attention as FA
@@ -44,17 +44,15 @@ def _cfg_params():
     return cfg, llama.init_params(jax.random.PRNGKey(0), cfg)
 
 
-def _plan_args(paged: bool):
+def _plan_args():
     """Plan arrays in the order the macro-steps take them after `feed`."""
     i32 = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
-    head = (i32(K), jnp.zeros(K, bool), i32(K, A, P), i32(K, A))
-    if not paged:
-        return head + (i32(K, A), i32(K, A))                        # slots, rems
-    return head + (i32(K, A), i32(K, A), i32(K, A),                 # starts, slots, rems
-                   jnp.zeros((K, A), jnp.uint32), i32(K, N_SLOTS, MB),
-                   jnp.zeros((K, N_SLOTS), jnp.float32), i32(K, N_SLOTS),
-                   jnp.ones((K, N_SLOTS), jnp.float32),
-                   jnp.full((K, N_SLOTS, NS), -1, jnp.int32))
+    return (i32(K), jnp.zeros(K, bool), i32(K, A, P), i32(K, A),
+            i32(K, A), i32(K, A), i32(K, A),                        # starts, slots, rems
+            jnp.zeros((K, A), jnp.uint32), i32(K, N_SLOTS, MB),
+            jnp.zeros((K, N_SLOTS), jnp.float32), i32(K, N_SLOTS),
+            jnp.ones((K, N_SLOTS), jnp.float32),
+            jnp.full((K, N_SLOTS, NS), -1, jnp.int32))
 
 
 def _spec_parts():
@@ -71,8 +69,7 @@ def _program(name: str):
     cfg, params = _cfg_params()
     feed = jnp.zeros(N_SLOTS, jnp.int32)
     dense = lambda: D.init_cache(cfg, 1, MAX_LEN)  # noqa: E731
-    slots = lambda: D.init_slot_cache(cfg, N_SLOTS, MAX_LEN)  # noqa: E731
-    paged = lambda: D.init_paged_cache(cfg, N_SLOTS, N_BLOCKS, BLOCK)  # noqa: E731
+    pool = lambda: D.init_paged_cache(cfg, N_SLOTS, N_BLOCKS, BLOCK)  # noqa: E731
     blocks = jnp.zeros(2, jnp.int32)
     kv = jnp.zeros((cfg.n_layers, 2, BLOCK, cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
     if name == "prefill":
@@ -85,44 +82,42 @@ def _program(name: str):
         return D._jitted_sample_loop(cfg, 3), (
             params, dense(), jnp.zeros((1, cfg.vocab_size), jnp.float32),
             jax.random.PRNGKey(0), 1.0, 0, 1.0)
-    if name == "prefill_into_slots":
-        return D.jitted_prefill_into_slots(cfg), (
-            params, jnp.zeros((A, P), jnp.int32), jnp.zeros(A, jnp.int32),
-            jnp.zeros(A, jnp.int32), slots())
-    if name == "decode_chunk_slots":
-        return D.jitted_decode_chunk_slots(cfg, CHUNK), (params, slots(), feed)
-    if name == "macro_step_slots":
-        return D.jitted_macro_step_slots(cfg, CHUNK), (params, slots(), feed) + _plan_args(False)
     if name == "macro_step_slots_paged":
         return D.jitted_macro_step_slots_paged(cfg, CHUNK, sampled=True), (
-            params, paged(), feed) + _plan_args(True)
+            params, pool(), feed) + _plan_args()
     if name == "macro_step_slots_spec":
         draft_params, draft_cfg = _spec_parts()
         return D.jitted_macro_step_slots_spec(cfg, draft_cfg, CHUNK, N_SPEC), (
-            params, draft_params, paged(),
-            D.init_spec_cache(draft_cfg, N_SLOTS, N_BLOCKS, BLOCK), feed) + _plan_args(True)
+            params, draft_params, pool(),
+            D.init_spec_cache(draft_cfg, N_SLOTS, N_BLOCKS, BLOCK), feed) + _plan_args()
     if name == "gather_kv_blocks":
-        return D.jitted_gather_kv_blocks(), (paged(), blocks)
+        return paged.jitted_gather_kv_blocks(), (pool(), blocks)
     if name == "scatter_kv_blocks":
-        return D.jitted_scatter_kv_blocks(), (paged(), blocks, kv, kv)
+        return paged.jitted_scatter_kv_blocks(), (pool(), blocks, kv, kv)
     if name == "import_kv_blocks":
-        return D.jitted_import_kv_blocks(), (
-            paged(), blocks, kv, kv, jnp.int32(0), jnp.int32(0), jnp.int32(0),
+        return paged.jitted_import_kv_blocks(), (
+            pool(), blocks, kv, kv, jnp.int32(0), jnp.int32(0), jnp.int32(0),
             jnp.zeros(2, jnp.uint32))
     raise KeyError(name)
 
 
-PROGRAMS = ("prefill", "decode_step", "decode_loop", "sample_loop", "prefill_into_slots",
-            "decode_chunk_slots", "macro_step_slots", "macro_step_slots_paged",
+PROGRAMS = ("prefill", "decode_step", "decode_loop", "sample_loop", "macro_step_slots_paged",
             "macro_step_slots_spec", "gather_kv_blocks", "scatter_kv_blocks",
             "import_kv_blocks")
+# the factories of the block movers live with the movers, in models/paged.py
+IN_PAGED = {"gather_kv_blocks", "scatter_kv_blocks", "import_kv_blocks"}
 
 
 def test_every_jitted_factory_is_listed():
-    factories = {n for n in vars(D) if n.startswith(("jitted_", "_jitted_"))}
-    assert factories == {("_jitted_" if n in ("prefill", "decode_step", "decode_loop",
-                                              "sample_loop") else "jitted_") + n
-                         for n in PROGRAMS}
+    """A factory of either module is a case of PROGRAMS, in the one module
+    that holds it (a name imported from the other would be found twice)."""
+    def factories(module):
+        return {n for n in vars(module) if n.startswith(("jitted_", "_jitted_"))}
+
+    assert factories(paged) == {"jitted_" + n for n in IN_PAGED}
+    assert factories(D) == {("_jitted_" if n in ("prefill", "decode_step", "decode_loop",
+                                                 "sample_loop") else "jitted_") + n
+                            for n in set(PROGRAMS) - IN_PAGED}
 
 
 @pytest.mark.parametrize("name", PROGRAMS)
@@ -189,14 +184,13 @@ def _names_patched_out(monkeypatch):
     pl.pallas_call = lambda *a, name=None, **kw: real_pl.pallas_call(*a, **kw)
     with monkeypatch.context() as m:
         m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
-        m.setattr(D, "_bind", functools.partial)
+        m.setattr(paged, "_bind", functools.partial)
         m.setattr(FA, "pl", pl)
         yield
 
 
 def _lower_macro(name: str):
-    for f in (D.jitted_macro_step_slots, D.jitted_macro_step_slots_paged,
-              D.jitted_macro_step_slots_spec):
+    for f in (D.jitted_macro_step_slots_paged, D.jitted_macro_step_slots_spec):
         f.cache_clear()  # the factories memoize the jitted function
     jitted, args = _program(name)
     return jitted.lower(*args)
@@ -216,8 +210,8 @@ def _lower_train_step(monkeypatch):
     return step_fn.__wrapped__.trace(state, batch).lower(lowering_platforms=("tpu",))
 
 
-@pytest.mark.parametrize("name", ["macro_step_slots", "macro_step_slots_paged",
-                                  "macro_step_slots_spec", "train_step"])
+@pytest.mark.parametrize("name", ["macro_step_slots_paged", "macro_step_slots_spec",
+                                  "train_step"])
 def test_names_and_scopes_are_metadata_only(name, monkeypatch):
     lower = (functools.partial(_lower_train_step, monkeypatch) if name == "train_step"
              else functools.partial(_lower_macro, name))
@@ -230,12 +224,11 @@ def test_names_and_scopes_are_metadata_only(name, monkeypatch):
         assert 'kernel_name = "flash_fwd"' in named.as_text()
         assert 'kernel_name = "flash_fwd"' not in bare.as_text()
     else:
-        assert D.ADMIT_SCOPE in with_locations and D.DECODE_SCOPE in with_locations
-        assert D.ADMIT_SCOPE not in bare.as_text(debug_info=True)
+        assert paged.ADMIT_SCOPE in with_locations and paged.DECODE_SCOPE in with_locations
+        assert paged.ADMIT_SCOPE not in bare.as_text(debug_info=True)
         assert bare.as_text().startswith("module @jit__unknown")
     assert _stripped(named) == _stripped(bare)
-    for f in (D.jitted_macro_step_slots, D.jitted_macro_step_slots_paged,
-              D.jitted_macro_step_slots_spec):
+    for f in (D.jitted_macro_step_slots_paged, D.jitted_macro_step_slots_spec):
         f.cache_clear()  # nothing built under the patch outlives it
 
 
@@ -346,7 +339,7 @@ def _drive(eng, prompts_and_answers):
         # bounds every phase
         assert A == 1 << (eng.n_slots - 1).bit_length()
         admitting = [len(ph["admissions"]) for ph in phases if ph["admissions"]]
-        pieces = [D.admit_pieces(n, A, P) for n in admitting]
+        pieces = [paged.admit_pieces(n, A, P) for n in admitting]
         assert counts["admit_rows"] == P * sum(map(sum, pieces))
         assert counts["admit_pieces"] == sum(map(len, pieces)) >= counts["admit_phases"]
         assert counts["prompt_tokens"] <= counts["admit_rows"] <= A * P * len(admitting)
@@ -369,7 +362,7 @@ def test_ctx_chunks_follow_the_planned_contexts():
     eng = ContinuousBatchingEngine(params, cfg, n_slots=2, chunk=4, macro_phases=4,
                                    max_len=1024, block_size=16, prefix_cache=False)
     eng.shutdown()  # the plans below are made on this thread
-    C = D.decode_chunk_positions(16, 1024 // 16)
+    C = paged.decode_chunk_positions(16, 1024 // 16)
     assert eng._ctx_chunk == C == 128
     rng = np.random.default_rng(1)
     prompt = lambda n: rng.integers(0, cfg.vocab_size, n).tolist()  # noqa: E731

@@ -28,6 +28,7 @@ from ray_tpu.models import granite_hybrid as G
 from ray_tpu.models import granite_hybrid_decode as D
 from ray_tpu.ops import ssm_update as SU
 from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
+from tests.conftest import static_answers
 
 F32_RTOL = 1e-4
 BF16_ATOL = 0.1
@@ -288,9 +289,9 @@ def test_engine_serves_more_requests_than_lanes_like_the_static_path(tmp_path):
             m1 = eng.metrics()
         finally:
             jax.profiler.stop_trace()
-        for p, n, r in zip(prompts, answers, reqs):
+        for want, r in zip(static_answers(D.generate, params, cfg, prompts, answers), reqs):
             assert r.error is None
-            assert r.tokens == D.generate(params, np.asarray([p]), cfg, n)[0].tolist()
+            assert r.tokens == want
     finally:
         eng.shutdown()
     from jax.profiler import ProfileData

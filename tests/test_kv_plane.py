@@ -351,7 +351,7 @@ def test_gather_import_scatter_roundtrip():
     slot state."""
     import jax.numpy as jnp
 
-    from ray_tpu.models import llama_decode as D
+    from ray_tpu.models import paged
 
     L, n_blocks, bs, kvh, hd, n_slots = 2, 8, 4, 2, 6, 2
     rng = np.random.default_rng(0)
@@ -365,7 +365,7 @@ def test_gather_import_scatter_roundtrip():
         "rng": jnp.zeros((n_slots, 2), jnp.uint32),
     }
     src = kv_plane.pad_block_ids([2, 5, 3])
-    k, v = D.gather_kv_blocks(cache, jnp.asarray(src))
+    k, v = paged.gather_kv_blocks(cache, jnp.asarray(src))
     assert k.shape == (L, 4, bs, kvh, hd)  # padded to the pow-2 bucket
     np.testing.assert_array_equal(np.asarray(k)[:, 0], np.asarray(cache["k"])[:, 2])
     np.testing.assert_array_equal(np.asarray(v)[:, 2], np.asarray(cache["v"])[:, 3])
@@ -378,7 +378,7 @@ def test_gather_import_scatter_roundtrip():
         "rng": jnp.zeros((n_slots, 2), jnp.uint32),
     }
     dst = kv_plane.pad_block_ids([6, 1, 4])
-    out = D.import_kv_blocks(
+    out = paged.import_kv_blocks(
         dst_cache, jnp.asarray(dst), k, v, jnp.int32(1), jnp.int32(11),
         jnp.int32(7), jnp.asarray(np.array([3, 4], np.uint32)))
     np.testing.assert_array_equal(np.asarray(out["k"])[:, 6],
@@ -400,7 +400,7 @@ def test_gather_import_scatter_roundtrip():
         "remaining": jnp.full((n_slots,), 99, jnp.int32),
         "rng": jnp.ones((n_slots, 2), jnp.uint32),
     }
-    out2 = D.scatter_kv_blocks(zero_cache, jnp.asarray(dst), k, v)
+    out2 = paged.scatter_kv_blocks(zero_cache, jnp.asarray(dst), k, v)
     np.testing.assert_array_equal(np.asarray(out2["k"])[:, 6],
                                   np.asarray(cache["k"])[:, 2])
     assert int(out2["pos"][0]) == 99 and int(out2["remaining"][1]) == 99

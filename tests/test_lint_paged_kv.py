@@ -17,6 +17,7 @@ admit/evict/prefix-hit/stop workload must return every non-cache block
 reference by the time the requests finish.
 """
 import numpy as np
+import pytest
 
 import jax
 import jax.extend.core
@@ -318,3 +319,90 @@ def test_engine_source_has_one_mode_and_one_loop():
                  "while self._running: pass")
     assert _engine_mode_findings(two_modes) == [
         "self.paged", "_macro_fn(", "2 loops over self._running"]
+
+
+# ------------------------------------------- which way the imports point
+def _imports_of(source: str):
+    """[(module imported, the function the import stands in or None)] of a
+    source file: `from a.b import c` counts as `a.b.c` (it may be a module),
+    an import under `if TYPE_CHECKING:` as none."""
+    import ast
+
+    found = []
+
+    def walk(node, inside):
+        for child in ast.iter_child_nodes(node):
+            if isinstance(child, ast.If) and "TYPE_CHECKING" in ast.unparse(child.test):
+                continue
+            if isinstance(child, ast.Import):
+                found.extend((alias.name, inside) for alias in child.names)
+            elif isinstance(child, ast.ImportFrom):
+                found.extend((f"{child.module}.{alias.name}", inside) for alias in child.names)
+            walk(child, child.name if isinstance(child, (ast.FunctionDef, ast.AsyncFunctionDef))
+                 else inside)
+
+    walk(ast.parse(source), None)
+    return found
+
+
+def _import_direction_findings(name: str, source: str):
+    """What the model layer's one-way rule holds a file to, `name` its path
+    under ray_tpu/: models/paged.py imports no module of ray_tpu.models; a
+    model definition (a module of models/ that is neither paged nor a
+    `*_decode`) imports no decode module but in the `decode_module` property
+    by which its config names one; nothing under serve/ imports a decode
+    module (the engine reaches it through `cfg.decode_module`)."""
+    import re
+
+    imports = _imports_of(source)
+    decode = re.compile(r"ray_tpu\.models\.(\w+_decode)\b")
+    if name == "models/paged.py":
+        return [m for m, _ in imports if m.startswith("ray_tpu.models")]
+    if name.startswith("serve/"):
+        return [m for m, _ in imports if decode.match(m)]
+    if name.startswith("models/") and not name.endswith("_decode.py"):
+        return [m for m, inside in imports if decode.match(m) and inside != "decode_module"]
+    return []
+
+
+def _sources_under(package: str):
+    import pathlib
+
+    import ray_tpu
+
+    root = pathlib.Path(ray_tpu.__file__).parent
+    return {str(p.relative_to(root)): p.read_text() for p in sorted((root / package).rglob("*.py"))}
+
+
+MODEL_FILES = sorted(_sources_under("models"))
+
+
+def test_the_model_layer_is_what_the_lint_walks():
+    """Six decode modules, the skeleton they share, and a case below for
+    every file there is."""
+    assert "models/paged.py" in MODEL_FILES
+    assert len([f for f in MODEL_FILES if f.endswith("_decode.py")]) == 6
+
+
+@pytest.mark.parametrize("name", MODEL_FILES + ["serve/"])
+def test_imports_point_one_way(name):
+    files = _sources_under("serve") if name == "serve/" else {name: _sources_under("models")[name]}
+    assert {f: found for f, src in files.items()
+            if (found := _import_direction_findings(f, src))} == {}
+
+
+def test_the_import_lint_flags_what_it_keeps_out():
+    wrong = ("from ray_tpu.models import llama_decode as D\n"
+             "from ray_tpu.models.llama_decode import rows_a_piece\n"
+             "if TYPE_CHECKING:\n    from ray_tpu.models.llama import LlamaConfig\n"
+             "class C:\n    @property\n    def decode_module(self):\n"
+             "        from ray_tpu.models import afmoe_decode\n        return afmoe_decode\n")
+    assert _import_direction_findings("models/paged.py", wrong) == [
+        "ray_tpu.models.llama_decode", "ray_tpu.models.llama_decode.rows_a_piece",
+        "ray_tpu.models.afmoe_decode"]
+    assert _import_direction_findings("models/afmoe.py", wrong) == [
+        "ray_tpu.models.llama_decode", "ray_tpu.models.llama_decode.rows_a_piece"]
+    assert _import_direction_findings("serve/_internal/kv_plane.py", wrong) == [
+        "ray_tpu.models.llama_decode", "ray_tpu.models.llama_decode.rows_a_piece",
+        "ray_tpu.models.afmoe_decode"]
+    assert _import_direction_findings("models/afmoe_decode.py", wrong) == []

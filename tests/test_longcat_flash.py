@@ -48,6 +48,7 @@ from ray_tpu.models import sarvam_mla_decode
 from ray_tpu.ops.normalization import rms_norm
 from ray_tpu.ops.rope import apply_rope
 from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
+from tests.conftest import static_answers
 
 F32_RTOL = 1e-4
 BF16_ATOL = 0.15
@@ -345,10 +346,9 @@ class Lanes:
                          stop_ids=jnp.full((n, 1), -1, jnp.int32))
         self._admit, self._step = halves or _jitted_halves(cfg)
 
-    def admit(self, rows, bucket, new=8, width=None):
-        """rows: [(lane, prompt)]; the admission is `width` rows wide (the
-        rest padding rows of length 0) and `bucket` positions long."""
-        A = width or len(rows)
+    def admit(self, rows, bucket, new=8):
+        """rows: [(lane, prompt)], one admission row each, `bucket` positions long."""
+        A = len(rows)
         prompts = np.zeros((A, bucket), np.int32)
         lengths, slots = np.zeros(A, np.int32), np.zeros(A, np.int32)
         for i, (lane, p) in enumerate(rows):
@@ -411,31 +411,6 @@ def test_admission_then_decode_matches_the_reference_at_every_position(dtype):
     assert all(np.abs(pool[s]).max() > 0 for s in range(4))
     assert all(np.abs(pool[s] - pool[t]).max() > 0.1 for s in range(4) for t in range(s))
     assert np.abs(pool[..., cfg.latent_row:]).max() == 0  # the zero tail
-
-
-def test_a_padded_admission_is_each_prompt_admitted_alone():
-    """Right-padded prompts of unequal length and a row of length 0 in one
-    (4, 32) admission, whose padded rows choose no real expert: each lane's
-    first token, its latent rows at every real position of every plane and
-    the next step's logits are what the prompt gives admitted alone in a
-    bucket of its own length (in whole blocks)."""
-    cfg, _, params = _model()
-    prompts = [_tokens(1, 19, seed=3)[0], _tokens(1, 5, seed=4)[0], _tokens(1, 32, seed=5)[0]]
-    together = Lanes(cfg, params, n=3)
-    first = together.admit([(0, prompts[0]), (2, prompts[2]), (1, prompts[1])], bucket=32, width=4)
-
-    def rows_of(lanes, b, n):
-        pool = lanes.cache["latent"][:, 1 + b * lanes.mb:1 + (b + 1) * lanes.mb]
-        return np.asarray(pool).reshape(pool.shape[0], -1, pool.shape[-1])[:, :n]
-
-    admitted = [rows_of(together, b, len(p)) for b, p in enumerate(prompts)]
-    logits, _ = together.step()
-    for b, (i, p) in enumerate(zip((0, 2, 1), prompts)):
-        alone = Lanes(cfg, params, n=3)
-        assert alone.admit([(b, p)], bucket=-(-len(p) // BLOCK) * BLOCK)[0] == first[i]
-        got, want = admitted[b], rows_of(alone, b, len(p))
-        assert np.abs(got - want).max() <= 1e-4 * np.abs(want).max()
-        assert _worst(logits[b], alone.step()[0][b], jnp.float32) <= 1.0
 
 
 def _identity_term_dropped(orig):
@@ -542,9 +517,9 @@ def test_engine_serves_more_requests_than_lanes_and_its_spans_sum_to_its_counter
             m1 = eng.metrics()
         finally:
             jax.profiler.stop_trace()
-        for p, n, r in zip(prompts, answers, reqs):
+        for want, r in zip(static_answers(D.generate, params, cfg, prompts, answers), reqs):
             assert r.error is None
-            assert r.tokens == D.generate(params, np.asarray([p]), cfg, n)[0].tolist()
+            assert r.tokens == want
     finally:
         eng.shutdown()
     from jax.profiler import ProfileData

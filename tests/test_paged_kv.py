@@ -1,6 +1,6 @@
 """Paged-KV serving subsystem: block-table decode parity, radix prefix
 reuse, real sampling, and plan-and-repair stop handling
-(serve/_internal/ + models/llama_decode paged machinery)."""
+(serve/_internal/ + models/paged.py and llama_decode's paged halves)."""
 import numpy as np
 import pytest
 
@@ -59,6 +59,7 @@ def test_copy_kv_blocks_device_cow():
     import jax.numpy as jnp
 
     from ray_tpu.models import llama_decode as D
+    from ray_tpu.models import paged
     from ray_tpu.serve._internal.kv_blocks import BlockAllocator
 
     params, cfg = _tiny()
@@ -69,7 +70,7 @@ def test_copy_kv_blocks_device_cow():
     cache["k"] = cache["k"].at[:, table[1]].set(2.5)
     forked = a.fork(table)
     src, dst = a.ensure_writable(forked, 1)
-    cache = D.copy_kv_blocks(cache, np.asarray([src]), np.asarray([dst]))
+    cache = paged.copy_kv_blocks(cache, np.asarray([src]), np.asarray([dst]))
     np.testing.assert_array_equal(
         np.asarray(cache["k"][:, dst]), np.asarray(cache["k"][:, src])
     )
@@ -158,8 +159,9 @@ def test_block_leak_audit_mixed_workload():
 # ------------------------------------------------- device-level parity
 def test_paged_decode_matches_dense_wrapped_tables():
     """Paged decode with NON-CONTIGUOUS block tables that wrap the pool
-    out of order produces logits identical (1e-5) to the dense per-slot
-    cache, token for token."""
+    out of order produces logits identical (1e-5) to the dense batch
+    cache (what `generate` runs: one prompt a row at its true length),
+    token for token."""
     import jax.numpy as jnp
 
     from ray_tpu.models import llama_decode as D
@@ -176,11 +178,12 @@ def test_paged_decode_matches_dense_wrapped_tables():
     slots = np.arange(A, dtype=np.int32)
     rems = np.full(A, 5, np.int32)
 
-    dense = D.init_slot_cache(cfg, n_slots, MB * bs)
-    feed_d = jnp.zeros(n_slots, jnp.int32)
-    first_d, dense, feed_d = D.admit_slots_masked(
-        params, jnp.asarray(pr), jnp.asarray(lengths), jnp.asarray(slots),
-        jnp.asarray(rems), dense, feed_d, cfg)
+    prefill, decode_step = D._jitted_prefill(cfg), D._jitted_decode_step(cfg)  # `generate`'s own
+    dense, feed_d = [], []
+    for p in prompts:
+        last, cache = prefill(params, jnp.asarray([p], jnp.int32), D.init_cache(cfg, 1, MB * bs))
+        dense.append(cache)
+        feed_d.append(jnp.argmax(last, axis=-1).astype(jnp.int32))
 
     paged = D.init_paged_cache(cfg, n_slots, 12, bs)
     # shuffled, interleaved, wrapping the pool: slot 0 high-to-low,
@@ -199,18 +202,19 @@ def test_paged_decode_matches_dense_wrapped_tables():
         jnp.zeros(A, jnp.uint32), paged, feed_p, jnp.asarray(tables),
         greedy["temps"], greedy["top_ks"], greedy["top_ps"],
         greedy["stop_ids"], cfg)
-    np.testing.assert_array_equal(np.asarray(first_d), np.asarray(first_p))
+    np.testing.assert_array_equal(np.concatenate(feed_d), np.asarray(first_p))
 
     for _ in range(4):
-        logits_d, dense = D.decode_step_slots(params, dense, feed_d, cfg)
-        nxt_d = jnp.argmax(logits_d, axis=-1).astype(jnp.int32)
-        logits_p, nxt_p, paged = D.decode_step_slots_paged(
+        rows = [decode_step(params, c, f) for c, f in zip(dense, feed_d)]
+        logits_d = np.concatenate([np.asarray(lg) for lg, _ in rows])
+        dense = [c for _, c in rows]
+        feed_d = [jnp.argmax(lg, axis=-1).astype(jnp.int32) for lg, _ in rows]
+        logits_p, feed_p, paged = D.decode_step_slots_paged(
             params, paged, feed_p, jnp.asarray(tables), greedy["temps"],
             greedy["top_ks"], greedy["top_ps"], greedy["stop_ids"], cfg)
         np.testing.assert_allclose(
-            np.asarray(logits_d), np.asarray(logits_p), rtol=1e-5, atol=1e-5)
-        np.testing.assert_array_equal(np.asarray(nxt_d), np.asarray(nxt_p))
-        feed_d, feed_p = nxt_d, nxt_p
+            logits_d, np.asarray(logits_p), rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(np.concatenate(feed_d), np.asarray(feed_p))
 
 
 # ------------------------------- admission attention: parity and lowering
@@ -285,12 +289,13 @@ def test_admission_attention_matches_one_shot(name, monkeypatch):
     import jax.numpy as jnp
 
     from ray_tpu.models import llama_decode as D
+    from ray_tpu.models import paged
 
     params, cfg = _tiny()
     P, starts, lengths, tables, pool_k, pool_v, rng = _admission_case(name, cfg)
     A, bs, S = 4, _ADM_BS, _ADM_MB * _ADM_BS
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    assert D.PREFIX_CHUNK == 512 < S
+    assert paged.PREFIX_CHUNK == 512 < S
 
     # --- the attention alone, on a pool that holds the rows' suffixes
     q = rng.standard_normal((A, P, h, hd)).astype(np.float32)
@@ -303,7 +308,7 @@ def test_admission_attention_matches_one_shot(name, monkeypatch):
             k_layer[tables[n, blk], off] = k[n, t]
             v_layer[tables[n, blk], off] = v[n, t]
     args = [jnp.asarray(x) for x in (q, k, v, k_layer, v_layer, tables, starts)]
-    got = np.asarray(D._attend_admission(*args, cfg))
+    got = np.asarray(paged._attend_admission(*args, cfg))
     want = np.asarray(_one_shot_attention(*args, cfg))
     real = np.arange(P)[None, :] < lengths[:, None]  # (A, P) real queries
     assert real.any(axis=1).tolist() == (lengths > 0).tolist()
@@ -324,7 +329,7 @@ def test_admission_attention_matches_one_shot(name, monkeypatch):
             sampled=False)
 
     first_new, cache_new, feed_new = admit()
-    monkeypatch.setattr(D, "_attend_admission", _one_shot_attention)
+    monkeypatch.setattr(paged, "_attend_admission", _one_shot_attention)
     first_ref, cache_ref, feed_ref = admit()
     valid = lengths > 0
     np.testing.assert_array_equal(np.asarray(first_new)[valid], np.asarray(first_ref)[valid])
@@ -395,12 +400,12 @@ def test_admission_lowering_has_no_span_tensor(monkeypatch):
     the (A, heads, P, span) scores nor gathers a whole table span a row
     out of the pool, so the tensor PR 28 removed cannot come back
     unnoticed. The one-shot reference must trip both detectors."""
-    from ray_tpu.models import llama_decode as D
+    from ray_tpu.models import paged
 
     big, gathers = _admission_span_tensors()
     assert not big, f"admission materializes {big}"
     assert not gathers, f"admission gathers the table span: {gathers}"
-    monkeypatch.setattr(D, "_attend_admission", _one_shot_attention)
+    monkeypatch.setattr(paged, "_attend_admission", _one_shot_attention)
     big, gathers = _admission_span_tensors()
     assert big and gathers, "the lint failed to flag the one-shot formulation"
 
@@ -460,11 +465,11 @@ def test_decode_attention_matches_one_shot(name, layout, dtype):
     probabilities to 8 bits of mantissa at different scales before PV)."""
     import jax.numpy as jnp
 
-    from ray_tpu.models import llama_decode as D
+    from ray_tpu.models import paged
 
     pos, active = (np.asarray(x, np.int32) for x in DECODE_CASES[name])
     B, bs, MB, h, kvh, hd, li = 4, _DEC_BS, _DEC_MB, 4, 2, 16, 1
-    C = D.decode_chunk_positions(bs, MB)
+    C = paged.decode_chunk_positions(bs, MB)
     assert C == 128 and MB * bs == 8 * C
     rng = np.random.default_rng(sorted(DECODE_CASES).index(name))
     n_blocks = B * MB + 1
@@ -476,7 +481,7 @@ def test_decode_attention_matches_one_shot(name, layout, dtype):
     q = jnp.asarray(rng.standard_normal((B, h, hd)), jdt)
     args = (q, k_full, v_full, jnp.int32(li), jnp.asarray(tables), jnp.asarray(pos),
             jnp.asarray(active.astype(bool)), 0.2)
-    got = np.asarray(D.attend_decode_paged(*args), np.float32)
+    got = np.asarray(paged.attend_decode_paged(*args), np.float32)
     want = np.asarray(_one_shot_decode_attention(*args), np.float32)
     assert got.shape == want.shape == (B, h * hd) and np.isfinite(got).all()
     live = active.astype(bool)
@@ -539,12 +544,12 @@ def test_decode_lowering_has_no_layer_copy_and_no_span_gather(model, monkeypatch
     pool layer (n_blocks, bs, ...) nor one of a lane's whole table span
     (B, MB * bs, ...): gathered context, scores or probabilities. The
     one-shot reference must trip both detectors."""
-    from ray_tpu.models import llama_decode as D
+    from ray_tpu.models import paged
 
     layers, spans = _decode_span_tensors(model)
     assert not layers, f"the decode step copies a pool layer: {layers}"
     assert not spans, f"the decode step builds a table span: {spans}"
-    monkeypatch.setattr(D, "attend_decode_paged", _one_shot_decode_attention)
+    monkeypatch.setattr(paged, "attend_decode_paged", _one_shot_decode_attention)
     layers, spans = _decode_span_tensors(model)
     assert layers and spans, "the lint failed to flag the one-shot formulation"
     assert any(name == "gather" for name, _ in spans)
@@ -594,7 +599,7 @@ def test_layer_index_traced_matches_static():
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models import llama_decode as D
+    from ray_tpu.models import paged
 
     L, B, bs, MB, h, kvh, hd, P = 3, 2, 8, 4, 4, 2, 16, 16
     n_blocks = B * MB + 1
@@ -607,11 +612,11 @@ def test_layer_index_traced_matches_static():
     kP, vP = arr(B, P, kvh, hd), arr(B, P, kvh, hd)
     starts, valid = jnp.asarray([0, 8], jnp.int32), jnp.asarray([True, True])
     programs = {
-        "write_decode_kv": lambda li: D.write_decode_kv(
+        "write_decode_kv": lambda li: paged.write_decode_kv(
             k_full, v_full, li, k1, v1, tables, pos, active),
-        "attend_decode_paged": lambda li: D.attend_decode_paged(
+        "attend_decode_paged": lambda li: paged.attend_decode_paged(
             q, k_full, v_full, li, tables, pos, active, 0.25),
-        "write_admission_kv": lambda li: D.write_admission_kv(
+        "write_admission_kv": lambda li: paged.write_admission_kv(
             k_full, v_full, li, kP, vP, tables, starts, valid),
     }
     for name, f in programs.items():
@@ -664,7 +669,7 @@ def _head_splits_of_products(program):
 def test_paged_programs_split_heads_after_a_barrier(program, monkeypatch):
     """Lint: in each of Llama's paged programs no bare dot_general feeds a
     head reshape; the three projections pass one optimization barrier first
-    (llama_decode._qkv says what the compiler does otherwise). The
+    (llama._qkv says what the compiler does otherwise). The
     formulation they had until PR 32 must trip the detector."""
     from ray_tpu.models import llama_decode as D
 
@@ -688,6 +693,7 @@ def test_hybrid_macro_step_lowers_without_llamas_halves(monkeypatch):
     from ray_tpu.models import granite_hybrid as G
     from ray_tpu.models import granite_hybrid_decode as GD
     from ray_tpu.models import llama_decode as D
+    from ray_tpu.models import paged
     from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
 
     cfg = G.GraniteHybridConfig.tiny(dtype=jnp.float32)
@@ -699,7 +705,7 @@ def test_hybrid_macro_step_lowers_without_llamas_halves(monkeypatch):
 
     def lowered():
         # a fresh jit each time: the memoized one would hand back its trace
-        step = jax.jit(lambda *a: D.macro_step_slots_paged(
+        step = jax.jit(lambda *a: paged.macro_step_slots_paged(
             *a, chunk=2, cfg=cfg, sampled=False, admit=GD.admit_slots_paged,
             decode_step=GD.decode_step_slots_paged))
         return step.lower(

@@ -44,6 +44,7 @@ from ray_tpu.models import qwen3_next as M
 from ray_tpu.models import qwen3_next_decode as D
 from ray_tpu.ops import ssm_update as SU
 from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
+from tests.conftest import static_answers
 
 F32_RTOL = 1e-4
 BF16_RMS = 0.25
@@ -488,9 +489,9 @@ def test_engine_serves_more_requests_than_lanes_like_the_static_path():
         reqs = [eng.submit(p, n) for p, n in zip(prompts, answers)]
         assert all(r.done.wait(240) for r in reqs)
         m1 = eng.metrics()
-        for p, n, r in zip(prompts, answers, reqs):
+        for want, r in zip(static_answers(D.generate, params, cfg, prompts, answers), reqs):
             assert r.error is None
-            assert r.tokens == D.generate(params, np.asarray([p]), cfg, n)[0].tolist()
+            assert r.tokens == want
     finally:
         eng.shutdown()
     lane_steps = m1["useful_slot_steps"] - m0["useful_slot_steps"]

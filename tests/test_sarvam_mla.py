@@ -29,10 +29,11 @@ import pytest
 from benchmark import reference_sarvam_mla as R
 from benchmark import weights_sarvam_mla as W
 from ray_tpu.models import afmoe
-from ray_tpu.models import llama_decode as L
+from ray_tpu.models import paged
 from ray_tpu.models import sarvam_mla as M
 from ray_tpu.models import sarvam_mla_decode as D
 from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
+from tests.conftest import static_answers
 
 F32_RTOL = 1e-4
 BF16_ATOL = 0.15
@@ -256,10 +257,9 @@ class Lanes:
                          stop_ids=jnp.full((n, 1), -1, jnp.int32))
         self._admit, self._step = halves or _jitted_halves(cfg)
 
-    def admit(self, rows, bucket, new=8, width=None):
-        """rows: [(lane, prompt)]; the admission is `width` rows wide (the
-        rest padding rows of length 0) and `bucket` positions long."""
-        A = width or len(rows)
+    def admit(self, rows, bucket, new=8):
+        """rows: [(lane, prompt)], one admission row each, `bucket` positions long."""
+        A = len(rows)
         prompts = np.zeros((A, bucket), np.int32)
         lengths, slots = np.zeros(A, np.int32), np.zeros(A, np.int32)
         for i, (lane, p) in enumerate(rows):
@@ -315,37 +315,6 @@ def test_admission_then_decode_matches_the_reference_at_every_position(dtype):
     assert D.state_bytes_per_lane(cfg) == 0 and D.LATENT_POOL
 
 
-@pytest.mark.parametrize("chunk", [None, 16], ids=["pairs-at-once", "pairs-in-chunks-of-16"])
-def test_a_padded_admission_is_each_prompt_admitted_alone(chunk, monkeypatch):
-    """Right-padded prompts of unequal length and a row of length 0 in one
-    (4, 32) admission, whose padded rows choose no expert: each lane's first
-    token, its latent rows at every real position and the next step's logits
-    are what the prompt gives admitted alone in a bucket of its own length
-    (in whole blocks); with the pairs at once (the tiny admission has
-    fewer than `expert_ffn`'s chunk) and in chunks."""
-    cfg, _, params = _model()
-    if chunk is not None:
-        monkeypatch.setattr(afmoe, "expert_ffn", functools.partial(afmoe.expert_ffn, chunk=chunk))
-    halves = (jax.jit(functools.partial(D.admit_slots_paged, cfg=cfg, sampled=False)),
-              jax.jit(functools.partial(D.decode_step_slots_paged, cfg=cfg, sampled=False)))
-    prompts = [_tokens(1, 19, seed=3)[0], _tokens(1, 5, seed=4)[0], _tokens(1, 32, seed=5)[0]]
-    together = Lanes(cfg, params, n=3, halves=halves)
-    first = together.admit([(0, prompts[0]), (2, prompts[2]), (1, prompts[1])], bucket=32, width=4)
-
-    def rows_of(lanes, b, n):
-        pool = lanes.cache["latent"][:, 1 + b * lanes.mb:1 + (b + 1) * lanes.mb]
-        return np.asarray(pool).reshape(pool.shape[0], -1, pool.shape[-1])[:, :n]
-
-    admitted = [rows_of(together, b, len(p)) for b, p in enumerate(prompts)]
-    logits, _ = together.step()
-    for b, (i, p) in enumerate(zip((0, 2, 1), prompts)):
-        alone = Lanes(cfg, params, n=3, halves=halves)
-        assert alone.admit([(b, p)], bucket=-(-len(p) // BLOCK) * BLOCK)[0] == first[i]
-        got, want = admitted[b], rows_of(alone, b, len(p))
-        assert np.abs(got - want).max() <= 1e-4 * np.abs(want).max()
-        assert _worst(logits[b], alone.step()[0][b], jnp.float32) <= 1.0
-
-
 def _no_rope_on_the_shared_key(orig):
     return lambda x, cos, sin, positions=None: x if x.shape[2] == 1 else orig(x, cos, sin, positions)
 
@@ -383,7 +352,7 @@ MUTATIONS = {
     "no-yarn-in-the-softmax-scale": (M.SarvamMlaConfig, "sm_scale",
                                      lambda orig: property(lambda c: c.q_head_dim ** -0.5)),
     "no-rope-on-the-shared-key": (M, "apply_rope", _no_rope_on_the_shared_key),
-    "values-from-all-the-row's-columns": (L, "attend_decode_paged", _values_from_every_column),
+    "values-from-all-the-row's-columns": (paged, "attend_decode_paged", _values_from_every_column),
     "bias-in-the-weight": (afmoe, "route", _bias_in_the_weight),
     "an-absent-expert's-pair-computed": (afmoe, "expert_ffn", _absent_pairs_computed),
 }
@@ -444,9 +413,9 @@ def test_engine_serves_more_requests_than_lanes_and_its_spans_sum_to_its_counter
             m1 = eng.metrics()
         finally:
             jax.profiler.stop_trace()
-        for p, n, r in zip(prompts, answers, reqs):
+        for want, r in zip(static_answers(D.generate, params, cfg, prompts, answers), reqs):
             assert r.error is None
-            assert r.tokens == D.generate(params, np.asarray([p]), cfg, n)[0].tolist()
+            assert r.tokens == want
     finally:
         eng.shutdown()
     from jax.profiler import ProfileData

@@ -99,7 +99,20 @@ def test_trainer_surfaces_worker_failure(ray_start_regular, tmp_path):
         trainer.fit()
 
 
-def test_trainer_gang_infeasible_raises(ray_start_regular, tmp_path):
+def test_trainer_gang_infeasible_raises(ray_start_regular, tmp_path, monkeypatch):
+    """No node will ever have 100 CPUs a worker: the reservation is waited for
+    and given up. The product waits 120 s (`WorkerGroup.__init__`); the test
+    hands `PlacementGroup.wait` 3 s of it, which the GCS waits out in full."""
+    from ray_tpu.util.placement_group import PlacementGroup
+
+    waited = []
+    wait = PlacementGroup.wait
+
+    def short_wait(self, timeout_seconds=60.0):
+        waited.append(timeout_seconds)
+        return wait(self, min(timeout_seconds, 3.0))
+
+    monkeypatch.setattr(PlacementGroup, "wait", short_wait)
     trainer = JaxTrainer(
         lambda c: None,
         scaling_config=ScalingConfig(num_workers=2, resources_per_worker={"CPU": 100}),
@@ -107,6 +120,7 @@ def test_trainer_gang_infeasible_raises(ray_start_regular, tmp_path):
     )
     with pytest.raises(RuntimeError, match="reserve"):
         trainer.fit()
+    assert waited == [120]  # the product's own wait, asked for once
 
 
 def test_trainer_jax_training_loop(ray_start_regular, tmp_path):
