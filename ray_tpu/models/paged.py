@@ -28,7 +28,7 @@ reaches a decode module only through `cfg.decode_module`
 (tests/test_lint_paged_kv.py holds all three).
 
 WHAT A DECODE MODULE OFFERS THE ENGINE (tests/test_decode_modules.py holds
-the six to it). `cfg.decode_module` of a model's config names the module;
+the seven to it). `cfg.decode_module` of a model's config names the module;
 `ContinuousBatchingEngine` and `serve/llm.py` take from it, by name:
 
   init_paged_cache(cfg, n_slots, n_blocks, block_size) -> cache

@@ -1,5 +1,5 @@
 """What a decode module offers the engine (models/paged.py's docstring), held
-against the six `cfg.decode_module`s.
+against the seven `cfg.decode_module`s.
 
 Nothing is compiled: shapes come from `jax.eval_shape`, which traces the
 model's macro-step at its tiny config (one phase, one admission row of one
@@ -11,7 +11,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import afmoe, granite_hybrid, llama, longcat_flash, paged, qwen3_next, sarvam_mla
+from ray_tpu.models import (afmoe, granite_hybrid, llama, longcat_flash, paged, phi4flash, qwen3_next,
+                            sarvam_mla)
 from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
 
 CONFIGS = {
@@ -21,6 +22,7 @@ CONFIGS = {
     "sarvam_mla": lambda: sarvam_mla.SarvamMlaConfig.tiny(dtype=jnp.float32),
     "qwen3_next": lambda: qwen3_next.Qwen3NextConfig.tiny(dtype=jnp.float32),
     "longcat_flash": lambda: longcat_flash.LongcatFlashConfig.tiny(dtype=jnp.float32),
+    "phi4flash": lambda: phi4flash.Phi4FlashConfig.tiny(dtype=jnp.float32),
 }
 LANES, BLOCKS, BLOCK, K, A, CHUNK = 2, 5, 8, 1, 1, 2
 

@@ -20,6 +20,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 
@@ -772,6 +773,143 @@ def test_longcat_flash_shortest_bucket_decodes_with_pool_and_weights_in_place(on
         assert "/decode_chunk/" in text and f"/{scope}/" in text, scope
 
 
+# ---------------------------------------------------------------- ISSUE 49
+def _phi4flash_macro_step(one_chip, A, P, kernel: bool = True):
+    """Phi-4-mini-flash's paged macro-step at the published widths, whole (32
+    layers, the 200,064-row vocabulary) at `reasoning-generate`'s 64 lanes
+    and table span of 2048, compiled for the described chip at the (A, P)
+    program, the admission's attention through the flash kernel and the state
+    update through its kernel, as the chip runs them, or through plain XLA."""
+    from unittest import mock
+
+    from ray_tpu.models import phi4flash as M
+    from ray_tpu.models import phi4flash_decode as D
+    from ray_tpu.ops import s6_update as S6
+    from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
+
+    cfg = M.Phi4FlashConfig()
+    B, bs, K = 64, 16, 8
+    MB = cfg.max_seq_len // bs
+    arr, shaped = _shapes_on(one_chip)
+    params = shaped(jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
+    with mock.patch.object(S6, "_on_tpu", lambda: kernel), mock.patch.object(FA, "_on_tpu", lambda: True):
+        return D.jitted_macro_step_slots_paged.__wrapped__(cfg, 8, sampled=False).lower(
+            params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
+            arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
+            arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
+            arr((K, B, MAX_STOP_TOKENS))).compile()
+
+
+def _phi4flash_moved(text):
+    """Operations of an optimized Phi-4-mini-flash macro-step that copy the
+    pool's ONE layer, a ring stack or the stacked SSM state anywhere, or, in a
+    decode step (by its `decode_chunk` scope), put out a whole layer of a ring
+    stack or of the state outside the state update's kernel. The in-place
+    writes are dynamic-update-slice fusions whose result is the stack they
+    were given; an admission 64 rows wide has rows of a ring layer's and a
+    state layer's shape of its own, which are neither. With `s6_step` in place
+    of the kernel (`kernel=False`) the one-row program (1, 16) trips it: two
+    `select_dynamic-update-slice` fusions, the pair scan's and layer 16's,
+    put out f32[9,64,16,5120] in every decode step (compiled only, PR 49; no
+    case of its own beside the kernel's)."""
+    import re
+
+    stacks = re.compile(r"bf16\[1,8193,16,1280\]|bf16\[8,64,512,1280\]|f32\[9,64,16,5120\]")
+    # in a decode step the kernel alone puts out the state, stack or layer
+    layers = re.compile(r"bf16\[(1,)?64,512,1280\]|f32\[(9,|1,)?64,16,5120\]")
+    line = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(")
+    moved = []
+    for ln in text.splitlines():
+        m = line.match(ln)
+        if not m or '"estimated_cycles"' not in ln:
+            continue
+        name, out, op = m.groups()
+        for s in re.findall(r"(?:bf16|f32)\[[\d,]+\]", out):
+            if (op == "copy" and stacks.fullmatch(s)) or (
+                    layers.fullmatch(s) and "decode_chunk" in ln and op != "custom-call"):
+                moved.append((name, op, s))
+    return moved
+
+
+def _phi4flash_steps_its_state_in_place(text):
+    """A decode step's state update is the kernel `s6_update`, one call in the
+    rolled pair scan and one for layer 16, the stack aliased onto its output,
+    and nothing moves the pool, a ring stack or the state (`_phi4flash_moved`)."""
+    updates = [ln for ln in text.splitlines() if "custom-call(" in ln and " %s6_update" in ln]
+    assert len(updates) == 2 and all("decode_chunk" in ln and "/s6_update/" in ln for ln in updates)
+    assert all("output_to_operand_aliasing={{1}: (8, {})}" in ln for ln in updates)
+    assert not _phi4flash_moved(text), _phi4flash_moved(text)
+
+
+def test_phi4flash_widest_admission_fits_the_chip_with_pool_rings_and_state_in_place(one_chip):
+    """(A, P) = (64, 512), up to 32,768 admitted tokens, the program of the
+    cell's longest bucket with its seven admission bodies and the decode
+    body (the shortest, (1, 16), has the next test): 7.71 GB of weights, ONE
+    pool layer of 0.67 GB, eight ring layers of 1.34 GB, 0.19 GB of float32
+    states and conv tails go in (the cache donated), 3.47 GB of temporaries (an MLP's (64, 512, 20480) products the
+    largest), 13.4 GB of the chip's 16 (compiled only, PR 49). Each body's
+    window and full attentions are the flash kernel over queries laid out 128
+    wide (one call in the rolled pair scan, one for the full layer); the
+    decode step's state update is the kernel `s6_update`, one call in the
+    rolled pair scan and one for layer 16, the stack aliased; nothing copies
+    the pool, a ring stack or the state, and none of their layers is sliced
+    out. THE CROSS-DECODER OF AN ADMISSION RUNS ONE ROW A PROMPT: under
+    `admit_prefill` its products are (rows, .), never (rows x 512, .)."""
+    import re
+
+    compiled = _phi4flash_macro_step(one_chip, 64, 512)
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+    print(f"memory_analysis (64, 512): arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, total {total / 1e9:.2f} GB")
+    assert 9.9e9 < m.argument_size_in_bytes < 10.0e9 and m.alias_size_in_bytes > 2.2e9
+    assert total < 14.0e9, total
+    text = compiled.as_text()
+    assert _admission_bodies(text) == (7, 1)
+    kernels = re.findall(r"%flash_fwd[.\d]* = \((bf16\[[\d,]+\])[^=]*custom-call\(", text)
+    assert sorted(kernels) == sorted(
+        [f"bf16[{40 * rows},512,128]" for rows in (1, 2, 4, 8, 16, 32, 64) for _ in range(2)]), kernels
+    _phi4flash_steps_its_state_in_place(text)
+    # the cross-decoder's products in an admission: a memory unit's (rows,
+    # 5120), a cross attention's (rows, 2560) and its scores (rows, 40, 512):
+    # nothing as large as one width of one position a row, (64 x 512, 128)
+    out_type = re.compile(r" = (\(.*?\)|\S+) [\w\-]+\(")
+    sizes = [int(np.prod([int(d) for d in dims.split(",")]))
+             for ln in text.splitlines()
+             if '"estimated_cycles"' in ln and "/admit_prefill/" in ln
+             and ("/gmu/" in ln or "/cross_attn/" in ln)
+             for dims in re.findall(r"(?:bf16|f32)\[([\d,]+)\]", out_type.search(ln).group(1))]
+    assert sizes and max(sizes) < 64 * 512 * 128, max(sizes)
+    # the layers stay rolled (two kernel calls for nine Mamba layers) and every
+    # scope of the model is in the program, each in its half
+    for scope in ("s6_proj", "s6_update", "diff_window", "diff_full", "cross_attn", "gmu"):
+        assert re.search(rf"/decode_chunk/[\w/]*{scope}/", text), scope
+    assert re.search(r"/admit_prefill/[\w/]*s6_scan/", text)
+
+
+def test_phi4flash_decode_only_dispatch_fits_the_chip_and_steps_the_state_in_place(one_chip):
+    """(A, P) = (1, 16): the program of a dispatch that admits nothing, or one
+    short prompt, which is most of `reasoning-generate`'s dispatches (answers
+    of 256-1,024 tokens behind prompts of 129-512). One admission body and the
+    decode body; 9.93 GB of arguments (weights, the ONE pool layer, the eight
+    rings, the states) and under 0.1 GB of temporaries (compiled only, PR
+    49), the cache donated. The decode step is what the widest program's is:
+    the state update is the kernel `s6_update`, two calls for nine Mamba
+    layers, the stack aliased; nothing copies the pool, a ring stack or the
+    state, and none of their layers is put out by anything else."""
+    compiled = _phi4flash_macro_step(one_chip, 1, 16)
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+    print(f"memory_analysis (1, 16): arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, total {total / 1e9:.2f} GB")
+    assert 9.9e9 < m.argument_size_in_bytes < 10.0e9 and m.alias_size_in_bytes > 2.2e9
+    assert m.temp_size_in_bytes < 0.1e9 and total < 10.1e9, (m.temp_size_in_bytes, total)
+    text = compiled.as_text()
+    assert _admission_bodies(text) == (1, 1)
+    _phi4flash_steps_its_state_in_place(text)
+
+
 # ------------------------------------------------------ the kernels alone
 # Seconds each, and LAST in the file: pytest-xdist hands a worker its next
 # file when two tests of this one are left, and a file queued behind two
@@ -946,5 +1084,26 @@ def test_delta_rule_update_kernel_compiles_with_the_stack_aliased(one_chip):
         f32((L, H, K)), f32((L, H, V)), f32((L, H))).compile()
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes == 4 * M_ * L * H * K * V
+    assert m.temp_size_in_bytes < 1e6, m.temp_size_in_bytes
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+
+def test_s6_update_kernel_compiles_with_the_stack_aliased(one_chip):
+    """The kernel of ops/s6_update.py at the cell's shapes (9 Mamba-1 layers x
+    64 lanes x 16 x 5120 float32, 0.19 GB; a lane's whole (16, 5120) row, 0.33
+    MB, a block) compiles for the chip with the stack aliased and nothing
+    beside it."""
+    from ray_tpu.ops import s6_update as S6
+
+    M_, L, N, c = 9, 64, 16, 5120
+    assert S6.supported(N, c) and S6.channels_per_block(N, c) == c
+    arr, _ = _shapes_on(one_chip)
+    f32 = functools.partial(arr, dtype=jnp.float32)
+    compiled = jax.jit(S6._s6_update_pallas, donate_argnums=(0,)).lower(
+        f32((M_, L, N, c)), arr(()), arr((L,)), arr((1,)), f32((L, c)), f32((L, c)), f32((N, c)),
+        f32((L, N)), f32((L, N))).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 4 * M_ * L * N * c
     assert m.temp_size_in_bytes < 1e6, m.temp_size_in_bytes
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
