@@ -803,9 +803,11 @@ def _phi4flash_macro_step(one_chip, A, P, kernel: bool = True):
 
 def _phi4flash_moved(text):
     """Operations of an optimized Phi-4-mini-flash macro-step that copy the
-    pool's ONE layer, a ring stack or the stacked SSM state anywhere, or, in a
-    decode step (by its `decode_chunk` scope), put out a whole layer of a ring
-    stack or of the state outside the state update's kernel. The in-place
+    pool's ONE layer, the decode steps' fetched context (a K or a V buffer of
+    16 chunks x 64 lanes x 128 positions, as large as the pool), a ring stack
+    or the stacked SSM state anywhere, or, in a decode step (by its
+    `decode_chunk` scope), put out a whole layer of a ring stack or of the
+    state outside the state update's kernel. The in-place
     writes are dynamic-update-slice fusions whose result is the stack they
     were given; an admission 64 rows wide has rows of a ring layer's and a
     state layer's shape of its own, which are neither. With `s6_step` in place
@@ -815,7 +817,8 @@ def _phi4flash_moved(text):
     case of its own beside the kernel's)."""
     import re
 
-    stacks = re.compile(r"bf16\[1,8193,16,1280\]|bf16\[8,64,512,1280\]|f32\[9,64,16,5120\]")
+    stacks = re.compile(r"bf16\[1,8193,16,1280\]|bf16\[16,64,128,1280\]|bf16\[8,64,512,1280\]|"
+                        r"f32\[9,64,16,5120\]")
     # in a decode step the kernel alone puts out the state, stack or layer
     layers = re.compile(r"bf16\[(1,)?64,512,1280\]|f32\[(9,|1,)?64,16,5120\]")
     line = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(")
@@ -832,23 +835,62 @@ def _phi4flash_moved(text):
     return moved
 
 
+def _phi4flash_pool_reads(text):
+    """How a decode step of an optimized Phi-4-mini-flash program (64 lanes,
+    blocks of 16, chunks of 128 positions) reads its ONE pool layer, among the
+    operations that are instructions of their own (not inside a fused
+    computation): (the `op_name`s of those that put out a gathered chunk of
+    the pool, bf16[512,16,1280]: 512 blocks, K or V; those that put out a
+    chunk of the fetched context, bf16[64,128,1280], that is, copy out of the
+    buffer what the products should read where it lies; the loops under
+    `cross_attn`: ONE, the readers' loop over chunks, while the
+    cross-decoder's scan stays rolled, seven were it unrolled)."""
+    import re
+
+    line = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (\(.*?\)|\S+) [\w\-]+\(.*op_name=\"([^\"]*)\"")
+    gathers, chunks, fused = [], [], False
+    for ln in text.splitlines():
+        if ln.startswith(("%", "ENTRY")):  # a computation opens: a fusion's body or not
+            fused = ln.startswith("%fused_computation")
+        m = None if fused else line.match(ln)
+        if m and "decode_chunk" in m.group(2):
+            shapes = re.findall(r"bf16\[[\d,]+\]", m.group(1))
+            gathers += [m.group(2) for s in shapes if s == "bf16[512,16,1280]"]
+            chunks += [m.group(2) for s in shapes if s in ("bf16[64,128,1280]", "bf16[1,64,128,1280]")]
+    return gathers, chunks, _loops_under(text, "decode_chunk", "cross_attn")
+
+
 def _phi4flash_steps_its_state_in_place(text):
     """A decode step's state update is the kernel `s6_update`, one call in the
     rolled pair scan and one for layer 16, the stack aliased onto its output,
-    and nothing moves the pool, a ring stack or the state (`_phi4flash_moved`)."""
+    and nothing moves the pool, the fetched context, a ring stack or the state
+    (`_phi4flash_moved`). THE POOL IS READ ONCE A STEP (PR 50): two operations
+    gather a chunk of it, K's and V's, under the full layer's scope and none
+    under `cross_attn`; the full layer attends each chunk as gathered and the
+    seven readers behind it read their chunk of the fetched context where it
+    lies, inside their products (nothing puts one out); the cross-decoder's
+    scan is rolled."""
     updates = [ln for ln in text.splitlines() if "custom-call(" in ln and " %s6_update" in ln]
     assert len(updates) == 2 and all("decode_chunk" in ln and "/s6_update/" in ln for ln in updates)
     assert all("output_to_operand_aliasing={{1}: (8, {})}" in ln for ln in updates)
     assert not _phi4flash_moved(text), _phi4flash_moved(text)
+    gathers, chunks, cross_loops = _phi4flash_pool_reads(text)
+    assert len(gathers) == 2 and all("/diff_full/" in g for g in gathers), gathers
+    assert not chunks, chunks
+    assert len(cross_loops) == 1, cross_loops
 
 
 def test_phi4flash_widest_admission_fits_the_chip_with_pool_rings_and_state_in_place(one_chip):
     """(A, P) = (64, 512), up to 32,768 admitted tokens, the program of the
     cell's longest bucket with its seven admission bodies and the decode
     body (the shortest, (1, 16), has the next test): 7.71 GB of weights, ONE
-    pool layer of 0.67 GB, eight ring layers of 1.34 GB, 0.19 GB of float32
-    states and conv tails go in (the cache donated), 3.47 GB of temporaries (an MLP's (64, 512, 20480) products the
-    largest), 13.4 GB of the chip's 16 (compiled only, PR 49). Each body's
+    pool layer of 0.67 GB, the decode steps' fetched context as large again
+    (PR 50: scratch in the donated cache, so that no step makes or fills it),
+    eight ring layers of 1.34 GB, 0.19 GB of float32 states and conv tails go
+    in (the cache donated), 3.47 GB of temporaries (an MLP's (64, 512, 20480)
+    products the largest), 14.07 GB of the chip's 16 (compiled only, PR 50;
+    13.40 before the context, PR 49; 14.74 with the context made once a
+    dispatch inside the program: 4.81 GB of temporaries). Each body's
     window and full attentions are the flash kernel over queries laid out 128
     wide (one call in the rolled pair scan, one for the full layer); the
     decode step's state update is the kernel `s6_update`, one call in the
@@ -863,8 +905,8 @@ def test_phi4flash_widest_admission_fits_the_chip_with_pool_rings_and_state_in_p
     total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
     print(f"memory_analysis (64, 512): arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
           f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, total {total / 1e9:.2f} GB")
-    assert 9.9e9 < m.argument_size_in_bytes < 10.0e9 and m.alias_size_in_bytes > 2.2e9
-    assert total < 14.0e9, total
+    assert 10.55e9 < m.argument_size_in_bytes < 10.65e9 and m.alias_size_in_bytes > 2.85e9
+    assert total < 14.5e9, total
     text = compiled.as_text()
     assert _admission_bodies(text) == (7, 1)
     kernels = re.findall(r"%flash_fwd[.\d]* = \((bf16\[[\d,]+\])[^=]*custom-call\(", text)
@@ -892,9 +934,13 @@ def test_phi4flash_decode_only_dispatch_fits_the_chip_and_steps_the_state_in_pla
     """(A, P) = (1, 16): the program of a dispatch that admits nothing, or one
     short prompt, which is most of `reasoning-generate`'s dispatches (answers
     of 256-1,024 tokens behind prompts of 129-512). One admission body and the
-    decode body; 9.93 GB of arguments (weights, the ONE pool layer, the eight
-    rings, the states) and under 0.1 GB of temporaries (compiled only, PR
-    49), the cache donated. The decode step is what the widest program's is:
+    decode body; 10.60 GB of arguments (weights, the ONE pool layer and the
+    fetched context of its size, the eight rings, the states) and under 0.1 GB
+    of temporaries, 10.63 GB (compiled only, PR 50; 9.93 + 0.03 before the
+    context, PR 49), the cache donated. The decode step is what the widest
+    program's is: the pool read once, two gathers of a chunk under the full
+    layer's scope and none under `cross_attn`, the cross attentions' products
+    reading the context where it lies;
     the state update is the kernel `s6_update`, two calls for nine Mamba
     layers, the stack aliased; nothing copies the pool, a ring stack or the
     state, and none of their layers is put out by anything else."""
@@ -903,11 +949,46 @@ def test_phi4flash_decode_only_dispatch_fits_the_chip_and_steps_the_state_in_pla
     total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
     print(f"memory_analysis (1, 16): arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
           f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, total {total / 1e9:.2f} GB")
-    assert 9.9e9 < m.argument_size_in_bytes < 10.0e9 and m.alias_size_in_bytes > 2.2e9
-    assert m.temp_size_in_bytes < 0.1e9 and total < 10.1e9, (m.temp_size_in_bytes, total)
+    assert 10.55e9 < m.argument_size_in_bytes < 10.65e9 and m.alias_size_in_bytes > 2.85e9
+    assert m.temp_size_in_bytes < 0.1e9 and total < 10.8e9, (m.temp_size_in_bytes, total)
     text = compiled.as_text()
     assert _admission_bodies(text) == (1, 1)
     _phi4flash_steps_its_state_in_place(text)
+
+
+def test_eight_readers_through_the_pool_trip_the_detector_of_pool_reads(one_chip):
+    """The parent's read path alone, at the cell's shapes (no whole program:
+    the queries come in as arguments): the full layer and seven
+    cross-attention layers in a rolled scan, each through
+    `attend_decode_paged` under the step's scopes. Four operations gather a
+    chunk of the pool, two of them under `cross_attn` in the scan's body (run
+    seven times a step), which `_phi4flash_steps_its_state_in_place` holds to
+    two under the full layer's scope and none (compiled only, PR 50)."""
+    from ray_tpu.models import paged
+
+    B, bs, MB, h, row = 64, 16, 128, 40, 1280
+    arr, _ = _shapes_on(one_chip)
+
+    def macro_step_slots_paged(k_full, v_full, qs, tables, pos, active):
+        def attend(q):
+            return paged.attend_decode_paged(q, k_full, v_full, 0, tables, pos, active, 0.125)
+
+        def cross(o, q):
+            with jax.named_scope("cross_attn"):
+                return o + attend(q), None
+
+        with jax.named_scope(paged.DECODE_SCOPE):
+            with jax.named_scope("diff_full"):
+                o = attend(qs[0])
+            return jax.lax.scan(cross, o, qs[1:])[0]
+
+    pool = arr((1, B * MB + 1, bs, row), jnp.bfloat16)
+    text = jax.jit(macro_step_slots_paged).lower(
+        pool, pool, arr((8, B, h, 128), jnp.bfloat16), arr((B, MB)), arr((B,)),
+        arr((B,), jnp.bool_)).compile().as_text()
+    gathers, _, cross_loops = _phi4flash_pool_reads(text)
+    assert len(gathers) == 4 and sum("/cross_attn/" in g for g in gathers) == 2, gathers
+    assert len(cross_loops) == 1, cross_loops
 
 
 # ------------------------------------------------------ the kernels alone
