@@ -32,7 +32,12 @@ Admission leaves in a row's ring the last `window` positions of its prompt
 (a padded admission row writes nothing); the decode step writes the new
 position over the oldest and reads the ring whole, masked to the slots that
 hold a position; a full layer writes and reads the pool as every model's
-does. Release needs no device work: the next admission overwrites the ring.
+does. The step's write is `write_ring_tokens`: on a TPU ONE call a window
+layer of the kernel of ops/ring_write.py, which puts K's and V's row of
+every lane where they lie in the aliased stacks; elsewhere, and for a ring
+its tiles do not take, `write_ring_token`, a loop of in-place updates over
+the lanes, which is the definition. Release needs no device work: the next
+admission overwrites the ring.
 """
 from __future__ import annotations
 
@@ -102,6 +107,19 @@ def write_ring_token(ring, wi, kv, pos):
                                             (wi, b, pos[b] % window, 0))
 
     return jax.lax.fori_loop(0, kv.shape[0], write, ring)
+
+
+def write_ring_tokens(wk, wv, wi, k, v, pos):
+    """A decode step's write into BOTH rings of window layer `wi`: k, v (B,
+    row) into slot pos[b] % window of every lane. On a TPU, for rings its
+    tiles take, ONE call of the kernel of ops/ring_write.py, which puts each
+    row where it lies in the aliased stacks; elsewhere `write_ring_token`, the
+    definition, on each. The stacks come out the same byte for byte."""
+    from ray_tpu.ops import ring_write  # Pallas: imported where it is traced
+
+    if ring_write.engages(wk.shape[2], wk.shape[3], wk.dtype):
+        return ring_write.write_rows(wk, wv, wi, k, v, pos)
+    return write_ring_token(wk, wi, k, pos), write_ring_token(wv, wi, v, pos)
 
 
 def ring_slots_held(pos, window: int):
@@ -207,8 +225,7 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
             q, k, v, gate = M.qkvg(layer, a[:, None, :], cfg)
             q = apply_rope(q, cos, sin, pos[:, None])
             k = apply_rope(k, cos, sin, pos[:, None])
-            wk = write_ring_token(wk, wi, k.reshape(B, -1), pos)
-            wv = write_ring_token(wv, wi, v.reshape(B, -1), pos)
+            wk, wv = write_ring_tokens(wk, wv, wi, k.reshape(B, -1), v.reshape(B, -1), pos)
             out = M.gated_out(attend_decode_ring(q[:, 0], wk, wv, wi, pos, scale),
                               gate[:, 0], layer, cfg)
         return out, (k_full, v_full, wk, wv, counts)

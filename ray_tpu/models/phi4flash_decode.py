@@ -22,7 +22,10 @@ admission and decode step and this module's cache pytree:
             step makes or zero-fills an array as large as the pool
   wk, wv    (window layers, lanes, window, kv_row)  each lane's RING of the
             last `sliding_window` positions of every window layer, as
-            models/afmoe_decode.py keeps them
+            models/afmoe_decode.py keeps them; a decode step's new row goes
+            in through that module's `write_ring_tokens`: on a TPU the
+            kernel of ops/ring_write.py, one call a window layer for K and V
+            of all lanes, elsewhere its loop of in-place updates
   conv      (Mamba layers, taps - 1, lanes, d_inner)  each lane's conv tail
   ssm       (Mamba layers, lanes, N, d_inner) float32  each lane's SSM state
             (N on the second-minor axis: 16 as a minor one would be padded to
@@ -201,8 +204,7 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
         k_full, v_full, wk, wv, conv, ssm = carry
         with jax.named_scope(M.SCOPE_WINDOW):
             q, k, v = M.qkv(layer, a, cfg)
-            wk = rings.write_ring_token(wk, wi, k, pos)
-            wv = rings.write_ring_token(wv, wi, v, pos)
+            wk, wv = rings.write_ring_tokens(wk, wv, wi, k, v, pos)
             o = M.diff_attention(
                 layer, q, lambda q: rings.attend_decode_ring(q, wk, wv, wi, pos, scale).reshape(q.shape),
                 li, cfg)

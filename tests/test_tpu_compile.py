@@ -348,9 +348,13 @@ def test_hybrid_state_update_kernel_compiles_and_the_layers_stay_rolled(one_chip
 def _afmoe_macro_step(one_chip, A, P):
     """The AFMoE decoder's paged macro-step at `trinity-mini.serve`'s widths
     (one dense and four expert layers of 128 experts, 8 lanes, a table span
-    of 8192), compiled for the described chip at the (A, P) variant."""
+    of 8192), compiled for the described chip at the (A, P) variant, the
+    decode step's ring write through its kernel, as the chip runs it."""
+    from unittest import mock
+
     from ray_tpu.models import afmoe as M
     from ray_tpu.models import afmoe_decode as D
+    from ray_tpu.ops import ring_write as RW
     from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
 
     cfg = M.AfmoeConfig(layer_types=(M.SLIDING,) * 4 + (M.FULL,), n_dense_layers=1,
@@ -361,11 +365,38 @@ def _afmoe_macro_step(one_chip, A, P):
     arr, shaped = _shapes_on(one_chip)
     params = shaped(jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
     cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
-    return D.jitted_macro_step_slots_paged(cfg, 8, sampled=False).lower(
-        params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
-        arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
-        arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
-        arr((K, B, MAX_STOP_TOKENS))).compile()
+    with mock.patch.object(RW, "_on_tpu", lambda: True):
+        return D.jitted_macro_step_slots_paged.__wrapped__(cfg, 8, sampled=False).lower(
+            params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
+            arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
+            arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
+            arr((K, B, MAX_STOP_TOKENS))).compile()
+
+
+def _ring_writes(text, stack):
+    """How a decode step of an optimized program writes its new token into
+    ring stacks of shape `stack` (as HLO prints it): (the `ring_write` kernel's
+    calls under `decode_chunk`, one a run of window layers while the layers
+    stay rolled; the operations of their own under `decode_chunk` that are a
+    dynamic-update-slice putting out a ring stack, which is the loop's write,
+    one a lane, K and V; the copies of a ring stack anywhere)."""
+    import re
+
+    line = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(")
+    calls, updates, copies = [], [], []
+    for ln in text.splitlines():
+        m = line.match(ln)
+        if not m:
+            continue
+        name, out, op = m.groups()
+        if op == "custom-call" and name.startswith("ring_write") and "decode_chunk" in ln:
+            calls.append(ln)
+        elif stack in out and "dynamic-update-slice" in ln.split(" = ")[1].split(", metadata")[0] \
+                and "decode_chunk" in ln:
+            updates.append(name)
+        elif stack in out and op == "copy":
+            copies.append(name)
+    return calls, updates, copies
 
 
 def test_afmoe_macro_step_reads_the_expert_stacks_in_place(one_chip, monkeypatch):
@@ -390,6 +421,15 @@ def test_afmoe_macro_step_reads_the_expert_stacks_in_place(one_chip, monkeypatch
     copied = [ln.split(" = ")[0].strip() for ln in compiled.as_text().splitlines()
               if '"estimated_cycles"' in ln and whole_layer.search(ln.split(" = ")[1].split("(")[0])]
     assert not copied, copied
+    # the decode step's token write into the rings (PR 51): the kernel
+    # `ring_write`, one call for the dense window layer and ONE for the three
+    # expert window layers' rolled scan, both stacks aliased onto its results;
+    # no dynamic-update-slice of the loop's puts out a ring stack in a decode
+    # step and nothing copies one
+    calls, updates, copies = _ring_writes(compiled.as_text(), "bf16[4,8,2048,512]")
+    assert len(calls) == 2 and all("/attn_window/" in ln for ln in calls), calls
+    assert all("output_to_operand_aliasing={{0}: (4, {}), {1}: (5, {})}" in ln for ln in calls)
+    assert not updates and not copies, (updates, copies)
 
 
 @functools.lru_cache(maxsize=2)
@@ -784,6 +824,7 @@ def _phi4flash_macro_step(one_chip, A, P, kernel: bool = True):
 
     from ray_tpu.models import phi4flash as M
     from ray_tpu.models import phi4flash_decode as D
+    from ray_tpu.ops import ring_write as RW
     from ray_tpu.ops import s6_update as S6
     from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
 
@@ -793,7 +834,8 @@ def _phi4flash_macro_step(one_chip, A, P, kernel: bool = True):
     arr, shaped = _shapes_on(one_chip)
     params = shaped(jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
     cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
-    with mock.patch.object(S6, "_on_tpu", lambda: kernel), mock.patch.object(FA, "_on_tpu", lambda: True):
+    with mock.patch.object(S6, "_on_tpu", lambda: kernel), mock.patch.object(FA, "_on_tpu", lambda: True), \
+            mock.patch.object(RW, "_on_tpu", lambda: True):
         return D.jitted_macro_step_slots_paged.__wrapped__(cfg, 8, sampled=False).lower(
             params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
             arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
@@ -869,11 +911,18 @@ def _phi4flash_steps_its_state_in_place(text):
     under `cross_attn`; the full layer attends each chunk as gathered and the
     seven readers behind it read their chunk of the fetched context where it
     lies, inside their products (nothing puts one out); the cross-decoder's
-    scan is rolled."""
+    scan is rolled. THE NEW TOKEN GOES INTO THE RINGS THROUGH THE KERNEL (PR
+    51): `ring_write`, ONE call in the rolled pair scan for all eight window
+    layers, both stacks aliased onto its results; no dynamic-update-slice of
+    the loop's puts out a ring stack in a decode step, nothing copies one."""
     updates = [ln for ln in text.splitlines() if "custom-call(" in ln and " %s6_update" in ln]
     assert len(updates) == 2 and all("decode_chunk" in ln and "/s6_update/" in ln for ln in updates)
     assert all("output_to_operand_aliasing={{1}: (8, {})}" in ln for ln in updates)
     assert not _phi4flash_moved(text), _phi4flash_moved(text)
+    calls, ring_updates, ring_copies = _ring_writes(text, "bf16[8,64,512,1280]")
+    assert len(calls) == 1 and "/diff_window/" in calls[0], calls
+    assert "output_to_operand_aliasing={{0}: (4, {}), {1}: (5, {})}" in calls[0]
+    assert not ring_updates and not ring_copies, (ring_updates, ring_copies)
     gathers, chunks, cross_loops = _phi4flash_pool_reads(text)
     assert len(gathers) == 2 and all("/diff_full/" in g for g in gathers), gathers
     assert not chunks, chunks
@@ -1168,6 +1217,27 @@ def test_delta_rule_update_kernel_compiles_with_the_stack_aliased(one_chip):
     assert m.temp_size_in_bytes < 1e6, m.temp_size_in_bytes
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
+
+
+def test_ring_write_kernel_compiles_with_the_stacks_aliased(one_chip):
+    """The kernel of ops/ring_write.py at `reasoning-generate`'s shapes (8
+    window layers x 64 lanes x 512 slots x 1,280 columns bfloat16, twice:
+    1.34 GB; a lane's block ONE sublane tile of 16 slots, 40 KB) and at
+    `mixed-context-generate`'s (4 x 8 x 2,048 x 512) compiles for the chip
+    with both stacks aliased and nothing beside them."""
+    from ray_tpu.ops import ring_write as RW
+
+    arr, _ = _shapes_on(one_chip)
+    bf16 = functools.partial(arr, dtype=jnp.bfloat16)
+    for W, L, window, row in ((8, 64, 512, 1280), (4, 8, 2048, 512)):
+        assert RW.supported(window, row, jnp.bfloat16) and RW.slots_per_tile(jnp.bfloat16) == 16
+        compiled = jax.jit(RW._ring_write_pallas, donate_argnums=(0, 1)).lower(
+            bf16((W, L, window, row)), bf16((W, L, window, row)), arr(()), arr((L,)),
+            bf16((L, row)), bf16((L, row))).compile()
+        m = compiled.memory_analysis()
+        assert m.alias_size_in_bytes == 2 * 2 * W * L * window * row
+        assert m.temp_size_in_bytes < 1e6, m.temp_size_in_bytes
+        assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
 def test_s6_update_kernel_compiles_with_the_stack_aliased(one_chip):
