@@ -35,15 +35,16 @@ reaches a decode module only through `cfg.decode_module`
 (tests/test_lint_paged_kv.py holds all three).
 
 WHAT A DECODE MODULE OFFERS THE ENGINE (tests/test_decode_modules.py holds
-the seven to it). `cfg.decode_module` of a model's config names the module;
+the eight to it). `cfg.decode_module` of a model's config names the module;
 `ContinuousBatchingEngine` and `serve/llm.py` take from it, by name:
 
   init_paged_cache(cfg, n_slots, n_blocks, block_size) -> cache
       The model's device state, a dict of arrays with at least `pos` and
       `remaining` (n_slots,) int32 and `rng` (n_slots, 2) uint32. What else
-      it holds (K/V pools, a latent pool, rings, recurrent rows, `counts`)
-      is the module's own: the engine hands the dict back to the macro-step
-      and never looks inside, except through the block movers below.
+      it holds (K/V pools, a latent pool, rings, recurrent rows, `counts`;
+      brumby_decode holds lane state and NO pool) is the module's own: the
+      engine hands the dict back to the macro-step and never looks inside,
+      except through the block movers below.
   jitted_macro_step_slots_paged(cfg, chunk, sampled=True) -> jitted program
       Memoised (`functools.lru_cache`) `jax.jit` of the module's own
       `macro_step_slots_paged` under that name (`_bind`; a device trace

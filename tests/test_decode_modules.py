@@ -1,5 +1,5 @@
 """What a decode module offers the engine (models/paged.py's docstring), held
-against the seven `cfg.decode_module`s.
+against the eight `cfg.decode_module`s.
 
 Nothing is compiled: shapes come from `jax.eval_shape`, which traces the
 model's macro-step at its tiny config (one phase, one admission row of one
@@ -11,8 +11,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import (afmoe, granite_hybrid, llama, longcat_flash, paged, phi4flash, qwen3_next,
-                            sarvam_mla)
+from ray_tpu.models import (afmoe, brumby, granite_hybrid, llama, longcat_flash, paged, phi4flash,
+                            qwen3_next, sarvam_mla)
 from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
 
 CONFIGS = {
@@ -23,6 +23,7 @@ CONFIGS = {
     "qwen3_next": lambda: qwen3_next.Qwen3NextConfig.tiny(dtype=jnp.float32),
     "longcat_flash": lambda: longcat_flash.LongcatFlashConfig.tiny(dtype=jnp.float32),
     "phi4flash": lambda: phi4flash.Phi4FlashConfig.tiny(dtype=jnp.float32),
+    "brumby": lambda: brumby.BrumbyConfig.tiny(dtype=jnp.float32),
 }
 LANES, BLOCKS, BLOCK, K, A, CHUNK = 2, 5, 8, 1, 1, 2
 
@@ -84,8 +85,10 @@ def test_a_decode_module_offers_what_the_engine_takes(name):
     # the optional names and what they stand for
     if hasattr(D, "LATENT_POOL"):
         assert D.LATENT_POOL is True and not {"k", "v"} & set(cache)
-    else:
+    elif {"k", "v"} & set(cache):
         assert {"k", "v"} <= set(cache)  # what the block movers of models/paged.py move
+    else:  # no pool at all: a lane's context is its state, and the engine refuses the block movers
+        assert D.state_bytes_per_lane(cfg) > 0
     speculation = {"init_spec_cache", "jitted_macro_step_slots_spec"} & set(vars(D))
     if D.state_bytes_per_lane(cfg) or hasattr(D, "LATENT_POOL"):
         assert not speculation  # the engine refuses a draft model for it

@@ -1046,6 +1046,99 @@ def test_eight_readers_through_the_pool_trip_the_detector_of_pool_reads(one_chip
 # whole-program compiles would stand two minutes.
 
 
+# ---------------------------------------------------------------- ISSUE 52
+def _brumby_macro_step(one_chip, A, P, n_layers: int = 6):
+    """Brumby's paged macro-step at `brumby-14b-base.serve`'s widths (all 40
+    query and 8 KV heads, the whole 151,936-row vocabulary, `n_layers` of the
+    40 layers, 16 lanes, a table span of 4096), compiled for the described
+    chip at the (A, P) variant, the state update through its kernel as the
+    chip runs it."""
+    from unittest import mock
+
+    from ray_tpu.models import brumby as M
+    from ray_tpu.models import brumby_decode as D
+    from ray_tpu.ops import retention_update as RU
+    from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
+
+    cfg = M.BrumbyConfig(n_layers=n_layers, max_seq_len=4096)
+    B, bs, K = 16, 16, 8
+    MB = cfg.max_seq_len // bs
+    arr, shaped = _shapes_on(one_chip)
+    params = shaped(jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
+    with mock.patch.object(RU, "_on_tpu", lambda: True):
+        return D.jitted_macro_step_slots_paged.__wrapped__(cfg, 8, sampled=False).lower(
+            params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
+            arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
+            arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
+            arr((K, B, MAX_STOP_TOKENS))).compile()
+
+
+def _brumby_steps_its_state_in_place(text, n_layers: int = 6):
+    """A decode step's state update is the kernel `retention_update`, ONE call
+    in the rolled layer scan with the stack aliased onto its second result;
+    no operation copies the stacked state (f32[layers,16,8,136,8320], 3.48 GB
+    at 6 layers) and none puts out a layer of it: the admissions write their
+    rows through dynamic-update-slice fusions whose result is the stack."""
+    import re
+
+    updates = [ln for ln in text.splitlines() if "custom-call(" in ln and " %retention_update" in ln]
+    assert len(updates) == 1 and "decode_chunk" in updates[0] and "/retention_update/" in updates[0]
+    assert "output_to_operand_aliasing={{1}: (7, {})}" in updates[0]
+    stack = f"f32[{n_layers},16,8,136,8320]"
+    moved = [(n, op, s) for n, op, shapes in _outputs_of_own_operations(text) for s in shapes
+             if (s == stack and op == "copy") or re.fullmatch(r"f32\[(1,)?16,8,136,8320\]", s)]
+    assert not moved, moved
+
+
+def test_brumby_widest_admission_fits_the_chip_with_weights_and_state_in_place(one_chip):
+    """(A, P) = (16, 2048), up to 32,768 admitted tokens, the widest phase
+    any configuration here compiles, with its five admission bodies: 7.08 GB
+    of weights and 3.48 GB of sixteen lanes' float32 state go in (the cache
+    donated), 2.32 GB of temporaries (the admission a row at a time: a row's
+    (8, 5, 2048, 2048) float32 squared scores 0.67 GB, phi(k) of 2,048
+    positions 0.27), 12.87 GB of the chip's 16 (compiled only, PR 52). That
+    is how the configuration chose its 6 layers: at 8 the same program totals
+    15.36 GB (13.03 of arguments), over the 15 the rule allows. The decode
+    step's state update is the kernel `retention_update`, one call for all
+    layers with the stack aliased; nothing copies the state stack or slices a
+    layer out of it; every scope of the model is in the program, each in its
+    half."""
+    import re
+
+    compiled = _brumby_macro_step(one_chip, 16, 2048)
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+    print(f"memory_analysis (16, 2048): arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, total {total / 1e9:.2f} GB")
+    assert 10.5e9 < m.argument_size_in_bytes < 10.6e9 and m.alias_size_in_bytes > 3.47e9
+    assert total < 15e9, total
+    text = compiled.as_text()
+    assert _admission_bodies(text) == (5, 1)
+    _brumby_steps_its_state_in_place(text)
+    for scope in ("retention_proj", "retention_update"):
+        assert re.search(rf"/decode_chunk/[\w/]*{scope}/", text), scope
+    for scope in ("retention_proj", "retention_scan"):
+        assert re.search(rf"/admit_prefill/[\w/]*{scope}/", text), scope
+
+
+def test_brumby_decode_only_dispatch_fits_the_chip_and_steps_the_state_in_place(one_chip):
+    """(A, P) = (1, 16): the program of a dispatch that admits nothing, most
+    of `longform-generate`'s (answers of 128-512 tokens). 10.55 GB of
+    arguments and 0.01 GB of temporaries, 10.56 GB (compiled only, PR 52), the
+    cache donated; the state is stepped in place by the one kernel call."""
+    compiled = _brumby_macro_step(one_chip, 1, 16)
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+    print(f"memory_analysis (1, 16): arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, total {total / 1e9:.2f} GB")
+    assert 10.5e9 < m.argument_size_in_bytes < 10.6e9 and m.alias_size_in_bytes > 3.47e9
+    assert m.temp_size_in_bytes < 0.1e9 and total < 10.7e9, (m.temp_size_in_bytes, total)
+    text = compiled.as_text()
+    assert _admission_bodies(text) == (1, 1)
+    _brumby_steps_its_state_in_place(text)
+
+
 @pytest.mark.parametrize("name", SHAPES)
 def test_flash_forward_kernel_compiles(one_chip, name):
     shape = SHAPES[name]
@@ -1256,5 +1349,31 @@ def test_s6_update_kernel_compiles_with_the_stack_aliased(one_chip):
         f32((L, N)), f32((L, N))).compile()
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes == 4 * M_ * L * N * c
+    assert m.temp_size_in_bytes < 1e6, m.temp_size_in_bytes
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_retention_update_kernel_compiles_with_the_stack_aliased_and_the_layers_rolled(one_chip):
+    """The kernel of ops/retention_update.py at the cell's shapes (6 layers x
+    16 lanes x 8 KV heads x 136 x 8,320 float32, 3.48 GB; a KV head's state in
+    five blocks of (136, 1,664), 0.9 MB each, the five query heads' products
+    accumulated across them, a block's thirteen lane-rows of phi(q) and phi(k)
+    made in the kernel by static rotations) compiles for the chip with the stack aliased and
+    nothing beside it: ssm_update's kernels want a head's state in one block
+    four times over, and `heads_per_block` finds none for 4.5 MB a head."""
+    from ray_tpu.ops import retention_update as RU
+    from ray_tpu.ops import ssm_update as SU
+
+    M_, L, KV, rows, W = 6, 16, 8, 136, 8320
+    assert RU.supported(rows, W, 5) and RU.blocks_of(rows, W) == 5
+    assert not SU.supported(KV, W, 128) and SU.heads_per_block(KV, W, 128) == 0
+    arr, _ = _shapes_on(one_chip)
+    f32 = functools.partial(arr, dtype=jnp.float32)
+    compiled = jax.jit(functools.partial(RU._retention_update_pallas, G=5, eps=1e-6),
+                       donate_argnums=(0,)).lower(
+        f32((M_, L, KV, rows, W)), arr(()), arr((L,)), arr((1,)), f32((L, KV, 1, 128)),
+        f32((L, KV, 1, 128)), f32((L, KV, 1, 128)), f32((L, KV, 8, 128))).compile()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes == 4 * M_ * L * KV * rows * W
     assert m.temp_size_in_bytes < 1e6, m.temp_size_in_bytes
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
