@@ -17,14 +17,12 @@ and repairs when resolved tokens reveal early stops (serve/llm_engine.py).
 This module holds what does not depend on the model: the macro-step's phase
 and step skeleton (`macro_step_slots_paged`, `admit_phase`, `admit_pieces`),
 the pool's writes and reads (`write_decode_kv`, `attend_decode_paged`,
-`write_admission_kv`, `_attend_admission`, `write_lane_rows`; for a pool
-layer that SEVERAL layers of one decode step read, `decode_context_scratch`,
-`fetch_decode_context` and `attend_decode_fetched`: the first reader gathers
-the layer's live context out of the pool, once a step, and keeps it for the
-others, where each `attend_decode_paged` would gather it again: a fact of a
-model's structure that its decode module states by which of the two it
-calls, today phi4flash_decode alone; with one reader a step
-`attend_decode_paged` moves the same bytes and keeps no buffer), what an
+`write_admission_kv`, `_attend_admission`, `write_lane_rows`;
+`attend_decode_paged` is the DEFINITION of a decode step's read of the pool,
+and the one read path here: ops/paged_decode_attention.py is the same
+attention as a kernel that reads a flat K/V pool in place, each lane for its
+own blocks, which a decode module may take where it engages, today
+phi4flash_decode alone, whose pool layer has eight readers a step), what an
 admission and a decode step end with (`finish_admission`,
 `finish_decode_step`, `sample_tokens`), the block movers of the KV plane
 (`gather_kv_blocks`, `import_kv_blocks`, `scatter_kv_blocks`,
@@ -216,67 +214,6 @@ def decode_chunk_positions(block_size: int, max_blocks: int) -> int:
     return min(max(DECODE_CHUNK // block_size, 1), max_blocks) * block_size
 
 
-def _lay_out_decode_query(q, row, v_cols: int = 0):
-    """One query a lane, q (B, h, hd), laid out for chunks of pool rows
-    `row` (`attend_decode_paged` says how, for both layouts): (the query as
-    the score product takes it, the einsums of the scores and of the
-    probabilities with the values, the accumulator's columns a query row, and
-    `heads_of`: the attended float32 accumulator -> (B, h * hv) in q's dtype)."""
-    B, h, hd = q.shape
-    flat = len(row) == 1
-    kvh = row[0] // hd if flat else row[0]
-    hv = v_cols or hd  # a head's value columns
-    if flat:
-        own = jnp.eye(kvh, dtype=q.dtype)[None, :, None, :, None]  # head k's columns
-        qx = (q.reshape(B, kvh, h // kvh, 1, hd) * own).reshape(B, h, kvh * hd)
-        qk, pv = "bhc,bsc->bhs", "bhs,bsc->bhc"
-    else:
-        qx = q.reshape(B, kvh, h // kvh, hd)
-        qk, pv = "bkgd,bskd->bkgs", "bkgs,bskd->bkgd"
-
-    def heads_of(o):
-        if flat:
-            o = (o.reshape(B, kvh, h // kvh, kvh, hv) * own.astype(jnp.float32)).sum(axis=3)
-        return o.reshape(B, h * hv).astype(q.dtype)
-
-    return qx, qk, pv, qx.shape[-1] // hd * hv, heads_of
-
-
-def _live_chunks(pos, active, C: int):
-    """Chunks of C positions that the longest live lane's context spans: the
-    trip count of a step's read of the pool, data in the program (0 when no
-    lane is live)."""
-    longest = jnp.max(jnp.where(active, pos + 1, 0))
-    return (longest + C - 1) // C
-
-
-def _attend_decode_chunks(laid_out, read_chunk, n_chunks, C: int, pos, scale, held=()):
-    """`_lay_out_decode_query`'s queries over chunks 0 .. n_chunks - 1 of C
-    positions, chunk i's keys and values each (B, C, *row) from
-    `read_chunk(i, held) -> (kc, vc, held)` (`held`: what the reader carries
-    from chunk to chunk, the buffers that a fetch fills; nothing for a plain
-    reader), lane b's positions past pos[b] masked, under ONE online softmax:
-    bf16 operands, f32 scores, softmax and accumulation, probabilities cast
-    to the value dtype for the PV product. -> (the attention, held)"""
-    qx, qk, pv, cols, heads_of = laid_out
-    stat = qx.shape[:-1]
-
-    def chunk(i, carry):
-        held, soft = carry
-        kc, vc, held = read_chunk(i, held)
-        s = jnp.einsum(qk, qx, kc, preferred_element_type=jnp.float32) * scale
-        live = (i * C + jnp.arange(C))[None, :] <= pos[:, None]  # (B, C)
-        live = live.reshape(stat[:1] + (1,) * (len(stat) - 1) + (C,))
-        return held, _online_softmax_update(soft, s, live, vc, pv)
-
-    held, (acc, _, l) = jax.lax.fori_loop(
-        0, n_chunks, chunk,
-        (held, (jnp.zeros(stat + (cols,), jnp.float32),
-                jnp.full(stat, NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32))),
-    )
-    return heads_of(acc / jnp.where(l == 0.0, 1.0, l)[..., None]), held  # no chunk ran: zeros
-
-
 def attend_decode_paged(q, k_full, v_full, li, tables, pos, active, scale,
                         v_cols: int = 0):
     """Decode attention in proportion to the context the lanes hold: one
@@ -309,98 +246,44 @@ def attend_decode_paged(q, k_full, v_full, li, tables, pos, active, scale,
     value is the first `v_cols` columns of its own key row (a latent cache:
     [c | rotary part], values c). A chunk is gathered once and read twice;
     the return is (B, h * v_cols)."""
-    B = q.shape[0]
+    B, h, hd = q.shape
     bs, MB = k_full.shape[2], tables.shape[1]
     row = k_full.shape[3:]
-    laid_out = _lay_out_decode_query(q, row, v_cols)
+    flat = len(row) == 1
+    kvh = row[0] // hd if flat else row[0]
+    hv = v_cols or hd  # a head's value columns
+    if flat:
+        own = jnp.eye(kvh, dtype=q.dtype)[None, :, None, :, None]  # head k's columns
+        qx = (q.reshape(B, kvh, h // kvh, 1, hd) * own).reshape(B, h, kvh * hd)
+        qk, pv = "bhc,bsc->bhs", "bhs,bsc->bhc"
+    else:
+        qx = q.reshape(B, kvh, h // kvh, hd)
+        qk, pv = "bkgd,bskd->bkgs", "bkgs,bskd->bkgd"
+    stat = qx.shape[:-1]
     C = decode_chunk_positions(bs, MB)
     cb = C // bs  # blocks a chunk
     # whole chunks only: the tail names the null block and is never live
     chunked = jnp.pad(tables, ((0, 0), (0, -MB % cb)))
+    longest = jnp.max(jnp.where(active, pos + 1, 0))
 
-    def gathered(i, held):
+    def chunk(i, carry):
         blocks = jax.lax.dynamic_slice_in_dim(chunked, i * cb, cb, axis=1)
         kc = k_full[li, blocks].reshape((B, C) + row)
         vc = kc[..., :v_cols] if v_full is None else v_full[li, blocks].reshape((B, C) + row)
-        return kc, vc, held
+        s = jnp.einsum(qk, qx, kc, preferred_element_type=jnp.float32) * scale
+        live = (i * C + jnp.arange(C))[None, :] <= pos[:, None]  # (B, C)
+        live = live.reshape((B,) + (1,) * (len(stat) - 1) + (C,))
+        return _online_softmax_update(carry, s, live, vc, pv)
 
-    return _attend_decode_chunks(laid_out, gathered, _live_chunks(pos, active, C), C, pos, scale)[0]
-
-
-def decode_context_scratch(n_slots: int, span: int, block_size: int, row, dtype):
-    """Zeros to fetch a step's live context into (`fetch_decode_context`): a
-    K and a V buffer, CHUNK-MAJOR, (chunks of `span` positions, n_slots, C,
-    *row) with C = `decode_chunk_positions`. As large as every lane's whole
-    span in a pool layer, and nothing ever reads a chunk of it that its own
-    step did not write: it is scratch that outlives the step (an entry of the
-    model's donated cache), never made or filled a step."""
-    C = decode_chunk_positions(block_size, -(-span // block_size))
-    shape = (-(-span // C), n_slots, C) + tuple(row)
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
-
-
-def _held_query(q, row):
-    """`_lay_out_decode_query` with the laid-out query made ONCE, before the
-    loop over chunks. Left to itself the compiler re-derives it from q in
-    every iteration (a broadcast and a reshape of (B, h, kvh * hd): 14 of an
-    iteration's 74 us at 64 lanes of 1,280 columns on a v5e, PR 50).
-    `attend_decode_paged` does without: its six callers' programs are not
-    this PR's to change."""
-    qx, *rest = _lay_out_decode_query(q, row)
-    return (jax.lax.optimization_barrier(qx), *rest)
-
-
-def fetch_decode_context(q, scratch, k_full, v_full, li, tables, pos, active, scale):
-    """`attend_decode_paged(q, k_full, v_full, li, ...)` for the FIRST of
-    several readers that layer `li` of the pools has in one decode step, and
-    the lanes' live context kept for the others: each chunk of the loop (the
-    same blocks, the same trip count, so nothing past the longest live lane
-    is touched) is gathered out of the pool once, attended where the gather
-    leaves it, and written into chunk i of `scratch`
-    (`decode_context_scratch`'s pair, updated in place).
-    -> (the attention (B, h * hd), the pair for `attend_decode_fetched`).
-    Chunks the loop did not reach keep what an earlier step left, and no
-    reader of this step reaches them either. The chunks are the scratch's:
-    `attend_decode_paged`'s own wherever the tables span a chunk or more.
-
-    For a pool layer that SEVERAL layers of a step read (written by one layer
-    and attended by others as well: phi4flash_decode), where each reader
-    through `attend_decode_paged` gathers the same blocks again. With one
-    reader a step this moves the same bytes and keeps a buffer besides: call
-    `attend_decode_paged`."""
-    n, B, C = scratch[0].shape[:3]
-    row = k_full.shape[3:]
-    cb, MB = C // k_full.shape[2], tables.shape[1]
-    if MB > n * cb:
-        raise ValueError(f"tables of {MB} blocks span more than the scratch's {n} chunks of {cb}")
-    chunked = jnp.pad(tables, ((0, 0), (0, n * cb - MB)))  # the tail names the null block
-
-    def gathered_and_kept(i, bufs):
-        blocks = jax.lax.dynamic_slice_in_dim(chunked, i * cb, cb, axis=1)
-        kc, vc = (pool[li, blocks].reshape((B, C) + row) for pool in (k_full, v_full))
-        return kc, vc, tuple(jax.lax.dynamic_update_index_in_dim(buf, c, i, 0)
-                             for buf, c in zip(bufs, (kc, vc)))
-
-    return _attend_decode_chunks(_held_query(q, row), gathered_and_kept,
-                                 _live_chunks(pos, active, C), C, pos, scale, tuple(scratch))
-
-
-def attend_decode_fetched(q, context, pos, active, scale):
-    """`attend_decode_paged` for a further reader of a fetched context
-    (`fetch_decode_context`'s pair): the SAME chunks in the same order under
-    the same online softmax, chunk i an index on the buffers' leading axis
-    (the pattern by which a layer scan reads its stacked weights in place; a
-    slice of an inner axis, or a reshape behind the slice, is copied out).
-    q (B, h, hd) -> (B, h * hd), equal to `attend_decode_paged`'s."""
-    kbuf, vbuf = context
-    C = kbuf.shape[2]
-
-    def fetched(i, held):
-        return (jax.lax.dynamic_index_in_dim(kbuf, i, 0, keepdims=False),
-                jax.lax.dynamic_index_in_dim(vbuf, i, 0, keepdims=False), held)
-
-    return _attend_decode_chunks(_held_query(q, kbuf.shape[3:]), fetched,
-                                 _live_chunks(pos, active, C), C, pos, scale)[0]
+    acc, _, l = jax.lax.fori_loop(
+        0, (longest + C - 1) // C, chunk,
+        (jnp.zeros(stat + (qx.shape[-1] // hd * hv,), jnp.float32),
+         jnp.full(stat, NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32)),
+    )
+    o = acc / jnp.where(l == 0.0, 1.0, l)[..., None]  # no chunk ran: zeros
+    if flat:
+        o = (o.reshape(B, kvh, h // kvh, kvh, hv) * own.astype(jnp.float32)).sum(axis=3)
+    return o.reshape(B, h * hv).astype(q.dtype)
 
 
 def write_decode_kv(k_full, v_full, li, k, v, tables, pos, active):

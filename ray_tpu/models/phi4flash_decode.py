@@ -7,19 +7,13 @@ admission and decode step and this module's cache pytree:
 
   k, v      (1, n_blocks, bs, kv_row)  THE block pool: layer `half + 1`
             writes it; tables are host state as ever. A row is n_kv_heads / 2
-            pairs of 2 x head_dim, read as such (models/phi4flash.py says why)
-  ctx       a K and a V buffer (chunks of max_seq_len, lanes, chunk, kv_row):
-            the lanes' LIVE CONTEXT of the pool, chunk-major. A decode step
-            has eight readers of the one pool layer, so the full layer, behind
-            its own write, gathers the live chunks out of the pool ONCE,
-            attending each as it passes (`paged.fetch_decode_context`), and
-            the seven cross-decoder attentions attend the copy
-            (`paged.attend_decode_fetched`): each through
-            `attend_decode_paged` would gather the same blocks again, eight
-            times a step (PR 50). Scratch: a step reads only the chunks
-            it wrote itself, nothing of it outlives a step or counts as a
-            lane's state, and it lives in the donated cache only so that no
-            step makes or zero-fills an array as large as the pool
+            pairs of 2 x head_dim, read as such (models/phi4flash.py says why).
+            A decode step has EIGHT readers of this one layer, the full layer
+            behind its own write and the seven cross-decoder attentions, and
+            each reads it where it lies (`attend_pool`): on a TPU the kernel
+            of ops/paged_decode_attention.py, every lane for its own blocks
+            through its table; elsewhere `paged.attend_decode_paged`, the
+            definition
   wk, wv    (window layers, lanes, window, kv_row)  each lane's RING of the
             last `sliding_window` positions of every window layer, as
             models/afmoe_decode.py keeps them; a decode step's new row goes
@@ -80,8 +74,6 @@ def init_paged_cache(cfg: Phi4FlashConfig, n_slots: int, n_blocks: int,
     return {
         "k": jnp.zeros(pool, cfg.dtype),
         "v": jnp.zeros(pool, cfg.dtype),
-        "ctx": paged.decode_context_scratch(n_slots, cfg.max_seq_len, block_size, (cfg.kv_row,),
-                                            cfg.dtype),
         "wk": jnp.zeros(ring, cfg.dtype),
         "wv": jnp.zeros(ring, cfg.dtype),
         "conv": jnp.zeros((cfg.n_mamba_layers, cfg.mamba_d_conv - 1, n_slots, cfg.d_inner),
@@ -171,10 +163,26 @@ def admit_slots_paged(params, prompts, lengths, starts, slots, rems, seeds,
     first, pos, rem, feed, rng = paged.finish_admission(
         M.logits_of(params, x_last, cfg), cache, feed, valid, lengths, starts,
         slots, rems, seeds, temps, top_ks, top_ps, stop_ids, sampled)
-    cache = {"k": k_full, "v": v_full, "ctx": cache["ctx"], "wk": wk, "wv": wv, "conv": conv,
-             "ssm": ssm, "counts": cache["counts"] + jnp.asarray([A * P, A], jnp.int32),
+    cache = {"k": k_full, "v": v_full, "wk": wk, "wv": wv, "conv": conv, "ssm": ssm,
+             "counts": cache["counts"] + jnp.asarray([A * P, A], jnp.int32),
              "pos": pos, "remaining": rem, "rng": rng}
     return first, cache, feed
+
+
+def attend_pool(k_full, v_full, tables, pos, active, scale):
+    """`diff_attention`'s `attend` over the ONE pool layer, for each of a
+    decode step's eight readers: q (B, h, 2 hd) -> (B, h, 2 hd),
+    `paged.attend_decode_paged`'s attention. On a TPU, for pools its tiles
+    take, the kernel of ops/paged_decode_attention.py, which reads the pool in
+    place, each lane for its own blocks; elsewhere the definition, which
+    gathers every lane's chunks up to the longest live lane's."""
+    from ray_tpu.ops import paged_decode_attention as kernel  # Pallas: imported where it is traced
+
+    def attend(q):
+        read = kernel.attend if kernel.engages(q, k_full, v_full) else paged.attend_decode_paged
+        return read(q, k_full, v_full, 0, tables, pos, active, scale).reshape(q.shape)
+
+    return attend
 
 
 def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
@@ -183,9 +191,9 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
     """One token on every lane, with llama_decode.decode_step_slots_paged's
     arguments and returns: all layers on (lanes, 1). A lane that is not live
     (remaining == 0) keeps its conv tail and state as they are and aims its
-    K/V write at the null block; its logits mean nothing. The pool is read
-    ONCE, by the full layer behind its own write, which keeps what it reads
-    in `cache["ctx"]`; the cross-decoder's seven attend that copy."""
+    K/V write at the null block; its logits mean nothing. The full layer,
+    behind its own write, and the cross-decoder's seven read the pool where
+    it lies (`attend_pool`)."""
     pos = cache["pos"]
     active = cache["remaining"] > 0
     live = live_rows(active)  # one list for the step's every layer
@@ -212,34 +220,22 @@ def decode_step_slots_paged(params, cache, tokens, tables, temps, top_ks,
 
     def full_mixer(layer, ai, li, a, carry):
         k_full, v_full, wk, wv, conv, ssm = carry
-        ctx = None  # what `attend` leaves for the cross-decoder
-
-        def attend(q):
-            # EIGHT layers of this step read this pool layer: out of the pool
-            # once, here, and the copy handed on
-            nonlocal ctx
-            o, ctx = paged.fetch_decode_context(
-                q, cache["ctx"], k_full, v_full, 0, tables, pos, active, scale)
-            return o.reshape(q.shape)
-
         with jax.named_scope(M.SCOPE_FULL):
             q, k, v = M.qkv(layer, a, cfg)
             k_full, v_full = paged.write_decode_kv(
                 k_full, v_full, 0, k[:, None, :], v[:, None, :], tables, pos, active)
-            o = M.diff_attention(layer, q, attend, li, cfg)
-            return o @ layer["wo"] + layer["bo"], ctx, (k_full, v_full, wk, wv, conv, ssm)
+            o = M.diff_attention(layer, q, attend_pool(k_full, v_full, tables, pos, active, scale), li, cfg)
+            return o @ layer["wo"] + layer["bo"], None, (k_full, v_full, wk, wv, conv, ssm)
 
-    x, m, ctx, (k_full, v_full, wk, wv, conv, ssm) = M.self_decoder(
+    x, m, _, (k_full, v_full, wk, wv, conv, ssm) = M.self_decoder(
         params, M.embed_tokens(params, tokens, cfg),
         (cache["k"], cache["v"], cache["wk"], cache["wv"], cache["conv"], cache["ssm"]), cfg,
         mamba, window_mixer, full_mixer)
-    x = M.cross_decoder(
-        params, x, m, cfg,
-        lambda q: paged.attend_decode_fetched(q, ctx, pos, active, scale).reshape(q.shape))
+    x = M.cross_decoder(params, x, m, cfg, attend_pool(k_full, v_full, tables, pos, active, scale))
     logits = M.logits_of(params, x, cfg)
     nxt, new_pos, remaining, rng = paged.finish_decode_step(
         logits, cache, active, temps, top_ks, top_ps, stop_ids, sampled)
-    cache = {"k": k_full, "v": v_full, "ctx": ctx, "wk": wk, "wv": wv, "conv": conv, "ssm": ssm,
+    cache = {"k": k_full, "v": v_full, "wk": wk, "wv": wv, "conv": conv, "ssm": ssm,
              "counts": cache["counts"], "pos": new_pos, "remaining": remaining, "rng": rng}
     return logits, nxt, cache
 

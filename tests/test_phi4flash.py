@@ -386,71 +386,29 @@ def test_the_two_depth_admissions_first_token_is_the_all_rows_ones():
     assert np.asarray(lanes.cache["counts"]).tolist() == [3 * 32, 3]
 
 
-# ------------------------------------ the pool read once for eight readers
-# pos and active of five lanes on tables 20 blocks of 16 wide (a span of 320:
-# chunks of 128, the last one half padding): a context that ends on a chunk's
-# boundary, one a position past it, a short one, a lane at the table span, a
-# lane that is not live and would be the longest
-LANE_CASES = {
-    "unequal-lanes": ([127, 128, 4, 319, 300], [True, True, True, True, False]),
-    "two-chunks-of-three": ([127, 128, 4, 129, 319], [True, True, True, True, False]),
-    "no-live-lane": ([127, 128, 4, 319, 300], [False] * 5),
-}
+# ------------------------------------ the pool read in place by its eight readers
+def test_decode_steps_through_the_kernel_hold_the_references_tolerance(monkeypatch):
+    """The comparison through the cache with every one of the pool's eight
+    readers a step through the kernel of ops/paged_decode_attention.py (what a
+    TPU runs; here in the TPU interpret mode, which takes any shape, `engages`
+    patched): the same tolerance with the same room, and the cache without a
+    fetched context (tests/test_paged_decode_attention.py holds the kernel to
+    `attend_decode_paged` lane case by lane case)."""
+    from jax.experimental.pallas import tpu as pltpu
 
+    from ray_tpu.ops import paged_decode_attention as PDA
 
-@pytest.mark.parametrize("lanes", sorted(LANE_CASES))
-@pytest.mark.parametrize("row", [(160,), (2, 32)], ids=["flat", "gqa"])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-def test_the_context_fetched_once_is_attended_as_the_pool_is(dtype, row, lanes):
-    """`fetch_decode_context` (the first reader, which keeps what it gathers)
-    and `attend_decode_fetched` (the readers behind it) give what
-    `attend_decode_paged` gives, EXACTLY (the same chunks in the same order
-    under the same online softmax), for each of three readers with queries of
-    their own, on a pool whose tables are shuffled and whose layer is not the
-    first. The scratch starts as NaN: a reader that touched a chunk the fetch
-    did not reach, or the fetch a chunk past the longest live lane, would
-    show."""
-    B, MB, bs, hd, h = 5, 20, 16, 32, 10
-    rng = np.random.default_rng([50, len(row)])
-    pool = (2, 1 + B * MB, bs) + row
-    k_full, v_full = (jnp.asarray(rng.normal(size=pool), dtype) for _ in range(2))
-    tables = jnp.asarray(1 + rng.permutation(B * MB).reshape(B, MB), jnp.int32)
-    pos, active = (jnp.asarray(a) for a in LANE_CASES[lanes])
-    pos = pos.astype(jnp.int32)
-    scratch = jax.tree.map(lambda z: jnp.full_like(z, jnp.nan),
-                           paged.decode_context_scratch(B, MB * bs, bs, row, dtype))
-    assert scratch[0].shape == (3, B, 128) + row  # chunk-major, whole chunks of the span
-    ctx = None
-    for reader in range(3):
-        q = jnp.asarray(rng.normal(size=(B, h, hd)), dtype)
-        want = paged.attend_decode_paged(q, k_full, v_full, 1, tables, pos, active, hd ** -0.5)
-        if ctx is None:
-            got, ctx = paged.fetch_decode_context(q, scratch, k_full, v_full, 1, tables, pos, active,
-                                                  hd ** -0.5)
-        else:
-            got = paged.attend_decode_fetched(q, ctx, pos, active, hd ** -0.5)
-        assert got.dtype == want.dtype == dtype
-        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32),
-                                      err_msg=f"reader {reader}")
-        assert (np.abs(np.asarray(want, np.float32)).max() > 0) == bool(active.any())
-    reached = -(-int(jnp.max(jnp.where(active, pos + 1, 0))) // 128)
-    for buf in ctx:
-        assert not np.isnan(np.asarray(buf[:reached], np.float32)).any()
-        assert np.isnan(np.asarray(buf[reached:], np.float32)).all()
-
-
-def test_tables_wider_than_the_scratch_are_refused_when_the_step_is_traced():
-    """The scratch is sized by `cfg.max_seq_len` when the cache is made, the
-    tables by the engine's `max_len`: an engine given a longer span than the
-    model's own fails at its first dispatch, where a fetch past the scratch
-    would be clamped onto its last chunk in silence."""
-    k_full = jnp.zeros((1, 41, 16, 160), jnp.float32)
-    scratch = paged.decode_context_scratch(2, 256, 16, (160,), jnp.float32)  # 2 chunks of 8 blocks
-    q, pos, active = jnp.zeros((2, 5, 32), jnp.float32), jnp.zeros((2,), jnp.int32), jnp.ones((2,), bool)
-    paged.fetch_decode_context(q, scratch, k_full, k_full, 0, jnp.ones((2, 16), jnp.int32), pos, active, 1.0)
-    with pytest.raises(ValueError, match="span more than the scratch"):
-        paged.fetch_decode_context(q, scratch, k_full, k_full, 0, jnp.ones((2, 17), jnp.int32), pos, active,
-                                   1.0)
+    calls = []
+    monkeypatch.setattr(PDA, "engages", lambda q, k, v: calls.append(q.shape) or True)
+    cfg, key, params = _model()
+    halves = (functools.partial(D.admit_slots_paged, cfg=cfg, sampled=False),
+              functools.partial(D.decode_step_slots_paged, cfg=cfg, sampled=False))
+    with pltpu.force_tpu_interpret_mode():
+        worst, firsts_agree, lanes = _through_the_cache(cfg, key, params, halves=tuple(map(jax.jit, halves)))
+    assert worst <= 1.0 and firsts_agree
+    # traced once: the full layer's call and the rolled cross-decoder's
+    assert calls == [(2, cfg.n_heads, 2 * cfg.head_dim)] * 2
+    assert sorted(lanes.cache) == ["conv", "counts", "k", "pos", "remaining", "rng", "ssm", "v", "wk", "wv"]
 
 
 # ------------------------------------------------------- wrong variants
@@ -485,17 +443,17 @@ def _state_in_bfloat16(orig):
 
 
 def _scores_in_bfloat16(orig):
-    def attend_decode_fetched(q, context, pos, active, scale):
+    def attend_decode_paged(q, k_full, v_full, li, tables, pos, active, scale):
         # the scores rounded to bfloat16 before the softmax: as a bfloat16
         # score product would leave them
         s_round = lambda s: s.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
         orig_update = paged._online_softmax_update
         paged._online_softmax_update = lambda c, s, live, vc, pv: orig_update(c, s_round(s), live, vc, pv)
         try:
-            return orig(q, context, pos, active, scale)
+            return orig(q, k_full, v_full, li, tables, pos, active, scale)
         finally:
             paged._online_softmax_update = orig_update
-    return attend_decode_fetched
+    return attend_decode_paged
 
 
 MUTATIONS = {
@@ -503,8 +461,8 @@ MUTATIONS = {
     "memory-taken-after-the-gate": (M, "mamba_token", _memory_after_the_gate),
     "window-edge-off-by-one": (afmoe_decode, "ring_slots_held", _window_edge_off_by_one),
     "state-in-bfloat16": (M, "s6_step", _state_in_bfloat16),
-    # seven of the pool's eight readers attend through this one since PR 50
-    "scores-in-bfloat16": (paged, "attend_decode_fetched", _scores_in_bfloat16),
+    # the pool's eight readers attend through this one off the TPU (PR 53)
+    "scores-in-bfloat16": (paged, "attend_decode_paged", _scores_in_bfloat16),
 }
 
 

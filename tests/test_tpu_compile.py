@@ -824,6 +824,7 @@ def _phi4flash_macro_step(one_chip, A, P, kernel: bool = True):
 
     from ray_tpu.models import phi4flash as M
     from ray_tpu.models import phi4flash_decode as D
+    from ray_tpu.ops import paged_decode_attention as PDA
     from ray_tpu.ops import ring_write as RW
     from ray_tpu.ops import s6_update as S6
     from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
@@ -835,7 +836,7 @@ def _phi4flash_macro_step(one_chip, A, P, kernel: bool = True):
     params = shaped(jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
     cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
     with mock.patch.object(S6, "_on_tpu", lambda: kernel), mock.patch.object(FA, "_on_tpu", lambda: True), \
-            mock.patch.object(RW, "_on_tpu", lambda: True):
+            mock.patch.object(RW, "_on_tpu", lambda: True), mock.patch.object(PDA, "_on_tpu", lambda: True):
         return D.jitted_macro_step_slots_paged.__wrapped__(cfg, 8, sampled=False).lower(
             params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
             arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
@@ -845,8 +846,9 @@ def _phi4flash_macro_step(one_chip, A, P, kernel: bool = True):
 
 def _phi4flash_moved(text):
     """Operations of an optimized Phi-4-mini-flash macro-step that copy the
-    pool's ONE layer, the decode steps' fetched context (a K or a V buffer of
-    16 chunks x 64 lanes x 128 positions, as large as the pool), a ring stack
+    pool's ONE layer, a buffer of the fetched context's shape (16 chunks x 64
+    lanes x 128 positions, as large as the pool: PR 50's scratch, gone with
+    PR 53), a ring stack
     or the stacked SSM state anywhere, or, in a decode step (by its
     `decode_chunk` scope), put out a whole layer of a ring stack or of the
     state outside the state update's kernel. The in-place
@@ -883,35 +885,40 @@ def _phi4flash_pool_reads(text):
     operations that are instructions of their own (not inside a fused
     computation): (the `op_name`s of those that put out a gathered chunk of
     the pool, bf16[512,16,1280]: 512 blocks, K or V; those that put out a
-    chunk of the fetched context, bf16[64,128,1280], that is, copy out of the
-    buffer what the products should read where it lies; the loops under
-    `cross_attn`: ONE, the readers' loop over chunks, while the
-    cross-decoder's scan stays rolled, seven were it unrolled)."""
+    chunk of 64 lanes' context, bf16[64,128,1280]; the loops under
+    `cross_attn`: the XLA readers' loop over chunks, ONE while the
+    cross-decoder's scan stays rolled; the calls of the kernel
+    `paged_decode_attention`, whole lines)."""
     import re
 
-    line = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (\(.*?\)|\S+) [\w\-]+\(.*op_name=\"([^\"]*)\"")
-    gathers, chunks, fused = [], [], False
+    line = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(.*op_name=\"([^\"]*)\"")
+    gathers, chunks, kernel, fused = [], [], [], False
     for ln in text.splitlines():
         if ln.startswith(("%", "ENTRY")):  # a computation opens: a fusion's body or not
             fused = ln.startswith("%fused_computation")
         m = None if fused else line.match(ln)
-        if m and "decode_chunk" in m.group(2):
-            shapes = re.findall(r"bf16\[[\d,]+\]", m.group(1))
-            gathers += [m.group(2) for s in shapes if s == "bf16[512,16,1280]"]
-            chunks += [m.group(2) for s in shapes if s in ("bf16[64,128,1280]", "bf16[1,64,128,1280]")]
-    return gathers, chunks, _loops_under(text, "decode_chunk", "cross_attn")
+        if m and "decode_chunk" in m.group(4):
+            shapes = re.findall(r"bf16\[[\d,]+\]", m.group(2))
+            gathers += [m.group(4) for s in shapes if s == "bf16[512,16,1280]"]
+            chunks += [m.group(4) for s in shapes if s in ("bf16[64,128,1280]", "bf16[1,64,128,1280]")]
+            if m.group(3) == "custom-call" and m.group(1).startswith("paged_decode_attention"):
+                kernel.append(ln)
+    return gathers, chunks, _loops_under(text, "decode_chunk", "cross_attn"), kernel
 
 
 def _phi4flash_steps_its_state_in_place(text):
     """A decode step's state update is the kernel `s6_update`, one call in the
     rolled pair scan and one for layer 16, the stack aliased onto its output,
-    and nothing moves the pool, the fetched context, a ring stack or the state
-    (`_phi4flash_moved`). THE POOL IS READ ONCE A STEP (PR 50): two operations
-    gather a chunk of it, K's and V's, under the full layer's scope and none
-    under `cross_attn`; the full layer attends each chunk as gathered and the
-    seven readers behind it read their chunk of the fetched context where it
-    lies, inside their products (nothing puts one out); the cross-decoder's
-    scan is rolled. THE NEW TOKEN GOES INTO THE RINGS THROUGH THE KERNEL (PR
+    and nothing moves the pool, a ring stack or the state
+    (`_phi4flash_moved`). THE POOL IS READ WHERE IT LIES (PR 53): the kernel
+    `paged_decode_attention` at its two sites, the full layer's under
+    `diff_full` and ONE under `cross_attn` for the seven readers of the rolled
+    cross-decoder, each with both pools whole among its operands (bf16[1,8193,
+    16,1280], in main memory: nothing copies the layer, `_phi4flash_moved`);
+    nothing gathers a chunk of the pool or puts out a chunk of 64 lanes'
+    context, no loop over chunks is left under `cross_attn`, and no buffer of
+    the fetched context's shape (PR 50's scratch) is anywhere in the program.
+    THE NEW TOKEN GOES INTO THE RINGS THROUGH THE KERNEL (PR
     51): `ring_write`, ONE call in the rolled pair scan for all eight window
     layers, both stacks aliased onto its results; no dynamic-update-slice of
     the loop's puts out a ring stack in a decode step, nothing copies one."""
@@ -923,23 +930,25 @@ def _phi4flash_steps_its_state_in_place(text):
     assert len(calls) == 1 and "/diff_window/" in calls[0], calls
     assert "output_to_operand_aliasing={{0}: (4, {}), {1}: (5, {})}" in calls[0]
     assert not ring_updates and not ring_copies, (ring_updates, ring_copies)
-    gathers, chunks, cross_loops = _phi4flash_pool_reads(text)
-    assert len(gathers) == 2 and all("/diff_full/" in g for g in gathers), gathers
-    assert not chunks, chunks
-    assert len(cross_loops) == 1, cross_loops
+    gathers, chunks, cross_loops, reads = _phi4flash_pool_reads(text)
+    assert not gathers and not chunks and not cross_loops, (gathers, chunks, cross_loops)
+    assert len(reads) == 2 and sorted("/cross_attn/" in ln for ln in reads) == [False, True], reads
+    assert all("/diff_full/" in ln or "/cross_attn/" in ln for ln in reads)
+    pools = "bf16[64,40,128]{2,1,0}, bf16[1,8193,16,1280]{3,2,1,0}, bf16[1,8193,16,1280]{3,2,1,0}}"
+    assert all(pools in ln for ln in reads), reads
+    assert "bf16[16,64,128,1280]" not in text
 
 
 def test_phi4flash_widest_admission_fits_the_chip_with_pool_rings_and_state_in_place(one_chip):
     """(A, P) = (64, 512), up to 32,768 admitted tokens, the program of the
     cell's longest bucket with its seven admission bodies and the decode
     body (the shortest, (1, 16), has the next test): 7.71 GB of weights, ONE
-    pool layer of 0.67 GB, the decode steps' fetched context as large again
-    (PR 50: scratch in the donated cache, so that no step makes or fills it),
-    eight ring layers of 1.34 GB, 0.19 GB of float32 states and conv tails go
-    in (the cache donated), 3.47 GB of temporaries (an MLP's (64, 512, 20480)
-    products the largest), 14.07 GB of the chip's 16 (compiled only, PR 50;
-    13.40 before the context, PR 49; 14.74 with the context made once a
-    dispatch inside the program: 4.81 GB of temporaries). Each body's
+    pool layer of 0.67 GB, eight ring layers of 1.34 GB, 0.19 GB of float32
+    states and conv tails go in (the cache donated), 3.47 GB of temporaries
+    (an MLP's (64, 512, 20480) products the largest), 13.40 GB of the chip's
+    16 (compiled only, PR 53: the pool's eight readers read it in place; 14.07
+    with PR 50's fetched context, a scratch as large as the pool in the
+    donated cache). Each body's
     window and full attentions are the flash kernel over queries laid out 128
     wide (one call in the rolled pair scan, one for the full layer); the
     decode step's state update is the kernel `s6_update`, one call in the
@@ -954,8 +963,8 @@ def test_phi4flash_widest_admission_fits_the_chip_with_pool_rings_and_state_in_p
     total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
     print(f"memory_analysis (64, 512): arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
           f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, total {total / 1e9:.2f} GB")
-    assert 10.55e9 < m.argument_size_in_bytes < 10.65e9 and m.alias_size_in_bytes > 2.85e9
-    assert total < 14.5e9, total
+    assert 9.88e9 < m.argument_size_in_bytes < 9.98e9 and m.alias_size_in_bytes > 2.18e9
+    assert total < 13.6e9, total  # under the chip's 16 GB and the parent's 14.07
     text = compiled.as_text()
     assert _admission_bodies(text) == (7, 1)
     kernels = re.findall(r"%flash_fwd[.\d]* = \((bf16\[[\d,]+\])[^=]*custom-call\(", text)
@@ -983,13 +992,12 @@ def test_phi4flash_decode_only_dispatch_fits_the_chip_and_steps_the_state_in_pla
     """(A, P) = (1, 16): the program of a dispatch that admits nothing, or one
     short prompt, which is most of `reasoning-generate`'s dispatches (answers
     of 256-1,024 tokens behind prompts of 129-512). One admission body and the
-    decode body; 10.60 GB of arguments (weights, the ONE pool layer and the
-    fetched context of its size, the eight rings, the states) and under 0.1 GB
-    of temporaries, 10.63 GB (compiled only, PR 50; 9.93 + 0.03 before the
-    context, PR 49), the cache donated. The decode step is what the widest
-    program's is: the pool read once, two gathers of a chunk under the full
-    layer's scope and none under `cross_attn`, the cross attentions' products
-    reading the context where it lies;
+    decode body; 9.93 GB of arguments (weights, the ONE pool layer, the eight
+    rings, the states) and under 0.1 GB of temporaries, 9.96 GB (compiled
+    only, PR 53; 10.63 with PR 50's fetched context), the cache donated. The
+    decode step is what the widest program's is: the pool read where it lies
+    by the kernel `paged_decode_attention` at its two sites, nothing gathered
+    out of it and no loop over chunks;
     the state update is the kernel `s6_update`, two calls for nine Mamba
     layers, the stack aliased; nothing copies the pool, a ring stack or the
     state, and none of their layers is put out by anything else."""
@@ -998,21 +1006,23 @@ def test_phi4flash_decode_only_dispatch_fits_the_chip_and_steps_the_state_in_pla
     total = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
     print(f"memory_analysis (1, 16): arguments {m.argument_size_in_bytes / 1e9:.2f} GB, "
           f"temporaries {m.temp_size_in_bytes / 1e9:.2f} GB, total {total / 1e9:.2f} GB")
-    assert 10.55e9 < m.argument_size_in_bytes < 10.65e9 and m.alias_size_in_bytes > 2.85e9
-    assert m.temp_size_in_bytes < 0.1e9 and total < 10.8e9, (m.temp_size_in_bytes, total)
+    assert 9.88e9 < m.argument_size_in_bytes < 9.98e9 and m.alias_size_in_bytes > 2.18e9
+    assert m.temp_size_in_bytes < 0.1e9 and total < 10.1e9, (m.temp_size_in_bytes, total)
     text = compiled.as_text()
     assert _admission_bodies(text) == (1, 1)
     _phi4flash_steps_its_state_in_place(text)
 
 
 def test_eight_readers_through_the_pool_trip_the_detector_of_pool_reads(one_chip):
-    """The parent's read path alone, at the cell's shapes (no whole program:
-    the queries come in as arguments): the full layer and seven
-    cross-attention layers in a rolled scan, each through
-    `attend_decode_paged` under the step's scopes. Four operations gather a
-    chunk of the pool, two of them under `cross_attn` in the scan's body (run
-    seven times a step), which `_phi4flash_steps_its_state_in_place` holds to
-    two under the full layer's scope and none (compiled only, PR 50)."""
+    """The XLA read path alone (the definition, what the program runs where
+    the kernel does not engage), at the cell's shapes (no whole program: the
+    queries come in as arguments): the full layer and seven cross-attention
+    layers in a rolled scan, each through `attend_decode_paged` under the
+    step's scopes. Four operations gather a chunk of the pool, two of them
+    under `cross_attn` in the scan's body (run seven times a step) with the
+    readers' loop over chunks, and no kernel is called:
+    `_phi4flash_steps_its_state_in_place` holds the program to no gather, no
+    such loop and the kernel at its two sites (compiled only, PR 50 / PR 53)."""
     from ray_tpu.models import paged
 
     B, bs, MB, h, row = 64, 16, 128, 40, 1280
@@ -1035,9 +1045,9 @@ def test_eight_readers_through_the_pool_trip_the_detector_of_pool_reads(one_chip
     text = jax.jit(macro_step_slots_paged).lower(
         pool, pool, arr((8, B, h, 128), jnp.bfloat16), arr((B, MB)), arr((B,)),
         arr((B,), jnp.bool_)).compile().as_text()
-    gathers, _, cross_loops = _phi4flash_pool_reads(text)
+    gathers, _, cross_loops, reads = _phi4flash_pool_reads(text)
     assert len(gathers) == 4 and sum("/cross_attn/" in g for g in gathers) == 2, gathers
-    assert len(cross_loops) == 1, cross_loops
+    assert len(cross_loops) == 1 and not reads, (cross_loops, reads)
 
 
 # ------------------------------------------------------ the kernels alone
@@ -1310,6 +1320,30 @@ def test_delta_rule_update_kernel_compiles_with_the_stack_aliased(one_chip):
     assert m.temp_size_in_bytes < 1e6, m.temp_size_in_bytes
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
+
+
+def test_paged_decode_attention_kernel_compiles_with_the_pools_in_main_memory(one_chip):
+    """The kernel of ops/paged_decode_attention.py at `reasoning-generate`'s
+    shapes (64 lanes of 40 query heads laid out 128 wide; ONE pool layer of
+    8,193 blocks of 16 x 1,280 bfloat16, 0.67 GB, K's and V's; tables of 128
+    blocks; two groups of 16 blocks of each pool in VMEM, 2.6 MB) and for a
+    float32 pool of several layers in blocks of its tile of 8 compiles for the
+    chip with both pools whole among its operands and nothing beside them:
+    no temporary, so no copy or slice of a pool."""
+    from ray_tpu.ops import paged_decode_attention as PDA
+
+    arr, _ = _shapes_on(one_chip)
+    for L, bs, dtype in ((1, 16, jnp.bfloat16), (4, 8, jnp.float32)):
+        B, MB, h, hd, row = 64, 128, 40, 128, 1280
+        pool = arr((L, B * MB + 1, bs, row), dtype)
+        assert PDA.supported((B, h, hd), pool.shape, dtype) and PDA.group_blocks(bs) * bs == 256
+        compiled = jax.jit(functools.partial(PDA._paged_decode_attention_pallas, scale=0.125)).lower(
+            arr((B, h, hd), dtype), pool, pool, arr(()), arr((B, MB)), arr((B,)), arr((B,), jnp.bool_)).compile()
+        m = compiled.memory_analysis()
+        assert m.argument_size_in_bytes > 2 * np.prod(pool.shape) * jnp.dtype(dtype).itemsize
+        assert m.temp_size_in_bytes < 1e6, m.temp_size_in_bytes
+        assert m.output_size_in_bytes == 4 * B * h * hd and m.alias_size_in_bytes == 0
+        assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
 def test_ring_write_kernel_compiles_with_the_stacks_aliased(one_chip):
