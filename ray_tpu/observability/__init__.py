@@ -55,8 +55,34 @@ ENGINE_SPANS = (
     "engine.fetch",     # inside resolve: the blocking device-to-host reads of the dispatch's tokens
 )
 
+# A request's own account (ISSUE 54), written ONCE, where the engine finishes the request: one more
+# `TraceAnnotation` of no length from the same thread into the same trace, inside the `engine.resolve` that
+# delivered its last token (a cancelled request's is written by the thread that cancelled it, a shed one's
+# inside `engine.intake`). Not one of ENGINE_SPANS: those tile the loop's iterations, this one is a record.
+# Every time is `time.perf_counter()` of the engine's process (CLOCK_MONOTONIC) in whole microseconds.
+# Stats: `rid`; `reason` (`length`, `stop`, `cancelled`, `shed`, `migrated`, `error`); `tokens`;
+#   `submit_us`, `done_us`: the two ends, absolute, to set against a client's stamps of the same rid;
+#   `seq_first`, `seq_last`: the `seq` of its admitting dispatch and of the dispatch whose resolve finished it
+#     (its parents: `engine.dispatch(seq)` / `engine.resolve(seq)`, and through them the device's executions);
+#   the five HOST STATIONS, which tile [submit, done] exactly (each boundary is one stamp used on both sides):
+#     `unseen_us` submit -> the start of the first plan that found it, `lane_wait_us` -> the start of the plan
+#     that admits it, `plan_us` -> its admitting dispatch is enqueued, `flight_us` -> the fetch of the last
+#     dispatch it rides has returned, `deliver_us` -> done (the requests delivered before it in that resolve);
+#   the PLAN'S COUNTS, summed over every dispatch it rode (`llm_engine._dispatch_counts`' walk): `dispatches`;
+#     `lead_steps`, `lead_phases`, `lead_rows` (decode steps, admitting phases and their token rows that its
+#     admitting dispatch runs before its own phase), `own_rows` (the rows of its own admitting phase),
+#     `decode_steps` (its takes), `stall_phases`, `stall_rows` (others' admitting phases it was live through, in
+#     ANY dispatch of its life, and their rows), `tail_steps`, `tail_phases`, `tail_rows` (what its last dispatch
+#     still runs after its last token exists);
+#   `ahead_us` (enqueue of its admitting dispatch -> the return of the fetch of the dispatch in flight then; 0
+#     where the device was idle), `late` (how many of its dispatches the host came to fetch after the result
+#     was ready), `spec` (1 where the engine plans verify rounds of a draft model: the counts are estimates).
+# The same figures are fields of the lifeline's `finish` event: `request_timeline(rid)` shows them with no profiler.
+REQUEST_SPAN = "engine.request"
+
 __all__ = [
     "ENGINE_SPANS",
+    "REQUEST_SPAN",
     "StepTelemetry",
     "instrument_step",
     "export_trace",

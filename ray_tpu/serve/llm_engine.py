@@ -113,6 +113,21 @@ engine to wrap, so this is the green-field TPU-native equivalent
   in PERF_LEDGER.jsonl and PERF.md section 5. `metrics()` keeps the
   counters: dispatches per token, lane occupancy, TTFT / TPOT
   percentiles, block utilisation.
+- A REQUEST'S OWN ACCOUNT. Those spans and counts describe DISPATCHES;
+  a request rides several (four or five under short plans, dozens where
+  an answer is a thousand tokens). So each request keeps an account of
+  its own (`_Account`), filled by the one walk `_dispatch_counts` makes
+  of a plan and by two stamps a dispatch (`_Flight`: enqueued, fetched),
+  all on `perf_counter`, and the engine writes it ONCE, where the
+  request ends, as the span `engine.request`
+  (`observability.REQUEST_SPAN`, inside the `engine.resolve` that
+  delivered its last token) and as the fields of the lifeline's last
+  event of the rid (`request_timeline`): five host stations that tile
+  submit to finish exactly, the plan's counts over its whole life (lead,
+  own admission, decode steps, others' admissions it sat through, what
+  its last dispatch ran after its last token), how long its admitting
+  dispatch stood behind the one in flight, and how many of its resolves
+  the host came late for.
 
 Static batching (the decode module's `generate`) remains the one-shot
 path of `serve/llm.py` with `continuous=False`.
@@ -130,7 +145,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ray_tpu.observability import ENGINE_SPANS
+from ray_tpu.observability import ENGINE_SPANS, REQUEST_SPAN
 from ray_tpu.observability import flight_recorder as _flightrec
 from ray_tpu.observability import lifeline as _lifeline
 from ray_tpu.util.metrics import metric_singletons as _metric_singletons
@@ -270,6 +285,46 @@ class _LatencyHist:
         return out
 
 
+class _Flight:
+    """One dispatch's stamps (`perf_counter`), shared by the requests that
+    ride it: `t_enq` when `_dispatch_macro` had enqueued it, `t_fetched`
+    when the fetch of its resolve had returned, `ahead_s` from `t_enq` to
+    the return of the fetch of the dispatch that was in flight then (0.0
+    where the device was idle; `behind` is the dispatch enqueued behind
+    this one, until this one's fetch has told it), `late_before` the
+    engine's count of late resolves before this one's."""
+    __slots__ = ("seq", "t_enq", "t_fetched", "ahead_s", "late_before", "behind")
+
+    def __init__(self, seq: int, late_before: int = 0):
+        self.seq = seq
+        self.t_enq: Optional[float] = None
+        self.t_fetched: Optional[float] = None
+        self.ahead_s = 0.0
+        self.late_before = late_before
+        self.behind: Optional["_Flight"] = None
+
+
+class _Account:
+    """A request's own account over every dispatch it rides: `first`, its
+    admitting dispatch, and the plan's counts, added by `_dispatch_counts`
+    in the walk it makes anyway (what each is, is said beside
+    `observability.REQUEST_SPAN`; `_request_stats` makes the span's stats
+    of it). Integers and one reference; made at submit."""
+    __slots__ = ("first", "dispatches", "lead_steps", "lead_phases", "lead_rows",
+                 "own_rows", "decode_steps", "stall_phases", "stall_rows",
+                 "tail_steps", "tail_phases", "tail_rows")
+
+    def __init__(self):
+        self.first: Optional[_Flight] = None
+        self.dispatches = self.lead_steps = self.lead_phases = self.lead_rows = 0
+        self.own_rows = self.decode_steps = self.stall_phases = self.stall_rows = 0
+        self.tail_steps = self.tail_phases = self.tail_rows = 0
+
+
+# the counts of an `_Account`, in the order the span carries them
+_ACCOUNT_COUNTS = _Account.__slots__[1:]
+
+
 class _Request:
     __slots__ = ("prompt", "max_new_tokens", "tokens", "done", "error",
                  "exc", "on_done", "sampling", "finish_reason",
@@ -277,7 +332,7 @@ class _Request:
                  "_t_submit", "_t_seen", "_t_admit", "_t_first", "_t_done",
                  "_trace_ctx", "_start", "_blocks", "_blocks_freed",
                  "_done_lock", "rid", "_rid_b", "_migrate", "export",
-                 "_resume", "_qtok")
+                 "_resume", "_qtok", "_acct")
 
     def __init__(self, prompt, max_new_tokens, on_done=None, sampling=None,
                  rid: Optional[str] = None):
@@ -335,6 +390,8 @@ class _Request:
         self._t_admit: Optional[float] = None
         self._t_first: Optional[float] = None
         self._t_done: Optional[float] = None
+        # its own account across every dispatch it rides (`_Account`)
+        self._acct = _Account()
         # trace context captured on the SUBMITTING thread (the engine
         # loop runs in its own thread, where the contextvar is unset):
         # the dispatches this request rides parent under it, so a slow
@@ -360,7 +417,8 @@ def _suffix_len(req: "_Request") -> int:
 VACANT_PLAN_S = 0.040
 
 # the counts of `_dispatch_counts` that `engine.metrics()` sums as they are
-_PLAN_SUMS = ("ctx_tokens", "prompt_pairs", "admit_rows", "admit_pieces", "admit_phases",
+_PLAN_SUMS = ("ctx_chunks", "past_window_lane_steps",
+              "ctx_tokens", "prompt_pairs", "admit_rows", "admit_pieces", "admit_phases",
               "vacant_lane_steps", "blocked_lane_steps", "spent_lane_steps",
               "plan_wait_us", "lane_wait_us", "admitted_first_plan",
               "admit_lead_steps", "admit_lead_phases", "stall_lane_phases")
@@ -374,6 +432,61 @@ def _wait_us(req: "_Request") -> Tuple[int, int]:
     start - submit) exactly, and is 0 where one plan did both."""
     seen = round((req._t_seen - req._t_submit) * 1e6)
     return seen, round((req._t_admit - req._t_submit) * 1e6) - seen
+
+
+def _request_stats(req: "_Request", reason: str, fetched: Optional["_Flight"] = None,
+                   late: int = 0, spec: bool = False) -> Dict[str, Any]:
+    """The stats of a request's `engine.request` span (all but `rid`), and
+    the fields of its lifeline's `finish` event: ONE dict a request, made
+    where it ends. `fetched` is the dispatch whose resolve delivers its
+    last token (none where it ends outside a resolve), `late` the
+    engine's count of late resolves so far.
+
+    The five host stations are differences of ONE list of stamps, each
+    rounded as (stamp - submit) in whole microseconds, as `_wait_us` does,
+    so they add up to round((done - submit) * 1e6) exactly and the first two
+    are `_wait_us`' own. A stamp the request never got (it was cancelled,
+    shed, failed or migrated away before that station's end) takes the next
+    one it has, `done` at the last: the station it ended in runs to `done`
+    and the later ones read 0. So a request shed or cancelled in the queue
+    lacks `plan_us`, `flight_us` and `deliver_us`; one cancelled in flight,
+    by a thread that is not inside a resolve, `deliver_us`, and its
+    `flight_us` runs to the cancel; one migrated away ends in the resolve of
+    its admitting dispatch and has all five. A migration RESUMED here was
+    never admitted by a plan (`_admit_resumes`): no `_t_seen`, no
+    `_t_admit`, no admitting dispatch, so submit to its last fetch reads as
+    `unseen_us`, `seq_first` is -1 and `ahead_us`, `late` and the lead are
+    0. The counts are the PLAN's: where a stop token, a cancel or an error
+    ends a request ahead of its plan they count what was planned for it, a
+    dispatch enqueued after the one that finished it included (`dispatches`
+    can pass `seq_last - seq_first + 1` there), and under a draft model
+    (`spec` 1) `decode_steps` is verify rounds and every count an estimate."""
+    acct, first = req._acct, req._acct.first
+    done = req._t_done if req._t_done is not None else time.perf_counter()
+    stamps = [done,
+              fetched.t_fetched if fetched is not None else None,
+              first.t_enq if first is not None else None,
+              req._t_admit, req._t_seen]
+    for i in range(1, 5):  # backwards: a missing stamp takes the next one
+        if stamps[i] is None:
+            stamps[i] = stamps[i - 1]
+    t0 = req._t_submit
+    # microseconds since submit at each stamp: the stations are their differences
+    at_done, at_fetched, at_enq, at_admit, at_seen = (round((t - t0) * 1e6) for t in stamps)
+    submit_us = round(t0 * 1e6)
+    stats = {"reason": reason, "tokens": len(req.tokens),
+             "submit_us": submit_us, "done_us": submit_us + at_done,
+             "seq_first": first.seq if first is not None else -1,
+             "seq_last": fetched.seq if fetched is not None else -1,
+             "unseen_us": at_seen, "lane_wait_us": at_admit - at_seen,
+             "plan_us": at_enq - at_admit, "flight_us": at_fetched - at_enq,
+             "deliver_us": at_done - at_fetched}
+    for key in _ACCOUNT_COUNTS:
+        stats[key] = getattr(acct, key)
+    stats["ahead_us"] = round(first.ahead_s * 1e6) if first is not None else 0
+    stats["late"] = late - first.late_before if first is not None else 0
+    stats["spec"] = int(spec)
+    return stats
 
 
 def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
@@ -445,54 +558,93 @@ def _dispatch_counts(phases: List[Dict[str, Any]], recurrent: bool = False,
 
     `short` is 1 where a vacant lane closed the plan (`_plan` marks that
     phase, its last, with the vacancy quantum it decoded by) and `q` that
-    quantum in steps; both 0 in every other plan."""
+    quantum in steps; both 0 in every other plan.
+
+    The same walk adds to each request's own account (`req._acct`, an
+    `_Account`; so it is made ONCE a plan): one more of its `dispatches`;
+    at its admission `lead_steps`, `lead_phases`, `lead_rows` (what
+    `admit_lead_steps` / `admit_lead_phases` sum over the dispatch, and
+    the `admit_rows` of those phases) and `own_rows` (its phase's rows);
+    its takes as `decode_steps`; `stall_phases` / `stall_rows` for every
+    admitting phase it rides and was not admitted by; and, where this
+    dispatch holds its last token, `tail_steps` (its part of
+    `finish_wait_steps`), `tail_phases`, `tail_rows`. Two cuts of one
+    plan: over the dispatches of requests that lived only inside them the
+    requests' `lead_steps`, `lead_phases`, `stall_phases`, `decode_steps`
+    and `tail_steps` sum to the dispatches' `admit_lead_steps`,
+    `admit_lead_phases`, `stall_lane_phases`, `lane_steps` and
+    `finish_wait_steps`."""
     from ray_tpu.models.paged import admit_pieces as pieces_of
 
     total = sum(ph["steps"] for ph in phases)
     done = 0  # decode steps of this dispatch run so far
-    last: Dict[int, int] = {}  # finishing request -> `done` at its last token
+    # finishing request -> (its account, `done`, `admit_phases` and
+    # `admit_rows` when its last token exists)
+    last: Dict[int, Tuple[_Account, int, int, int]] = {}
+    rode = set()  # the requests this dispatch has been counted for
     admissions = prompt_tokens = prefix_tokens = lane_steps = 0
     plan_wait = lane_wait = first_plan = lead_steps = lead_phases = 0
     admit_phases = admit_pieces = admit_rows = stall = vacant = blocked = spent = 0
     for ph in phases:
         admissions += len(ph["admissions"])
+        new = {id(req) for _, req in ph["admissions"]}
+        own_rows = 0  # the token rows of this phase's admissions
+        if new:
+            pieces = pieces_of(len(ph["admissions"]), *variant)
+            own_rows = sum(pieces) * variant[1]
         for _, req in ph["admissions"]:
             prompt_tokens += _suffix_len(req)
             prefix_tokens += req._start  # > 0: the admission's prefix loop runs
-            if req._remaining == 0:
-                last[id(req)] = done  # the prefill's token, unless it decodes
             seen_us, lane_us = _wait_us(req)
             plan_wait += seen_us
             lane_wait += lane_us
             first_plan += req._t_seen == req._t_admit
             lead_steps += done
             lead_phases += admit_phases
+            acct = req._acct
+            rode.add(id(req))
+            acct.dispatches += 1
+            acct.lead_steps, acct.lead_phases, acct.lead_rows = done, admit_phases, admit_rows
+            acct.own_rows = own_rows
+            if req._remaining == 0:  # the prefill's token, unless it decodes
+                last[id(req)] = (acct, done, admit_phases + 1, admit_rows + own_rows)
+        if new:
+            admit_phases += 1
+            admit_pieces += len(pieces)
+            admit_rows += own_rows
         done += ph["steps"]
-        new = {id(req) for _, req in ph["admissions"]}
         riding = len(ph["takes"])
         decoding = 0  # admitted in this phase and decoding in it
         for _, req, take in ph["takes"]:
             lane_steps += take
-            decoding += id(req) in new
+            acct, key = req._acct, id(req)
+            acct.decode_steps += take
+            if key not in rode:
+                rode.add(key)
+                acct.dispatches += 1
+            if key in new:
+                decoding += 1
+            elif new:  # live through an admission that is not its own
+                acct.stall_phases += 1
+                acct.stall_rows += own_rows
             if take and req._remaining == 0:
-                last[id(req)] = done
+                last[key] = (acct, done, admit_phases, admit_rows)
         if new:
-            admit_phases += 1
-            pieces = pieces_of(len(ph["admissions"]), *variant)
-            admit_pieces += len(pieces)
-            admit_rows += sum(pieces) * variant[1]
             stall += riding - decoding
         idle = len(new) - decoding  # admitted here, no decode step owed here
         empty = n_slots - riding - idle
         spent += idle * ph["steps"]
         blocked += ph.get("blocked", 0) * ph["steps"]
         vacant += ph.get("vacant", empty - ph.get("blocked", 0)) * ph["steps"]
+    for acct, d, phases_then, rows_then in last.values():
+        acct.tail_steps = total - d
+        acct.tail_phases, acct.tail_rows = admit_phases - phases_then, admit_rows - rows_then
     q = phases[-1].get("short", 0) if phases else 0
     counts = {"phases": len(phases), "steps": total, "admissions": admissions,
               "prompt_tokens": prompt_tokens, "prefix_tokens": prefix_tokens,
               "lane_steps": lane_steps,
               "finishing": len(last),
-              "finish_wait_steps": sum(total - d for d in last.values()),
+              "finish_wait_steps": sum(total - d for _, d, _, _ in last.values()),
               "ctx_tokens": _ctx_tokens(phases), "prompt_pairs": _prompt_pairs(phases),
               "admit_rows": admit_rows, "admit_pieces": admit_pieces,
               "admit_phases": admit_phases, "plan_wait_us": plan_wait,
@@ -789,6 +941,14 @@ class ContinuousBatchingEngine:
         self._admit_s: deque = deque(maxlen=5)
         self._pending: deque = deque()       # fetch frontier: tagged entries
         self._planned: Dict[int, Dict[str, int]] = {}  # seq -> plan counts, until resolved
+        # the requests' accounts (`_Account`): seq -> the stamps of a dispatch
+        # until its fetch has returned, the dispatch enqueued last, the one
+        # whose resolve is delivering, and the resolves the host came late
+        # for (never reset: a request's `late` is a difference of it)
+        self._flights: Dict[int, _Flight] = {}
+        self._last_flight: Optional[_Flight] = None
+        self._resolving: Optional[_Flight] = None
+        self._late = 0
         # KV-plane plumbing: inbound migrations (fetched payloads
         # awaiting a slot), cross-thread jobs the loop executes at plan
         # boundaries (allocator/trie mutation stays loop-thread-only),
@@ -835,7 +995,10 @@ class ContinuousBatchingEngine:
         # per-process crash ring: per-dispatch events land here with ONE
         # ring write (no allocation, no pickle, no RPC — lint-pinned)
         self._fr = _flightrec.get_recorder()
-        self._m = {"dispatches": 0, "short_plans": 0, "tokens_out": 0, "slot_steps": 0,
+        self._m = {"dispatches": 0, "short_plans": 0,
+                   # resolves the host came to after the result was ready
+                   "late_resolves": 0,
+                   "tokens_out": 0, "slot_steps": 0,
                    "useful_slot_steps": 0, "wasted_steps": 0,
                    "prefill_tokens": 0, "reused_prefix_tokens": 0,
                    "kv_blocks_peak_in_use": 0, "shed_queue_full": 0,
@@ -1048,11 +1211,10 @@ class ContinuousBatchingEngine:
         cancel racing normal delivery loses cleanly: _finish's atomic
         test-and-set makes whoever gets there first the sole completer."""
         if _finish(req, error=msg, reason="cancelled"):
+            stats = self._request_span(req, "cancelled")
             if req.rid:
                 _lifeline.record(req.rid, "finish", ctx=req._trace_ctx,
-                                 rid_b=req._rid_b, engine=self.name,
-                                 reason="cancelled",
-                                 tokens=len(req.tokens))
+                                 rid_b=req._rid_b, engine=self.name, **stats)
                 _lifeline.finish(req.rid)
             self._wake.set()
 
@@ -1370,6 +1532,21 @@ class ContinuousBatchingEngine:
             pass
         return m
 
+    def _request_span(self, req: _Request, reason: str,
+                      fetched: Optional[_Flight] = None) -> Dict[str, Any]:
+        """Write a request's `engine.request` span
+        (`observability.REQUEST_SPAN`), once, where it ends: an annotation
+        of no length on the calling thread, its stats the request's own
+        account (`_request_stats`). `fetched` is the dispatch whose resolve
+        is delivering, for a request that ends inside one. Returns the
+        stats, which the caller hands to the lifeline's last event of the
+        rid as they are."""
+        stats = _request_stats(req, reason, fetched, self._late,
+                               self.draft_params is not None)
+        with self._span(REQUEST_SPAN, rid=req.rid or "", **stats):
+            pass
+        return stats
+
     def request_timeline(self, rid: str) -> List[Dict[str, Any]]:
         """One rid's process-local lifeline, time-sorted, with the
         macro-step dispatches the lane rode joined in at READ time: the
@@ -1378,7 +1555,11 @@ class ContinuousBatchingEngine:
         ring for dispatch records inside the request's [first, last]
         event window. Cluster-wide stitching (prefill→decode hop,
         redispatch attempts) happens a level up — the serve controller
-        fans this out per replica and merges by rid."""
+        fans this out per replica and merges by rid. The rid's last event
+        here (`finish`, `shed`, `migrate` or `error`) carries the request's
+        own account, the stats of its `engine.request` span
+        (`observability.REQUEST_SPAN`): where one slow request's time went,
+        station by station, with no profiler."""
         evs = [dict(e) for e in _lifeline.events(rid)]
         ts = [e["t"] for e in evs]
         if ts:
@@ -1608,6 +1789,7 @@ class ContinuousBatchingEngine:
                 self._free_request_blocks(req)
                 if _finish(req, reason="length"):
                     self._m["requests_completed"] += 1
+                    self._request_span(req, "length")
 
     def _migrate_out(self, req: _Request) -> None:
         """Export a prefill-pool request's KV at its first token: ONE
@@ -1630,11 +1812,12 @@ class ContinuousBatchingEngine:
         except Exception as e:  # noqa: BLE001 — device/object-plane errors
             from ray_tpu.serve.errors import ReplicaDiedError
 
+            stats = self._request_span(req, "error", self._resolving)
             if req.rid:
                 _lifeline.record(req.rid, "error", ctx=req._trace_ctx,
                                  rid_b=req._rid_b, engine=self.name,
                                  error=f"kv export failed: "
-                                       f"{type(e).__name__}")
+                                       f"{type(e).__name__}", **stats)
             self._free_request_blocks(req)
             _finish(req, exc=ReplicaDiedError(
                 f"kv export failed: {type(e).__name__}: {e}", started=False))
@@ -1658,10 +1841,11 @@ class ContinuousBatchingEngine:
             dur = req._t_done - req._t_submit
             ema = self._ema_service_s
             self._ema_service_s = dur if ema <= 0.0 else 0.8 * ema + 0.2 * dur
+            stats = self._request_span(req, "migrated", self._resolving)
             if req.rid:
                 _lifeline.record(req.rid, "migrate", ctx=req._trace_ctx,
                                  rid_b=req._rid_b, engine=self.name,
-                                 blocks=n_data)
+                                 blocks=n_data, **stats)
                 # terminal on THIS engine (the request lives on at the
                 # decode pool, in that process's store) — age the buffer
                 _lifeline.finish(req.rid)
@@ -1889,6 +2073,7 @@ class ContinuousBatchingEngine:
         self._last_P = P
         B, MB = self.n_slots, self._mb
         seq = self._m["dispatches"]
+        flight = _Flight(seq, self._late)  # its admissions' accounts point at it
         steps = np.zeros(K, np.int32)
         has_admit = np.zeros(K, bool)
         prompts = np.zeros((K, A, P), np.int32)
@@ -1911,6 +2096,7 @@ class ContinuousBatchingEngine:
             stops[k] = ph["stops"]
             for a, (slot, req) in enumerate(ph["admissions"]):
                 has_admit[k] = True
+                req._acct.first = flight
                 suffix = req.prompt[req._start:]
                 prompts[k, a, : len(suffix)] = suffix
                 lengths[k, a] = len(suffix)
@@ -1970,18 +2156,20 @@ class ContinuousBatchingEngine:
             self._m["useful_slot_steps"] += live
             if self.state_bytes:
                 self._m["state_lane_steps"] += live
-        if self._window:
-            self._m["past_window_lane_steps"] += _past_window_lane_steps(
-                phases, self._window)
         for key in _PLAN_SUMS:
             self._m[key] += counts.get(key, 0)
         self._planned[seq] = counts
         if self._ctx_chunk:
-            self._m["ctx_chunks"] += _ctx_chunks(phases, self._ctx_chunk)
             self._m["span_chunks"] += sum(ph["steps"] for ph in phases) * -(
                 -self._mb * self.block_size // self._ctx_chunk)
-        if not self._pending:  # an idle device starts on it now
-            self._t_started = time.perf_counter()
+        # enqueued: the one stamp a dispatch, the end of `plan_us` and the
+        # start of `flight_us` of the requests it admits
+        flight.t_enq = now = time.perf_counter()
+        if self._pending:  # it runs behind the dispatch in flight: `ahead_us`
+            self._last_flight.behind = flight
+        else:  # an idle device starts on it now
+            self._t_started = now
+        self._flights[seq] = self._last_flight = flight
         self._pending.append(entry)
 
     def _shed_expired(self) -> None:
@@ -2005,11 +2193,12 @@ class ContinuousBatchingEngine:
 
             for r, late in shed:
                 self._m["deadline_expired"] += 1
+                stats = self._request_span(r, "shed")
                 if r.rid:
+                    stats["reason"] = "DeadlineExceededError"  # the event's own, as before
                     _lifeline.record(r.rid, "shed", ctx=r._trace_ctx,
                                      rid_b=r._rid_b, engine=self.name,
-                                     reason="DeadlineExceededError",
-                                     a=late)
+                                     a=late, **stats)
                     _lifeline.finish(r.rid)
                 _finish(r, exc=DeadlineExceededError(
                     f"deadline passed {late:.2f}s into the queue"))
@@ -2052,13 +2241,20 @@ class ContinuousBatchingEngine:
         dispatch) to this resolve's end, if the host was on time for both
         (each still ran when the host came to fetch it: an interval that
         holds a compile, a collection or a device left idle by a late host
-        is no reading)."""
+        is no reading). A resolve the host came late for (the result was
+        ready before it asked) says so on its span, `late`, and is counted:
+        `late_resolves` of `metrics()`, and `late` of every request that
+        rides it (`_Flight.late_before`)."""
         entry = self._pending.popleft()
         planned = self._planned.pop(entry[4], {})
-        on_time = entry[2] is not None and not entry[2].is_ready()
+        late = int(entry[2] is not None and entry[2].is_ready())
+        on_time = entry[2] is not None and not late
+        self._flights[entry[4]].late_before = self._late
+        self._late += late
+        self._m["late_resolves"] += late
         # the span repeats its dispatch's plan counts: a trace that starts
         # after a dispatch still knows what its execution was planned to do
-        with self._span(_SPAN_RESOLVE, seq=entry[4], **planned) as span:
+        with self._span(_SPAN_RESOLVE, seq=entry[4], late=late, **planned) as span:
             counted = self._resolve(entry)
             if counted:  # the dispatch's device counters, as the span's stats
                 span.set_metadata(**counted)
@@ -2244,13 +2440,15 @@ class ContinuousBatchingEngine:
                 dur = req._t_done - req._t_submit
                 ema = self._ema_service_s
                 self._ema_service_s = dur if ema <= 0.0 else 0.8 * ema + 0.2 * dur
+                # the request's own account, once: a span in the trace and
+                # the fields of the lifeline's event (one dict a request)
+                stats = self._request_span(req, req.finish_reason, self._resolving)
                 if req.rid:
                     _lifeline.record(req.rid, "finish",
                                      ctx=req._trace_ctx, rid_b=req._rid_b,
                                      engine=self.name,
-                                     reason=req.finish_reason,
-                                     tokens=len(req.tokens),
-                                     a=float(len(req.tokens)), b=dur * 1e3)
+                                     a=float(len(req.tokens)), b=dur * 1e3,
+                                     **stats)
                     _lifeline.finish(req.rid)
                 self._wake.set()  # repair promptly: slot + blocks are free
             if req._migrate:
@@ -2276,14 +2474,26 @@ class ContinuousBatchingEngine:
             self._pending.appendleft(entry)
             raise
 
+    def _fetched(self, seq: int) -> None:
+        """The fetch of dispatch `seq` has returned: the one stamp a
+        resolve, the end of `flight_us` of the requests this resolve
+        finishes (`_deliver` reads it off `_resolving`), and of `ahead_us`
+        of those admitted by the dispatch that was enqueued behind it."""
+        flight = self._resolving = self._flights.pop(seq)
+        flight.t_fetched = now = time.perf_counter()
+        behind, flight.behind = flight.behind, None
+        if behind is not None:
+            behind.ahead_s = now - behind.t_enq
+
     def _resolve_inner(self, entry) -> None:
         if entry[0] == "spec":
-            _, toks_counts, firsts_dev, phases, _seq = entry
+            _, toks_counts, firsts_dev, phases, seq = entry
             toks_dev, counts_dev = toks_counts
             with self._span(_SPAN_FETCH):
                 toks = np.asarray(toks_dev)      # (K, chunk, B, n_spec + 1)
                 counts = np.asarray(counts_dev)  # (K, chunk, B)
                 firsts = np.asarray(firsts_dev)
+            self._fetched(seq)
             for k, ph in enumerate(phases):
                 for a, (_slot, req) in enumerate(ph["admissions"]):
                     self._deliver(req, [int(firsts[k, a])])
@@ -2321,12 +2531,13 @@ class ContinuousBatchingEngine:
                             est = max(1, est)
                         req._rounds_est = max(0, est)
             return
-        _, toks_dev, firsts_dev, phases, _seq, *counted_dev = entry
+        _, toks_dev, firsts_dev, phases, seq, *counted_dev = entry
         with self._span(_SPAN_FETCH):
             toks = np.asarray(toks_dev)
             firsts = np.asarray(firsts_dev)
             counted = {name: int(v) for dev in counted_dev for name, v in
                        zip(self._device_counters, np.asarray(dev))}
+        self._fetched(seq)
         for name, v in counted.items():
             self._m[name] += v
         for k, ph in enumerate(phases):
@@ -2384,10 +2595,12 @@ class ContinuousBatchingEngine:
         for req in doomed:
             self._dec_qtok(req)
             self._free_request_blocks(req)
+            # one that ended before the engine did has written its span
+            stats = {} if req.done.is_set() else self._request_span(req, "error")
             if req.rid:
                 _lifeline.record(req.rid, "error", ctx=req._trace_ctx,
                                  rid_b=req._rid_b, engine=self.name,
-                                 error=f"engine died: {msg}"[:200])
+                                 error=f"engine died: {msg}"[:200], **stats)
                 _lifeline.finish(req.rid)
             _finish(req, error=msg, exc=ReplicaDiedError(
                 f"engine died: {msg}", started=len(req.tokens) > 0))

@@ -28,7 +28,7 @@ import pytest
 
 from ray_tpu.models import llama, paged
 from ray_tpu.models import llama_decode as D
-from ray_tpu.observability import ENGINE_SPANS
+from ray_tpu.observability import ENGINE_SPANS, REQUEST_SPAN
 from ray_tpu.ops import flash_attention as FA
 from ray_tpu.serve import llm_engine
 from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
@@ -236,7 +236,7 @@ def test_names_and_scopes_are_metadata_only(name, monkeypatch):
 def _req(prompt_len, remaining, start=0, max_new=1, submit=0.0, seen=0.0, admit=0.0):
     return types.SimpleNamespace(prompt=[0] * prompt_len, _start=start, _remaining=remaining,
                                  max_new_tokens=max_new, _t_submit=submit, _t_seen=seen,
-                                 _t_admit=admit)
+                                 _t_admit=admit, _acct=llm_engine._Account())
 
 
 def test_finish_wait_steps_on_a_hand_built_plan():
@@ -592,9 +592,11 @@ def test_macro_loop_spans_in_a_profiler_trace(tmp_path):
     lines = _engine_events(tmp_path)
     assert len(lines) == 1, "engine spans on more than the engine's loop thread"
     (events,) = lines.values()
-    assert {e[0] for e in events} == set(ENGINE_SPANS)
+    # the loop's spans, and one `engine.request` a finished request (tests/test_request_account.py)
+    assert {e[0] for e in events} == set(ENGINE_SPANS) | {REQUEST_SPAN}
+    assert sum(e[0] == REQUEST_SPAN for e in events) == len(reqs)
 
-    top = [e for e in events if e[0] != "engine.fetch"]
+    top = [e for e in events if e[0] not in ("engine.fetch", REQUEST_SPAN)]
     for (_, _, end, _), (name, start, _, _) in zip(top, top[1:]):
         assert start >= end, f"{name} begins inside the span before it"
     for name, start, end, _ in events:
