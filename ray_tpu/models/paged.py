@@ -20,9 +20,10 @@ the pool's writes and reads (`write_decode_kv`, `attend_decode_paged`,
 `write_admission_kv`, `_attend_admission`, `write_lane_rows`;
 `attend_decode_paged` is the DEFINITION of a decode step's read of the pool,
 and the one read path here: ops/paged_decode_attention.py is the same
-attention as a kernel that reads a flat K/V pool in place, each lane for its
-own blocks, which a decode module may take where it engages, today
-phi4flash_decode alone, whose pool layer has eight readers a step), what an
+attention as a kernel that reads a flat pool in place, each lane for its
+own blocks, which a decode module may take where it engages: phi4flash_decode,
+whose K/V pool layer has eight readers a step, and sarvam_mla_decode, whose
+latent pool is its single-pool form), what an
 admission and a decode step end with (`finish_admission`,
 `finish_decode_step`, `sample_tokens`), the block movers of the KV plane
 (`gather_kv_blocks`, `import_kv_blocks`, `scatter_kv_blocks`,

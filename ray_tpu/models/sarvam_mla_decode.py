@@ -28,9 +28,11 @@ engine refuse those options by name until they know this pool.
 The admission EXPANDS (every head's keys and values from c, a causal
 attention over the prompt through ops/flash_attention) and writes the rows;
 the decode step ABSORBS (W_uk into the query, W_uv onto the output) and
-attends the pool's rows themselves through `attend_decode_paged`'s
-single-pool form: one "KV head" whose key is the row and whose value is the
-same row's first columns, a chunk gathered once.
+attends the pool's rows themselves in `attend_decode_paged`'s single-pool
+form: one "KV head" whose key is the row and whose value is the same row's
+first columns (`decode_mixer`: on a TPU the kernel of
+ops/paged_decode_attention.py, elsewhere the definition's loop, a chunk
+gathered once).
 """
 from __future__ import annotations
 
@@ -89,15 +91,21 @@ def admit_mixer(layer, plane, a, pool, cos, sin, adm_tables, starts, valid, cfg)
 
 def decode_mixer(layer, plane, a, pool, cos, sin, tables, pos, active, cfg):
     """The attention half of a decode step: one position a (B, d) a lane the
-    absorbed way, over plane `plane` of the pool."""
+    absorbed way, over plane `plane` of the pool, read where it lies: on a TPU,
+    for a pool its tiles take, the kernel of ops/paged_decode_attention.py
+    (each lane for its own blocks, ONE DMA a block, the value sliced from the
+    key's buffer); elsewhere the definition, which gathers every lane's chunks
+    up to the longest live lane's."""
+    from ray_tpu.ops import paged_decode_attention as kernel  # Pallas: imported where it is traced
+
     B, r = a.shape[0], cfg.kv_lora_rank
     with jax.named_scope(M.SCOPE_PROJ):
         q_nope, q_rope, row = M.project(layer, a[:, None, :], cos, sin, pos[:, None], cfg)
         q = _padded(jnp.concatenate([M.absorb_q(layer, q_nope[:, 0]), q_rope[:, 0]], axis=-1), cfg)
     with jax.named_scope(M.SCOPE_CTX):
         pool, _ = paged.write_decode_kv(pool, None, plane, _padded(row, cfg), None, tables, pos, active)
-        o_lat = paged.attend_decode_paged(q, pool, None, plane, tables, pos, active, cfg.sm_scale,
-                                          v_cols=r)
+        read = kernel.attend if kernel.engages(q, pool, None, r) else paged.attend_decode_paged
+        o_lat = read(q, pool, None, plane, tables, pos, active, cfg.sm_scale, v_cols=r)
     with jax.named_scope(M.SCOPE_PROJ):
         out = M.absorbed_out(layer, o_lat.reshape(B, cfg.n_heads, r), cfg) @ layer["wo"]
     return out, pool

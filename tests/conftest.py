@@ -55,6 +55,41 @@ def static_answers(generate, params, cfg, prompts, answers):
             for p, n in zip(prompts, answers)]
 
 
+def latent_decode_steps_by_each_reader(Lanes, D, cfg, params, prompts, monkeypatch):
+    """A latent decode module's decode steps (`Lanes` of the model's test
+    file: an admission of `prompts`, then six steps) run three ways, and what
+    each leaves: {way: (logits (6, lanes, V), the pool)}. "loop": as the CPU
+    runs them. "kernel": `engages` patched true and the TPU interpret mode,
+    which takes any shape. "tiles-refuse": a TPU by the backend test alone, on
+    this pool of blocks of 4, which the tiles do not take; `attend` patched to
+    fail. Also returns what `engages` saw in the kernel's way: (the query's
+    shape, `v_full`, `v_cols`) a traced call."""
+    import functools
+
+    import jax
+    import numpy as np
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_tpu.ops import paged_decode_attention as PDA
+
+    def run():
+        halves = (functools.partial(D.admit_slots_paged, cfg=cfg, sampled=False),
+                  functools.partial(D.decode_step_slots_paged, cfg=cfg, sampled=False))
+        lanes = Lanes(cfg, params, n=len(prompts), halves=tuple(map(jax.jit, halves)))
+        lanes.admit(list(enumerate(prompts)), bucket=32, new=8)
+        return np.stack([lanes.step()[0] for _ in range(6)]), np.asarray(lanes.cache["latent"])
+
+    ways, seen = {"loop": run()}, []
+    with monkeypatch.context() as m:
+        m.setattr(PDA, "_on_tpu", lambda: True)
+        m.setattr(PDA, "attend", lambda *a, **k: pytest.fail("the kernel was called"))
+        ways["tiles-refuse"] = run()
+    with monkeypatch.context() as m, pltpu.force_tpu_interpret_mode():
+        m.setattr(PDA, "engages", lambda q, k, v, v_cols=0: seen.append((q.shape, v, v_cols)) or True)
+        ways["kernel"] = run()
+    return ways, seen
+
+
 @pytest.fixture(scope="module")
 def ray_start_regular():
     import ray_tpu

@@ -48,7 +48,7 @@ from ray_tpu.models import sarvam_mla_decode
 from ray_tpu.ops.normalization import rms_norm
 from ray_tpu.ops.rope import apply_rope
 from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
-from tests.conftest import static_answers
+from tests.conftest import latent_decode_steps_by_each_reader, static_answers
 
 F32_RTOL = 1e-4
 BF16_ATOL = 0.15
@@ -411,6 +411,25 @@ def test_admission_then_decode_matches_the_reference_at_every_position(dtype):
     assert all(np.abs(pool[s]).max() > 0 for s in range(4))
     assert all(np.abs(pool[s] - pool[t]).max() > 0.1 for s in range(4) for t in range(s))
     assert np.abs(pool[..., cfg.latent_row:]).max() == 0  # the zero tail
+
+
+def test_decode_mixer_takes_the_kernel_where_it_engages_and_the_loop_elsewhere(monkeypatch):
+    """tests/test_sarvam_mla.py's, on this model's pool of two planes a layer:
+    both sublayers' decode attentions are the single-pool form of the kernel
+    of ops/paged_decode_attention.py where `engages` says so (patched; the TPU
+    interpret mode), each over its own plane, and six steps' logits and all
+    four planes' written rows are the definition's; on a TPU with this pool of
+    blocks of 4 the definition runs, to the CPU's bits."""
+    cfg, _, params = _model()
+    ways, seen = latent_decode_steps_by_each_reader(
+        Lanes, D, cfg, params, [_tokens(1, 19, seed=3)[0], _tokens(1, 5, seed=4)[0]], monkeypatch)
+    assert seen and set(seen) == {((2, cfg.n_heads, 128), None, cfg.kv_lora_rank)}
+    (logits, pool), (k_logits, k_pool) = ways["loop"], ways["kernel"]
+    assert all(np.abs(pool[s]).max() > 0 for s in range(4))
+    assert np.abs(k_logits - logits).max() <= 1e-5 * np.abs(logits).max()
+    np.testing.assert_allclose(k_pool, pool, rtol=1e-5, atol=1e-5 * np.abs(pool).max())
+    np.testing.assert_array_equal(ways["tiles-refuse"][0], logits)
+    np.testing.assert_array_equal(ways["tiles-refuse"][1], pool)
 
 
 def _identity_term_dropped(orig):

@@ -33,7 +33,7 @@ from ray_tpu.models import paged
 from ray_tpu.models import sarvam_mla as M
 from ray_tpu.models import sarvam_mla_decode as D
 from ray_tpu.serve.llm_engine import ContinuousBatchingEngine
-from tests.conftest import static_answers
+from tests.conftest import latent_decode_steps_by_each_reader, static_answers
 
 F32_RTOL = 1e-4
 BF16_ATOL = 0.15
@@ -313,6 +313,26 @@ def test_admission_then_decode_matches_the_reference_at_every_position(dtype):
     assert "k" not in cache and "v" not in cache
     assert cache["latent"].shape == (cfg.n_layers, 9, BLOCK, D.pool_row(cfg))
     assert D.state_bytes_per_lane(cfg) == 0 and D.LATENT_POOL
+
+
+def test_decode_mixer_takes_the_kernel_where_it_engages_and_the_loop_elsewhere(monkeypatch):
+    """Where `engages` says so (patched; the TPU interpret mode takes any
+    shape) every layer's decode attention is the single-pool form of the
+    kernel of ops/paged_decode_attention.py, and six steps' logits and the
+    pool's written rows are the definition's (the same chunks in the same
+    order under the same online softmax: float32's last digits). On a TPU with
+    this pool of blocks of 4, which the tiles do not take, the definition runs
+    and the kernel is not called: the CPU's bits."""
+    cfg, _, params = _model()
+    ways, seen = latent_decode_steps_by_each_reader(
+        Lanes, D, cfg, params, [_tokens(1, 19, seed=3)[0], _tokens(1, 5, seed=4)[0]], monkeypatch)
+    # traced once a layer loop (the dense layer, the rolled expert layers): ONE pool, values the latent's columns
+    assert seen and set(seen) == {((2, cfg.n_heads, D.pool_row(cfg)), None, cfg.kv_lora_rank)}
+    (logits, pool), (k_logits, k_pool) = ways["loop"], ways["kernel"]
+    assert np.abs(pool).max() > 0 and np.abs(k_logits - logits).max() <= 1e-5 * np.abs(logits).max()
+    np.testing.assert_allclose(k_pool, pool, rtol=1e-5, atol=1e-5 * np.abs(pool).max())
+    np.testing.assert_array_equal(ways["tiles-refuse"][0], logits)
+    np.testing.assert_array_equal(ways["tiles-refuse"][1], pool)
 
 
 def _no_rope_on_the_shared_key(orig):

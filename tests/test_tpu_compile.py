@@ -438,8 +438,11 @@ def _mla_macro_step(one_chip, A, P):
     `sarvam-105b.serve`'s widths (one dense and four expert layers holding 32
     of the router's 128 experts, a 65,536-row vocabulary, 8 lanes, a table
     span of 8192), compiled for the described chip at the (A, P) variant."""
+    from unittest import mock
+
     from ray_tpu.models import sarvam_mla as M
     from ray_tpu.models import sarvam_mla_decode as D
+    from ray_tpu.ops import paged_decode_attention as PDA
     from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
 
     cfg = M.SarvamMlaConfig(vocab_size=65536, n_layers=5, held_count=32, max_seq_len=8192)
@@ -448,11 +451,38 @@ def _mla_macro_step(one_chip, A, P):
     arr, shaped = _shapes_on(one_chip)
     params = shaped(jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
     cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
-    return D.jitted_macro_step_slots_paged(cfg, 8, sampled=False).lower(
-        params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
-        arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
-        arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
-        arr((K, B, MAX_STOP_TOKENS))).compile()
+    with mock.patch.object(PDA, "_on_tpu", lambda: True):  # the decode attention as the chip runs it
+        return D.jitted_macro_step_slots_paged(cfg, 8, sampled=False).lower(
+            params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
+            arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
+            arr((K, B, MB)), arr((K, B), jnp.float32), arr((K, B)), arr((K, B), jnp.float32),
+            arr((K, B, MAX_STOP_TOKENS))).compile()
+
+
+def _latent_pool_is_read_where_it_lies(text, lanes, pool):
+    """A decode step of an optimized latent-attention macro-step reads its pool
+    (`pool`, its HLO shape) through the single-pool form of the kernel
+    `paged_decode_attention` (PR 55): a custom call at each of its TWO call
+    sites (sarvam-105b's dense layer and its rolled expert layers; LongCat's
+    two sublayers of the rolled double layer), each under `decode_chunk/.../
+    mla_ctx` by its name stack (the benchmark's `scope_of` counts it there, or
+    the two decode rooflines read high), the pool whole among its operands,
+    its result (lanes, 64, 512) float32. No operation anywhere puts out a
+    gathered chunk of `lanes` lanes' context, bf16[lanes,128,640] or its
+    blocks [lanes,8,16,640] (the loop's did: 30 mentions in the parent's
+    text), and of the loops under `mla_ctx` of the decode half only the token
+    write's is left, one a call site (four with the loops over chunks)."""
+    import re
+
+    calls = [ln for ln in text.splitlines() if re.match(r"\s*%paged_decode_attention[.\d]* = \S+ custom-call\(", ln)]
+    assert len(calls) == 2, calls
+    for ln in calls:
+        name = re.search(r'op_name="([^"]*)"', ln).group(1)
+        assert "/decode_chunk/" in name and name.endswith("/mla_ctx/jit(_paged_decode_attention_pallas)/"
+                                                          "paged_decode_attention/pallas_call"), name
+        assert f" = f32[{lanes},64,512]" in ln and f"bf16[{lanes},64,640]{{2,1,0}}, {pool}{{3,2,1,0}}}}" in ln, ln
+    assert not re.search(rf"bf16\[{lanes},(128|8,16),640\]", text)
+    assert len(_loops_under(text, "decode_chunk", "mla_ctx")) == 2
 
 
 def test_mla_macro_step_reads_pool_latent_weights_and_experts_in_place(one_chip, monkeypatch):
@@ -483,6 +513,7 @@ def test_mla_macro_step_reads_pool_latent_weights_and_experts_in_place(one_chip,
     moved = re.compile(r"bf16\[(5,|1,)?(64,128,512|64,512,128|512,64,128|128,64,512|32,4096,2048|"
                        r"32,2048,4096)\]")
     assert not [(n, s) for n, _, shapes in ops for s in shapes if moved.fullmatch(s)]
+    _latent_pool_is_read_where_it_lies(compiled.as_text(), 8, "bf16[5,4097,16,640]")
 
 
 def test_mla_widest_admission_fits_the_chip_and_attends_through_the_kernel(one_chip, monkeypatch):
@@ -716,6 +747,7 @@ def _longcat_flash_macro_step(one_chip, A, P):
 
     from ray_tpu.models import longcat_flash as M
     from ray_tpu.models import longcat_flash_decode as D
+    from ray_tpu.ops import paged_decode_attention as PDA
     from ray_tpu.serve._internal.sampling import MAX_STOP_TOKENS
 
     cfg = M.LongcatFlashConfig(vocab_size=16384, n_layers=4, held_count=16, max_seq_len=1024)
@@ -724,7 +756,7 @@ def _longcat_flash_macro_step(one_chip, A, P):
     arr, shaped = _shapes_on(one_chip)
     params = shaped(jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
     cache = shaped(jax.eval_shape(lambda: D.init_paged_cache(cfg, B, B * MB + 1, bs)))
-    with mock.patch.object(FA, "_on_tpu", lambda: True):
+    with mock.patch.object(FA, "_on_tpu", lambda: True), mock.patch.object(PDA, "_on_tpu", lambda: True):
         return D.jitted_macro_step_slots_paged.__wrapped__(cfg, 8, sampled=False).lower(
             params, cache, arr((B,)), arr((K,)), arr((K,), jnp.bool_), arr((K, A, P)),
             arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A)), arr((K, A), jnp.uint32),
@@ -790,6 +822,7 @@ def test_longcat_flash_widest_admission_fits_the_chip_with_both_planes_and_weigh
     assert not _longcat_flash_moved(text), _longcat_flash_moved(text)
     writes = [ln for ln in text.splitlines() if " = bf16[8,2049,16,640]" in ln and '"estimated_cycles"' in ln]
     assert writes and all("dynamic_update_slice" in ln and "/mla_ctx/" in ln for ln in writes)
+    _latent_pool_is_read_where_it_lies(text, 32, "bf16[8,2049,16,640]")
 
 
 def test_longcat_flash_shortest_bucket_decodes_with_pool_and_weights_in_place(one_chip):
@@ -809,6 +842,7 @@ def test_longcat_flash_shortest_bucket_decodes_with_pool_and_weights_in_place(on
     assert _admission_bodies(text) == (6, 1)
     assert not _longcat_flash_moved(text), _longcat_flash_moved(text)
     assert not _loops_under(text, "decode_chunk", "moe_experts")
+    _latent_pool_is_read_where_it_lies(text, 32, "bf16[8,2049,16,640]")
     for scope in ("mla_proj", "mla_absorb", "mla_ctx", "ffn_dense", "moe_route", "moe_experts", "moe_zero"):
         assert "/decode_chunk/" in text and f"/{scope}/" in text, scope
 
@@ -1344,6 +1378,31 @@ def test_paged_decode_attention_kernel_compiles_with_the_pools_in_main_memory(on
         assert m.temp_size_in_bytes < 1e6, m.temp_size_in_bytes
         assert m.output_size_in_bytes == 4 * B * h * hd and m.alias_size_in_bytes == 0
         assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("cell,B,MB,planes", [("agent-fanout-generate", 32, 64, 8), ("longdoc-qa", 8, 512, 5),
+                                              ("twice-agent-fanouts-lanes", 64, 64, 8)])
+def test_paged_decode_attention_single_pool_form_compiles_at_the_latent_cells_shapes(one_chip, cell, B, MB, planes):
+    """The single-pool form (PR 55) at the two latent cells' shapes (queries
+    (B, 64, 640) over a pool of `planes` planes of B x MB + 1 blocks of 16 x
+    640 bfloat16, values a row's first 512 columns; two groups of 32 blocks
+    in VMEM, 1.3 MB; queries and float32 results 16 lanes a grid step of 32
+    or 64, all 8 of 8) compiles for the chip with the pool whole among its
+    operands and nothing beside it: no temporary, so no copy or slice of the
+    pool and no gathered chunk."""
+    from ray_tpu.ops import paged_decode_attention as PDA
+
+    arr, _ = _shapes_on(one_chip)
+    pool = arr((planes, B * MB + 1, 16, 640), jnp.bfloat16)
+    assert PDA.supported((B, 64, 640), pool.shape, jnp.bfloat16, 512)
+    assert PDA.lanes_per_step(B, 64, 640, 16, 640, jnp.bfloat16, 512) == min(B, 16)
+    compiled = jax.jit(functools.partial(PDA._paged_decode_attention_pallas, scale=0.07, v_cols=512)).lower(
+        arr((B, 64, 640), jnp.bfloat16), pool, None, arr(()), arr((B, MB)), arr((B,)), arr((B,), jnp.bool_)).compile()
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes > np.prod(pool.shape) * 2
+    assert m.temp_size_in_bytes < 1e6, m.temp_size_in_bytes
+    assert m.output_size_in_bytes == 4 * B * 64 * 512 and m.alias_size_in_bytes == 0
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
 
 
 def test_ring_write_kernel_compiles_with_the_stacks_aliased(one_chip):
