@@ -22,7 +22,8 @@ after each half):
   width `moe_d_ff`, the shared one `moe_d_ff * n_shared_experts`. No
   capacity, no dropped token: `route` and `expert_ffn` are the ONE router
   and the ONE expert product of the plain forward, the admission and the
-  decode step. The expert products are over the (row, expert) pairs the
+  decode step; a train step (models/lfm2_moe.py) takes `route` as it is and
+  `expert_ffn_train`, the same sort and products in a form with a backward. The expert products are over the (row, expert) pairs the
   router chose, sorted by expert, as ragged products (`ops/grouped_matmul`):
   work in proportion to `top_k`, never to the number of experts.
 
@@ -255,6 +256,45 @@ def route(u, router, bias, cfg: AfmoeConfig):
     return chosen.astype(jnp.int32), w * cfg.route_scale
 
 
+def _sorted_pairs(u, chosen, experts, at, cfg: AfmoeConfig, live=None):
+    """What `expert_ffn` and `expert_ffn_train` share: the sort of the
+    (row, expert) pairs into groups and the three ragged products over
+    sorted pairs. Returns (order (N * top_k,) the pairs sorted by group, those
+    in no group last; sizes (E,) pairs a held expert; held (N * top_k,) bool
+    or None where every expert is held; products(pairs, sizes) -> SwiGLU of
+    each pair's expert, the group sizes being layer `at`'s in the stack)."""
+    N, k = chosen.shape
+    first, E = cfg.held_experts
+    n_layers = experts["w_gate"].shape[0]
+    pair_expert = chosen.reshape(-1)
+    held = None
+    if (first, E) != (0, cfg.n_experts):
+        held = (pair_expert >= first) & (pair_expert < first + E)
+        pair_expert = jnp.where(held, pair_expert - first, E)
+    if live is not None:
+        pair_expert = jnp.where(jnp.repeat(live, k), pair_expert, E)
+    order = jnp.argsort(pair_expert, stable=True)
+    sizes = jnp.sum(pair_expert[:, None] == jnp.arange(E)[None, :], axis=0, dtype=jnp.int32)
+    stack = lambda name: experts[name].reshape((n_layers * E,) + experts[name].shape[2:])  # noqa: E731
+
+    def products(pairs, sizes, in_group=None):
+        """SwiGLU of each pair's expert for `pairs` (indices into the N *
+        top_k), sorted by group; `sizes` (E,) pairs a group of layer `at`.
+        `in_group` (bool a pair; a backward pass gives it) cuts the pairs in
+        no group off from u's gradient: a ragged product leaves their rows
+        of its result unwritten, forward and backward alike."""
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_layers * E,), jnp.int32), sizes, (at * E,))
+        rows = u[pairs // k]
+        if in_group is not None:
+            rows = jnp.where(in_group[:, None], rows, 0)
+        gate = jax.nn.silu(grouped_matmul(rows, stack("w_gate"), groups).astype(F32))
+        act = gate.astype(cfg.dtype) * grouped_matmul(rows, stack("w_up"), groups)
+        return grouped_matmul(act, stack("w_down"), groups)
+
+    return order, sizes, held, products
+
+
 def expert_ffn(u, chosen, w, experts, at, cfg: AfmoeConfig, live=None, chunk: int = PAIR_CHUNK):
     """sum_e w_e SwiGLU_e(u) over each row's chosen experts: u (N, d),
     chosen and w (N, top_k). The N * top_k (row, expert) pairs are sorted
@@ -285,28 +325,7 @@ def expert_ffn(u, chosen, w, experts, at, cfg: AfmoeConfig, live=None, chunk: in
     no group is sorted and nothing else.
     Returns (out (N, d), rows a held expert (E,) int32)."""
     N, k = chosen.shape
-    first, E = cfg.held_experts
-    n_layers = experts["w_gate"].shape[0]
-    pair_expert = chosen.reshape(-1)
-    held = None
-    if (first, E) != (0, cfg.n_experts):
-        held = (pair_expert >= first) & (pair_expert < first + E)
-        pair_expert = jnp.where(held, pair_expert - first, E)
-    if live is not None:
-        pair_expert = jnp.where(jnp.repeat(live, k), pair_expert, E)
-    order = jnp.argsort(pair_expert, stable=True)
-    sizes = jnp.sum(pair_expert[:, None] == jnp.arange(E)[None, :], axis=0, dtype=jnp.int32)
-    stack = lambda name: experts[name].reshape((n_layers * E,) + experts[name].shape[2:])  # noqa: E731
-
-    def products(pairs, sizes):
-        """SwiGLU of each pair's expert for `pairs` (indices into the N *
-        top_k), sorted by group; `sizes` (E,) pairs a group of layer `at`."""
-        groups = jax.lax.dynamic_update_slice(
-            jnp.zeros((n_layers * E,), jnp.int32), sizes, (at * E,))
-        rows = u[pairs // k]
-        gate = jax.nn.silu(grouped_matmul(rows, stack("w_gate"), groups).astype(F32))
-        act = gate.astype(cfg.dtype) * grouped_matmul(rows, stack("w_up"), groups)
-        return grouped_matmul(act, stack("w_down"), groups)
+    order, sizes, held, products = _sorted_pairs(u, chosen, experts, at, cfg, live)
 
     if N * k > chunk:
         out = _expert_ffn_in_chunks(w, order, sizes, products, u.shape[1], chunk)
@@ -351,6 +370,100 @@ def _expert_ffn_in_chunks(w, order, sizes, products, d: int, chunk: int):
         return total.at[rows].add(y.astype(F32) * w[pairs][:, None], mode="drop")
 
     return jax.lax.fori_loop(0, -(-n_in // chunk), add_chunk, jnp.zeros((N, d), F32))
+
+
+def _pairs_sum(u, chosen, w, experts, cfg: AfmoeConfig, lo: int, width: int):
+    """`expert_ffn`'s sum over the sorted pairs [lo, lo + width) alone, by the
+    same sort and the same products: (float32 (N, d), rows a held expert
+    (E,)). `experts` is ONE layer's, (E, ...): a stack of one layer."""
+    N, k = chosen.shape
+    order, sizes, _, products = _sorted_pairs(
+        u, chosen, {name: e[None] for name, e in experts.items()}, 0, cfg)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    pairs = order[lo:lo + width]
+    # a row past the last group holds nothing meaningful, and on the chip
+    # nothing finite: the product never writes it. It is cut off on both
+    # sides, so that neither its value nor its cotangent (which would reach
+    # u through the gather and w through the weighting) goes anywhere
+    in_group = lo + jnp.arange(width) < ends[-1]
+    y = products(pairs, jnp.clip(ends - lo, 0, width) - jnp.clip(starts - lo, 0, width), in_group)
+    y = jnp.where(in_group[:, None], y.astype(F32), 0) * w.astype(F32).reshape(-1)[pairs][:, None]
+    return jnp.zeros((N, u.shape[1]), F32).at[jnp.where(in_group, pairs // k, N)].add(y, mode="drop"), sizes
+
+
+def _held_pairs(chosen, cfg: AfmoeConfig):
+    first, count = cfg.held_experts
+    return jnp.sum((chosen >= first) & (chosen < first + count))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _pairs_sum_behind(u, chosen, w, experts, cfg: AfmoeConfig, chunk: int):
+    """`_pairs_sum` over every sorted pair behind the first `chunk`, under a
+    `lax.cond` that is not taken where no pair in a group lies there, forward
+    or backward. The backward pass is written out (the branch makes its pass
+    again and takes its vjp) so that what it keeps is its operands where
+    they lie: `cond`'s own reverse mode kept a zero-filled copy of every
+    intermediate AND of the experts for the branch not taken, 5.5 GB over
+    twelve layers at LFM2-8B-A1B's widths (compiled only, PR 57)."""
+    return _pairs_sum_behind_fwd(u, chosen, w, experts, cfg, chunk)[0]
+
+
+def _pairs_sum_behind_fwd(u, chosen, w, experts, cfg, chunk):
+    N, k = chosen.shape
+    out = jax.lax.cond(
+        _held_pairs(chosen, cfg) > chunk,
+        lambda: _pairs_sum(u, chosen, w, experts, cfg, chunk, N * k - chunk)[0],
+        lambda: jnp.zeros((N, u.shape[1]), F32))
+    return out, (u, chosen, w, experts)
+
+
+def _pairs_sum_behind_bwd(cfg, chunk, res, g):
+    import numpy as np
+
+    u, chosen, w, experts = res
+    N, k = chosen.shape
+
+    def grads():
+        _, vjp = jax.vjp(lambda u, w, experts: _pairs_sum(
+            u, chosen, w, experts, cfg, chunk, N * k - chunk)[0], u, w, experts)
+        return vjp(g)
+
+    du, dw, dexperts = jax.lax.cond(
+        _held_pairs(chosen, cfg) > chunk, grads,
+        lambda: jax.tree.map(jnp.zeros_like, (u, w, experts)))
+    return du, np.zeros(chosen.shape, jax.dtypes.float0), dw, dexperts
+
+
+_pairs_sum_behind.defvjp(_pairs_sum_behind_fwd, _pairs_sum_behind_bwd)
+
+
+def expert_ffn_train(u, chosen, w, experts, cfg: AfmoeConfig, chunk: int):
+    """`expert_ffn` for a train step: the same sum, by the same sort and the
+    same products (`_sorted_pairs`), in a form reverse-mode differentiation
+    takes. `_expert_ffn_in_chunks` loops as far as the pairs in a group reach,
+    a trip count that is data, and a while loop has no transpose. Here the
+    first `chunk` sorted pairs go through ONE straight pass, and whatever
+    pairs in a group lie behind them through a second, over all the rest,
+    that a step whose held pairs fit the first does not take
+    (`_pairs_sum_behind`). Nothing is dropped: a router that sends every row
+    to one held expert runs both. The caller sizes `chunk` so that a router
+    in balance needs the first pass alone (models/lfm2_moe.py): a pass reads
+    every held expert's three matrices and, backward, makes their gradients,
+    so passes are few and long.
+
+    `experts` is ONE layer's held experts, (E, ...): a train step walks its
+    layers one tree a layer, and a product's gradient is then that layer's
+    and no stack's. Held, not held and `w` as `expert_ffn` has them; a train
+    step has no dead rows, so there is no `live`. Gradients reach u (through
+    the gathered rows), w and the experts; `chosen` is a choice and has none.
+    Returns (out (N, d), rows a held expert (E,) int32)."""
+    N, k = chosen.shape
+    chunk = min(chunk, N * k)
+    out, sizes = _pairs_sum(u, chosen, w, experts, cfg, 0, chunk)
+    if chunk < N * k:
+        out = out + _pairs_sum_behind(u, chosen, w, experts, cfg, chunk)
+    return out.astype(cfg.dtype), sizes
 
 
 def moe_ffn(m, p, cfg: AfmoeConfig, live=None):

@@ -44,6 +44,14 @@ def build_sharded_train_step(
     events and a live MFU estimate (FLOPs from the model's analytic
     `flops_per_token` at the batch's token shape) flow to the metrics
     pipeline and the unified trace at zero change to the compiled HLO.
+
+    `model` offers `init_params`, `logical_axes`, `loss_fn` and (for the
+    telemetry) `flops_per_token`. It MAY also offer `buffers(cfg)`, a twin
+    tree of bools that is True at a leaf which is a buffer and no parameter
+    (a router's choice bias): such a leaf takes no update, no weight decay
+    and no optimizer state; and `loss_and_metrics`, `loss_fn` returning
+    (loss, dict of scalars) whose scalars ride in the step's metrics beside
+    the loss. A model with neither gets the step it always got.
     """
     from ray_tpu.models import llama as L
 
@@ -55,6 +63,9 @@ def build_sharded_train_step(
         optax.clip_by_global_norm(grad_clip),
         optax.adamw(learning_rate, b1=0.9, b2=0.95, weight_decay=weight_decay),
     )
+    if hasattr(model, "buffers"):
+        labels = jax.tree.map(lambda b: "buffer" if b else "param", model.buffers(cfg))
+        tx = optax.multi_transform({"param": tx, "buffer": optax.set_to_zero()}, labels)
 
     param_shardings = jax.tree.map(
         lambda ax: rules.named_sharding(mesh, ax),
@@ -66,18 +77,24 @@ def build_sharded_train_step(
 
     replicated = NamedSharding(mesh, PartitionSpec())
 
+    with_metrics = hasattr(model, "loss_and_metrics")
+
     def loss(params, batch):
+        if with_metrics:
+            return model.loss_and_metrics(params, batch, cfg, mesh, rules)
         return model.loss_fn(params, batch, cfg, mesh, rules)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def step_fn(state, batch):
-        l, grads = jax.value_and_grad(loss)(state["params"], batch)
-        updates, opt = tx.update(grads, state["opt"], state["params"])
-        params = optax.apply_updates(state["params"], updates)
-        gnorm = optax.global_norm(grads)
+        l, grads = jax.value_and_grad(loss, has_aux=with_metrics)(state["params"], batch)
+        l, extra = l if with_metrics else (l, {})
+        with jax.named_scope("optimizer"):
+            updates, opt = tx.update(grads, state["opt"], state["params"])
+            params = optax.apply_updates(state["params"], updates)
+            gnorm = optax.global_norm(grads)
         return (
             {"params": params, "opt": opt, "step": state["step"] + 1},
-            {"loss": l, "grad_norm": gnorm, "step": state["step"] + 1},
+            {**extra, "loss": l, "grad_norm": gnorm, "step": state["step"] + 1},
         )
 
     if telemetry:

@@ -1183,6 +1183,48 @@ def test_brumby_decode_only_dispatch_fits_the_chip_and_steps_the_state_in_place(
     _brumby_steps_its_state_in_place(text)
 
 
+def test_lfm2_moe_train_step_fits_and_makes_an_expert_layers_gradient_once_where_it_lies(topo, monkeypatch):
+    """`pretrain-moe-8k`'s train step (LFM2-8B-A1B's widths, 2 x 8,192
+    tokens, remat, a chip's 8 of 32 experts, a quarter of the vocabulary) at
+    three of its twelve layers: a dense conv layer, an attention expert layer
+    and a conv expert layer, every kind of its four that the cell runs. The
+    expert layer's backward is ragged products the compiler makes kernels
+    of: a layer has its first pass (three forward, three made again under
+    remat, three for the rows' gradients, three for the matrices') and the
+    same twelve behind a conditional for a router out of balance; each
+    matrices' gradient is ONE layer's (8, ...) and no stack's; and the step
+    keeps no copy of a branch not taken (a `cond` under plain reverse mode
+    kept 0.5 GB a layer: 17.5 GB at twelve layers against 13.6 now)."""
+    import re
+
+    from benchmark import common
+    from benchmark.rehearse_lfm2_moe import kept_config, step_and_shapes
+    from ray_tpu.models import lfm2_moe
+
+    monkeypatch.setattr(FA, "_on_tpu", lambda: True)
+    cf = common.load_json(f"{common.BENCH_DIR}/configs/lfm2-8b-a1b.train.json")
+    step_fn, state, batch, cfg = step_and_shapes(
+        kept_config(cf, 3), {**cf["train"], "seq_len": 8192, "batch": 2}, topo.devices[0])
+    assert cfg.kinds == (("conv", "dense"), ("full_attention", "moe"), ("conv", "moe"))
+    params = state["params"]
+    lowered = step_fn.lower(state, batch)
+    assert lowered.as_text().count("tpu_custom_call") >= 4   # the flash kernels, forward and backward
+    compiled = lowered.compile()
+    ragged = re.findall(r"^\s*%ragged-dot-none[.\d]* = (bf16\[[\d,]*\])", compiled.as_text(), re.M)
+    assert len(ragged) == 2 * 24
+    grads = [shape for shape in ragged if shape.count(",") == 2]
+    assert sorted(set(grads)) == ["bf16[8,1792,2048]", "bf16[8,2048,1792]"] and len(grads) == 2 * 6
+    first_pass = lfm2_moe.pair_chunk(cfg, 2 * 8192)
+    assert first_pass == 20480
+    assert {int(shape[5:].split(",")[0]) for shape in ragged if shape.count(",") == 1} == {
+        first_pass, 4 * 2 * 8192 - first_pass}
+    m = compiled.memory_analysis()
+    n_params = sum(int(x.size) for x in jax.tree.leaves(params))
+    assert m.argument_size_in_bytes >= 6 * n_params - 6 * 3 * 32  # no moments for the choice bias
+    # the gradients and ONE layer's working set: 3.9 GB here, 5.3 with the copies
+    assert m.temp_size_in_bytes < 4.4e9
+
+
 @pytest.mark.parametrize("name", SHAPES)
 def test_flash_forward_kernel_compiles(one_chip, name):
     shape = SHAPES[name]
