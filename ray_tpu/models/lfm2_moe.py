@@ -43,10 +43,10 @@ What a train-step builder asks of a model (`train/step.py`): `init_params`,
 
 Params are one pytree with one dict a LAYER (`params["layers"][i]`), not
 stacks over layers: the layers are of four kinds and are walked in Python,
-each under its own `jax.checkpoint`, so every leaf's gradient is made once,
-where it lies; a stack read through a dynamic index would have its whole
-gradient made anew in every layer's backward (1.9 GB for the experts at
-LFM2-8B-A1B's widths and twelve layers).
+each under its own `jax.checkpoint` (`llama.remat_layer`), so every leaf's
+gradient is made once, where it lies; a stack read through a dynamic index
+would have its whole gradient made anew in every layer's backward (1.9 GB for
+the experts at LFM2-8B-A1B's widths and twelve layers).
 
 Precision as models/llama.py has it: weights and activations in `cfg.dtype`,
 matrix products accumulate in float32; norms, the convolution's sum, softmax,
@@ -63,7 +63,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import afmoe
 from ray_tpu.models.afmoe import _dense, make_swiglu
-from ray_tpu.models.llama import _attention
+from ray_tpu.models.llama import _attention, remat_layer
 from ray_tpu.ops.normalization import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
@@ -318,7 +318,7 @@ def hidden(params, tokens, cfg: Lfm2MoeConfig, mesh=None, rules=None):
     for layer, kinds in zip(params["layers"], cfg.kinds):
         fn = functools.partial(_layer, kinds=kinds, cfg=cfg, mesh=mesh, rules=rules)
         if cfg.remat:
-            fn = jax.checkpoint(fn)
+            fn = remat_layer(fn)
         x, s = fn(layer, x, cos_sin)
         sizes.append(s)
     return x, jnp.stack(sizes)

@@ -281,6 +281,19 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh=None, rules=None):
     return blockwise_attention(q, k, v, True, min(512, q.shape[1]))
 
 
+def remat_layer(fn):
+    """`fn` (one layer) under `jax.checkpoint`: the backward pass makes every
+    activation again from the layer's arguments, but for the flash kernel's
+    output and row statistics, which cost a whole forward kernel to make and
+    one array of the layer's input's size to hold. The one remat policy of
+    every model's layers (`cfg.remat`); where no flash attention runs inside
+    `fn` nothing bears the names and this is the plain checkpoint."""
+    from ray_tpu.ops.flash_attention import LSE_NAME, OUT_NAME
+
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(OUT_NAME, LSE_NAME))
+
+
 def _moe_expert_fn(pe, t):
     """One expert's SwiGLU on its token queue [C, D]."""
     gate = jax.nn.silu((t @ pe["w_gate"]).astype(jnp.float32)).astype(t.dtype)
@@ -444,7 +457,7 @@ def forward_with_aux(params, tokens, cfg: LlamaConfig, mesh=None, rules=None):
         def stage_fn(stage_layers, xm):
             lf = functools.partial(_layer_fn, cfg=cfg)
             if cfg.remat:
-                lf = jax.checkpoint(lf)
+                lf = remat_layer(lf)
 
             def body(x, layer):
                 x2, _aux = lf(layer, x, (cos, sin))
@@ -465,7 +478,7 @@ def forward_with_aux(params, tokens, cfg: LlamaConfig, mesh=None, rules=None):
     else:
         layer_fn = functools.partial(_layer_fn, cfg=cfg, mesh=mesh, rules=rules)
         if cfg.remat:
-            layer_fn = jax.checkpoint(layer_fn)
+            layer_fn = remat_layer(layer_fn)
 
         def scan_body(carry, layer):
             x, aux = carry

@@ -437,8 +437,23 @@ def flash_attention_fwd(q, k, v, causal: bool = True, sm_scale: Optional[float] 
                                q_shared, k_shared)
 
 
+# What the custom VJP's forward rule calls its two outputs, so that a remat
+# policy can keep them (`models/llama.py`'s `remat_layer`): the backward kernels
+# read q, k, v, o, lse, and where q, k, v are a projection and a rotation away
+# from a layer's input, o and lse cost the whole forward kernel again. Kept
+# below the kernels, the import too: a kernel's source lines are part of its
+# program's lowered text, and no serve program's is to change by a character.
+from jax.ad_checkpoint import checkpoint_name  # noqa: E402
+
+OUT_NAME = "flash_attention_out"
+LSE_NAME = "flash_attention_lse"
+
+
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     o, lse = _flash_fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k)
+    # named BEFORE they part into primal output and residuals: what the layer
+    # hands on and what the backward kernels read are the one named array
+    o, lse = checkpoint_name(o, OUT_NAME), checkpoint_name(lse, LSE_NAME)
     return o, (q, k, v, o, lse)
 
 

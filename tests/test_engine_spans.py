@@ -220,7 +220,7 @@ def test_names_and_scopes_are_metadata_only(name, monkeypatch):
         bare = lower()
     with_locations = named.as_text(debug_info=True)
     if name == "train_step":
-        assert with_locations.count("tpu_custom_call") == 4  # fwd, its remat, dK/dV, dQ
+        assert with_locations.count("tpu_custom_call") == 3  # fwd (once: remat keeps what it made), dK/dV, dQ
         assert 'kernel_name = "flash_fwd"' in named.as_text()
         assert 'kernel_name = "flash_fwd"' not in bare.as_text()
     else:

@@ -120,3 +120,100 @@ def test_rope_relative_property():
 
     assert abs(dot_at(5, 3) - dot_at(10, 8)) < 1e-4
     assert abs(dot_at(5, 3) - dot_at(6, 3)) > 1e-6
+
+
+# --------------------------------------------------------------------------
+# A rematted layer keeps the flash attention's output and row statistics
+# (`llama.remat_layer`): the backward pass runs the attention forward once a
+# layer, not twice. On the CPU `flash_attention`'s forward rule falls back to
+# `_fwd_impl`, under the same two names.
+
+REMAT_B, REMAT_T = 2, 32
+
+
+def _remat_case(model, remat=True):
+    """(cfg, params, loss(params), a rematted attention layer, its arguments)
+    of a tiny dense Llama or LFM2, float32, through `flash_attention`."""
+    import functools
+
+    from ray_tpu.models import lfm2_moe, llama
+
+    tokens = np.random.default_rng(0).integers(0, 256, (REMAT_B, REMAT_T + 1), dtype=np.int32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (REMAT_B, REMAT_T, 1), jnp.float32)
+    if model == "llama":
+        cfg = llama.LlamaConfig.tiny(attn_impl="flash", remat=remat, dtype=jnp.float32)
+        params = llama.init_params(jax.random.PRNGKey(0), cfg)
+        layer = jax.tree.map(lambda p: p[0], params["layers"])
+        fn, loss = functools.partial(llama._layer_fn, cfg=cfg), llama.loss_fn
+    else:
+        cfg = lfm2_moe.Lfm2MoeConfig.tiny(attn_impl="flash", remat=remat, dtype=jnp.float32)
+        params = lfm2_moe.init_params(jax.random.PRNGKey(0), cfg)
+        at = cfg.layer_types.index(lfm2_moe.FULL)
+        layer = params["layers"][at]
+        fn, loss = functools.partial(lfm2_moe._layer, kinds=cfg.kinds[at], cfg=cfg), lfm2_moe.loss_fn
+    args = (layer, jnp.broadcast_to(x, (REMAT_B, REMAT_T, cfg.d_model)),
+            rope_frequencies(cfg.head_dim, REMAT_T, cfg.rope_theta))
+    return cfg, params, lambda p: loss(p, {"tokens": tokens}, cfg), llama.remat_layer(fn), args
+
+
+def _attention_forwards(jaxpr, cfg):
+    """Row maxima of (B, T, heads) in `jaxpr` and every jaxpr under it: what
+    the attention's forward takes (`_fwd_impl`'s running maximum, once in its
+    loop's body) and its backward, which reads lse, does not."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "reduce_max" and eqn.outvars[0].aval.shape == (
+                REMAT_B, REMAT_T, cfg.n_heads):
+            n += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _attention_forwards(sub, cfg)
+    return n
+
+
+@pytest.mark.parametrize("model", ["llama", "lfm2_moe"])
+def test_a_rematted_layer_keeps_its_arguments_and_the_attentions_output_and_statistics(model):
+    from jax._src.ad_checkpoint import saved_residuals
+
+    cfg, _, _, layer_fn, args = _remat_case(model)
+    kept = [aval.shape for aval, why in saved_residuals(layer_fn, *args)
+            if not why.startswith("from the argument")]
+    assert sorted(kept) == [(REMAT_B, REMAT_T, cfg.n_heads),
+                            (REMAT_B, REMAT_T, cfg.n_heads, cfg.head_dim)]
+
+
+@pytest.mark.parametrize("model", ["llama", "lfm2_moe"])
+def test_the_gradient_runs_the_attention_forward_once_a_layer_where_a_plain_checkpoint_runs_it_twice(
+        model, monkeypatch):
+    from ray_tpu.models import lfm2_moe, llama
+
+    cfg, params, loss, _, _ = _remat_case(model)
+    # the scanned layers are one body, the walked layers have one attention layer
+    bodies = 1 if model == "llama" else cfg.layer_types.count(lfm2_moe.FULL)
+    assert _attention_forwards(jax.make_jaxpr(jax.grad(loss))(params).jaxpr, cfg) == bodies
+    for module in (llama, lfm2_moe):
+        monkeypatch.setattr(module, "remat_layer", jax.checkpoint)
+    assert _attention_forwards(jax.make_jaxpr(jax.grad(loss))(params).jaxpr, cfg) == 2 * bodies
+
+
+@pytest.mark.parametrize("model", ["llama", "lfm2_moe"])
+def test_gradients_under_remat_equal_those_without(model):
+    _, params, loss, _, _ = _remat_case(model)
+    plain_loss = _remat_case(model, remat=False)[2]
+    with jax.default_matmul_precision("highest"):
+        (l1, g1), (l0, g0) = (jax.jit(jax.value_and_grad(f))(params) for f in (loss, plain_loss))
+    np.testing.assert_allclose(float(l1), float(l0), rtol=1e-6)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g1), jax.tree.leaves(g0)):
+        scale = float(jnp.abs(b).max()) + 1e-30
+        np.testing.assert_allclose(np.array(a) / scale, np.array(b) / scale, atol=2e-6,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def test_the_forward_outside_the_vjp_names_nothing(qkv):
+    """`flash_attention_fwd` is what every serve admission calls: its program
+    is the one it was before the names."""
+    from ray_tpu.ops.flash_attention import flash_attention, flash_attention_fwd
+
+    assert "name[" not in str(jax.make_jaxpr(flash_attention_fwd)(*qkv))
+    assert "name[" not in str(jax.make_jaxpr(lambda q, k, v: flash_attention(q, k, v, True))(*qkv))
+    grad = jax.make_jaxpr(jax.grad(lambda q, k, v: flash_attention(q, k, v, True).sum(), (0, 1, 2)))(*qkv)
+    assert str(grad).count("name[") == 2
